@@ -98,10 +98,10 @@ block, ``validate_bench_serve_disagg``): a real actor fleet —
 process — behind the load-aware router, driven open-loop at a
 fraction of measured monolith capacity, reporting throughput vs the
 monolith (process contention makes this an honest <1x on the 2-core
-CPU container; the TPU arm in tools/hw_session.sh is where
-disaggregation pays) and pinning per-replica steady-state recompiles
-at ZERO from the replicas' beat counters.  The **chaos arm** then
-SIGKILLs the busiest decode replica mid-sweep under Poisson load:
+CPU container; whether disaggregation pays on chips is not measured)
+and pinning per-replica steady-state recompiles at ZERO from the
+replicas' beat counters.  The **chaos arm** then SIGKILLs the busiest
+decode replica mid-sweep under Poisson load:
 zero lost requests (failover re-submission onto survivors), with
 failover detection latency and client-deduped re-emission counts in
 the block.  ``RLT_DISAGG_REPLICAS=0`` skips the phase.
@@ -155,15 +155,6 @@ SPEC_REQUESTS = 16
 # real serving mix does.
 SPEC_MAX_NEW = 32
 SPEC_NOISE_SWEEP = (0.002, 0.01)    # identity-tail perturbation scales
-
-
-def _detect_backend() -> str:
-    try:
-        return jax.default_backend()
-    except RuntimeError as e:
-        sys.stderr.write(f"TPU backend unavailable ({e}); CPU fallback\n")
-        jax.config.update("jax_platforms", "cpu")
-        return jax.default_backend()
 
 
 def _prompts(n: int, vocab: int, length: int = PROMPT_LEN,
@@ -1189,7 +1180,7 @@ def _slo_block(module, params, serve_cfg: ServeConfig, cfg,
 
 
 def main() -> None:
-    on_tpu = _detect_backend() == "tpu"
+    on_tpu = jax.default_backend() == "tpu"
     if on_tpu:
         cfg = GPTConfig(vocab_size=50304, n_layer=12, n_head=12,
                         d_model=768, seq_len=1024, warmup_steps=10)

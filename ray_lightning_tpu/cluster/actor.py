@@ -229,6 +229,9 @@ def _decode_call_error(actor_name: str, payload: Any) -> BaseException:
     return RemoteError(actor_name, payload)
 
 
+_CONNECT_TIMEOUT_S = 60.0
+
+
 def _child_main() -> None:
     """Entry point of the actor subprocess (``python -m ...cluster.actor``).
 
@@ -253,7 +256,15 @@ def _child_main() -> None:
 
     _drain.install_signal_handlers()
     authkey = bytes.fromhex(sys.stdin.readline().strip())
-    sock = socket.create_connection((host, port), timeout=60)
+    sock = socket.create_connection(
+        (host, port), timeout=_CONNECT_TIMEOUT_S
+    )
+    # The bound is for CONNECTING only.  Left on the socket it ends
+    # the main loop's recv after a minute without driver traffic —
+    # exiting the process under a fit that simply takes longer — and
+    # caps sendall's total time, which a GB-scale result package passes.
+    # A dead driver closes the socket; that, not silence, ends the loop.
+    sock.settimeout(None)
     sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
     rpc.send_frame(sock, authkey)
 

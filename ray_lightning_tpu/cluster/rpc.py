@@ -24,6 +24,7 @@ from typing import Any
 import cloudpickle
 
 _LEN = struct.Struct("!Q")
+_RECV_CHUNK = 1 << 24
 # Public alias: callers that stream a frame in pieces (the queue's
 # chunked sender) must emit the exact same header this module parses.
 FRAME_HEADER = _LEN
@@ -45,12 +46,17 @@ def send_frame(sock: socket.socket, payload: bytes) -> None:
 
 
 def recv_exact(sock: socket.socket, n: int) -> bytes:
-    buf = bytearray()
-    while len(buf) < n:
-        chunk = sock.recv(n - len(buf))
-        if not chunk:
+    # One buffer, filled in place: ``sock.recv(remaining)`` allocates
+    # ``remaining`` bytes per call, which for a GB-scale state stream is
+    # a GB-scale allocation for every few hundred KB received.
+    buf = bytearray(n)
+    view = memoryview(buf)
+    got = 0
+    while got < n:
+        k = sock.recv_into(view[got:got + _RECV_CHUNK])
+        if not k:
             raise ConnectionError("socket closed mid-frame")
-        buf.extend(chunk)
+        got += k
     return bytes(buf)
 
 

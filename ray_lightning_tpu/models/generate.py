@@ -363,8 +363,8 @@ def generate(
     # Int8 weight-only storage pays off where decode is HBM-bandwidth
     # bound (TPU: int8 is what HBM streams, the convert fuses into the
     # matmul).  Off-TPU the per-token dequant inside the decode scan
-    # COSTS more than the bandwidth it saves (BENCH_r05: 3345.7 int8 vs
-    # 4025.3 fp tokens/s on CPU), so hoist it: dequantize ONCE per call,
+    # COSTS more than the bandwidth it saves (a CPU run: 3345.7 int8 vs
+    # 4025.3 fp tokens/s), so hoist it: dequantize ONCE per call,
     # outside the scan — same math, amortized over every generated
     # token.
     from ray_lightning_tpu.models.quant import (

@@ -360,7 +360,79 @@ class GPT(TpuModule):
                 q, k, v, mesh, seq_axis=self.seq_axis,
                 layout=self.ring_layout,
             )
-        return causal_attention(q, k, v, impl=self.attn_impl)
+        return causal_attention(
+            q, k, v, impl=self.attn_impl, **self._attention_mesh()
+        )
+
+    def _attention_mesh(self) -> Dict[str, Any]:
+        """The mesh decides where the flash kernel may run (ops/
+        attention.py): bare on one device or inside an already
+        per-device body (shard_map step mode, the grad-sync island), in
+        a shard_map island on a batch-only GSPMD mesh."""
+        trainer = getattr(self, "trainer", None)
+        return {
+            "mesh": getattr(trainer, "mesh", None),
+            "manual": (
+                getattr(trainer, "step_mode", "gspmd") != "gspmd"
+                or bool(getattr(trainer, "grad_sync_active", False))
+            ),
+        }
+
+    def _on_one_tpu(self) -> bool:
+        """Single-chip TPU run: where a bare ``pallas_call`` (opaque to
+        the GSPMD partitioner) may sit directly in the step."""
+        mesh = getattr(getattr(self, "trainer", None), "mesh", None)
+        return (
+            (mesh is None or getattr(mesh, "size", 1) == 1)
+            and jax.default_backend() == "tpu"
+        )
+
+    def _ce_island(self, batch_dim: int) -> bool:
+        """Multi-chip TPU mesh on which the CE kernels run per device in
+        a shard_map island (batch-only sharding, replicated head)."""
+        trainer = getattr(self, "trainer", None)
+        mesh = getattr(trainer, "mesh", None)
+        return (
+            mesh is not None and getattr(mesh, "size", 1) > 1
+            and jax.default_backend() == "tpu"
+            and self._batch_only_mesh(trainer, batch_dim)
+        )
+
+    def kernel_paths(self, batch_size: int) -> Dict[str, str]:
+        """Which implementation each optional-kernel site of the
+        training step takes for ``batch_size`` sequences under the
+        attached trainer's mesh — the predicates the forward itself
+        evaluates (backend, mesh, shapes, ``RLT_DISABLE_KERNELS``),
+        named for logs and artifacts.  Nothing is compiled."""
+        from ray_lightning_tpu.ops.attention import (
+            _flash_supported,
+            _multi_device,
+        )
+        from ray_lightning_tpu.ops.cross_entropy import _pallas_fwd_ok
+        from ray_lightning_tpu.ops.layer_norm import _kernel_selected
+
+        cfg = self.config
+        c = self._compute_dtype()
+        am = self._attention_mesh()
+        attn = self.attn_impl
+        if attn == "auto":
+            q = jax.ShapeDtypeStruct(
+                (batch_size, cfg.seq_len, cfg.n_head, cfg.head_dim), c
+            )
+            attn = "flash" if _flash_supported(q, **am) else "xla"
+        if (attn == "flash" and _multi_device(am["mesh"])
+                and not am["manual"]):
+            attn = "flash-island"
+        ce_kernel = _pallas_fwd_ok(cfg.d_model, c)
+        if ce_kernel and self._on_one_tpu():
+            ce = "pallas"
+        elif ce_kernel and self._ce_island(batch_size):
+            ce = "pallas-island"
+        else:
+            ce = "scan"
+        ln = ("pallas" if _kernel_selected(cfg.d_model, self._on_one_tpu())
+              else "xla")
+        return {"attention": attn, "cross_entropy": ce, "layer_norm": ln}
 
     def _moe_groups(self) -> int:
         """Routing groups = data-parallel shard count, so each group's
@@ -453,11 +525,7 @@ class GPT(TpuModule):
         B, T = tokens.shape
         # Fused-LN gate: same constraint as the CE kernels — pallas_call
         # is opaque to the GSPMD partitioner, so single chip only.
-        mesh = getattr(getattr(self, "trainer", None), "mesh", None)
-        lnp = (
-            (mesh is None or getattr(mesh, "size", 1) == 1)
-            and jax.default_backend() == "tpu"
-        )
+        lnp = self._on_one_tpu()
         x = self._constrain_residual(
             (params["wte"][tokens] + params["wpe"][:T]).astype(c)
         )
@@ -648,20 +716,16 @@ class GPT(TpuModule):
         #    shard_map island (one dwte psum in the backward);
         #  * anything else (TP head, ZeRO-3 params, SP, shard_map step
         #    mode) — the GSPMD-safe vocab-chunk scan.
-        trainer = getattr(self, "trainer", None)
-        mesh = getattr(trainer, "mesh", None)
-        single = mesh is None or getattr(mesh, "size", 1) == 1
-        on_tpu = jax.default_backend() == "tpu"
         c = self._compute_dtype()
-        if (not single and on_tpu
-                and self._batch_only_mesh(trainer, x.shape[0])):
+        if self._ce_island(x.shape[0]):
             loss = fused_lm_head_cross_entropy_sharded(
-                x, params["wte"], targets, mesh, compute_dtype=c,
+                x, params["wte"], targets, self.trainer.mesh,
+                compute_dtype=c,
             ).mean()
         else:
             loss = fused_lm_head_cross_entropy(
                 x, params["wte"], targets, compute_dtype=c,
-                use_pallas=single and on_tpu,
+                use_pallas=self._on_one_tpu(),
             ).mean()
         return loss, aux
 
@@ -782,9 +846,8 @@ def residual_save_bytes(
 ) -> int:
     """Analytic bytes the remat backward SAVES per step under a policy —
     the accounting behind the bench's ``residual_policy`` block (chip
-    truth comes from the profiler's dynamic-update-slice lines via
-    ``tools/hw_session.sh``; this is the model that says which arm to
-    expect to win and by how much).
+    truth comes from the profiler's dynamic-update-slice lines; this is
+    the model that says which arm to expect to win and by how much).
 
     Per layer, the saved set is: the scan CARRY (the block's residual-
     stream input, stacked across layers by the scan — the top profiler

@@ -123,8 +123,8 @@ def dequantize_decode_params(params: Dict[str, Any]) -> Dict[str, Any]:
     ONCE instead of at every consumption site.  Used by the generation
     path to hoist the dequant out of the decode scan on backends where
     weight bytes are not the decode bottleneck (CPU: the per-token
-    ``int8 → f32`` convert costs more than the bandwidth it saves —
-    BENCH_r05 measured the int8 tree 17% SLOWER there).  The rounding
+    ``int8 → f32`` convert costs more than the bandwidth it saves — a
+    CPU run measured the int8 tree 17% SLOWER there).  The rounding
     already baked into the int8 storage is kept — this is a placement
     change, not a precision change.
     """

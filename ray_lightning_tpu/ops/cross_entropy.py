@@ -46,7 +46,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_lightning_tpu.ops.kernel_probe import _interpret
+from ray_lightning_tpu.ops.kernel_probe import (
+    _interpret,
+    kernel_family_disabled,
+)
 
 __all__ = [
     "fused_lm_head_cross_entropy",
@@ -119,7 +122,7 @@ def _ce_fwd_kernel(x_ref, w_ref, t_ref, loss_ref, lse_ref, m_sc, s_sc, g_sc,
     vi = pl.program_id(1)
 
     def _c(val):  # promote kernel constants under the interpreter
-        return jax.lax.pvary(val, tuple(vma)) if vma else val
+        return _to_varying(val, vma)
 
     @pl.when(vi == 0)
     def _init():
@@ -205,10 +208,14 @@ def _pad_vocab(wte, compute_dtype):
 
 def _vma_of(val) -> frozenset:
     """Manual mesh axes ``val`` varies over (empty outside shard_map)."""
-    try:
-        return frozenset(jax.typeof(val).vma)
-    except (AttributeError, TypeError):
-        return frozenset()
+    return frozenset(jax.typeof(val).vma)
+
+
+def _to_varying(val, vma):
+    """Promote ``val`` to vary over every axis of ``vma`` it does not
+    already vary over."""
+    missing = tuple(frozenset(vma) - _vma_of(val))
+    return jax.lax.pcast(val, missing, to="varying") if missing else val
 
 
 def _out_struct(shape, dtype, vma):
@@ -237,8 +244,7 @@ def _ce_fwd_pallas(x, wte, targets, compute_dtype):
     # operands' axes; outputs vary the same way.
     vma = _vma_of(x2) | _vma_of(t2) | _vma_of(wp)
     if vma:
-        x2, t2, wp = (jax.lax.pvary(v, tuple(vma - _vma_of(v)))
-                      for v in (x2, t2, wp))
+        x2, t2, wp = (_to_varying(v, vma) for v in (x2, t2, wp))
     num_vb = vpad // bv
     interp = _interpret()
     kernel = partial(
@@ -271,39 +277,17 @@ def _ce_fwd_pallas(x, wte, targets, compute_dtype):
     return loss[:n, 0].reshape(shape), lse[:n, 0].reshape(shape)
 
 
-def _pallas_fwd_ok(x, wte, targets, compute_dtype) -> bool:
-    """The kernel path needs a lane-aligned, VMEM-sized feature dim;
-    other shapes use the scan path (ragged token counts are fine — they
-    are zero-padded).  The d cap is in compute-dtype BYTES: the VMEM
-    budget was sized for bf16 tiles, so f32 compute halves the allowed
-    feature dim rather than overflowing VMEM at lowering time."""
-    d = x.shape[-1]
+def _pallas_fwd_ok(d: int, compute_dtype) -> bool:
+    """The kernel path needs a lane-aligned, VMEM-sized feature dim and
+    the ``ce`` family not switched off (``RLT_DISABLE_KERNELS``); other
+    shapes use the scan path (ragged token counts are fine — they are
+    zero-padded).  The d cap is in compute-dtype BYTES: the VMEM budget
+    was sized for bf16 tiles, so f32 compute halves the allowed feature
+    dim rather than overflowing VMEM at lowering time.  This is the
+    whole gate: a kernel the compiler refuses is an error."""
     max_d = _CE_MAX_D * 2 // jnp.dtype(compute_dtype).itemsize
-    return d % 128 == 0 and d <= max_d
-
-
-def _kernel_path_available(d: int, compute_dtype) -> bool:
-    """Per-(d, dtype) Mosaic probe: compile+run the fwd and both bwd
-    kernels at the caller's feature dim and compute dtype (tile VMEM
-    footprint depends on exactly these), falling back to the scan path
-    if the backend rejects them (see :mod:`.kernel_probe`)."""
-    from ray_lightning_tpu.ops.kernel_probe import kernel_available
-
-    def probe():
-        x = jnp.ones((_CE_BLOCK_T, d), jnp.float32) * 0.01
-        w = jnp.ones((_CE_BLOCK_V, d), jnp.float32) * 0.01
-        t = jnp.zeros((_CE_BLOCK_T,), jnp.int32)
-
-        def probe_loss(x, w):
-            return _fused_ce(
-                x, w, t, 1, jnp.dtype(compute_dtype), True
-            ).mean()
-
-        jax.block_until_ready(jax.grad(probe_loss, argnums=(0, 1))(x, w))
-
-    return kernel_available(
-        ("ce", d, jnp.dtype(compute_dtype).name), probe
-    )
+    return (d % 128 == 0 and d <= max_d
+            and not kernel_family_disabled("ce"))
 
 
 def _ce_logits_tile(x_ref, w_ref, vi, block_v, vocab_size, vma=()):
@@ -314,7 +298,7 @@ def _ce_logits_tile(x_ref, w_ref, vi, block_v, vocab_size, vma=()):
     fresh constants (iota) must be promoted to the refs' varying type.
     Compiled Mosaic never sees it."""
     def _c(val):
-        return jax.lax.pvary(val, tuple(vma)) if vma else val
+        return _to_varying(val, vma)
 
     logits = jax.lax.dot_general(
         x_ref[...], w_ref[...], (((1,), (1,)), ((), ())),
@@ -344,8 +328,9 @@ def _ce_bwd_dx_kernel(x_ref, w_ref, t_ref, lse_ref, g_ref, dx_ref, acc_sc,
 
     @pl.when(vi == 0)
     def _init():
-        zeros = jnp.zeros(acc_sc.shape, jnp.float32)
-        acc_sc[...] = jax.lax.pvary(zeros, tuple(vma)) if vma else zeros
+        acc_sc[...] = _to_varying(
+            jnp.zeros(acc_sc.shape, jnp.float32), vma
+        )
 
     logits, vpos = _ce_logits_tile(
         x_ref, w_ref, vi, block_v, vocab_size, vma
@@ -372,8 +357,9 @@ def _ce_bwd_dw_kernel(x_ref, w_ref, t_ref, lse_ref, g_ref, dw_ref, acc_sc,
 
     @pl.when(ti == 0)
     def _init():
-        zeros = jnp.zeros(acc_sc.shape, jnp.float32)
-        acc_sc[...] = jax.lax.pvary(zeros, tuple(vma)) if vma else zeros
+        acc_sc[...] = _to_varying(
+            jnp.zeros(acc_sc.shape, jnp.float32), vma
+        )
 
     logits, vpos = _ce_logits_tile(
         x_ref, w_ref, vi, block_v, vocab_size, vma
@@ -413,8 +399,7 @@ def _ce_bwd_pallas(x, wte, targets, lse, g, compute_dtype):
            | _vma_of(lse2))
     if vma:
         x2, t2, wp, g2, lse2 = (
-            jax.lax.pvary(v, tuple(vma - _vma_of(v)))
-            for v in (x2, t2, wp, g2, lse2)
+            _to_varying(v, vma) for v in (x2, t2, wp, g2, lse2)
         )
     num_vb = vpad // bv
     num_tb = n_pad // bt
@@ -517,10 +502,7 @@ def _match_vma(val: jax.Array, ref: jax.Array) -> jax.Array:
     primal must itself be unvarying — for built-in ops JAX inserts this
     psum when transposing the implicit ``pvary``; a custom_vjp bwd rule
     must do it by hand (VMA type checking rejects the rule otherwise)."""
-    try:
-        extra = tuple(sorted(jax.typeof(val).vma - jax.typeof(ref).vma))
-    except (AttributeError, TypeError):
-        return val
+    extra = tuple(sorted(_vma_of(val) - _vma_of(ref)))
     return jax.lax.psum(val, extra) if extra else val
 
 
@@ -609,10 +591,8 @@ def fused_lm_head_cross_entropy(
     """
     if num_chunks is None:
         num_chunks = _pick_num_chunks(wte.shape[0])
-    pallas = (
-        bool(use_pallas)
-        and _pallas_fwd_ok(x, wte, targets, compute_dtype)
-        and _kernel_path_available(x.shape[-1], compute_dtype)
+    pallas = bool(use_pallas) and _pallas_fwd_ok(
+        x.shape[-1], compute_dtype
     )
     return _fused_ce(
         x, wte, targets, num_chunks, jnp.dtype(compute_dtype), pallas
@@ -643,9 +623,7 @@ def _fused_ce_shmap_fwd(x, wte, targets, mesh, batch_axes, num_chunks,
         )
         return loss, lse
 
-    from ray_lightning_tpu.utils.jax_compat import shard_map
-
-    loss, lse = shard_map(
+    loss, lse = jax.shard_map(
         local, mesh=mesh, in_specs=(Pb, P(), Pb), out_specs=(Pb, Pb),
         check_vma=False,
     )(x, wte, targets)
@@ -670,9 +648,7 @@ def _fused_ce_shmap_bwd(mesh, batch_axes, num_chunks, compute_dtype,
         # partial dwte of its batch shard).
         return dxl, jax.lax.psum(dwp, axes)
 
-    from ray_lightning_tpu.utils.jax_compat import shard_map
-
-    dx, dwte = shard_map(
+    dx, dwte = jax.shard_map(
         local, mesh=mesh,
         in_specs=(Pb, P(), Pb, Pb, Pb), out_specs=(Pb, P()),
         check_vma=False,
@@ -709,9 +685,8 @@ def fused_lm_head_cross_entropy_sharded(
     psum of the dwte partials in the backward — identical math to the
     GSPMD scan path, minus every chunk intermediate's HBM round-trip.
 
-    Falls back to the scan inside the island when the kernel gate
-    (shape/probe) rejects, so callers can use it unconditionally for
-    replicated-head meshes.
+    Runs the scan inside the island when the shape gate rejects, so
+    callers can use it unconditionally for replicated-head meshes.
     """
     if batch_axes is None:
         batch_axes = tuple(
@@ -732,8 +707,8 @@ def fused_lm_head_cross_entropy_sharded(
     if num_chunks is None:
         num_chunks = _pick_num_chunks(wte.shape[0])
     pallas = use_pallas is not False and _pallas_fwd_ok(
-        x, wte, targets, compute_dtype
-    ) and _kernel_path_available(x.shape[-1], compute_dtype)
+        x.shape[-1], compute_dtype
+    )
     return _fused_ce_shmap(
         x, wte, targets, mesh, tuple(batch_axes), num_chunks,
         jnp.dtype(compute_dtype), pallas,

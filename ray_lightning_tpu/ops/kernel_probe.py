@@ -1,48 +1,30 @@
-"""Shared Mosaic-availability probing for optional Pallas kernels.
+"""Shared switches for the optional Pallas kernels.
 
 Every optional kernel in :mod:`ray_lightning_tpu.ops` has a numerically
-identical XLA/scan fallback; a training step must never die on a
-kernel-compile error when the fallback exists.  :func:`kernel_available`
-runs a caller-supplied probe (compile+execute the kernels at
-representative shapes) once per cache key and downgrades failures:
+identical XLA/scan path.  Which of the two a call takes is a function of
+its shapes, dtype, mesh and ``RLT_DISABLE_KERNELS`` — never of whether a
+trial compile happened to succeed: a kernel the chip's compiler refuses
+is an error that reaches the user, and ``tests/test_chip_compile.py``
+compiles each kernel for the chip at the main path's shapes so that a
+refusal is found before a chip is.
 
-* compile-class errors (``NotImplementedError``, or any message naming
-  Mosaic, VMEM, lowering, or INVALID_ARGUMENT) cache ``False`` — the
-  kernel will never work here, use the fallback permanently;
-* everything else — including bare ``ValueError``/``TypeError``, which
-  can be raised transiently at dispatch time under momentary device
-  pressure — falls back for the current call and re-probes next time,
-  but only up to :data:`_MAX_IDENTICAL_FAILURES` consecutive *identical*
-  failures: a permanent breakage whose message the marker list misses
-  must not re-run a multi-second compile on every dispatch forever.  A
-  different message resets the count (a changing error is evidence of a
-  transient environment, not a fixed compiler verdict).
-
-Off-TPU (the Pallas interpreter) kernels always work: probes are
-skipped.
+Off-TPU the kernels run under the Pallas interpreter.
 """
 
 from __future__ import annotations
 
 import os
-from typing import Callable, Hashable
 
 import jax
 
-__all__ = ["kernel_available", "kernel_family_disabled", "_interpret"]
-
-_CACHE: dict = {}
-# key -> (last failure message, consecutive identical-failure count).
-_FAILURES: dict = {}
-_MAX_IDENTICAL_FAILURES = 3
+__all__ = ["kernel_family_disabled", "_interpret"]
 
 
 def kernel_family_disabled(family: str) -> bool:
     """A/B switch for on-hardware kernel experiments: set
     ``RLT_DISABLE_KERNELS=ce,ln,flash`` (any subset) to force the
-    fallback path for those kernel families.  Read per call, so one
-    process can bench both arms.  ``bench.py``'s ``kernel_path`` field
-    reports the effective result."""
+    XLA path for those kernel families.  Read per call, so one
+    process can bench both arms."""
     raw = os.environ.get("RLT_DISABLE_KERNELS", "")
     return family in {s.strip() for s in raw.split(",") if s.strip()}
 
@@ -52,63 +34,3 @@ def _interpret() -> bool:
     meshes) runs the kernels under the Pallas interpreter — the single
     source for that decision across all optional kernels."""
     return jax.default_backend() != "tpu"
-
-# Substrings that mark an exception as "will never compile here".  Kept
-# compiler-specific on purpose: a bare ValueError/TypeError raised at
-# dispatch time (e.g. under momentary device pressure) must stay
-# retryable, so generic words like "lower" alone do not qualify.
-_COMPILE_ERROR_MARKERS = (
-    "mosaic",
-    "vmem",
-    "invalid_argument",
-    "failed to lower",
-    "lowering rule",
-    "unsupported lowering",
-    "not implemented",
-)
-
-
-def kernel_available(key: Hashable, probe: Callable[[], None]) -> bool:
-    """True when the kernels behind ``key`` work on this backend.
-
-    Keys are ``(family, ...)`` tuples; a family disabled via
-    ``RLT_DISABLE_KERNELS`` reports unavailable regardless of backend.
-    """
-    family = key[0] if isinstance(key, tuple) and key else str(key)
-    if kernel_family_disabled(family):
-        return False
-    if _interpret():
-        return True
-    cached = _CACHE.get(key)
-    if cached is not None:
-        return cached
-    try:
-        probe()
-        _CACHE[key] = True
-        _FAILURES.pop(key, None)
-        return True
-    except Exception as e:
-        import warnings
-
-        msg = f"{type(e).__name__}: {e}"
-        permanent = isinstance(e, NotImplementedError) or any(
-            m in msg.lower() for m in _COMPILE_ERROR_MARKERS
-        )
-        if not permanent:
-            # Bounded retry for unrecognized failures: N consecutive
-            # IDENTICAL messages ⇒ treat as permanent (the marker list
-            # missed it) instead of paying the probe compile on every
-            # dispatch.  A different message resets the count.
-            last_msg, count = _FAILURES.get(key, (None, 0))
-            count = count + 1 if msg == last_msg else 1
-            _FAILURES[key] = (msg, count)
-            if count >= _MAX_IDENTICAL_FAILURES:
-                permanent = True
-                _FAILURES.pop(key, None)
-        if permanent:
-            _CACHE[key] = False
-        warnings.warn(
-            f"Pallas kernels {key!r} unavailable ({msg}); using the "
-            f"fallback path{'' if permanent else ' for this call'}."
-        )
-        return False

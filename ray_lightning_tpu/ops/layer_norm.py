@@ -8,25 +8,29 @@ the mean/var pass and again for the normalization, plus f32 temporaries
 kernels read each (block_t, d) tile once, keep the f32 statistics in
 registers, and write the output once; the backward recomputes x̂ from
 the saved per-row (mu, rstd) — d-sized reductions stay in-tile, and the
-cross-token dgamma/dbeta reductions emit tiny per-block partials summed
-by one XLA reduction.  The no-grad (eval) primal compiles a y-only
-kernel: no statistics are written at all.
+cross-token dgamma/dbeta reductions accumulate in one resident (1, d)
+output block across the token grid.  The no-grad (eval) primal compiles
+a y-only kernel: no statistics are written at all.
 
 Dispatch mirrors :mod:`.cross_entropy`: callers opt in on single-chip
-paths (``pallas_call`` is opaque to the GSPMD partitioner), shapes must
-be lane-aligned, and a one-time Mosaic probe (:mod:`.kernel_probe`)
-falls back to the plain XLA math — which is also the exact
-reference-numerics path (f32 stats, tested parity 1e-6).
+paths (``pallas_call`` is opaque to the GSPMD partitioner) and shapes
+must be lane-aligned; anything else runs the plain XLA math — which is
+also the exact reference-numerics path (f32 stats, tested parity 1e-6).
+Which path a call takes is a function of its shapes and
+``RLT_DISABLE_KERNELS`` only: a kernel the compiler refuses is an error
+(``tests/test_chip_compile.py`` compiles these for the chip).
 """
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
-from ray_lightning_tpu.ops.kernel_probe import _interpret, kernel_available
+from ray_lightning_tpu.ops.kernel_probe import (
+    _interpret,
+    kernel_family_disabled,
+)
 
 __all__ = ["layer_norm"]
 
@@ -76,10 +80,17 @@ def _ln_bwd_kernel(x_ref, g_ref, dy_ref, mu_ref, rs_ref, dx_ref, dg_ref,
     m1 = jnp.mean(dyg, axis=1, keepdims=True)
     m2 = jnp.mean(dyg * xhat, axis=1, keepdims=True)
     dx_ref[...] = ((dyg - m1 - xhat * m2) * rs).astype(dx_ref.dtype)
-    # Cross-token reductions: per-block partials, summed by XLA (the
-    # partial tensors are (num_blocks, d) — negligible traffic).
-    dg_ref[...] = jnp.sum(dy * xhat, axis=0, keepdims=True)
-    db_ref[...] = jnp.sum(dy, axis=0, keepdims=True)
+    # Cross-token reductions: every grid step maps dg/db to the same
+    # (1, d) block, so it stays resident in VMEM and accumulates (a
+    # (1, d) block per step on an (nb, d) array is not a legal TPU
+    # tile: the sublane dim must be 8-aligned or the whole array).
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        dg_ref[...] = jnp.zeros_like(dg_ref)
+        db_ref[...] = jnp.zeros_like(db_ref)
+
+    dg_ref[...] += jnp.sum(dy * xhat, axis=0, keepdims=True)
+    db_ref[...] += jnp.sum(dy, axis=0, keepdims=True)
 
 
 def _pad_tokens(x2, n):
@@ -95,8 +106,6 @@ def _pad_tokens(x2, n):
 def _ln_fwd_pallas(x, g, b, want_stats):
     """Returns ``y`` (x's shape/dtype) and, when ``want_stats``, PADDED
     ``(n_pad, _STAT_W)`` f32 (mu, rstd) ready for the backward."""
-    from jax.experimental import pallas as pl
-
     shape = x.shape
     d = shape[-1]
     x2 = x.reshape(-1, d)
@@ -125,8 +134,6 @@ def _ln_fwd_pallas(x, g, b, want_stats):
 
 
 def _ln_bwd_pallas(x, g, dy, mu_pad, rs_pad):
-    from jax.experimental import pallas as pl
-
     shape = x.shape
     d = shape[-1]
     x2 = x.reshape(-1, d)
@@ -140,20 +147,19 @@ def _ln_bwd_pallas(x, g, dy, mu_pad, rs_pad):
     row_spec = pl.BlockSpec((bt, d), lambda i: (i, 0))
     vec_spec = pl.BlockSpec((1, d), lambda i: (0, 0))
     stat_spec = pl.BlockSpec((bt, _STAT_W), lambda i: (i, 0))
-    part_spec = pl.BlockSpec((1, d), lambda i: (i, 0))
-    dx, dg_p, db_p = pl.pallas_call(
+    dx, dg, db = pl.pallas_call(
         _ln_bwd_kernel,
         out_shape=(
             jax.ShapeDtypeStruct((n_pad, d), x.dtype),
-            jax.ShapeDtypeStruct((nb, d), jnp.float32),
-            jax.ShapeDtypeStruct((nb, d), jnp.float32),
+            jax.ShapeDtypeStruct((1, d), jnp.float32),
+            jax.ShapeDtypeStruct((1, d), jnp.float32),
         ),
         grid=(nb,),
         in_specs=[row_spec, vec_spec, row_spec, stat_spec, stat_spec],
-        out_specs=(row_spec, part_spec, part_spec),
+        out_specs=(row_spec, vec_spec, vec_spec),
         interpret=_interpret(),
     )(x2, g.reshape(1, d), dy2, mu_pad, rs_pad)
-    return dx[:n].reshape(shape), dg_p.sum(0), db_p.sum(0)
+    return dx[:n].reshape(shape), dg[0], db[0]
 
 
 @jax.custom_vjp
@@ -176,18 +182,11 @@ def _fused_ln_bwd(res, dy):
 _fused_ln.defvjp(_fused_ln_fwd, _fused_ln_bwd)
 
 
-def _kernels_available(d: int, dtype) -> bool:
-    def probe():
-        x = jnp.ones((_LN_BLOCK_T, d), dtype)
-        g = jnp.ones((d,), jnp.float32)
-        b = jnp.zeros((d,), jnp.float32)
-        jax.block_until_ready(
-            jax.grad(lambda x, g, b: _fused_ln(x, g, b).mean().astype(
-                jnp.float32
-            ), argnums=(0, 1, 2))(x, g, b)
-        )
-
-    return kernel_available(("ln", d, jnp.dtype(dtype).name), probe)
+def _kernel_selected(d: int, use_pallas: bool) -> bool:
+    """The fused path's whole gate: opted in, lane-aligned, not
+    switched off."""
+    return (bool(use_pallas) and d % _LANE == 0
+            and not kernel_family_disabled("ln"))
 
 
 def layer_norm(x, g, b, use_pallas: bool = False):
@@ -198,8 +197,6 @@ def layer_norm(x, g, b, use_pallas: bool = False):
     opaque to the GSPMD partitioner); anything else runs the identical
     XLA math.
     """
-    d = x.shape[-1]
-    if (use_pallas and d % _LANE == 0
-            and _kernels_available(d, x.dtype)):
+    if _kernel_selected(x.shape[-1], use_pallas):
         return _fused_ln(x, g, b)
     return _xla_layer_norm(x, g, b)

@@ -19,10 +19,9 @@ Two implementations, selected ONCE at engine build (never per call):
   container) it is the only sensible path.
 * ``pallas`` — a per-row kernel that scalar-prefetches ``ids`` and DMAs
   ONLY the selected adapter's factors into VMEM (the gathered einsum
-  materializes an ``(W, d, r)`` copy first).  TPU-gated through the
-  shared :mod:`.kernel_probe` machinery with the xla path as fallback;
-  ``RLT_LORA_BGMV=xla|pallas`` forces an arm for A/B runs
-  (``tools/hw_session.sh``).
+  materializes an ``(W, d, r)`` copy first).  Selected on TPU
+  (:func:`resolve_bgmv_impl`); ``RLT_LORA_BGMV=xla|pallas`` forces an
+  arm for A/B runs.
 """
 
 from __future__ import annotations
@@ -32,8 +31,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-from ray_lightning_tpu.ops.kernel_probe import kernel_available
 
 __all__ = ["lora_delta", "apply_lora", "bgmv_xla", "bgmv_pallas",
            "resolve_bgmv_impl"]
@@ -79,59 +76,52 @@ def bgmv_pallas(h: jax.Array, a: jax.Array, b: jax.Array,
 
     def kernel(ids_ref, h_ref, a_ref, b_ref, out_ref):
         del ids_ref  # consumed by the index maps
-        t = jnp.dot(h_ref[...], a_ref[0],
+        t = jnp.dot(h_ref[0], a_ref[0],
                     preferred_element_type=jnp.float32)
-        out_ref[...] = jnp.dot(
+        out_ref[0] = jnp.dot(
             t, b_ref[0].astype(jnp.float32),
             preferred_element_type=jnp.float32,
         ).astype(out_ref.dtype)
 
+    # Rows ride a unit middle axis — ``(W, 1, d)`` in ``(1, 1, d)``
+    # blocks: a TPU block's last two dims must be (8, 128)-aligned or
+    # span the array's, and a one-row ``(1, d)`` block of ``(W, d)`` is
+    # neither once W > 1.
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(W,),
         in_specs=[
-            pl.BlockSpec((1, d), lambda w, ids: (w, 0)),
+            pl.BlockSpec((1, 1, d), lambda w, ids: (w, 0, 0)),
             pl.BlockSpec((1, a.shape[1], a.shape[2]),
                          lambda w, ids: (ids[w], 0, 0)),
             pl.BlockSpec((1, b.shape[1], b.shape[2]),
                          lambda w, ids: (ids[w], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, k), lambda w, ids: (w, 0)),
+        out_specs=pl.BlockSpec((1, 1, k), lambda w, ids: (w, 0, 0)),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((W, k), h.dtype),
+        out_shape=jax.ShapeDtypeStruct((W, 1, k), h.dtype),
         interpret=_interpret(),
-    )(ids.astype(jnp.int32), h, a.astype(h.dtype), b.astype(h.dtype))
+    )(ids.astype(jnp.int32), h.reshape(W, 1, d), a.astype(h.dtype),
+      b.astype(h.dtype)).reshape(W, k)
 
 
-def resolve_bgmv_impl(d: int, r: int, k: int, dtype) -> str:
+def resolve_bgmv_impl() -> str:
     """Pick the BGMV arm once (engine build time, never per dispatch).
 
-    ``RLT_LORA_BGMV`` forces an arm; otherwise the Pallas kernel is
-    probed at the call shapes through :func:`kernel_available` — on TPU
-    a failed probe (tiny ranks Mosaic will not tile) falls back to the
-    gathered einsum permanently, off-TPU the gather is simply the
-    faster path so the kernel is not selected at all.
+    ``RLT_LORA_BGMV`` forces an arm; otherwise the Pallas kernel on TPU
+    and the gathered einsum elsewhere (off-TPU the gather is simply the
+    faster path).  Nothing is compiled to decide: the kernel's blocks
+    span each operand's last two dims, so the chip's compiler takes any
+    ``(d, r, k)`` and dtype (``tests/test_chip_compile.py`` holds a case), and a
+    shape it did refuse would be an error, not a silent change of arm.
     """
     forced = os.environ.get("RLT_LORA_BGMV", "").strip().lower()
     if forced in ("xla", "pallas"):
         return forced
-    if jax.default_backend() != "tpu":
-        return "xla"
-
-    def probe():
-        h = jnp.zeros((2, d), dtype)
-        a = jnp.zeros((2, d, r), dtype)
-        b = jnp.zeros((2, r, k), dtype)
-        jax.block_until_ready(
-            bgmv_pallas(h, a, b, jnp.zeros((2,), jnp.int32))
-        )
-
-    ok = kernel_available(("lora_bgmv", d, r, k, jnp.dtype(dtype).name),
-                          probe)
-    return "pallas" if ok else "xla"
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 def lora_delta(h: jax.Array, a: jax.Array, b: jax.Array,

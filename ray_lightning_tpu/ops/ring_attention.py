@@ -146,12 +146,10 @@ def ring_causal_attention(
     # Initial carries must carry the same varying-manual-axes type as the
     # loop outputs (shard_map VMA typing) — mark them varying over every
     # axis the inputs vary over.
-    from ray_lightning_tpu.utils.jax_compat import pcast, vma_of
-
-    vma = vma_of(q)
+    vma = tuple(jax.typeof(q).vma)
 
     def varying(x):
-        return pcast(x, vma, to="varying")
+        return jax.lax.pcast(x, vma, to="varying")
 
     acc0 = varying(jnp.zeros((b, h, s_loc, d), jnp.float32))
     m0 = varying(jnp.full((b, h, s_loc, 1), _NEG_INF, jnp.float32))
@@ -187,8 +185,6 @@ def ring_attention_sharded(
     should instead permute tokens once at the data layer
     (:func:`zigzag_indices`) and call the per-device body directly.
     """
-    from ray_lightning_tpu.utils.jax_compat import shard_map
-
     from ray_lightning_tpu.parallel import sharding as shardlib
 
     if layout not in ("contiguous", "zigzag"):
@@ -211,7 +207,7 @@ def ring_attention_sharded(
         order = jnp.asarray(zigzag_indices(q.shape[1], n))
         inv = jnp.argsort(order)
         q, k, v = (jnp.take(x, order, axis=1) for x in (q, k, v))
-    out = shard_map(
+    out = jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
     )(q, k, v)
     if layout == "zigzag":

@@ -71,8 +71,6 @@ KNOBS: Tuple[EnvKnob, ...] = (
     EnvKnob("RLT_MEGASTEP", True, "fused micro-steps per dispatch"),
     EnvKnob("RLT_UPDATE_SHARDING", True, "cross-replica sharded update"),
     # -- driver-side knobs (never bridged verbatim) ----------------------
-    EnvKnob("RLT_COMPILE_CACHE", False,
-            "bridged as JAX_COMPILATION_CACHE_DIR, not verbatim"),
     EnvKnob("RLT_ELASTIC_MIN_WORKERS", False, "governor floor (driver)"),
     EnvKnob("RLT_ELASTIC_GROW_AFTER_S", False, "grow-back arm (driver)"),
     EnvKnob("RLT_TPU_CHIPS_PER_HOST", False, "host-topology hint (driver)"),

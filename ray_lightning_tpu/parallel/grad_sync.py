@@ -51,8 +51,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_lightning_tpu.ops import collective_quant as cq
-from ray_lightning_tpu.utils.jax_compat import shard_map
-
 from . import sharding as shardlib
 
 __all__ = [
@@ -100,7 +98,7 @@ class GradCommConfig:
         """None | str | dict | GradCommConfig → GradCommConfig.
 
         ``None`` reads the ``RLT_GRAD_COMM`` env bus (workers inherit the
-        driver's env exactly like ``RLT_COMPILE_CACHE``); absent that, the
+        driver's env through ``env_per_worker``); absent that, the
         default is full-width — compression is always opt-in.
         """
         if isinstance(value, cls):
@@ -528,7 +526,7 @@ class GradSync:
                 grads, new_resid = _sync_buckets(grads, residual[0])
                 return grads, logs, new_resid[None]
 
-            return shard_map(
+            return jax.shard_map(
                 island,
                 mesh=self.mesh,
                 in_specs=(P(), P(axes), batch_spec, P()),
@@ -541,7 +539,7 @@ class GradSync:
             grads, _ = _sync_buckets(grads, None)
             return grads, logs
 
-        return shard_map(
+        return jax.shard_map(
             island,
             mesh=self.mesh,
             in_specs=(P(), batch_spec, P()),
@@ -611,7 +609,7 @@ class GradSync:
                 )(params, residual[0])
                 return grads, _pmean_logs(logs), new_resid[None]
 
-            return shard_map(
+            return jax.shard_map(
                 island,
                 mesh=self.mesh,
                 in_specs=(P(), P(axes), batch_spec, P()),
@@ -628,7 +626,7 @@ class GradSync:
             )(params)
             return grads, _pmean_logs(logs)
 
-        return shard_map(
+        return jax.shard_map(
             island,
             mesh=self.mesh,
             in_specs=(P(), batch_spec, P()),

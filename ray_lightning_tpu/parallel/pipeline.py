@@ -38,8 +38,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_lightning_tpu.utils.jax_compat import pcast
-
 __all__ = ["pipeline_apply", "pipelined_scan", "layer_splits"]
 
 
@@ -137,8 +135,8 @@ def pipelined_scan(
     # Initial carries must hold the varying-manual-axes type the loop
     # body produces (same shard_map VMA discipline as ring_attention).
     init = (
-        pcast(zeros, (axis_name,), to="varying"),
-        pcast(out0, (axis_name,), to="varying"),
+        jax.lax.pcast(zeros, (axis_name,), to="varying"),
+        jax.lax.pcast(out0, (axis_name,), to="varying"),
     )
     (_, outputs), _ = jax.lax.scan(tick, init, jnp.arange(ticks))
     # Replicate the last stage's outputs across the pipe group: sum a
@@ -162,8 +160,6 @@ def pipeline_apply(
     shard.  The batch is split into ``num_microbatches`` (default: one
     per stage — callers should raise it to shrink the bubble).
     """
-    from ray_lightning_tpu.utils.jax_compat import shard_map
-
     n_stages = mesh.shape[pipe_axis]
     if num_microbatches is not None and num_microbatches < 1:
         raise ValueError(f"num_microbatches must be >= 1, got "
@@ -189,7 +185,7 @@ def pipeline_apply(
         lambda _: P(pipe_axis), stacked_params
     )
     fn = functools.partial(pipelined_scan, stage_fn, axis_name=pipe_axis)
-    out = shard_map(
+    out = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(param_spec, P()),

@@ -126,8 +126,6 @@ def _shard_map_raw_step(
 ):
     """The unjitted shard_map step (explicit per-device collectives) —
     shared by the single-step jit and the megastep scan."""
-    from ray_lightning_tpu.utils.jax_compat import shard_map
-
     # The shard_map flavor replicates the train state on every device
     # (the Horovod duality: explicit per-device collectives, no state
     # sharding).  Combining it with ZeRO or TP-annotated modules would
@@ -177,7 +175,7 @@ def _shard_map_raw_step(
         new_state = state.apply_gradients(grads, tx)
         return new_state, logs
 
-    return shard_map(
+    return jax.shard_map(
         per_device_step,
         mesh=mesh,
         in_specs=(repl_spec, batch_spec, repl_spec),
@@ -359,8 +357,6 @@ def build_eval_step(
         )
 
     if mode == "shard_map":
-        from ray_lightning_tpu.utils.jax_compat import shard_map
-
         # Same refusal as the train step: shard_map replicates params, so
         # a ZeRO-3/TP-placed model would silently all-gather here.
         _refuse_sharded_state(params_shardings, "shard_map eval")
@@ -378,7 +374,7 @@ def build_eval_step(
             return jax.lax.pmean(logs, axis_name=data_axis)
 
         return ledgered_jit(
-            shard_map(
+            jax.shard_map(
                 per_device,
                 mesh=mesh,
                 in_specs=(P(), P(data_axis)),

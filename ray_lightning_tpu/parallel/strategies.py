@@ -56,6 +56,7 @@ from ray_lightning_tpu.parallel import env_bus
 from ray_lightning_tpu.parallel.overlap import normalize_grad_overlap
 from ray_lightning_tpu.fault.drain import PreemptedError
 from ray_lightning_tpu.util import process_results
+from ray_lightning_tpu.utils.compile_cache import compile_cache_dir
 
 log = logging.getLogger(__name__)
 
@@ -343,26 +344,19 @@ class TpuStrategy:
         normalize_grad_overlap(grad_overlap_segments)
         self.grad_overlap_segments = grad_overlap_segments
         self.env_per_worker = dict(env_per_worker or {})
-        # Persistent XLA compilation cache (RLT_COMPILE_CACHE=dir): the
-        # first GPT-2-scale compile costs 20-40s on this platform; a
-        # shared on-disk cache amortizes it across worker respawns
-        # (elastic restarts), tuner trials, and sessions.  Forwarded as
-        # JAX_COMPILATION_CACHE_DIR, which must land BEFORE the worker's
-        # first jax import — exactly the pre-exec env contract actors
-        # already provide (≙ the reference's env bus, ray_ddp.py:215-228).
-        cache_dir = os.environ.get("RLT_COMPILE_CACHE")
-        if cache_dir and "JAX_COMPILATION_CACHE_DIR" not in self.env_per_worker:
-            self.env_per_worker["JAX_COMPILATION_CACHE_DIR"] = cache_dir
-            # Mirror the driver-side hook's threshold: without this,
-            # worker compiles under jax's ~1s default are silently not
-            # cached — exactly the nondeterminism the knob exists to
-            # remove.
-            self.env_per_worker.setdefault(
-                "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0"
-            )
+        # Persistent XLA compilation cache: workers receive the
+        # directory (JAX's own variable where set, else the fixed
+        # in-checkout path — utils/compile_cache.py) BEFORE their first
+        # jax import — exactly the pre-exec env contract actors already
+        # provide (≙ the reference's env bus, ray_ddp.py:215-228).  It
+        # amortizes the step compile across worker respawns (elastic
+        # restarts), tuner trials, and sessions.
+        self.env_per_worker.setdefault(
+            "JAX_COMPILATION_CACHE_DIR", compile_cache_dir()
+        )
         # Worker env bus: every forward-marked knob in the central
-        # registry (parallel/env_bus.py) rides the same bridge
-        # RLT_COMPILE_CACHE does — remote workers (node agents, Ray
+        # registry (parallel/env_bus.py) rides the same bridge the
+        # compile-cache directory does — remote workers (node agents, Ray
         # runtime_env) inherit the AGENT's env, not the driver's, so
         # without this a driver-side RLT_GRAD_COMM would silently
         # resolve to full-width on exactly the multi-host topology

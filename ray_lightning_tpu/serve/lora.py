@@ -141,7 +141,7 @@ class AdapterPool:
             "proj_a": jnp.zeros((L, N1, d, rank), dtype),
             "proj_b": jnp.zeros((L, N1, rank, d), dtype),
         }
-        self.impl = impl or resolve_bgmv_impl(d, rank, 3 * d, dtype)
+        self.impl = impl or resolve_bgmv_impl()
         # ONE scatter program for any slot (slot index is an operand) —
         # built here so a hot add can never construct a fresh jit on
         # the request path (rlt-lint RLT001 guards add()).  NO buffer

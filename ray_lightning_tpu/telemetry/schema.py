@@ -1978,7 +1978,7 @@ def validate_bench_host_overhead(block: Any,
 # The bench opt_state block: the HBM-traffic diet's acceptance surface.
 # ``bytes_*`` are ANALYTIC persistent AdamW moment bytes
 # (models/optim.py:opt_state_bytes — the chip truth is the optimizer
-# line in the per-op profile, tools/hw_session.sh); ``hbm_ratio`` =
+# line in the per-op profile); ``hbm_ratio`` =
 # bytes_f32 / bytes_int8 (the >= 3.5x acceptance bar);
 # ``loss_rel_diff_vs_f32`` is the measured A/B fit parity at the int8_ef
 # grad-comm tolerance; ``update_sharding`` records the resolved
@@ -2020,7 +2020,7 @@ def validate_bench_opt_state(block: Any,
 # dynamic-update-slice lines); ``vs_baseline`` is the measured
 # tokens/sec ratio of the active arm against the baseline policy when
 # the probe ran (remat fits measure nothing on the CPU container —
-# nullable, chip numbers via tools/hw_session.sh).
+# nullable off-chip).
 _BENCH_RESIDUAL_REQUIRED = {
     "policy": str,
     "baseline_policy": str,

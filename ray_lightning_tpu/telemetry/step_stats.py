@@ -94,7 +94,9 @@ def flops_for_module(module: Any) -> Tuple[Optional[float], Optional[int]]:
     return None, None
 
 
-# Peak bf16 FLOP/s per chip by device_kind substring (dense MXU peak).
+# Peak bf16 FLOP/s per chip by device_kind substring (dense MXU peak;
+# Google Cloud TPU documentation, per-generation system architecture
+# pages).  A TPU that is not in the table is an error, not a default.
 _PEAK_FLOPS = (
     ("v5 lite", 197e12),   # v5e
     ("v5e", 197e12),
@@ -107,9 +109,9 @@ _PEAK_FLOPS = (
 
 
 def peak_flops_per_chip() -> Optional[float]:
-    """Dense bf16 peak of the local accelerator, or ``None`` when the
-    backend has no published peak (CPU meshes: an "MFU" against an
-    arbitrary denominator would be noise, so none is reported).
+    """Dense bf16 peak of the local accelerator, or ``None`` off-TPU
+    (CPU meshes: an "MFU" against an arbitrary denominator would be
+    noise, so none is reported).  A TPU the table does not know raises.
     ``RLT_TELEMETRY_PEAK`` overrides (also how CPU tests pin the MFU
     math)."""
     env = os.environ.get("RLT_TELEMETRY_PEAK")
@@ -117,17 +119,18 @@ def peak_flops_per_chip() -> Optional[float]:
         return float(env)
     import jax
 
-    try:
-        dev = jax.devices()[0]
-    except RuntimeError:
-        return None
+    dev = jax.devices()[0]
     if dev.platform != "tpu":
         return None
     kind = dev.device_kind.lower()
     for key, peak in _PEAK_FLOPS:
         if key in kind:
             return peak
-    return 197e12  # unknown TPU: assume v5e-class
+    raise ValueError(
+        f"no published bf16 peak for TPU device_kind "
+        f"{dev.device_kind!r}: add it to telemetry/step_stats.py "
+        "_PEAK_FLOPS with its source, or set RLT_TELEMETRY_PEAK"
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -301,52 +301,6 @@ def test_bench_reports_both_mfu_conventions():
     assert cfg_flops_full - cfg_flops_causal == pytest.approx(attn_full / 2)
 
 
-# -- (r4-a) kernel_probe: transient vs permanent classification --------------
-
-def test_kernel_probe_bare_valueerror_is_retryable(monkeypatch):
-    """A bare ValueError (e.g. dispatch-time failure under momentary
-    device pressure) must NOT permanently disable the kernels: the next
-    call re-probes and can succeed."""
-    from ray_lightning_tpu.ops import kernel_probe
-
-    monkeypatch.setattr(kernel_probe, "_interpret", lambda: False)
-    monkeypatch.setattr(kernel_probe, "_CACHE", {})
-    calls = {"n": 0}
-
-    def probe():
-        calls["n"] += 1
-        if calls["n"] == 1:
-            raise ValueError("transient dispatch failure")
-
-    with pytest.warns(UserWarning, match="for this call"):
-        assert kernel_probe.kernel_available("k", probe) is False
-    # Re-probed on the next call and recovered.
-    assert kernel_probe.kernel_available("k", probe) is True
-    assert calls["n"] == 2
-
-
-@pytest.mark.parametrize("exc", [
-    NotImplementedError("no lowering"),
-    ValueError("Mosaic failed to compile"),
-    RuntimeError("Ran out of VMEM"),
-])
-def test_kernel_probe_compiler_errors_are_permanent(monkeypatch, exc):
-    from ray_lightning_tpu.ops import kernel_probe
-
-    monkeypatch.setattr(kernel_probe, "_interpret", lambda: False)
-    monkeypatch.setattr(kernel_probe, "_CACHE", {})
-    calls = {"n": 0}
-
-    def probe():
-        calls["n"] += 1
-        raise exc
-
-    with pytest.warns(UserWarning):
-        assert kernel_probe.kernel_available("k", probe) is False
-    assert kernel_probe.kernel_available("k", probe) is False
-    assert calls["n"] == 1  # cached, never re-probed
-
-
 # -- (r4-b) queue put() ack read cannot hang forever -------------------------
 
 def test_queue_put_times_out_on_wedged_server(monkeypatch):
@@ -617,69 +571,6 @@ def test_loopcontext_tracks_pending_writes(tmp_path):
     assert ctx.checkpoint_write_pending(path) is False  # write done
     assert os.path.exists(path)
     ctx.close_checkpoint_writer()
-
-
-# -- (r5-d) kernel probe retries are bounded ---------------------------------
-
-def test_kernel_probe_caches_false_after_repeated_identical_failures(
-    monkeypatch,
-):
-    from ray_lightning_tpu.ops import kernel_probe
-
-    monkeypatch.setattr(kernel_probe, "_interpret", lambda: False)
-    monkeypatch.setattr(kernel_probe, "_CACHE", {})
-    monkeypatch.setattr(kernel_probe, "_FAILURES", {})
-    calls = []
-
-    def probe():
-        calls.append(1)
-        raise ValueError("unlisted permanent breakage")
-
-    key = ("test-family", 1)
-    with pytest.warns(UserWarning):
-        for _ in range(5):
-            assert kernel_probe.kernel_available(key, probe) is False
-    # Probe ran exactly the retry budget, then False was cached.
-    assert len(calls) == kernel_probe._MAX_IDENTICAL_FAILURES
-    assert kernel_probe._CACHE[key] is False
-
-
-def test_kernel_probe_changing_errors_reset_the_retry_count(monkeypatch):
-    from ray_lightning_tpu.ops import kernel_probe
-
-    monkeypatch.setattr(kernel_probe, "_interpret", lambda: False)
-    monkeypatch.setattr(kernel_probe, "_CACHE", {})
-    monkeypatch.setattr(kernel_probe, "_FAILURES", {})
-    msgs = iter(["a", "b", "a", "b", "a", "b"])
-    calls = []
-
-    def probe():
-        calls.append(1)
-        raise ValueError(next(msgs))
-
-    key = ("test-family", 2)
-    with pytest.warns(UserWarning):
-        for _ in range(6):
-            kernel_probe.kernel_available(key, probe)
-    # Alternating messages never hit the identical-failure budget.
-    assert len(calls) == 6
-    assert key not in kernel_probe._CACHE
-
-
-def test_kernel_probe_success_still_cached_once(monkeypatch):
-    from ray_lightning_tpu.ops import kernel_probe
-
-    monkeypatch.setattr(kernel_probe, "_interpret", lambda: False)
-    monkeypatch.setattr(kernel_probe, "_CACHE", {})
-    calls = []
-
-    def probe():
-        calls.append(1)
-
-    key = ("test-family", 3)
-    assert kernel_probe.kernel_available(key, probe) is True
-    assert kernel_probe.kernel_available(key, probe) is True
-    assert len(calls) == 1
 
 
 # -- (r5-e) concurrent tuner fail-fast ---------------------------------------

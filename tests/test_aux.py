@@ -177,67 +177,92 @@ def test_pbt_sweep_of_gpt_lr(tmp_path):
     assert np.isfinite(analysis.best_result["loss"])
 
 
-def test_compile_cache_knob(tmp_path, monkeypatch):
-    """RLT_COMPILE_CACHE: the fit enables jax's persistent compilation
-    cache and populates the directory; workers additionally receive
-    JAX_COMPILATION_CACHE_DIR through the strategy env bus."""
-    import os
+def test_compile_cache_dir_from_environment(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: that directory is used — by jax
+    itself, which reads its own variable — and the package points
+    ``jax.config`` nowhere else; workers receive the same directory
+    through the strategy's env bus."""
+    import subprocess
+    import sys
 
-    import numpy as np
+    import jax as _jax
 
-    from ray_lightning_tpu.core.trainer import Trainer
-    from ray_lightning_tpu.models import BoringDataModule, BoringModel
-    from ray_lightning_tpu.parallel.strategies import LocalStrategy, RayStrategy
+    from ray_lightning_tpu.parallel.strategies import RayStrategy
+    from ray_lightning_tpu.utils import compile_cache as cc
 
     cache = str(tmp_path / "xla_cache")
-    monkeypatch.setenv("RLT_COMPILE_CACHE", cache)
-
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache)
+    assert cc.compile_cache_dir() == cache
     s = RayStrategy(num_workers=1)
     assert s.env_per_worker["JAX_COMPILATION_CACHE_DIR"] == cache
-    # Threshold mirrored to workers (jax's ~1s default would skip fast
-    # compiles nondeterministically).
-    assert s.env_per_worker[
-        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] == "0"
+    before = _jax.config.jax_compilation_cache_dir
+    cc.enable_compile_cache()
+    assert _jax.config.jax_compilation_cache_dir == before
 
-    trainer = Trainer(strategy=LocalStrategy(), max_epochs=1,
-                      default_root_dir=str(tmp_path),
-                      enable_checkpointing=False)
-    trainer.fit(BoringModel(), BoringDataModule())
-    assert np.isfinite(trainer.callback_metrics["train_loss"])
+    # A fresh process (jax reads the variable at import): the fit's
+    # compiles land in that directory and nowhere else.
+    script = (
+        "from ray_lightning_tpu.core.trainer import Trainer\n"
+        "from ray_lightning_tpu.models import BoringDataModule, "
+        "BoringModel\n"
+        "import jax\n"
+        f"t = Trainer(max_epochs=1, default_root_dir={str(tmp_path)!r}, "
+        "enable_checkpointing=False)\n"
+        "t.fit(BoringModel(), BoringDataModule())\n"
+        "print('CACHE_DIR', jax.config.jax_compilation_cache_dir)\n"
+    )
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(
+        os.environ, JAX_ENABLE_COMPILATION_CACHE="true",
+        JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+        PYTHONPATH=repo + os.pathsep + os.environ.get("PYTHONPATH", ""),
+    )
+    default_before = os.path.isdir(cc.DEFAULT_CACHE_DIR)
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
+                          capture_output=True, text=True, timeout=420)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert f"CACHE_DIR {cache}" in proc.stdout
     assert os.path.isdir(cache) and os.listdir(cache), (
         "compilation cache dir not populated"
     )
-    # Eval/predict-only sessions enable the cache too.
-    n_before = len(os.listdir(cache))
-    preds = trainer.predict(BoringModel(), BoringDataModule())
-    assert len(preds) > 0
-    assert len(os.listdir(cache)) >= n_before
+    assert os.path.isdir(cc.DEFAULT_CACHE_DIR) == default_before
 
 
-def test_compile_cache_knob_disables_on_unset(tmp_path, monkeypatch):
-    """Unsetting RLT_COMPILE_CACHE before a later compile really stops
-    cache writes (jax memoizes its cache decision — the disable path
-    must reset it, not just flip the config)."""
+def test_compile_cache_defaults_to_fixed_checkout_path(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR unset: one fixed path inside the
+    checkout — never a temporary, pid- or time-derived one — for this
+    process and for its workers."""
     import jax as _jax
-    import jax.numpy as _jnp
+    from jax.experimental.compilation_cache import compilation_cache
 
-    from ray_lightning_tpu.core.loop import _enable_compile_cache
+    from ray_lightning_tpu.parallel.strategies import RayStrategy
+    from ray_lightning_tpu.utils import compile_cache as cc
 
-    cache = str(tmp_path / "xla_cache2")
-    monkeypatch.setenv("RLT_COMPILE_CACHE", cache)
-    _enable_compile_cache()
-    assert _jax.config.jax_compilation_cache_dir == cache
-    # Force a compile so jax initializes (and memoizes) the cache.
-    _jax.jit(lambda x: x * 2 + 1)(_jnp.arange(7)).block_until_ready()
-    n_on = len(os.listdir(cache))
-    assert n_on > 0
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    assert cc.compile_cache_dir() == cc.DEFAULT_CACHE_DIR
+    s = RayStrategy(num_workers=1)
+    assert (s.env_per_worker["JAX_COMPILATION_CACHE_DIR"]
+            == cc.DEFAULT_CACHE_DIR)
+    # An explicit env_per_worker entry still wins.
+    s = RayStrategy(num_workers=1,
+                    env_per_worker={"JAX_COMPILATION_CACHE_DIR": "/x"})
+    assert s.env_per_worker["JAX_COMPILATION_CACHE_DIR"] == "/x"
 
-    monkeypatch.delenv("RLT_COMPILE_CACHE")
-    _enable_compile_cache()
-    assert _jax.config.jax_compilation_cache_dir is None
-    # A NEW compile in the "off" arm must not write the old directory.
-    _jax.jit(lambda x: x * 3 - 4)(_jnp.arange(11)).block_until_ready()
-    assert len(os.listdir(cache)) == n_on
+    was = _jax.config.jax_compilation_cache_dir
+    try:
+        _jax.config.update("jax_compilation_cache_dir", None)
+        cc.enable_compile_cache()
+        assert (_jax.config.jax_compilation_cache_dir
+                == cc.DEFAULT_CACHE_DIR)
+        # A directory the caller configured is left alone.
+        _jax.config.update("jax_compilation_cache_dir", "/elsewhere")
+        cc.enable_compile_cache()
+        assert _jax.config.jax_compilation_cache_dir == "/elsewhere"
+    finally:
+        _jax.config.update("jax_compilation_cache_dir", was)
+        compilation_cache.reset_cache()
 
 
 class TestSWA:

@@ -347,3 +347,57 @@ class TestBackend:
 def test_find_free_port():
     p = find_free_port()
     assert 1024 <= p <= 65535
+
+
+def test_actor_call_outlasts_the_connect_timeout():
+    """The child's connect timeout must not stay on its socket: left
+    there, a minute without driver traffic ends the child's receive
+    loop — exiting the process under a fit that simply takes longer —
+    and caps the total time ``sendall`` may take for a result package
+    (found on the chip, PR 21: a cold-cache GPT-2-small fit died with
+    "died before answering").  A fake driver, a child whose connect
+    timeout is cut to 0.3 s, and a call that sleeps four times that."""
+    import socket
+    import subprocess
+    import sys
+
+    from ray_lightning_tpu.cluster import rpc
+
+    server = socket.socket()
+    server.bind(("127.0.0.1", 0))
+    server.listen(1)
+    server.settimeout(30)
+    script = (
+        "import ray_lightning_tpu.cluster.actor as a\n"
+        "a._CONNECT_TIMEOUT_S = 0.3\n"
+        "a._child_main()\n"
+    )
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=repo + os.pathsep
+               + os.environ.get("PYTHONPATH", ""))
+    child = subprocess.Popen(
+        [sys.executable, "-c", script, "127.0.0.1",
+         str(server.getsockname()[1])],
+        stdin=subprocess.PIPE, env=env,
+    )
+    try:
+        child.stdin.write(b"00ff\n")
+        child.stdin.flush()
+        conn, _ = server.accept()
+        conn.settimeout(30)
+        assert rpc.recv_frame(conn) == bytes.fromhex("00ff")
+
+        def slow():
+            import time as _t
+
+            _t.sleep(1.2)
+            return "answered"
+
+        rpc.send_frame(conn, rpc.dumps(("call", 7, (slow, (), {}))))
+        assert rpc.loads(rpc.recv_frame(conn)) == ("ok", 7, "answered")
+        rpc.send_frame(conn, rpc.dumps(("exit",)))
+        assert rpc.loads(rpc.recv_frame(conn))[0] == "bye"
+        assert child.wait(10) == 0
+    finally:
+        child.kill()
+        server.close()

@@ -69,7 +69,7 @@ def test_env_bus_forwarded_to_worker_env():
     os.environ["RLT_GRAD_COMM"] = "int8_ef"
     try:
         s = RayStrategy(num_workers=1)
-        # The env bus rides env_per_worker like RLT_COMPILE_CACHE, so
+        # The env bus rides env_per_worker like the compile-cache dir, so
         # remote workers (agent/Ray spawned — they inherit the AGENT's
         # env, not the driver's) still see the driver's request.
         assert s.env_per_worker["RLT_GRAD_COMM"] == "int8_ef"
@@ -148,8 +148,6 @@ def _per_device_partials(n, size, seed=0):
 def test_int8_all_reduce_matches_psum_within_quant_error(mesh8):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ray_lightning_tpu.utils.jax_compat import shard_map
-
     size, block = 8 * 256, 64
     parts = _per_device_partials(8, size)
 
@@ -159,7 +157,7 @@ def test_int8_all_reduce_matches_psum_within_quant_error(mesh8):
         )
         return red[None], err[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh8, in_specs=(P("data"),),
         out_specs=(P("data"), P("data")), check_vma=False,
     )

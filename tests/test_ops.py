@@ -262,20 +262,6 @@ class TestFusedCEPallas:
             err = float(jnp.abs(a - b).max())
             assert err < 1e-5, f"{name} max err {err}"
 
-    def test_kernel_probe_failure_falls_back(self, monkeypatch):
-        """If the one-time Mosaic probe marked the kernels unavailable,
-        use_pallas=True must silently take the scan path."""
-        import ray_lightning_tpu.ops.cross_entropy as ce
-
-        monkeypatch.setattr(ce, "_kernel_path_available",
-                            lambda d, dt: False)
-        x, wte, t = self._inputs()
-        fused = ce.fused_lm_head_cross_entropy(
-            x, wte, t, compute_dtype=jnp.float32, use_pallas=True)
-        naive = ce.naive_lm_head_cross_entropy(
-            x, wte, t, compute_dtype=jnp.float32)
-        assert float(jnp.abs(fused - naive).max()) < 1e-5
-
     def test_misaligned_d_falls_back_to_scan(self):
         """d=64 is not lane-aligned: use_pallas must silently take the
         scan path and still match."""
@@ -461,8 +447,8 @@ def test_zigzag_indices_partition():
 
 class TestKernelDisableSwitch:
     """RLT_DISABLE_KERNELS: the on-hardware A/B switch must force the
-    fallback per family and be reflected by the probes (bench.py records
-    kernel_path from exactly these)."""
+    XLA path per family and be reflected by the selection predicates
+    (bench.py records kernel_path from exactly these)."""
 
     def test_family_disable_forces_fallback(self, monkeypatch):
         from ray_lightning_tpu.ops import kernel_probe
@@ -471,12 +457,16 @@ class TestKernelDisableSwitch:
         assert kernel_probe.kernel_family_disabled("ce")
         assert kernel_probe.kernel_family_disabled("ln")
         assert not kernel_probe.kernel_family_disabled("flash")
-        # Even under the interpreter (CPU), a disabled family reports
-        # unavailable — no probe runs.
-        assert kernel_probe.kernel_available(
-            ("ce", 128, "float32"), lambda: None) is False
-        assert kernel_probe.kernel_available(
-            ("flash", 128), lambda: None) is True  # interpret: no probe
+        # The per-family gates read the switch (and nothing else that
+        # could differ between two calls with the same shapes).
+        from ray_lightning_tpu.ops.cross_entropy import _pallas_fwd_ok
+        from ray_lightning_tpu.ops.layer_norm import _kernel_selected
+
+        assert _pallas_fwd_ok(128, jnp.float32) is False
+        assert _kernel_selected(128, True) is False
+        monkeypatch.setenv("RLT_DISABLE_KERNELS", "flash")
+        assert _pallas_fwd_ok(128, jnp.float32) is True
+        assert _kernel_selected(128, True) is True
 
     def test_flash_disable_switch(self, monkeypatch):
         import jax.numpy as jnp
@@ -486,6 +476,68 @@ class TestKernelDisableSwitch:
         q = jnp.zeros((1, 256, 4, 64), jnp.float32)
         monkeypatch.setenv("RLT_DISABLE_KERNELS", "flash")
         assert _flash_supported(q) is False
+
+    def test_flash_selection_reads_the_mesh(self, monkeypatch):
+        """A Mosaic kernel cannot be partitioned by GSPMD: on a
+        multi-device mesh ``auto`` takes flash only where a shard_map
+        island can hold it (batch-only axes, divisible batch) or the
+        caller's body is already per-device."""
+        import jax
+        import numpy as np
+        from jax.sharding import Mesh
+
+        from ray_lightning_tpu.ops import attention as att
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        q = jax.ShapeDtypeStruct((8, 256, 4, 64), jnp.bfloat16)
+        devs = np.array(jax.devices()[:4])
+        dp = Mesh(devs.reshape(4), ("data",))
+        dp_fsdp = Mesh(devs.reshape(2, 2), ("data", "fsdp"))
+        tp = Mesh(devs.reshape(2, 2), ("data", "tensor"))
+        sp = Mesh(devs.reshape(4), ("sp",))
+        assert att._flash_supported(q) is True            # one device
+        assert att._flash_supported(q, dp) is True        # island
+        assert att._flash_supported(q, dp_fsdp) is True
+        assert att._flash_supported(q, tp) is False       # heads sharded
+        assert att._flash_supported(q, sp) is False       # seq sharded
+        assert att._flash_supported(q, tp, manual=True) is True
+        odd = jax.ShapeDtypeStruct((6, 256, 4, 64), jnp.bfloat16)
+        assert att._flash_supported(odd, dp) is False     # 6 % 4
+        # An explicit impl="flash" where no island fits is an error,
+        # never a silent change of path.
+        x = jnp.zeros((6, 256, 4, 64), jnp.bfloat16)
+        with pytest.raises(ValueError, match="per device"):
+            att.causal_attention(x, x, x, impl="flash", mesh=dp)
+
+    def test_flash_island_matches_xla_on_cpu_mesh(self, monkeypatch):
+        """The island's arithmetic (interpreted kernel per device) on a
+        4-device data mesh, forward and backward, against the XLA
+        reference."""
+        import jax
+        import numpy as np
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        from ray_lightning_tpu.ops import attention as att
+
+        mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+        sh = NamedSharding(mesh, P("data"))
+        ks = jax.random.split(jax.random.PRNGKey(0), 3)
+        q, k, v = (jax.device_put(
+            jax.random.normal(kk, (4, 128, 2, 64), jnp.float32), sh
+        ) for kk in ks)
+
+        def loss(impl, **kw):
+            return lambda q, k, v: (att.causal_attention(
+                q, k, v, impl=impl, **kw) ** 2).sum()
+
+        got = jax.jit(jax.value_and_grad(
+            loss("flash", mesh=mesh), argnums=(0, 1, 2)))(q, k, v)
+        ref = jax.jit(jax.value_and_grad(
+            loss("xla"), argnums=(0, 1, 2)))(q, k, v)
+        assert abs(float(got[0]) - float(ref[0])) < 1e-3 * abs(
+            float(ref[0]))
+        for a, b in zip(got[1], ref[1]):
+            assert float(jnp.abs(a - b).max()) < 2e-4
 
     def test_disabled_ce_still_correct(self, monkeypatch):
         """Numerics with the family disabled: the scan fallback answers."""

@@ -220,14 +220,14 @@ class TestBgmv:
         from ray_lightning_tpu.ops import lora as ops_lora
 
         monkeypatch.setenv("RLT_LORA_BGMV", "pallas")
-        assert ops_lora.resolve_bgmv_impl(16, 4, 48, jnp.float32) \
+        assert ops_lora.resolve_bgmv_impl() \
             == "pallas"
         monkeypatch.setenv("RLT_LORA_BGMV", "xla")
-        assert ops_lora.resolve_bgmv_impl(16, 4, 48, jnp.float32) \
+        assert ops_lora.resolve_bgmv_impl() \
             == "xla"
         monkeypatch.delenv("RLT_LORA_BGMV")
         # Off-TPU the gather is the selected path.
-        assert ops_lora.resolve_bgmv_impl(16, 4, 48, jnp.float32) \
+        assert ops_lora.resolve_bgmv_impl() \
             == "xla"
 
 

@@ -35,11 +35,10 @@ def main() -> None:
     ap.add_argument("--bv", type=int, nargs="*", default=[256, 512, 1024])
     args = ap.parse_args()
 
-    from bench import _detect_backend
-
-    if _detect_backend() != "tpu":
-        print("not on TPU — interpreter timings are meaningless; exiting")
-        return
+    if jax.default_backend() != "tpu":
+        raise SystemExit(
+            "not on TPU — interpreter timings are meaningless"
+        )
 
     from ray_lightning_tpu.ops import cross_entropy as ce
 
@@ -57,9 +56,6 @@ def main() -> None:
     results = []
     for bt, bv in itertools.product(args.bt, args.bv):
         ce._CE_BLOCK_T, ce._CE_BLOCK_V = bt, bv
-        from ray_lightning_tpu.ops import kernel_probe
-
-        kernel_probe._CACHE.clear()
         try:
             g = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))
             out = g(x, wte)
