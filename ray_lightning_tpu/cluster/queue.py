@@ -19,8 +19,9 @@ from __future__ import annotations
 import queue as _pyqueue
 import socket
 import threading
+import time
 import uuid
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 from . import rpc
 
@@ -237,7 +238,11 @@ class DriverQueue:
                     if fresh:
                         self._seen[cid] = seq
                 if fresh:
-                    self._items.put(item)
+                    # With its receipt time: a consumer that drains in
+                    # its own loop (the serve engine, blocked on the
+                    # device for a whole tick) would otherwise date the
+                    # item from the drain.
+                    self._items.put((time.monotonic(), item))
                 # Ack whether fresh or a replay (a replay means the ack —
                 # not the item — was lost on the previous attempt).
                 conn.sendall(b"\x01")
@@ -253,10 +258,14 @@ class DriverQueue:
         return self._items.empty()
 
     def get_nowait(self) -> Any:
-        return self._items.get_nowait()
+        return self._items.get_nowait()[1]
 
     def get(self, timeout: Optional[float] = None) -> Any:
-        return self._items.get(timeout=timeout)
+        return self._items.get(timeout=timeout)[1]
+
+    def get_nowait_stamped(self) -> Tuple[float, Any]:
+        """``(time.monotonic() at receipt, item)``."""
+        return self._items.get_nowait()
 
     def shutdown(self) -> None:
         self._closed.set()

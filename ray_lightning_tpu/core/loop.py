@@ -37,6 +37,7 @@ from ray_lightning_tpu.parallel.overlap import (
     resolve_grad_overlap,
 )
 from ray_lightning_tpu.telemetry import Telemetry
+from ray_lightning_tpu.telemetry.spans import phase as _detached_phase
 from ray_lightning_tpu.telemetry import program_ledger
 from ray_lightning_tpu.utils.compile_cache import enable_compile_cache
 from ray_lightning_tpu.utils.state_stream import (
@@ -308,6 +309,12 @@ def _resolve_megastep(config: FitConfig) -> int:
     return int(value)
 
 
+def _phase_opener(tel: Optional[Telemetry]):
+    """``Telemetry.phase`` of this rank, or the detached primitive (the
+    profiler annotation alone) where no telemetry is attached."""
+    return _detached_phase if tel is None else tel.phase
+
+
 class LoopContext:
     """Worker-side trainer context (the ``trainer`` arg of every hook)."""
 
@@ -405,13 +412,15 @@ class LoopContext:
             # restart path (``sharded_ckpt.save_shard``) still persists
             # it cheaply — each host writes only its own rows.
             state = TrainState(state.params, state.opt_state, state.step)
-        tel = self.telemetry
-        if tel is None:
-            return shardlib.host_replicated_copy(state, self.mesh)
-        with tel.span("host_transfer"):
+        with self.timed("host_transfer"):
             out = shardlib.host_replicated_copy(state, self.mesh)
-        tel.add_counter("host_transfers", 1)
+        if self.telemetry is not None:
+            self.telemetry.add_counter("host_transfers", 1)
         return out
+
+    def timed(self, name: str, layer: str = "train", **args):
+        """One timed phase of this rank's loop."""
+        return _phase_opener(self.telemetry)(name, layer, **args)
 
     def checkpoint_payload(self, extra: Optional[Dict[str, Any]] = None) -> dict:
         return {
@@ -441,15 +450,9 @@ class LoopContext:
             return
         if self.telemetry is not None:
             self.telemetry.add_counter("checkpoint_writes", 1)
-        tracer = (
-            self.telemetry.tracer if self.telemetry is not None else None
-        )
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         if not async_write:
-            if tracer is None:
-                state_stream_to_file(to_state_stream(payload), path)
-                return
-            with tracer.span("checkpoint_write", path=path):
+            with self.timed("checkpoint_write", path=path):
                 state_stream_to_file(to_state_stream(payload), path)
             return
         if self._ckpt_queue is None:
@@ -469,7 +472,8 @@ class LoopContext:
             self._ckpt_lock = threading.Lock()
             q, errors = self._ckpt_queue, self._ckpt_errors
             pending, lock = self._ckpt_pending, self._ckpt_lock
-            wtracer = tracer  # tracer holds no device state — safe capture
+            # The telemetry holds no device state — safe capture.
+            wphase = _phase_opener(self.telemetry)
 
             def writer():  # captures the queue/list, NOT self — the
                 # LoopContext (with its device-side state) must stay
@@ -480,14 +484,9 @@ class LoopContext:
                         if item is None:
                             return
                         p, pl = item
-                        t0 = time.perf_counter()
-                        state_stream_to_file(to_state_stream(pl), p)
-                        if wtracer is not None:
-                            wtracer.record(
-                                "checkpoint_write", t0,
-                                time.perf_counter() - t0,
-                                args={"path": p, "async": True},
-                            )
+                        with wphase("checkpoint_write", "train", path=p,
+                                    **{"async": True}):
+                            state_stream_to_file(to_state_stream(pl), p)
                     except BaseException as e:  # noqa: BLE001
                         errors.append(e)
                     finally:
@@ -1073,7 +1072,8 @@ class _AsyncLogFetch:
             return
         logs, extra = self._pending
         self._pending = None
-        logs, extra = jax.device_get((logs, extra))
+        with self._ctx.timed("log_fetch"):
+            logs, extra = jax.device_get((logs, extra))
         self._ctx.log_metrics(logs)
         if extra:
             self._ctx.log_metrics(extra)
@@ -1923,10 +1923,10 @@ def _run_fit_inner(
         last_batch_idx = -1
         batch_idx = skip - 1  # absolute index of the last COMPLETED batch
         # Telemetry marks: ``t_mark`` is set at the end of each loop body,
-        # so the gap to the next batch's arrival is exactly the time spent
-        # blocked on the (prefetched) input pipeline — data_wait.
+        # so the gap to the next batch's arrival is the step stats' data
+        # wait; the ``data_wait`` phase is the part of it blocked in the
+        # (prefetched) input pipeline's next().
         t_mark = time.perf_counter()
-        tracer = tel.tracer
         items = _prefetched(
             source, lambda b: _place_batch(b, mesh),
             telemetry=tel if tel.enabled else None,
@@ -1934,8 +1934,14 @@ def _run_fit_inner(
             place_stride=_place_stride,
         )
         try:
-            for gbatch, n_inner in items:
-                t_ready = time.perf_counter()
+            batches = iter(items)
+            while True:
+                with tel.phase("data_wait") as wait_ph:
+                    item = next(batches, None)
+                if item is None:
+                    break
+                gbatch, n_inner = item
+                data_wait_s = wait_ph.t0 + wait_ph.dur - t_mark
                 if (
                     config.limit_train_batches >= 0
                     and batch_idx + 1 >= config.limit_train_batches
@@ -1983,9 +1989,10 @@ def _run_fit_inner(
                         chaos.fire("step", step=ctx.micro_step,
                                    epoch=epoch, rank=global_rank)
                         rng = jax.random.fold_in(base_rng, ctx.micro_step)
-                        t_disp = time.perf_counter()
-                        ctx.state, logs = train_step(ctx.state, gb, rng)
-                        t_disp_end = time.perf_counter()
+                        with tel.phase("compile" if first_use
+                                       else "dispatch") as disp_ph:
+                            ctx.state, logs = train_step(
+                                ctx.state, gb, rng)
                         # Periodic device sampling: make THIS step's wall
                         # time include device execution (async dispatch
                         # hides it otherwise).  Never per-step — that
@@ -1994,7 +2001,8 @@ def _run_fit_inner(
                         sampled = (tel_stats is not None
                                    and tel_stats.should_sample())
                         if sampled:
-                            jax.block_until_ready(logs)
+                            with tel.phase("sample_sync"):
+                                jax.block_until_ready(logs)
                         epoch_mean.update(logs)
                         ctx.micro_step += 1
                         ctx.progress += 1  # heartbeat liveness counter
@@ -2007,18 +2015,19 @@ def _run_fit_inner(
                         # -- megastep stride: ONE dispatch, n micro-steps
                         # fused in a lax.scan, metrics accumulated on
                         # device; the host does integer bookkeeping only.
-                        t_disp = time.perf_counter()
-                        ctx.state, saux = multi_step(
-                            ctx.state, gb, base_rng,
-                            np.int32(ctx.micro_step),
-                        )
-                        t_disp_end = time.perf_counter()
+                        with tel.phase("compile" if first_use
+                                       else "megastep") as disp_ph:
+                            ctx.state, saux = multi_step(
+                                ctx.state, gb, base_rng,
+                                np.int32(ctx.micro_step),
+                            )
                         sampled = (
                             tel_stats is not None
                             and tel_stats.should_sample_stride(n)
                         )
                         if sampled:
-                            jax.block_until_ready(saux)
+                            with tel.phase("sample_sync"):
+                                jax.block_until_ready(saux)
                         epoch_mean.update_stride(
                             saux["sum"], saux["cnt"], n
                         )
@@ -2053,10 +2062,11 @@ def _run_fit_inner(
                             if lr_schedule is not None else None
                         )
                         log_fetch.schedule(logs, extra)
-                    _call_hooks(
-                        callbacks, "on_train_batch_end", ctx, module,
-                        logs, batch_idx,
-                    )
+                    with tel.phase("callbacks"):
+                        _call_hooks(
+                            callbacks, "on_train_batch_end", ctx, module,
+                            logs, batch_idx,
+                        )
                     last_logs, last_batch_idx = logs, batch_idx
                     t_end = time.perf_counter()
                     if tel_stats is not None:
@@ -2066,16 +2076,16 @@ def _run_fit_inner(
                         if n == 1:
                             tel_stats.record_step(
                                 step_s=t_end - t_mark,
-                                data_wait_s=t_ready - t_mark,
-                                dispatch_s=t_disp_end - t_disp,
+                                data_wait_s=data_wait_s,
+                                dispatch_s=disp_ph.dur,
                                 examples=int(shape[0]) if shape else 1,
                                 sampled=sampled, compiled=first_use,
                             )
                         else:
                             tel_stats.record_stride(
                                 stride_s=t_end - t_mark,
-                                data_wait_s=t_ready - t_mark,
-                                dispatch_s=t_disp_end - t_disp,
+                                data_wait_s=data_wait_s,
+                                dispatch_s=disp_ph.dur,
                                 examples=(
                                     int(shape[0]) * int(shape[1])
                                     if shape and len(shape) > 1 else n
@@ -2103,19 +2113,10 @@ def _run_fit_inner(
                                         int(shape[0]) if shape else 1, 1
                                     )
                                 )
-                    if tracer.enabled:
-                        tracer.record(
-                            "data_wait", t_mark, t_ready - t_mark
-                        )
-                        tracer.record(
-                            "compile" if first_use
-                            else ("megastep" if n > 1 else "dispatch"),
-                            t_disp, t_disp_end - t_disp,
-                        )
                     t_mark = t_end
                     # Chaos-degraded slices after the first: the data was
                     # already resident, only the first slice paid wait.
-                    t_ready = t_mark
+                    data_wait_s = 0.0
                     # Drain agreement (mesh-coordinated): a SIGTERM on
                     # ANY rank drains every rank at the same boundary.
                     # The multi-process collective runs whenever the
@@ -2190,7 +2191,7 @@ def _run_fit_inner(
             and (epoch + 1) % config.check_val_every_n_epoch == 0
         ):
             ctx.phase = "validation"
-            with tel.span("validation", epoch=epoch):
+            with tel.phase("validation", epoch=epoch):
                 val_metrics = _run_validation(
                     module, eval_step, val_loader, ctx,
                     config.limit_val_batches,
@@ -2293,6 +2294,12 @@ def _run_fit_inner(
     # The gather is collective: every rank participates, then only rank 0
     # serializes and ships the bytes.
     gathered = ctx._gathered_state()
+    state_stream = None
+    if ctx.is_global_zero:
+        # Seconds at LM scale (PERF.md section 7): a named phase, and
+        # before the export and the snapshot below so that both carry it.
+        with tel.phase("result_package", "fit"):
+            state_stream = to_state_stream(gathered)
     _maybe_export_telemetry(tel, ctx.telemetry_dir)
     # Retire the live plane on the success path: a final "done" beat so
     # the monitor reads the coming silence as completion (not a hang),
@@ -2315,7 +2322,7 @@ def _run_fit_inner(
             break
     return {
         "rank": 0,
-        "state_stream": to_state_stream(gathered),
+        "state_stream": state_stream,
         "callback_metrics": {
             k: float(v) for k, v in ctx.callback_metrics.items()
         },
@@ -2433,7 +2440,7 @@ def run_eval(
     eval_step = step_fns.build_eval_step(
         module, mesh, kind, mode=mode, params_shardings=params_shardings
     )
-    with tel.span("validation", kind=kind):
+    with tel.phase("validation", kind=kind):
         metrics = _run_validation(
             module, eval_step, loader, ctx, config.limit_val_batches
         )
@@ -2488,12 +2495,12 @@ def run_predict(
 
     outputs: List[np.ndarray] = []
     for batch in loader:
-        with tel.span("dispatch"):
+        with tel.phase("dispatch"):
             out = predict_step(params, _place_batch(batch, mesh))
         # Host-local rows only: each host contributes its addressable
         # shards (its own slice of the global batch), ordered by shard
         # index so rows stay in loader order within the host.
-        with tel.span("host_transfer"):
+        with tel.phase("host_transfer"):
             if mesh is not None and world_size > 1:
                 shards = sorted(
                     out.addressable_shards,

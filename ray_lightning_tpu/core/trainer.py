@@ -25,6 +25,7 @@ from ray_lightning_tpu.core.callbacks import Callback, ModelCheckpoint
 from ray_lightning_tpu.core.data import TpuDataModule
 from ray_lightning_tpu.core.loop import FitConfig
 from ray_lightning_tpu.core.module import TpuModule
+from ray_lightning_tpu.telemetry.spans import phase
 from ray_lightning_tpu.utils.state_stream import load_state_stream
 
 __all__ = ["Trainer"]
@@ -247,7 +248,8 @@ class Trainer:
         ``ray_ddp.py:362-401``)."""
         rank0 = next(r for r in results if r.get("rank") == 0)
         self._state_stream = rank0["state_stream"]
-        self.state = load_state_stream(self._state_stream)
+        with phase("result_unpack", "fit"):
+            self.state = load_state_stream(self._state_stream)
         self.callback_metrics.update(rank0["callback_metrics"])
         self.logged_metrics.update(rank0["logged_metrics"])
         self.best_model_path = rank0["best_model_path"]

@@ -273,6 +273,7 @@ def _ce_fwd_pallas(x, wte, targets, compute_dtype):
             pltpu.VMEM((bt, _LANE), jnp.float32),
         ],
         interpret=interp,
+        name="rlt_ce_fwd",
     )(x2, wp, t2)
     return loss[:n, 0].reshape(shape), lse[:n, 0].reshape(shape)
 
@@ -421,6 +422,7 @@ def _ce_bwd_pallas(x, wte, targets, lse, g, compute_dtype):
         out_specs=pl.BlockSpec((bt, d), lambda t, v: (t, 0)),
         scratch_shapes=[pltpu.VMEM((bt, d), jnp.float32)],
         interpret=interp,
+        name="rlt_ce_bwd_dx",
     )(x2, wp, t2, lse2, g2)
 
     dw = pl.pallas_call(
@@ -438,6 +440,7 @@ def _ce_bwd_pallas(x, wte, targets, lse, g, compute_dtype):
         out_specs=pl.BlockSpec((bv, d), lambda v, t: (v, 0)),
         scratch_shapes=[pltpu.VMEM((bv, d), jnp.float32)],
         interpret=interp,
+        name="rlt_ce_bwd_dw",
     )(x2, wp, t2, lse2, g2)
 
     dx = dx[:n].reshape(x.shape)

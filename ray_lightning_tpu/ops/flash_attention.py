@@ -163,6 +163,7 @@ def _flash_fwd_bhsd(q, k, v, scale, block_q, block_k, want_lse=True):
         ],
         out_specs=(out_spec, lse_spec) if want_lse else out_spec,
         interpret=_interpret(),
+        name="rlt_flash_fwd",
     )(q, k, v)
     return result if want_lse else (result, None)
 
@@ -298,6 +299,7 @@ def _flash_bwd_bhsd(q, k, v, out, lse, g, scale, block_q, block_k):
             pl.BlockSpec((1, 1, s, d), lambda b, i: (b, i, 0, 0)),
         ),
         interpret=_interpret(),
+        name="rlt_flash_bwd",
     )(q, k, v, g, lse, out)
     dq = jnp.sum(dqp.astype(jnp.float32), axis=1).astype(q.dtype)
     return dq, dk, dv

@@ -126,6 +126,7 @@ def _ln_fwd_pallas(x, g, b, want_stats):
         out_specs=(row_spec, stat_spec, stat_spec)
         if want_stats else row_spec,
         interpret=_interpret(),
+        name="rlt_ln_fwd",
     )(x2, g.reshape(1, d), b.reshape(1, d))
     if want_stats:
         y, mu, rs = result
@@ -158,6 +159,7 @@ def _ln_bwd_pallas(x, g, dy, mu_pad, rs_pad):
         in_specs=[row_spec, vec_spec, row_spec, stat_spec, stat_spec],
         out_specs=(row_spec, vec_spec, vec_spec),
         interpret=_interpret(),
+        name="rlt_ln_bwd",
     )(x2, g.reshape(1, d), dy2, mu_pad, rs_pad)
     return dx[:n].reshape(shape), dg[0], db[0]
 

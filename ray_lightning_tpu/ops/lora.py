@@ -104,6 +104,7 @@ def bgmv_pallas(h: jax.Array, a: jax.Array, b: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((W, 1, k), h.dtype),
         interpret=_interpret(),
+        name="rlt_lora_bgmv",
     )(ids.astype(jnp.int32), h.reshape(W, 1, d), a.astype(h.dtype),
       b.astype(h.dtype)).reshape(W, k)
 

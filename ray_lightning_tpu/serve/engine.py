@@ -50,6 +50,7 @@ from ray_lightning_tpu.fault.inject import (
 from ray_lightning_tpu.telemetry.propagate import (
     child_context, trace_args,
 )
+from ray_lightning_tpu.telemetry.spans import SpanTracer, phase
 
 __all__ = ["ServeConfig", "ServeEngine", "ServeHandle", "ServeRejected"]
 
@@ -386,15 +387,16 @@ class ServeEngine:
         # "Distributed tracing"): wall-clock spans per critical-path
         # phase, exported as trace-serve-<name>.jsonl at stop() for
         # telemetry/trace_collect.py to stitch.  OFF unless trace_dir
-        # is set — the disabled tracer costs one attribute check.
-        from ray_lightning_tpu.telemetry.spans import SpanTracer
-
+        # is set.  The loop's own phases (PHASES["serve"]) are spans
+        # in the same ring; profiler annotations and tick_<phase>_us
+        # counters they are always.
         self._trace_dir = trace_dir
         self._trace_name = trace_name or uuid.uuid4().hex[:6]
         self.tracer = SpanTracer(
             enabled=trace_dir is not None, maxlen=16384, rank=0,
             clock=time.time,
         )
+        self._tick_us: Dict[str, int] = {}  # this iteration's phases
         self._build_programs()
 
         self._handles: Dict[str, ServeHandle] = {}  # guarded by self._lock
@@ -687,7 +689,7 @@ class ServeEngine:
                sample_seed: Optional[int] = None,
                on_token=None, rid: Optional[str] = None,
                _handoff: Optional[dict] = None,
-               _trace_ctx=None) -> ServeHandle:
+               _trace_ctx=None, _recv_t=None) -> ServeHandle:
         """Enqueue one request (thread-safe).  Returns a handle; a
         backpressure rejection is visible immediately as
         ``handle.status == "rejected"`` (and ``result()`` raises).
@@ -710,7 +712,9 @@ class ServeEngine:
 
         ``_handoff`` (internal, ``serve/dist/``) carries a prefill
         worker's exported KV payload — admission imports it instead of
-        running the local prefill program."""
+        running the local prefill program.  ``_recv_t`` (internal, the
+        queue plane) is when the request's frame landed in the inbox
+        (``time.monotonic()``); only ``queue_wait_us`` counts from it."""
         from ray_lightning_tpu.serve.scheduler import Request
 
         prompt = [int(t) for t in prompt]
@@ -785,7 +789,7 @@ class ServeEngine:
             temperature=float(temperature), eos_token_id=eos_token_id,
             top_k=top_k, spec=spec, adapter=adapter,
             deadline_s=deadline_s, sample_seed=sample_seed,
-            on_token=on_token, trace=trace_ctx,
+            on_token=on_token, trace=trace_ctx, recv_t=_recv_t,
         )
         req._trace_local = trace_local
         if _handoff is not None:
@@ -832,11 +836,45 @@ class ServeEngine:
     def step(self) -> bool:
         """One serve iteration: drain the queue plane, expire/admit,
         grow/preempt, one decode (or draft→verify) tick.  Returns True
-        when any work was done (False = idle)."""
+        when any work was done (False = idle).
+
+        The iteration is cut into the ``PHASES["serve"]`` phases
+        (``telemetry/spans.py``), chained on single clock reads so they
+        tile it: each is a profiler annotation ``rlt:serve/<phase>``
+        and a counter ``tick_<phase>_us``, summed locally and handed to
+        the stats under one lock at the end."""
+        t0 = time.perf_counter()
+        ph = self._tick_phase("inbox").__enter__()
+        try:
+            return self._step(ph)
+        finally:
+            ph.__exit__(None, None, None)
+            self._flush_tick(time.perf_counter() - t0, ticks=1)
+
+    def _tick_phase(self, name: str):
+        """A phase of the loop's own (``PHASES["serve"]``).  It is a
+        span too only while a request is in the engine: idle, the loop
+        turns every ``idle_wait_s`` and five spans a turn would push
+        the requests' spans out of the ring."""
+        inbox = self._inbox
+        busy = self.scheduler.has_work() or (
+            inbox is not None and not inbox.empty())
+        return (self.tracer.phase if busy else phase)(
+            name, "serve", self._tick_us, "tick_")
+
+    def _flush_tick(self, wall_s: float, ticks: int = 0) -> None:
+        tick = self._tick_us
+        tick["tick_us"] = round(wall_s * 1e6)
+        tick["ticks"] = ticks
+        self.stats.bump_many(tick)
+        tick.clear()
+
+    def _step(self, ph) -> bool:
         import jax.numpy as jnp
 
         _fault_fire("replica_tick")
         self._drain_inbox()
+        ph.then("schedule")
         with self._lock:
             if self.prefix_cache is not None and self._prefix_drops:
                 # Invalidate replaced/removed tenants' chains BEFORE
@@ -854,8 +892,11 @@ class ServeEngine:
         t_adm = now
         tr = self.tracer
         for slot, req, bucket in admissions:
+            ph.then("admit_dispatch", rid=req.rid, bucket=bucket,
+                    prompt_len=req.prompt_len)
             wait = now - req.arrival_t
-            self.stats.note_admitted(wait)
+            self.stats.note_admitted(
+                wait, None if req.recv_t is None else now - req.recv_t)
             ctx = req.trace if tr.enabled else None
             if ctx is not None:
                 tr.record(
@@ -876,7 +917,8 @@ class ServeEngine:
                     continue
                 handoff = None
                 self.stats.bump("prefills")
-                t_ph = time.time() if ctx is not None else 0.0
+                rph = self._request_phase(ctx, "prefill_compute", req,
+                                          bucket=bucket)
                 first = self._suffix_prefill(slot, req)
             else:
                 ids = np.asarray(  # rlt: noqa[RLT002] host block list, no device value
@@ -896,7 +938,9 @@ class ServeEngine:
                     padded_np = np.zeros((bucket,), np.int32)
                     padded_np[: req.prompt_len] = req.prompt
                     padded = jnp.asarray(padded_np)
-                t_ph = time.time() if ctx is not None else 0.0
+                rph = self._request_phase(
+                    ctx, "decode_admission" if handoff is not None
+                    else "prefill_compute", req, bucket=bucket)
             if bucket != 0 and handoff is not None:
                 # A prefill worker already ran this prompt: scatter its
                 # exported blocks into OUR allocator's blocks and
@@ -938,7 +982,9 @@ class ServeEngine:
                     self.draft_params, self._draft_pool, padded,
                     np.int32(req.prompt_len), ids,
                 )
+            ph.then("admit_wait", rid=req.rid)
             first = int(first)  # rlt: noqa[RLT002] deliberate TTFT sync at admission
+            ph.then("admit_emit", rid=req.rid)
             t_first = time.monotonic()
             # Per-admission wall in µs (host prep + prefill/import
             # dispatch + the TTFT sync above).  Paired with the
@@ -948,24 +994,18 @@ class ServeEngine:
             self.stats.bump(  # rlt: noqa[RLT002] host float, no device value
                 "admit_us", int((t_first - t_adm) * 1e6))
             t_adm = t_first
-            if ctx is not None:
+            if rph is not None:
                 # The int() above synced the device, so this interval
                 # covers dispatch + device compute of the admission.
-                t_sync = time.time()
-                phase = ("decode_admission" if handoff is not None
-                         else "prefill_compute")
-                tr.record(phase, t_ph, max(0.0, t_sync - t_ph),
-                          args=trace_args(child_context(ctx),
-                                          rid=req.rid, bucket=bucket))
-                self.stats.note_phase(phase, t_sync - t_ph)
+                rph.__exit__(None, None, None)
+                self.stats.note_phase(rph.name, rph.dur)
+                rph = self._request_phase(ctx, "first_token", req,
+                                          token_index=0)
             self.stats.note_first_token(t_first - req.arrival_t)
             done = self.scheduler.append_token(slot, first, now=t_first)
-            if ctx is not None:
-                ft_dur = max(0.0, time.time() - t_sync)
-                tr.record("first_token", t_sync, ft_dur,
-                          args=trace_args(child_context(ctx),
-                                          rid=req.rid, token_index=0))
-                self.stats.note_phase("first_token", ft_dur)
+            if rph is not None:
+                rph.__exit__(None, None, None)
+                self.stats.note_phase("first_token", rph.dur)
             self.stats.bump("tokens_out")
             if req.adapter is not None:
                 self.stats.note_adapter(req.adapter, tokens=1)
@@ -980,8 +1020,10 @@ class ServeEngine:
         # so resident slots keep emitting one token per step while a
         # long prompt fills in chunk by chunk (the no-stall contract).
         if self._chunk_jobs:
+            ph.then("chunk")
             worked = self._chunk_tick() or worked
 
+        ph.then("grow")
         # Per-slot speculative widths for THIS tick: the engine K,
         # capped per request (spec= knob) and by the tokens it has left
         # (a tick never drafts past max_new_tokens).  Zero everywhere
@@ -1033,13 +1075,26 @@ class ServeEngine:
         ]
         if active:
             worked = True
+            ph.then("decode_dispatch", slots=len(active))
             if any(widths[s] > 0 for s in active):
-                self._spec_tick(active, widths)
+                self._spec_tick(active, widths, ph)
             else:
-                self._decode_tick(active)
+                self._decode_tick(active, ph)
+        ph.then("housekeep")
         self._refresh_gauges()
         self._maybe_export()
         return worked
+
+    def _request_phase(self, ctx, name: str, req, **args):
+        """An entered span of ONE traced request (``PHASES["request"]``)
+        through the same primitive as the tick's phases, or None when
+        the request carries no trace context."""
+        if ctx is None:
+            return None
+        return self.tracer.phase(
+            name, "request",
+            **trace_args(child_context(ctx), rid=req.rid, **args),
+        ).__enter__()
 
     def _tick_widths(self) -> List[int]:
         """Drafted tokens per slot this tick (0 = plain decode)."""
@@ -1248,9 +1303,10 @@ class ServeEngine:
             req.adapter, req.prompt, self.scheduler._blocks[slot][:n]
         )
 
-    def _decode_tick(self, active: List[int]) -> None:
+    def _decode_tick(self, active: List[int], ph) -> None:
         """One token for every active slot — the non-speculative path
-        (and the fallback when no active slot drafts this tick)."""
+        (and the fallback when no active slot drafts this tick).
+        ``ph`` is the tick's open phase (``decode_dispatch``)."""
         import jax.numpy as jnp
 
         t0 = time.monotonic()
@@ -1277,8 +1333,10 @@ class ServeEngine:
                 seq_lens + 1,
             )
             self.stats.bump("draft_steps")
+        ph.then("decode_wait")
         # rlt: noqa[RLT002] deliberate: the tick must emit tokens
         toks = np.asarray(toks)
+        ph.then("emit")
         dt = time.monotonic() - t0
         self.stats.bump("decode_steps")
         # Tick wall in µs — with decode_steps/tokens_out it gives the
@@ -1299,7 +1357,8 @@ class ServeEngine:
             if done:
                 self._complete(slot)
 
-    def _spec_tick(self, active: List[int], widths: List[int]) -> None:
+    def _spec_tick(self, active: List[int], widths: List[int],
+                   ph) -> None:
         """One draft-propose / target-verify round.
 
         1. the draft model proposes up to K tokens per slot — K+1
@@ -1366,9 +1425,11 @@ class ServeEngine:
             )
             outs.append(prev)
         self.stats.bump("draft_steps", K + 1)
+        ph.then("decode_wait")
         outs = np.stack(  # rlt: noqa[RLT002] deliberate: host accept/reject
             [np.asarray(o) for o in outs]
         )  # (K+1, W)
+        ph.then("decode_dispatch")
 
         # Per-slot proposals: the K chain outputs starting at the
         # slot's gap offset.
@@ -1386,8 +1447,10 @@ class ServeEngine:
             jnp.asarray(sched.sample_seeds), self._tick_top_ks(),
             ad, ad_ids,
         )
+        ph.then("decode_wait")
         # rlt: noqa[RLT002] deliberate verify sync
         sampled = np.asarray(sampled)  # (W, K+1)
+        ph.then("emit")
         self.stats.bump("verify_steps")
         dt = time.monotonic() - t0
         # Same busy-time accounting as the plain decode tick, so the
@@ -1603,7 +1666,9 @@ class ServeEngine:
                 self._fail_pending(e)
                 return
             if not worked:
-                time.sleep(self.config.idle_wait_s)
+                with self._tick_phase("idle") as ph:
+                    time.sleep(self.config.idle_wait_s)
+                self._flush_tick(ph.dur)
 
     def _fail_pending(self, exc: BaseException) -> None:
         """The serve loop died: mark the engine dead (submit() refuses
@@ -1751,11 +1816,11 @@ class ServeEngine:
 
         while True:
             try:
-                item = self._inbox.get_nowait()
+                recv_t, item = self._inbox.get_nowait_stamped()
             except _pyqueue.Empty:
                 break
             try:
-                self._handle_queue_request(item)
+                self._handle_queue_request(item, recv_t)
             except Exception as e:  # noqa: BLE001 - a bad request must
                 # never take the serve loop down
                 import logging
@@ -1777,7 +1842,12 @@ class ServeEngine:
                         "serve: dropped malformed queue request: %s", e
                     )
 
-    def _handle_queue_request(self, item: dict) -> None:
+    def _handle_queue_request(self, item: dict,
+                              recv_t: Optional[float] = None) -> None:
+        """``recv_t`` is when the frame landed in the inbox
+        (``time.monotonic()``), up to a tick before this loop got round
+        to it; the ``queue_wait_us`` counter counts from there
+        (``arrival_t``, and with it TTFT and deadlines, from here)."""
         if not isinstance(item, dict):
             raise ValueError(f"not a serve item: {type(item).__name__}")
         kind = item.get("type")
@@ -1812,6 +1882,7 @@ class ServeEngine:
                 if deadline is None:
                     deadline = time.monotonic() + 10.0
                     item["_adapter_wait_deadline"] = deadline
+                    item["_recv_t"] = recv_t     # for the retry pass
                 if time.monotonic() < deadline:
                     self._deferred_inbox.append(item)
                     return
@@ -1884,6 +1955,8 @@ class ServeEngine:
                 sample_seed=fields.get("sample_seed"),
                 on_token=on_token, rid=rid, _handoff=handoff,
                 _trace_ctx=trace_ctx,
+                _recv_t=(recv_t if recv_t is not None
+                         else item.get("_recv_t")),
             )
         except FaultBlackhole:
             # Injected network partition on the read side: the frame

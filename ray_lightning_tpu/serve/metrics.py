@@ -18,6 +18,8 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from ray_lightning_tpu.telemetry.spans import PHASES
+
 __all__ = ["ServeStats", "percentile"]
 
 # Newest-N window per latency family.  4096 tokens at serving rates is
@@ -27,6 +29,13 @@ _RESERVOIR = 4096
 _COUNTER_KEYS = (
     "submitted", "admitted", "completed", "rejected", "expired",
     "preempted", "tokens_out", "prefills", "decode_steps",
+    # The loop's wall by phase, integer microseconds (engine.step():
+    # the phases tile one iteration, so they sum to tick_us), and
+    # arrival-to-admission summed over admissions.  Present from the
+    # start so that a window delta reads 0, not nothing, for a phase
+    # that did not occur in it.
+    "ticks", "tick_us", *(f"tick_{p}_us" for p in PHASES["serve"]),
+    "queue_wait_us",
 )
 
 
@@ -112,9 +121,24 @@ class ServeStats:
         with self._lock:
             self.counters[name] = self.counters.get(name, 0) + n
 
-    def note_admitted(self, wait_s: float) -> None:
+    def bump_many(self, deltas: Dict[str, int]) -> None:
+        """Several counters under one lock hold (the engine's phases of
+        one tick)."""
+        with self._lock:
+            counters = self.counters
+            for name, n in deltas.items():
+                counters[name] = counters.get(name, 0) + n
+
+    def note_admitted(self, wait_s: float,
+                      since_receipt_s: Optional[float] = None) -> None:
+        """``since_receipt_s`` (queue-plane requests) counts from the
+        frame's receipt in the inbox, ``wait_s`` from ``submit()``: the
+        counter takes the first where there is one."""
+        if since_receipt_s is None:
+            since_receipt_s = wait_s
         with self._lock:
             self.counters["admitted"] += 1
+            self.counters["queue_wait_us"] += round(since_receipt_s * 1e6)
             self._queue_wait.add(wait_s)
 
     def note_first_token(self, ttft_s: float) -> None:

@@ -87,6 +87,9 @@ class Request:
     # -- runtime (scheduler-owned) ------------------------------------------
     state: RequestState = RequestState.QUEUED
     arrival_t: float = field(default_factory=time.monotonic)
+    # When the request's frame landed in the engine's inbox (queue
+    # plane only; feeds the ``queue_wait_us`` counter, nothing else).
+    recv_t: Optional[float] = None
     admitted_t: Optional[float] = None
     first_token_t: Optional[float] = None
     finished_t: Optional[float] = None

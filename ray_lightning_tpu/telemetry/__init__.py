@@ -5,7 +5,9 @@ The observability subsystem (ISSUEs 2 + 3).  One import surface:
 * :class:`Telemetry` / :class:`TelemetryConfig` — the per-rank runtime
   and its tier knobs (``off`` / ``cheap`` default / ``full``), coerced
   from ``telemetry=`` on the strategies or the ``RLT_TELEMETRY`` env bus;
-* :class:`SpanTracer` — phase spans with JSONL + Chrome-trace export;
+* :class:`SpanTracer` — phase spans with JSONL + Chrome-trace export,
+  and ``phase()``: one timed phase as profiler annotation, counter and
+  span (:data:`PHASES` names them all);
 * :class:`StepStats` — step-time split, throughput, analytic-FLOPs MFU,
   recompile counters, device memory stats;
 * :func:`merge_snapshots` / :func:`host_stats` — driver-side fleet
@@ -16,8 +18,8 @@ The observability subsystem (ISSUEs 2 + 3).  One import surface:
   ``trainer.monitor_report``), :class:`FlightRecorder` (crash bundles),
   :class:`RankLogHandler` (rank-tagged log ring + forwarding), and
   :mod:`.export_prom` (OpenMetrics textfile/HTTP export);
-* :mod:`.trace_parse` / :mod:`.schema` — Chrome-trace parsing shared by
-  the tools, and the artifact-schema validators ``format.sh`` gates on;
+* :mod:`.schema` — the artifact-schema validators ``format.sh`` gates
+  on (device traces are read by ``benchmarks/lib/xplane.py``);
 * the **SLO & capacity plane** (ISSUE 18): :class:`TimeSeriesStore`
   (bounded fixed-interval ring store with windowed rate/percentile/
   slope/ETA queries), :class:`SloSpec` / :class:`SloEvaluator`

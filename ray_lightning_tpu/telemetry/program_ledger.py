@@ -59,6 +59,8 @@ from typing import (
     Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple,
 )
 
+from ray_lightning_tpu.telemetry.spans import phase
+
 __all__ = [
     "ArgSig",
     "LedgeredFunction",
@@ -560,9 +562,11 @@ class LedgeredFunction:
 
         baseline = (self._mru.sig if self._mru is not None
                     else self._ledger.last_signature(self.site))
-        t0 = time.perf_counter()
-        compiled = self._jit.lower(*args, **kwargs).compile()
-        compile_s = time.perf_counter() - t0
+        # A compile inside a profiled window is a named span on the
+        # trace's clock (``rlt:compile``, ``site=``), not only a count.
+        with phase("compile", site=self.site) as ph:
+            compiled = self._jit.lower(*args, **kwargs).compile()
+        compile_s = ph.dur
         cost = _cost_dict(compiled)
         record = ProgramRecord(
             site=self.site,

@@ -12,8 +12,9 @@ the duration of a stage.  It owns the three collectors:
 Tiers (``TelemetryConfig.tier``):
 
 * ``off``   — nothing recorded, no listener installed, no metric keys;
-* ``cheap`` — **the default**: counters + step stats + headline metrics
-  in ``callback_metrics``.  Budget: <1% per-step overhead (asserted by
+* ``cheap`` — **the default**: counters (the loop's phases among them,
+  ``<phase>_us``) + step stats + headline metrics in
+  ``callback_metrics``.  Budget: <1% per-step overhead (asserted by
   the overhead smoke test, measured precisely in ``BENCH_*``);
 * ``full``  — cheap + span recording + JSONL/Chrome export at fit end.
 
@@ -147,6 +148,14 @@ class Telemetry:
     # -- spans (delegation keeps call sites one-attribute deep) -------------
     def span(self, name: str, **args):
         return self.tracer.span(name, **args)
+
+    def phase(self, name: str, layer: str = "train", **args):
+        """One timed phase of the loop: profiler annotation
+        ``rlt:<layer>/<name>`` always, counter ``<name>_us`` unless the
+        tier is ``off``, a span at tier ``full`` (``spans.py``)."""
+        return self.tracer.phase(
+            name, layer, self.counters if self.enabled else None, **args
+        )
 
     # -- surfaces -----------------------------------------------------------
     def headline_metrics(self) -> Dict[str, float]:

@@ -1,10 +1,9 @@
 """Low-overhead span tracer: monotonic-clock phase timing per rank.
 
 The tracing half of the telemetry subsystem (SURVEY §5: the reference
-ships zero observability).  A :class:`SpanTracer` records named phases —
-``compile``, ``data_wait``, ``dispatch``, ``validation``,
-``checkpoint_write``, ``grad_sync``, ``host_transfer`` — into a bounded
-ring buffer, one tracer per rank.  Two export formats:
+ships zero observability).  A :class:`SpanTracer` records named phases
+(:data:`PHASES`) into a bounded ring buffer, one tracer per rank.  Two
+export formats:
 
 * **JSONL** — one span object per line (the machine-diffable form the
   schema checker validates, ``tools/check_telemetry_schema.py``);
@@ -13,11 +12,21 @@ ring buffer, one tracer per rank.  Two export formats:
   next to the ``jax.profiler`` traces ``ProfilerCallback`` captures.
 
 Overhead discipline: the tracer is OFF at the default cheap telemetry
-tier.  A disabled tracer's ``span()`` returns one preallocated no-op
-context manager (no generator, no allocation), so leaving instrumentation
-in the hot loop costs a single attribute check per call.  This module is
-deliberately jax-free — the schema checker imports it from ``format.sh``
-and must not pay (or require) a jax import.
+tier, and records nothing then.
+
+:meth:`SpanTracer.phase` is the one context manager there is
+(``span()`` and ``start_remote()`` return it too); the engine tick and
+the train loop are cut into it.  One timed phase feeds three sinks: a
+``jax.profiler.TraceAnnotation`` ``rlt:<layer>/<phase>`` held open for
+its duration (always; inert without a profiler session, and on the
+device trace's clock with one), an integer-microsecond counter in the
+dict it was given (always: the default tier's window deltas), and a
+:class:`Span` in the ring (tracer enabled only).  About 1 us a phase
+with nothing listening.
+
+This module imports without jax — the schema checker imports it from
+``format.sh`` and must not pay (or require) a jax import; ``phase()``
+looks ``jax.profiler`` up on first use.
 """
 
 from __future__ import annotations
@@ -29,19 +38,57 @@ import threading
 import time
 from typing import Any, Dict, List, NamedTuple, Optional
 
-__all__ = ["PHASES", "Span", "SpanTracer"]
+__all__ = ["PHASES", "Span", "SpanTracer", "phase", "phase_label"]
 
-#: Canonical phase names the loop instruments.  Free-form names are also
-#: accepted — these exist so dashboards and tests agree on spelling.
-PHASES = (
-    "compile",
-    "data_wait",
-    "dispatch",
-    "validation",
-    "checkpoint_write",
-    "grad_sync",
-    "host_transfer",
-)
+#: Every phase name the program opens, by layer: the profiler annotation
+#: is ``rlt:<layer>/<name>`` (``rlt:<name>`` for the layer ``""``), the
+#: span in the ring is ``<name>``.  Free-form names are also accepted —
+#: these exist so tests, docs and trace readers agree on spelling.
+PHASES = {
+    # One ServeEngine loop iteration, in order, without gap or overlap
+    # (counters ``tick_<name>_us``; docs/OBSERVABILITY.md has the table).
+    "serve": (
+        "inbox", "schedule", "admit_dispatch", "admit_wait", "admit_emit",
+        "chunk", "grow", "decode_dispatch", "decode_wait", "emit",
+        "housekeep", "idle",
+    ),
+    # Spans of ONE request (gated on its trace context, nested in or
+    # recorded beside the tick phases; ``telemetry/trace_collect.py``).
+    "request": (
+        "queue_wait", "prefill_compute", "decode_admission", "first_token",
+        "handoff_send", "handoff_transfer", "request",
+    ),
+    # The train loop (counters ``<name>_us``).
+    "train": (
+        "data_wait", "dispatch", "megastep", "compile", "sample_sync",
+        "callbacks", "log_fetch", "validation", "checkpoint_write",
+        "host_transfer", "grad_sync",
+    ),
+    # The end-of-fit state hand-back: rank 0 serialises, the driver
+    # (the same process under LocalStrategy) loads.
+    "fit": ("result_package", "result_unpack"),
+    # ``ledgered_jit`` when it compiles, with ``site=``.
+    "": ("compile",),
+}
+
+
+def phase_label(name: str, layer: str = "") -> str:
+    """The profiler-annotation name of a phase."""
+    return f"rlt:{layer}/{name}" if layer else f"rlt:{name}"
+
+
+_ANNOTATION: Any = False   # jax.profiler.TraceAnnotation, looked up once
+
+
+def _annotation_cls():
+    global _ANNOTATION
+    if _ANNOTATION is False:
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:  # noqa: BLE001 - no jax here: nothing to annotate
+            TraceAnnotation = None
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
 
 
 class Span(NamedTuple):
@@ -54,59 +101,76 @@ class Span(NamedTuple):
     args: Optional[Dict[str, Any]] = None
 
 
-class _NullCtx:
-    """Shared no-op context manager for the disabled tracer.  ``ctx``
-    mirrors :class:`_SpanCtx` so ``start_remote`` call sites read the
-    trace context unconditionally."""
+class _PhaseCtx:
+    """One timed phase with its three sinks (module docstring).  After
+    exit ``t0`` and ``dur`` hold the perf_counter reading at open and
+    the seconds elapsed, so a caller that needs the number (step stats)
+    does not time the same interval twice.  ``ctx`` is the span's own
+    :class:`~.propagate.TraceContext` when :meth:`SpanTracer.start_remote`
+    opened it (else None): the body injects it into outgoing frames and
+    the receiving process parents its spans here."""
 
-    __slots__ = ()
+    __slots__ = ("_tracer", "_layer", "_sink", "_prefix", "name", "args",
+                 "_ann", "_ts", "_depth", "t0", "dur", "ctx")
 
-    ctx = None
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_CTX = _NullCtx()
-
-
-class _SpanCtx:
-    """One live span: records on exit, tracks per-thread nesting depth.
-
-    ``ctx`` (a :class:`~.propagate.TraceContext` on spans opened via
-    :meth:`SpanTracer.start_remote`) is exposed so the body can inject
-    the span's OWN identity into outgoing frames — the receiving
-    process then parents its spans here."""
-
-    __slots__ = ("_tracer", "_name", "_args", "_t0", "_depth", "ctx")
-
-    def __init__(self, tracer: "SpanTracer", name: str, args, ctx=None):
+    def __init__(self, tracer: "SpanTracer", name: str, layer: str,
+                 sink: Optional[Dict[str, Any]], prefix: str, args):
         self._tracer = tracer
-        self._name = name
-        self._args = args
-        self.ctx = ctx
+        self._layer = layer
+        self._sink = sink
+        self._prefix = prefix
+        self.name = name
+        self.args = args
+        self._ann = None
+        self.dur = 0.0
+        self.ctx = None
+
+    def _open(self, t: float) -> None:
+        cls = _annotation_cls()
+        if cls is not None:
+            label = phase_label(self.name, self._layer)
+            self._ann = cls(label, **self.args) if self.args else cls(label)
+            self._ann.__enter__()
+        tr = self._tracer
+        if tr.enabled:
+            self._depth = tr._push(self.name)
+            self._ts = tr._clock()
+        else:
+            self._depth = -1
+        self.t0 = t
+
+    def _close(self, t: float) -> None:
+        self.dur = dur = t - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if self._sink is not None:
+            key = f"{self._prefix}{self.name}_us"
+            self._sink[key] = self._sink.get(key, 0) + round(dur * 1e6)
+        if self._depth >= 0:
+            self._tracer._pop()
+            self._tracer.record(self.name, self._ts, dur,
+                                depth=self._depth, args=self.args)
 
     def __enter__(self):
-        stack = self._tracer._stack()
-        self._depth = len(stack)
-        stack.append(self._name)
-        self._tracer.open_span = self._name
-        self._t0 = self._tracer._clock()
+        self._open(time.perf_counter())
         return self
 
     def __exit__(self, *exc):
-        t1 = self._tracer._clock()
-        stack = self._tracer._stack()
-        stack.pop()
-        self._tracer.open_span = stack[-1] if stack else None
-        self._tracer.record(
-            self._name, self._t0, t1 - self._t0,
-            depth=self._depth, args=self._args,
-        )
+        self._close(time.perf_counter())
         return False
+
+    def then(self, name: str, **args) -> "_PhaseCtx":
+        """Close this phase and open ``name`` on ONE clock read: phases
+        chained this way tile an iteration without gap or overlap.  The
+        same (entered) object is returned; close the last with
+        ``__exit__``."""
+        t = time.perf_counter()
+        self._close(t)
+        self.name = name
+        self.args = args or None
+        self._open(t)
+        return self
 
 
 class SpanTracer:
@@ -144,12 +208,32 @@ class SpanTracer:
             stack = self._local.stack = []
         return stack
 
+    def _push(self, name: str) -> int:
+        """Open ``name`` on this thread's stack; returns its depth."""
+        stack = self._stack()
+        stack.append(name)
+        self.open_span = name
+        return len(stack) - 1
+
+    def _pop(self) -> None:
+        stack = self._stack()
+        stack.pop()
+        self.open_span = stack[-1] if stack else None
+
     # -- recording ----------------------------------------------------------
-    def span(self, name: str, **args):
-        """Context manager timing one phase.  No-op when disabled."""
-        if not self.enabled:
-            return _NULL_CTX
-        return _SpanCtx(self, name, args or None)
+    def span(self, name: str, **args) -> "_PhaseCtx":
+        """:meth:`phase` with no layer and no counter."""
+        return self.phase(name, **args)
+
+    def phase(self, name: str, layer: str = "",
+              sink: Optional[Dict[str, Any]] = None, prefix: str = "",
+              **args) -> _PhaseCtx:
+        """Context manager timing one phase into its three sinks: the
+        profiler annotation ``rlt:<layer>/<name>`` (``args`` as its
+        stats), ``sink["<prefix><name>_us"]`` (integer microseconds,
+        when a sink is given) and, when the tracer is enabled, a
+        :class:`Span` ``<name>`` in the ring."""
+        return _PhaseCtx(self, name, layer, sink, prefix, args or None)
 
     def record(self, name: str, ts: float, dur: float, depth: int = 0,
                args: Optional[Dict[str, Any]] = None) -> None:
@@ -168,16 +252,20 @@ class SpanTracer:
         span parents to ``ctx`` (a :class:`~.propagate.TraceContext`
         from another process's wire frame) and carries its own fresh
         span id, exposed as ``.ctx`` on the returned manager so the
-        body can propagate further downstream.  No-op (and ``.ctx`` is
-        None) when the tracer is disabled or ``ctx`` is None."""
-        if not self.enabled or ctx is None:
-            return _NULL_CTX
-        from ray_lightning_tpu.telemetry.propagate import (
-            child_context, trace_args,
-        )
+        body can propagate further downstream.  With the tracer
+        disabled or ``ctx`` None no span is recorded and ``.ctx`` is
+        None (the annotation ``rlt:request/<name>`` there is always)."""
+        tracer, child = _DETACHED, None
+        if self.enabled and ctx is not None:
+            from ray_lightning_tpu.telemetry.propagate import (
+                child_context, trace_args,
+            )
 
-        child = child_context(ctx)
-        return _SpanCtx(self, name, trace_args(child, **args), ctx=child)
+            tracer, child = self, child_context(ctx)
+            args = trace_args(child, **args)
+        ph = tracer.phase(name, "request", **args)
+        ph.ctx = child
+        return ph
 
     def instant(self, name: str, **args) -> None:
         """Zero-duration metadata marker (e.g. the grad-sync plan)."""
@@ -251,3 +339,14 @@ class SpanTracer:
         with open(path, "w") as f:
             json.dump(doc, f)
         return len(doc["traceEvents"])
+
+
+_DETACHED = SpanTracer(enabled=False, maxlen=1)
+
+
+def phase(name: str, layer: str = "",
+          sink: Optional[Dict[str, Any]] = None, prefix: str = "",
+          **args) -> _PhaseCtx:
+    """:meth:`SpanTracer.phase` for a call site that has no tracer of
+    its own (``ledgered_jit``): annotation and counter, never a span."""
+    return _DETACHED.phase(name, layer, sink, prefix, **args)

@@ -146,7 +146,7 @@ def test_bgmv_pallas_compiles_for_eight_rows(one_chip):
     from ray_lightning_tpu.ops.lora import bgmv_pallas
 
     W, r, n_adapters = 8, 16, 4
-    _compile(
+    assert "rlt_lora_bgmv" in _compile(
         bgmv_pallas,
         _sds((W, D), jnp.bfloat16, one_chip),
         _sds((n_adapters, D, r), jnp.bfloat16, one_chip),
@@ -194,10 +194,10 @@ def test_sharded_ce_island_compiles_under_data4_mesh(data4):
 
 # -- the whole single-chip train step ---------------------------------------
 
-def test_gpt2_small_train_step_compiles_and_fits(one_chip):
+@pytest.fixture(scope="module")
+def small_step(one_chip):
     """``Trainer.fit``'s single-device step program for GPT-2-small
-    (bf16, remat, B=16): every kernel present, and arguments plus
-    temporaries inside the chip's 16 GB."""
+    (bf16, remat, B=16), compiled once for the file."""
     from types import SimpleNamespace
 
     from ray_lightning_tpu.core.module import TrainState
@@ -220,14 +220,38 @@ def test_gpt2_small_train_step_compiles_and_fits(one_chip):
     )
     batch = {"tokens": _sds((B, cfg.seq_len + 1), jnp.int32, one_chip)}
     rng = _sds((2,), jnp.uint32, one_chip)
-    compiled = jax.jit(
+    return jax.jit(
         _single_device_raw_step(module, tx), donate_argnums=0
     ).lower(state, batch, rng).compile()
-    text = compiled.as_text()
+
+
+def test_gpt2_small_train_step_compiles_and_fits(small_step):
+    """Every kernel present, and arguments plus temporaries inside the
+    chip's 16 GB."""
+    text = small_step.as_text()
     # flash fwd + dq + dkdv, CE fwd + dx + dw, LN fwd + bwd (several
     # sites each, scanned): at least 8 distinct Mosaic calls.
     assert text.count("tpu_custom_call") >= 8
-    mem = compiled.memory_analysis()
+    mem = small_step.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert total < 16e9, f"step needs {total / 1e9:.1f} GB"
+
+
+@pytest.mark.parametrize("kernel", [
+    "rlt_flash_fwd", "rlt_flash_bwd", "rlt_ce_fwd", "rlt_ce_bwd_dx",
+    "rlt_ce_bwd_dw", "rlt_ln_fwd", "rlt_ln_bwd",
+])
+def test_step_program_names_its_kernels(small_step, kernel):
+    """``pallas_call(name=...)`` reaches the compiled program: the
+    Mosaic custom call is an instruction named after the kernel
+    (wrapped in the transformation's name where one applies:
+    ``jvp_rlt_ce_fwd_``), which is what a device trace's ``XLA Ops``
+    event shows.  The device metrics match on it."""
+    import re
+
+    heads = [line.split(" custom-call(")[0].split(" = ")[0].strip()
+             for line in small_step.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    rx = re.compile(rf"^(ROOT )?%\w*{kernel}[_.\d]*$")
+    assert any(rx.match(h) for h in heads), (kernel, sorted(set(heads)))

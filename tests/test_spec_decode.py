@@ -625,9 +625,9 @@ class TestClientVariableWidth:
         seen = []
         orig = eng._handle_queue_request
 
-        def spy(item):
+        def spy(item, *recv_t):
             seen.append(item)
-            orig(item)
+            orig(item, *recv_t)
 
         eng._handle_queue_request = spy
         client = ServeClient(eng.queue_handle())

@@ -36,10 +36,6 @@ from ray_lightning_tpu.telemetry.schema import (
     validate_chrome_trace,
     validate_span_jsonl,
 )
-from ray_lightning_tpu.telemetry.trace_parse import (
-    bucket_totals,
-    collect_file,
-)
 
 from utils import get_trainer
 
@@ -102,21 +98,6 @@ def test_span_exports_schema_validate(tmp_path):
     assert validate_chrome_trace(doc) == []
     # Chrome events are µs and carry the rank as pid.
     assert all(ev["pid"] == 1 for ev in doc["traceEvents"])
-
-
-def test_trace_parse_roundtrip(tmp_path):
-    tracer = SpanTracer(enabled=True)
-    with tracer.span("dot_general"):
-        time.sleep(0.002)
-    with tracer.span("copy.3"):
-        pass
-    path = str(tmp_path / "trace.json")
-    tracer.export_chrome(path)
-    durs = collect_file(path)
-    assert set(durs) == {"dot_general", "copy.3"}
-    buckets = bucket_totals(durs)
-    assert buckets["matmul"] == durs["dot_general"]
-    assert buckets["layout"] == durs["copy.3"]
 
 
 # ---------------------------------------------------------------------------

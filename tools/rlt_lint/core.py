@@ -854,7 +854,8 @@ _PKG = "ray_lightning_tpu"
 #: RLT001 — no jit construction inside these (request/step/tick paths).
 _HOT_JIT = {
     f"{_PKG}/serve/engine.py": frozenset({
-        "ServeEngine.step", "ServeEngine._decode_tick",
+        "ServeEngine.step", "ServeEngine._step",
+        "ServeEngine._decode_tick",
         "ServeEngine._spec_tick", "ServeEngine._tick_widths",
         "ServeEngine._tick_top_ks", "ServeEngine._complete",
         "ServeEngine._handle_queue_request",
@@ -922,7 +923,8 @@ _HOT_JIT = {
 #: export-to-host), so only the decode/step/instruction loops gate.
 _HOT_SYNC = {
     f"{_PKG}/serve/engine.py": frozenset({
-        "ServeEngine.step", "ServeEngine._decode_tick",
+        "ServeEngine.step", "ServeEngine._step",
+        "ServeEngine._decode_tick",
         "ServeEngine._spec_tick", "ServeEngine._lora_operands",
         # Chunk ticks interleave with decode: a host sync per chunk
         # (beyond the final-chunk TTFT sync, which carries a noqa)
