@@ -22,7 +22,7 @@ __all__ = ["kernel_family_disabled", "_interpret"]
 
 def kernel_family_disabled(family: str) -> bool:
     """A/B switch for on-hardware kernel experiments: set
-    ``RLT_DISABLE_KERNELS=ce,ln,flash`` (any subset) to force the
+    ``RLT_DISABLE_KERNELS=ce,ln,flash,paged`` (any subset) to force the
     XLA path for those kernel families.  Read per call, so one
     process can bench both arms."""
     raw = os.environ.get("RLT_DISABLE_KERNELS", "")

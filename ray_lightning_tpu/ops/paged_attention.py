@@ -1,0 +1,297 @@
+"""Pallas TPU paged decode attention: one query token per slot against
+the KV blocks that are resident for it, read where they lie in the pool.
+
+The serving cache (``serve/kv_cache.py``) is one pool per tensor,
+``(L, N, Bs, H*Dh)``: a physical block of one layer is ``Bs`` rows of
+all heads side by side, one contiguous DMA.  A decode tick attends one
+new token per slot over positions ``[0, seq_len]``.  The XLA path
+gathers every slot's whole block table (``M x Bs`` positions) and masks
+afterwards; this kernel
+
+* takes ``block_tables``, the number of cache positions each slot holds
+  and the layer index by scalar prefetch, and walks per slot only the
+  ``ceil(seq_len / Bs)`` blocks that hold them (none for an inactive
+  slot), ``pages_per_chunk`` blocks a step, double-buffered: the next
+  chunk (of this slot, or the first of the next slot) is in flight while
+  the current one is computed;
+* leaves the pool in HBM (``memory_space=pl.ANY``) and never writes it:
+  the current token's own K and V rows arrive as operands and open the
+  online softmax as position ``seq_len``, so the caller scatters all
+  layers' rows into the pool once, after the layer loop;
+* computes per-head scores from the ``(T, H*Dh)`` tile on the MXU with
+  a block-diagonal query ``(H, H*Dh)`` (row ``h`` holds head ``h``'s
+  query in its own ``Dh`` columns, zeros elsewhere), and reads the
+  per-head output off the diagonal blocks of ``P @ V``;
+* keeps the XLA path's arithmetic: products of the stored values in
+  float32, softmax statistics and the accumulator in float32.  A float32
+  operand meeting a bfloat16 pool is split into three bfloat16 terms
+  (exact: 3 x 8 significand bits), stacked on the rows of ONE matmul, so
+  the probabilities are never rounded to bfloat16 before they meet V.
+
+Off-TPU the kernel runs under the Pallas interpreter (tests only: the
+serving path takes it on TPU alone, see :func:`paged_decode_supported`).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_lightning_tpu.ops.attention import _NEG_INF
+from ray_lightning_tpu.ops.kernel_probe import (
+    _interpret, kernel_family_disabled,
+)
+
+__all__ = ["paged_decode_attention", "paged_decode_supported"]
+
+# Cache positions one compute step covers (whole blocks): wide enough
+# that the score tile fills the 128 lanes, narrow enough that a slot's
+# last chunk wastes little on positions past its length.
+CHUNK_POSITIONS = 128
+
+
+def _sublane(dtype) -> int:
+    return 16 if dtype == jnp.bfloat16 else 8
+
+
+def paged_decode_supported(pool_k: jax.Array) -> bool:
+    """Whether ``attn_impl="auto"`` takes the kernel: a function of the
+    backend, the pool's shape and dtype and ``RLT_DISABLE_KERNELS``
+    (family ``paged``) — nothing is compiled to find out (the kernel's
+    own refusals are caught by ``tests/test_chip_compile.py``)."""
+    if kernel_family_disabled("paged"):
+        return False
+    if jax.default_backend() != "tpu":
+        return False
+    return paged_decode_tiles(pool_k)
+
+
+def paged_decode_tiles(pool_k: jax.Array) -> bool:
+    """Shapes and dtypes the kernel tiles: ``(L, N, Bs, H*Dh)`` with the
+    row of all heads a multiple of the 128 lanes and a block a whole
+    number of the dtype's sublane tiles."""
+    if pool_k.ndim != 4 or pool_k.dtype not in (jnp.bfloat16, jnp.float32):
+        return False
+    _, _, bs, hd = pool_k.shape
+    return hd % 128 == 0 and bs % _sublane(pool_k.dtype) == 0
+
+
+def _pages_per_chunk(m: int, bs: int) -> int:
+    """Largest divisor of the table width ``m`` whose blocks cover at
+    most ``CHUNK_POSITIONS`` positions (a chunk never straddles the end
+    of a table row)."""
+    p = max(1, min(m, CHUNK_POSITIONS // bs))
+    while m % p:
+        p -= 1
+    return p
+
+
+def _split3(a: jax.Array) -> jax.Array:
+    """float32 ``(R, C)`` -> bfloat16 ``(3R, C)`` whose three row groups
+    sum to ``a`` exactly."""
+    hi = a.astype(jnp.bfloat16)
+    rest = a - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return jnp.concatenate([hi, mid, lo], axis=0)
+
+
+def _dot_f32(a: jax.Array, b: jax.Array, dims) -> jax.Array:
+    """``a . b`` with every product exact in float32 and float32
+    accumulation, whatever the operands' dtypes (see module docstring)."""
+    if b.dtype == jnp.float32:
+        return jax.lax.dot_general(
+            a.astype(jnp.float32), b, dims,
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+    if a.dtype == b.dtype:
+        return jax.lax.dot_general(
+            a, b, dims, preferred_element_type=jnp.float32
+        )
+    r = a.shape[0]
+    parts = jax.lax.dot_general(
+        _split3(a), b, dims, preferred_element_type=jnp.float32
+    )
+    return (parts[2 * r:] + parts[r:2 * r]) + parts[:r]
+
+
+def _kernel(layer_ref, tables_ref, lens_ref,            # scalar prefetch
+            q_ref, kc_ref, vc_ref, k_hbm, v_hbm,        # inputs
+            o_ref,                                      # output
+            kbuf, vbuf, sems, buf_ref,                  # scratch
+            *, n_head, head_dim, rows, scale, pages, table_width,
+            block_size):
+    w = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    P, M, Bs = pages, table_width, block_size
+    T = P * Bs
+    layer = layer_ref[0]
+
+    def n_pages(slot):
+        return jnp.minimum(pl.cdiv(lens_ref[slot], Bs), M)
+
+    def chunk_dma(slot, c, buf, op):
+        """Start or wait the copies of chunk ``c`` of ``slot``: its
+        resident pages only (the rest of the buffer keeps older, finite
+        rows, and the mask hides them)."""
+        n = n_pages(slot)
+        for i in range(P):
+            j = c * P + i
+
+            @pl.when(j < n)
+            def _():
+                blk = tables_ref[slot * M + j]
+                for hbm, vmem, s in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                    copy = pltpu.make_async_copy(
+                        hbm.at[layer, blk],
+                        vmem.at[buf, pl.ds(i * Bs, Bs)],
+                        sems.at[s, buf],
+                    )
+                    getattr(copy, op)()
+
+    @pl.when(w == 0)
+    def _():
+        # Rows no copy has filled yet must be finite: a masked position
+        # contributes 0 x row.
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        buf_ref[0] = 0
+        chunk_dma(0, 0, 0, "start")
+
+    n_vis = lens_ref[w]
+    n_chunks = jnp.maximum(pl.cdiv(n_pages(w), P), 1)
+
+    hd = n_head * head_dim
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 1)
+    diag = (col >= row * head_dim) & (col < (row + 1) * head_dim)
+    q32 = jnp.where(diag, q_ref[0].astype(jnp.float32), 0.0)  # (rows, hd)
+    q_bd = q32.astype(q_ref.dtype)  # exact: a cast back to q's own dtype
+
+    # The current token is position seq_len: it opens the running
+    # softmax (max = its score, sum = 1, accumulator = its V row).
+    m0 = jnp.sum(q32 * kc_ref[0].astype(jnp.float32), axis=1,
+                 keepdims=True) * scale
+    l0 = jnp.ones((rows, 1), jnp.float32)
+    acc0 = jnp.broadcast_to(vc_ref[0].astype(jnp.float32), (rows, hd))
+
+    def body(c, carry):
+        acc, m, l, buf = carry
+        chunk_dma(w, c, buf, "wait")
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            chunk_dma(w, c + 1, 1 - buf, "start")
+
+        @pl.when((c + 1 == n_chunks) & (w + 1 < n_slots))
+        def _():
+            chunk_dma(w + 1, 0, 1 - buf, "start")
+
+        s = _dot_f32(q_bd, kbuf[buf], (((1,), (1,)), ((), ()))) * scale
+        pos = c * T + jax.lax.broadcasted_iota(jnp.int32, (rows, T), 1)
+        s = jnp.where(pos < n_vis, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_new = acc * corr + _dot_f32(
+            p, vbuf[buf], (((1,), (0,)), ((), ()))
+        )
+        return acc_new, m_new, l_new, 1 - buf
+
+    acc, _, l, buf = jax.lax.fori_loop(
+        0, n_chunks, body, (acc0, m0, l0, buf_ref[0])
+    )
+    buf_ref[0] = buf
+    o_ref[0] = jnp.sum(jnp.where(diag, acc / l, 0.0), axis=0, keepdims=True)
+
+
+def paged_decode_attention(
+    q: jax.Array,
+    k_cur: jax.Array,
+    v_cur: jax.Array,
+    k_pool: jax.Array,
+    v_pool: jax.Array,
+    layer: jax.Array,
+    block_tables: jax.Array,
+    seq_lens: jax.Array,
+    *,
+    n_head: int,
+    scale: float,
+) -> jax.Array:
+    """Attention of one new token per slot over its paged cache.
+
+    Args:
+        q: ``(W, H*Dh)`` queries (any float dtype).
+        k_cur, v_cur: ``(W, H*Dh)`` the new token's own K and V rows in
+            the pool's dtype (what the caller will store at position
+            ``seq_lens[w]``); the pool is not read there.
+        k_pool, v_pool: ``(L, N, Bs, H*Dh)``, read at ``layer`` only.
+        layer: scalar int32 layer index.
+        block_tables: ``(W, M)`` int32 physical block ids.
+        seq_lens: ``(W,)`` int32 cache positions already in the pool per
+            slot; positions ``[0, seq_lens[w])`` of the pool are visible
+            (clamped to the table's ``M * Bs``).
+
+    Returns:
+        ``(W, H*Dh)`` float32, head ``h`` in columns ``[h*Dh, (h+1)*Dh)``.
+    """
+    W, hd = q.shape
+    _, _, Bs, _ = k_pool.shape
+    M = block_tables.shape[1]
+    if not paged_decode_tiles(k_pool) or k_pool.shape[3] != hd:
+        raise ValueError(
+            f"rlt_paged_decode does not tile a {k_pool.dtype} pool of "
+            f"shape {k_pool.shape} for queries of width {hd}: take the "
+            f"XLA path (attn_impl='xla' or 'auto')"
+        )
+    P = _pages_per_chunk(M, Bs)
+    rows = -(-n_head // 16) * 16  # whole bf16 sublane tiles, _split3 stacks
+    kernel = functools.partial(
+        _kernel, n_head=n_head, head_dim=hd // n_head, rows=rows,
+        scale=scale, pages=P, table_width=M, block_size=Bs,
+    )
+    row_spec = pl.BlockSpec((1, 1, hd), lambda w, *_: (w, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(W,),
+        in_specs=[
+            row_spec, row_spec, row_spec,
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=row_spec,
+        scratch_shapes=[
+            pltpu.VMEM((2, P * Bs, hd), k_pool.dtype),
+            pltpu.VMEM((2, P * Bs, hd), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((W, 1, hd), jnp.float32),
+        # Slots run in order: each step leaves the next slot's first
+        # chunk in flight and the buffer index in SMEM.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        interpret=_interpret(),
+        name="rlt_paged_decode",
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        block_tables.astype(jnp.int32).reshape(-1),
+        jnp.minimum(seq_lens.astype(jnp.int32), M * Bs),
+        q.reshape(W, 1, hd),
+        k_cur.reshape(W, 1, hd),
+        v_cur.reshape(W, 1, hd),
+        k_pool,
+        v_pool,
+    )
+    return out.reshape(W, hd)

@@ -1339,6 +1339,15 @@ class ServeEngine:
         ph.then("emit")
         dt = time.monotonic() - t0
         self.stats.bump("decode_steps")
+        # What the paged decode kernel had to read against what the
+        # slots' tables span: host integers, before the lengths advance.
+        # A slot at seq_len holds positions [0, seq_len] in
+        # seq_len // Bs + 1 blocks, the one being written included.
+        resident = self.scheduler.seq_lens[active] // self.config.block_size
+        self.stats.bump_many({  # rlt: noqa[RLT002] host ints, no device value
+            "decode_kv_blocks_read": int(resident.sum()) + len(active),
+            "decode_kv_blocks_table": self.scheduler.block_tables.size,
+        })
         # Tick wall in µs — with decode_steps/tokens_out it gives the
         # capacity oracle per-bin (busy slots, tick cost) pairs, the
         # data its affine tick-cost fit needs (serve/capacity.py).

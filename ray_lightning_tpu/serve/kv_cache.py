@@ -7,7 +7,10 @@ lifetime, and a new request could not join until the whole batch
 finished.  The serving cache is instead a pool of fixed-size token
 blocks (the vLLM/PagedAttention layout, TPU-shaped):
 
-* **pool** — ``k``/``v`` each ``(L, num_blocks, block_size, H, Dh)``.
+* **pool** — ``k``/``v`` each ``(L, num_blocks, block_size, H*Dh)``:
+  a position's heads lie side by side in one row, so a block of one
+  layer is one contiguous, tile-dense ``(block_size, H*Dh)`` slab (a
+  minor ``(H, Dh)`` pair would pad every bf16 tile of (16, 128)).
   One allocation for the whole server, sized by memory, not by batch;
 * **block tables** — per-slot ``(max_blocks_per_seq,)`` int32 rows
   mapping a sequence's logical block index → physical pool block.
@@ -27,15 +30,22 @@ Device programs (pure functions, jitted by the engine):
   stacked-layer block scan the static path uses
   (``generate._trunk_blocks``), then the per-layer k/v scattered into
   the sequence's pool blocks.  Compiled once per bucket length;
-* :func:`paged_decode_step` — one token for EVERY slot: scatter the new
-  k/v into each slot's current block, gather each slot's blocks, and
-  attend under a ``position <= seq_len`` mask.  ONE fixed-width program
-  for the server's lifetime.
+* :func:`paged_decode_step` — one token for EVERY slot, attended over
+  positions ``[0, seq_len]``.  ONE fixed-width program for the server's
+  lifetime, in one of two forms chosen from the backend, the pool's
+  shape and its dtype (``attn_impl="auto"``): on TPU the pool stays
+  where it is — the ``rlt_paged_decode`` kernel
+  (``ops/paged_attention.py``) reads only the blocks ``seq_lens`` says
+  are resident, straight from the pool, takes the new token's own k/v
+  as operands, and all layers' new rows are scattered into the donated
+  pool once after the layer loop; elsewhere (CPU, shapes the kernel
+  does not tile) the XLA path scatters the new k/v per layer, gathers
+  each slot's whole table and masks.
 
-Numerics match the contiguous path by construction: the gather lays a
-sequence's blocks back into logical order, the mask hides exactly the
-slots the static path's causal mask hides, and scores/softmax/PV stay
-f32 (see ``generate._block_pass``).
+Numerics match the contiguous path by construction: both forms lay a
+sequence's blocks back into logical order, hide exactly the slots the
+static path's causal mask hides, and keep scores/softmax/PV in f32
+(see ``generate._block_pass``).
 """
 
 from __future__ import annotations
@@ -220,9 +230,10 @@ def truncate_to(
 class PagedKVCache:
     """The device block pool + its allocator.
 
-    ``pool`` is a ``{"k", "v"}`` dict of ``(L, N, Bs, H, Dh)`` arrays —
-    the same stacked-layer leading axis as the static cache, so the
-    layer scan is shared.  The engine owns the authoritative pool arrays
+    ``pool`` is a ``{"k", "v"}`` dict of ``(L, N, Bs, H*Dh)`` arrays —
+    the same stacked-layer leading axis as the static cache, indexed
+    ``[layer, block, offset]``; a row holds the position's heads side
+    by side (head ``h`` in columns ``[h*Dh, (h+1)*Dh)``).  The engine owns the authoritative pool arrays
     (they flow through the donated compiled steps); this object carries
     the geometry and the allocator.
     """
@@ -240,7 +251,7 @@ class PagedKVCache:
     def init_pool(self) -> Dict[str, jax.Array]:
         cfg = self.cfg
         shape = (cfg.n_layer, self.num_blocks, self.block_size,
-                 cfg.n_head, cfg.head_dim)
+                 cfg.n_head * cfg.head_dim)
         return {"k": jnp.zeros(shape, self.dtype),
                 "v": jnp.zeros(shape, self.dtype)}
 
@@ -259,7 +270,9 @@ class PagedKVCache:
         whatever free blocks ITS allocator hands out
         (:func:`import_blocks`) — physical ids never cross the wire,
         only logical block content, so producer and consumer pools
-        need not agree on anything but geometry.
+        need not agree on anything but geometry.  The payload is
+        ``(L, n, Bs, H, Dh)`` per tensor whatever the pool's own row
+        layout.
         """
         import numpy as np
 
@@ -269,7 +282,11 @@ class PagedKVCache:
             raise ValueError(
                 f"export_blocks: ids outside (trash, {self.num_blocks})"
             )
-        return {key: np.asarray(pool[key][:, ids]) for key in ("k", "v")}
+        cfg = self.cfg
+        wire = (cfg.n_layer, ids.size, self.block_size, cfg.n_head,
+                cfg.head_dim)
+        return {key: np.asarray(pool[key][:, ids]).reshape(wire)
+                for key in ("k", "v")}
 
 
 def import_blocks(
@@ -282,14 +299,19 @@ def import_blocks(
     compiles one executable per bucket block count, exactly like the
     bucketed prefill set, so steady-state imports never recompile).
 
-    ``block_ids`` come from the CONSUMER's allocator (never the trash
+    The payload is the wire's ``(L, n, Bs, H, Dh)`` per tensor
+    (:meth:`PagedKVCache.export_blocks`); the heads are folded into the
+    pool's rows here.  ``block_ids`` come from the CONSUMER's allocator
+    (never the trash
     block — the allocator cannot issue it), and the caller rewrites the
     slot's block table to these ids, so every trash-block invariant of
     the decode/verify programs is preserved by construction.
     """
     return {
         key: pool[key].at[:, block_ids].set(
-            payload[key].astype(pool[key].dtype)
+            payload[key].astype(pool[key].dtype).reshape(
+                payload[key].shape[:3] + (-1,)
+            )
         )
         for key in ("k", "v")
     }
@@ -560,6 +582,12 @@ class PrefixIndex:
         }
 
 
+def _heads(cfg: GPTConfig, z: jax.Array) -> jax.Array:
+    """``(..., H*Dh)`` rows (the pool's, or a projection's) as
+    ``(..., H, Dh)``."""
+    return z.reshape(z.shape[:-1] + (cfg.n_head, cfg.head_dim))
+
+
 def paged_prefill(
     cfg: GPTConfig,
     params: Dict[str, Any],
@@ -628,7 +656,7 @@ def paged_prefill(
     out = {}
     for key in ("k", "v"):
         per_block = tmp[key][:, 0].reshape(
-            cfg.n_layer, n, Bs, cfg.n_head, cfg.head_dim
+            cfg.n_layer, n, Bs, cfg.n_head * cfg.head_dim
         )
         out[key] = pool[key].at[:, block_ids].set(per_block)
     return logits, out
@@ -646,6 +674,7 @@ def paged_decode_step(
     adapters: Optional[Dict[str, jax.Array]] = None,
     adapter_ids: Optional[jax.Array] = None,
     lora_impl: str = "xla",
+    attn_impl: str = "auto",
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One token for every slot of the fixed-width active set.
 
@@ -663,23 +692,39 @@ def paged_decode_step(
             The draft chain of the speculative path dispatches this
             program at positions past some slots' allocated coverage
             (uniform chain length over non-uniform per-slot widths);
-            the limit redirects those strays.  ``None`` = the plain
-            serve decode program, graph-identical to pre-spec rounds.
+            the limit redirects those strays (their logits are not
+            used).  ``None`` = the plain serve decode program.
         adapters: optional stacked per-layer LoRA factor buffers
-            (``serve/lora.py`` pool; leading axis L rides the scan
-            like the KV pool) with per-slot ``adapter_ids`` int32 —
-            each slot's own adapter delta lands on its qkv/proj
-            projections (slot 0 = zero delta).  ``None`` = the
-            pre-LoRA program, byte-identical.
+            (``serve/lora.py`` pool; leading axis L rides the scan)
+            with per-slot ``adapter_ids`` int32 — each slot's own
+            adapter delta lands on its qkv/proj projections (slot 0 =
+            zero delta).  ``None`` = the pre-LoRA program.
+        attn_impl: ``"auto"`` takes the ``rlt_paged_decode`` kernel
+            where ``ops.paged_attention.paged_decode_supported`` says
+            so (TPU, a pool it tiles) and the XLA gather elsewhere;
+            ``"xla"`` / ``"pallas"`` name one (off TPU the kernel runs
+            under the Pallas interpreter: tests).
 
     Returns:
         ``(logits (W, V) f32, updated pool)``.
 
     ONE compiled program for any mix of sequence lengths: the per-slot
-    write position, the gather, and the visibility mask are all data,
-    never shapes — join-on-arrival/evict-on-finish between steps only
-    changes operand VALUES, so steady-state serving never recompiles.
+    write position, the blocks read, and the visibility mask are all
+    data, never shapes — join-on-arrival/evict-on-finish between steps
+    only changes operand VALUES, so steady-state serving never
+    recompiles.
     """
+    from ray_lightning_tpu.ops.paged_attention import (
+        paged_decode_attention, paged_decode_supported,
+    )
+
+    if attn_impl == "auto":
+        attn_impl = "pallas" if paged_decode_supported(pool["k"]) else "xla"
+    if attn_impl not in ("xla", "pallas"):
+        raise ValueError(
+            f"Unknown paged attention impl {attn_impl!r} (auto|xla|pallas)"
+        )
+    kernel = attn_impl == "pallas"
     c = compute_dtype
     Bs = pool["k"].shape[2]
     W, M = block_tables.shape
@@ -693,7 +738,7 @@ def paged_decode_step(
     blk_idx = pos // Bs
     if write_limit is not None:
         # Chain positions may run past the table width; the clamp keeps
-        # the gather in bounds and the limit sends the write to trash.
+        # the lookup in bounds and the limit sends the write to trash.
         blk_idx = jnp.minimum(blk_idx, M - 1)
     write_blk = jnp.take_along_axis(
         block_tables, blk_idx[:, None], axis=1
@@ -702,43 +747,49 @@ def paged_decode_step(
         write_blk = jnp.where(pos < write_limit, write_blk, TRASH_BLOCK)
     write_off = pos % Bs
     scale = cfg.head_dim ** -0.5
-    # Visible: cache positions [0, pos] inclusive — the current token's
-    # k/v are written before the gather, exactly the static path's
-    # causal frontier.
-    visible = jnp.arange(S)[None, :] <= pos[:, None]
 
-    def block(carry, layer):
-        x, = carry
-        if adapters is None:
-            p, k_pool, v_pool = layer  # (N, Bs, H, Dh) each
-            ad = None
-        else:
-            p, k_pool, v_pool, ad = layer
-        h = _layer_norm(x, p["ln1_g"], p["ln1_b"])
-        qkv = h @ resolve_weight(p, "qkv_w", c) + p["qkv_b"].astype(c)
-        qkv = apply_lora(qkv, h, ad, "qkv", adapter_ids, lora_impl)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-
-        def heads(z):
-            return z.reshape(W, cfg.n_head, cfg.head_dim)
-
-        k_pool = k_pool.at[write_blk, write_off].set(
-            heads(k).astype(k_pool.dtype)
-        )
-        v_pool = v_pool.at[write_blk, write_off].set(
-            heads(v).astype(v_pool.dtype)
-        )
-        ctx_k = k_pool[block_tables].reshape(W, S, cfg.n_head, cfg.head_dim)
-        ctx_v = v_pool[block_tables].reshape(W, S, cfg.n_head, cfg.head_dim)
+    def gathered_attention(q, k_pool, v_pool):
+        # Visible: cache positions [0, pos] inclusive — the current
+        # token's own k/v count, exactly the static path's causal
+        # frontier.  This form writes them before its gather; the
+        # kernel takes them as operands beside the pool's [0, pos).
+        visible = jnp.arange(S)[None, :] <= pos[:, None]
+        ctx_k = _heads(cfg, k_pool[block_tables].reshape(W, S, cfg.d_model))
+        ctx_v = _heads(cfg, v_pool[block_tables].reshape(W, S, cfg.d_model))
         scores = jnp.einsum(
-            "whd,wshd->whs", heads(q).astype(jnp.float32),
+            "whd,wshd->whs", _heads(cfg, q).astype(jnp.float32),
             ctx_k.astype(jnp.float32),
         ) * scale
         scores = jnp.where(visible[:, None, :], scores, _NEG_INF)
         probs = jax.nn.softmax(scores, axis=-1)
-        att = jnp.einsum(
+        return jnp.einsum(
             "whs,wshd->whd", probs, ctx_v.astype(jnp.float32)
-        ).reshape(W, cfg.d_model).astype(c)
+        ).reshape(W, cfg.d_model)
+
+    def block(carry, layer):
+        x, = carry
+        # kv: the layer's own (N, Bs, H*Dh) slices of the pool (XLA),
+        # or the layer's index into the whole pool (kernel).
+        p, kv, ad = layer
+        h = _layer_norm(x, p["ln1_g"], p["ln1_b"])
+        qkv = h @ resolve_weight(p, "qkv_w", c) + p["qkv_b"].astype(c)
+        qkv = apply_lora(qkv, h, ad, "qkv", adapter_ids, lora_impl)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        k = k.astype(pool["k"].dtype)
+        v = v.astype(pool["v"].dtype)
+        if kernel:
+            att = paged_decode_attention(
+                q, k, v, pool["k"], pool["v"], kv, block_tables, pos,
+                n_head=cfg.n_head, scale=scale,
+            )
+            out = (k, v)
+        else:
+            k_pool, v_pool = kv
+            k_pool = k_pool.at[write_blk, write_off].set(k)
+            v_pool = v_pool.at[write_blk, write_off].set(v)
+            att = gathered_attention(q, k_pool, v_pool)
+            out = (k_pool, v_pool)
+        att = att.astype(c)
         proj = att @ resolve_weight(p, "proj_w", c) + p["proj_b"].astype(c)
         proj = apply_lora(proj, att, ad, "proj", adapter_ids, lora_impl)
         x = x + proj
@@ -749,14 +800,26 @@ def paged_decode_step(
             x = x2[:, 0]
         else:
             x = _mlp_residual(x, p, c)
-        return (x,), (k_pool, v_pool)
+        return (x,), out
 
-    xs = (params["blocks"], pool["k"], pool["v"])
-    if adapters is not None:
-        xs = xs + (adapters,)
-    (x,), (k_new, v_new) = jax.lax.scan(block, (x,), xs)
+    if kernel:
+        # The pool is closed over, read-only inside the loop: no layer
+        # of it is sliced out or written back.  The L x W new rows go
+        # into the (donated) pool in one scatter afterwards.
+        kv = jnp.arange(cfg.n_layer, dtype=jnp.int32)
+    else:
+        kv = (pool["k"], pool["v"])
+    (x,), (k_out, v_out) = jax.lax.scan(
+        block, (x,), (params["blocks"], kv, adapters)
+    )
+    if kernel:
+        # Every index explicit, one row a window: a slice over the
+        # layer axis makes XLA re-lay the whole pool around the scatter.
+        at = (kv[:, None], write_blk[None, :], write_off[None, :])
+        k_out = pool["k"].at[at].set(k_out)
+        v_out = pool["v"].at[at].set(v_out)
     logits = _head_logits(params, x, c)
-    return logits, {"k": k_new, "v": v_new}
+    return logits, {"k": k_out, "v": v_out}
 
 
 def paged_verify_step(
@@ -826,29 +889,21 @@ def paged_verify_step(
 
     def block(carry, layer):
         x, = carry
-        if adapters is None:
-            p, k_pool, v_pool = layer  # (N, Bs, H, Dh) each
-            ad = None
-        else:
-            p, k_pool, v_pool, ad = layer
+        p, k_pool, v_pool, ad = layer  # pools (N, Bs, H*Dh) each
         h = _layer_norm(x, p["ln1_g"], p["ln1_b"])
         qkv = h @ resolve_weight(p, "qkv_w", c) + p["qkv_b"].astype(c)
         qkv = apply_lora(qkv, h, ad, "qkv", adapter_ids, lora_impl)
         q, k, v = jnp.split(qkv, 3, axis=-1)
-
-        def heads(z):
-            return z.reshape(W, T, cfg.n_head, cfg.head_dim)
-
         k_pool = k_pool.at[write_blk, write_off].set(
-            heads(k).astype(k_pool.dtype)
+            k.astype(k_pool.dtype)
         )
         v_pool = v_pool.at[write_blk, write_off].set(
-            heads(v).astype(v_pool.dtype)
+            v.astype(v_pool.dtype)
         )
-        ctx_k = k_pool[block_tables].reshape(W, S, cfg.n_head, cfg.head_dim)
-        ctx_v = v_pool[block_tables].reshape(W, S, cfg.n_head, cfg.head_dim)
+        ctx_k = _heads(cfg, k_pool[block_tables].reshape(W, S, cfg.d_model))
+        ctx_v = _heads(cfg, v_pool[block_tables].reshape(W, S, cfg.d_model))
         scores = jnp.einsum(
-            "wthd,wshd->whts", heads(q).astype(jnp.float32),
+            "wthd,wshd->whts", _heads(cfg, q).astype(jnp.float32),
             ctx_k.astype(jnp.float32),
         ) * scale
         scores = jnp.where(visible[:, None], scores, _NEG_INF)
@@ -866,10 +921,9 @@ def paged_verify_step(
             x = _mlp_residual(x, p, c)
         return (x,), (k_pool, v_pool)
 
-    xs = (params["blocks"], pool["k"], pool["v"])
-    if adapters is not None:
-        xs = xs + (adapters,)
-    (x,), (k_new, v_new) = jax.lax.scan(block, (x,), xs)
+    (x,), (k_new, v_new) = jax.lax.scan(
+        block, (x,), (params["blocks"], pool["k"], pool["v"], adapters)
+    )
     logits = _head_logits(params, x, c)
     return logits, {"k": k_new, "v": v_new}
 

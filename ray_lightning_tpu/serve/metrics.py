@@ -36,6 +36,9 @@ _COUNTER_KEYS = (
     # that did not occur in it.
     "ticks", "tick_us", *(f"tick_{p}_us" for p in PHASES["serve"]),
     "queue_wait_us",
+    # Per decode tick: blocks that hold the active slots' visible
+    # positions, and the W x M entries of the block tables.
+    "decode_kv_blocks_read", "decode_kv_blocks_table",
 )
 
 

@@ -28,6 +28,10 @@ from jax.sharding import PartitionSpec as P
 # GPT-2-small training shapes (B=16, T=1024, d=768, H=12, V=50304).
 B, T, D, H, DH, V = 16, 1024, 768, 12, 64, 50304
 N_TOK = B * T  # 16384 tokens -> 32 LayerNorm/CE token blocks
+# The serve cell (benchmarks/workloads/gpt2-large.serve-long.json):
+# GPT-2-large, 16 slots x 32 table entries of 32-position blocks, the
+# engine's default pool of 545 blocks.
+SERVE = dict(n_layer=36, n_head=20, W=16, M=32, Bs=32, N=545)
 
 
 @pytest.fixture(scope="module")
@@ -155,6 +159,29 @@ def test_bgmv_pallas_compiles_for_eight_rows(one_chip):
     )
 
 
+@pytest.mark.parametrize("pool,q", [
+    (jnp.bfloat16, jnp.bfloat16), (jnp.float32, jnp.float32),
+    (jnp.bfloat16, jnp.float32),
+], ids=["bf16", "f32", "f32_query_bf16_pool"])
+def test_paged_decode_kernel_compiles_at_serve_cell_shapes(one_chip, pool, q):
+    """One layer's paged decode attention at ``gpt2-large.serve-long``'s
+    shapes, in each mix of dtypes the kernel takes (a float32 operand
+    against a bf16 pool goes through the three-term split)."""
+    from ray_lightning_tpu.ops.paged_attention import paged_decode_attention
+
+    hd = SERVE["n_head"] * DH
+    row = _sds((SERVE["W"], hd), pool, one_chip)
+    kv = _sds((SERVE["n_layer"], SERVE["N"], SERVE["Bs"], hd), pool, one_chip)
+    assert "rlt_paged_decode" in _compile(
+        lambda *a: paged_decode_attention(
+            *a, n_head=SERVE["n_head"], scale=DH ** -0.5),
+        _sds((SERVE["W"], hd), q, one_chip), row, row, kv, kv,
+        _sds((), jnp.int32, one_chip),
+        _sds((SERVE["W"], SERVE["M"]), jnp.int32, one_chip),
+        _sds((SERVE["W"],), jnp.int32, one_chip),
+    )
+
+
 # -- kernels under a four-chip data mesh ------------------------------------
 
 def test_flash_compiles_under_data4_mesh(data4):
@@ -255,3 +282,71 @@ def test_step_program_names_its_kernels(small_step, kernel):
              if "tpu_custom_call" in line]
     rx = re.compile(rf"^(ROOT )?%\w*{kernel}[_.\d]*$")
     assert any(rx.match(h) for h in heads), (kernel, sorted(set(heads)))
+
+
+# -- the serve cell's decode program ----------------------------------------
+
+@pytest.fixture(scope="module")
+def serve_decode(one_chip):
+    """``paged_decode_step`` as the engine jits it (pool donated) for
+    GPT-2-large at the serve cell's shapes: float32 params, bf16
+    compute, bf16 pool."""
+    from ray_lightning_tpu.models import GPT, GPTConfig
+    from ray_lightning_tpu.serve.kv_cache import (
+        PagedKVCache, paged_decode_step,
+    )
+
+    cfg = GPTConfig(n_layer=SERVE["n_layer"], n_head=SERVE["n_head"],
+                    d_model=SERVE["n_head"] * DH)
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda l: _sds(l.shape, l.dtype, one_chip), tree)
+
+    params = abstract(jax.eval_shape(
+        GPT(cfg, attn_impl="auto").init_params, jax.random.PRNGKey(0)))
+    pool = abstract(jax.eval_shape(
+        PagedKVCache(cfg, SERVE["N"], SERVE["Bs"], jnp.bfloat16).init_pool))
+
+    def step(params, pool, tables, seq_lens, tokens):
+        return paged_decode_step(cfg, params, pool, tables, seq_lens,
+                                 tokens, compute_dtype=jnp.bfloat16)
+
+    return jax.jit(step, donate_argnums=1).lower(
+        params, pool, _sds((SERVE["W"], SERVE["M"]), jnp.int32, one_chip),
+        _sds((SERVE["W"],), jnp.int32, one_chip),
+        _sds((SERVE["W"],), jnp.int32, one_chip),
+    ).compile()
+
+
+def test_serve_decode_step_compiles_names_its_kernel_and_fits(serve_decode):
+    """The kernel is in the program under its name, and arguments plus
+    temporaries need less than the parent's whole-table gather did
+    (12.90 GB, the same compile at PR 24's tree; the chip has 15.75)."""
+    assert "%rlt_paged_decode" in serve_decode.as_text()
+    mem = serve_decode.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 12.9e9, f"decode needs {total / 1e9:.2f} GB"
+
+
+@pytest.mark.parametrize("opcode", ["copy", "convert", "gather",
+                                    "dynamic-slice"])
+def test_serve_decode_step_moves_no_pool_layer(serve_decode, opcode):
+    """No operation of the decode program copies, converts, gathers or
+    slices out a pool layer, the whole pool, or every slot's whole table
+    of positions: the whole-pool copy is found here, before chip time."""
+    import re
+
+    hd = SERVE["n_head"] * DH
+    layer = SERVE["N"] * SERVE["Bs"] * hd
+    whole = {layer, SERVE["n_layer"] * layer,
+             SERVE["W"] * SERVE["M"] * SERVE["Bs"] * hd}
+    rx = re.compile(
+        r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* ([\w\-]+)\(", re.M)
+    found = [m.group(0).strip()[:160]
+             for m in rx.finditer(serve_decode.as_text())
+             if m.group(2).startswith(opcode)
+             and int(np.prod([int(d) for d in m.group(1).split(",")]))
+             in whole]
+    assert not found, found

@@ -376,6 +376,7 @@ KERNELS = {
     "cross_entropy.py": {"rlt_ce_fwd", "rlt_ce_bwd_dx", "rlt_ce_bwd_dw"},
     "layer_norm.py": {"rlt_ln_fwd", "rlt_ln_bwd"},
     "lora.py": {"rlt_lora_bgmv"},
+    "paged_attention.py": {"rlt_paged_decode"},
 }
 
 

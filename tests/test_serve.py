@@ -232,9 +232,10 @@ class TestPagedParity:
         _, ref_cache = prefill(cfg, params, ref_cache,
                                jnp.asarray(padded[None, :5]))
         got_k = np.asarray(pool["k"][:, ids[0], :5])
+        # A pool row holds the position's heads side by side.
+        want_k = np.asarray(ref_cache["k"][:, 0, :5])
         np.testing.assert_allclose(
-            got_k, np.asarray(ref_cache["k"][:, 0, :5]),
-            rtol=1e-5, atol=1e-5,
+            got_k, want_k.reshape(got_k.shape), rtol=1e-5, atol=1e-5,
         )
 
     @pytest.mark.slow  # tier-1 diet (round 20): ~11s token-by-token

@@ -725,7 +725,9 @@ class TestKVExportImport:
             for k in ("k", "v")
         }
         src_ids = [3, 5]
-        pool = {k: pool[k].at[:, jnp.asarray(src_ids)].set(content[k])
+        # The wire keeps heads apart; a pool row holds them side by side.
+        pool = {k: pool[k].at[:, jnp.asarray(src_ids)].set(
+                    content[k].reshape(content[k].shape[:3] + (-1,)))
                 for k in pool}
         exported = cache.export_blocks(pool, src_ids)
         for k in ("k", "v"):
