@@ -1,5 +1,6 @@
 from .boring import BoringModel, BoringDataModule, XORModel, XORDataModule
 from .data_text import ByteLMDataModule, decode_bytes
+from .exaone_moe import ExaoneMoE, ExaoneMoEConfig
 from .generate import decode_step, generate, init_kv_cache, prefill
 from .gpt import (
     GPT,
@@ -30,6 +31,8 @@ __all__ = [
     "MNISTDataModule",
     "GPT",
     "GPTConfig",
+    "ExaoneMoE",
+    "ExaoneMoEConfig",
     "SyntheticLMDataModule",
     "add_lora_adapters",
     "extract_lora",

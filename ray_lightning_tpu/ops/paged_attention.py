@@ -35,6 +35,7 @@ serving path takes it on TPU alone, see :func:`paged_decode_supported`).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -124,8 +125,8 @@ def _kernel(layer_ref, tables_ref, lens_ref,            # scalar prefetch
             q_ref, kc_ref, vc_ref, k_hbm, v_hbm,        # inputs
             o_ref,                                      # output
             kbuf, vbuf, sems, buf_ref,                  # scratch
-            *, n_head, head_dim, rows, scale, pages, table_width,
-            block_size):
+            *, n_head, n_kv_head, head_dim, rows, scale, pages,
+            table_width, block_size):
     w = pl.program_id(0)
     n_slots = pl.num_programs(0)
     P, M, Bs = pages, table_width, block_size
@@ -166,11 +167,21 @@ def _kernel(layer_ref, tables_ref, lens_ref,            # scalar prefetch
     n_vis = lens_ref[w]
     n_chunks = jnp.maximum(pl.cdiv(n_pages(w), P), 1)
 
-    hd = n_head * head_dim
+    group = n_head // n_kv_head
+    hd = n_kv_head * head_dim           # the pool's row
     row = jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 1)
-    diag = (col >= row * head_dim) & (col < (row + 1) * head_dim)
-    q32 = jnp.where(diag, q_ref[0].astype(jnp.float32), 0.0)  # (rows, hd)
+    if group == 1:
+        diag = (col >= row * head_dim) & (col < (row + 1) * head_dim)
+        q_rows = q_ref[0]               # (1, hd): every row the same
+    else:
+        # Query head h scores K/V head h // group: row h holds its
+        # query in that head's columns, zeros elsewhere.
+        kv_of_row = row // group
+        diag = ((col >= kv_of_row * head_dim)
+                & (col < (kv_of_row + 1) * head_dim))
+        q_rows = jnp.concatenate([q_ref[0]] * n_kv_head, axis=1)
+    q32 = jnp.where(diag, q_rows.astype(jnp.float32), 0.0)  # (rows, hd)
     q_bd = q32.astype(q_ref.dtype)  # exact: a cast back to q's own dtype
 
     # The current token is position seq_len: it opens the running
@@ -208,7 +219,15 @@ def _kernel(layer_ref, tables_ref, lens_ref,            # scalar prefetch
         0, n_chunks, body, (acc0, m0, l0, buf_ref[0])
     )
     buf_ref[0] = buf
-    o_ref[0] = jnp.sum(jnp.where(diag, acc / l, 0.0), axis=0, keepdims=True)
+    out = jnp.where(diag, acc / l, 0.0)
+    if group == 1:
+        o_ref[0] = jnp.sum(out, axis=0, keepdims=True)
+    else:
+        # Row h's output lies in its K/V head's columns: fold the
+        # column groups onto one head's width.
+        o_ref[0] = functools.reduce(
+            jnp.add, [out[:, g * head_dim:(g + 1) * head_dim]
+                      for g in range(n_kv_head)])
 
 
 def paged_decode_attention(
@@ -223,15 +242,21 @@ def paged_decode_attention(
     *,
     n_head: int,
     scale: float,
+    n_kv_head: Optional[int] = None,
 ) -> jax.Array:
     """Attention of one new token per slot over its paged cache.
 
+    With ``n_kv_head`` fewer than ``n_head`` (grouped queries) the
+    pool's row is ``Hkv*Dh`` wide and query head ``h`` attends K/V head
+    ``h // (H / Hkv)``; ``None`` is one K/V head per query head, the
+    program it always was.
+
     Args:
         q: ``(W, H*Dh)`` queries (any float dtype).
-        k_cur, v_cur: ``(W, H*Dh)`` the new token's own K and V rows in
-            the pool's dtype (what the caller will store at position
+        k_cur, v_cur: ``(W, Hkv*Dh)`` the new token's own K and V rows
+            in the pool's dtype (what the caller will store at position
             ``seq_lens[w]``); the pool is not read there.
-        k_pool, v_pool: ``(L, N, Bs, H*Dh)``, read at ``layer`` only.
+        k_pool, v_pool: ``(L, N, Bs, Hkv*Dh)``, read at ``layer`` only.
         layer: scalar int32 layer index.
         block_tables: ``(W, M)`` int32 physical block ids.
         seq_lens: ``(W,)`` int32 cache positions already in the pool per
@@ -241,31 +266,40 @@ def paged_decode_attention(
     Returns:
         ``(W, H*Dh)`` float32, head ``h`` in columns ``[h*Dh, (h+1)*Dh)``.
     """
-    W, hd = q.shape
-    _, _, Bs, _ = k_pool.shape
+    W, qd = q.shape
+    _, _, Bs, hd = k_pool.shape
     M = block_tables.shape[1]
-    if not paged_decode_tiles(k_pool) or k_pool.shape[3] != hd:
+    n_kv_head = n_head if n_kv_head is None else n_kv_head
+    head_dim = qd // n_head
+    grouped = n_kv_head != n_head
+    if (not paged_decode_tiles(k_pool) or hd != n_kv_head * head_dim
+            or n_head % n_kv_head
+            or (grouped and (head_dim % 128 or n_head % 16))):
         raise ValueError(
             f"rlt_paged_decode does not tile a {k_pool.dtype} pool of "
-            f"shape {k_pool.shape} for queries of width {hd}: take the "
-            f"XLA path (attn_impl='xla' or 'auto')"
+            f"shape {k_pool.shape} for {n_head} query heads of "
+            f"{head_dim} on {n_kv_head} K/V heads: take the XLA path "
+            f"(attn_impl='xla' or 'auto')"
         )
     P = _pages_per_chunk(M, Bs)
     rows = -(-n_head // 16) * 16  # whole bf16 sublane tiles, _split3 stacks
     kernel = functools.partial(
-        _kernel, n_head=n_head, head_dim=hd // n_head, rows=rows,
-        scale=scale, pages=P, table_width=M, block_size=Bs,
+        _kernel, n_head=n_head, n_kv_head=n_kv_head, head_dim=head_dim,
+        rows=rows, scale=scale, pages=P, table_width=M, block_size=Bs,
     )
     row_spec = pl.BlockSpec((1, 1, hd), lambda w, *_: (w, 0, 0))
+    # Grouped queries arrive one head a row, (W, H, Dh), and leave so.
+    q_spec = pl.BlockSpec((1, n_head, head_dim), lambda w, *_: (w, 0, 0)) \
+        if grouped else row_spec
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(W,),
         in_specs=[
-            row_spec, row_spec, row_spec,
+            q_spec, row_spec, row_spec,
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=row_spec,
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((2, P * Bs, hd), k_pool.dtype),
             pltpu.VMEM((2, P * Bs, hd), v_pool.dtype),
@@ -276,7 +310,8 @@ def paged_decode_attention(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((W, 1, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(
+            (W, n_head, head_dim) if grouped else (W, 1, hd), jnp.float32),
         # Slots run in order: each step leaves the next slot's first
         # chunk in flight and the buffer index in SMEM.
         compiler_params=pltpu.CompilerParams(
@@ -288,10 +323,10 @@ def paged_decode_attention(
         jnp.asarray(layer, jnp.int32).reshape(1),
         block_tables.astype(jnp.int32).reshape(-1),
         jnp.minimum(seq_lens.astype(jnp.int32), M * Bs),
-        q.reshape(W, 1, hd),
+        q.reshape(W, n_head, head_dim) if grouped else q.reshape(W, 1, hd),
         k_cur.reshape(W, 1, hd),
         v_cur.reshape(W, 1, hd),
         k_pool,
         v_pool,
     )
-    return out.reshape(W, hd)
+    return out.reshape(W, qd)

@@ -328,31 +328,41 @@ class ServeClient:
                 return  # queue shut down
             if not isinstance(item, dict):
                 continue
-            pend = self._pending.get(str(item.get("rid")))
-            if pend is None:
-                continue
-            kind = item.get("type")
-            if kind == "serve_token":
-                idx, tok = int(item["index"]), int(item["token"])
-                if idx == len(pend.tokens):
-                    pend.tokens.append(tok)
-                elif idx < len(pend.tokens):
-                    pend.tokens[idx] = tok  # preemption re-emission
-                    self.re_emitted_tokens += 1
-                pend.stream.put(("token", (idx, tok)))
-            elif kind == "serve_done":
-                if pend.done.is_set():
-                    # Hedged pair: the first terminal report won; the
-                    # loser's later "cancelled" (or duplicate
-                    # "completed") must not overwrite it.
-                    continue
-                pend.status = item.get("status")
-                pend.reason = item.get("reason")
-                pend.error = item.get("error")
-                if item.get("tokens"):
-                    pend.tokens = [int(t) for t in item["tokens"]]
-                pend.stream.put(("done", None))
-                pend.done.set()
+            if item.get("type") == "serve_batch":
+                # One tick's replies in one frame
+                # (``ServeConfig.coalesce_replies``), in order.
+                for sub in item.get("items", ()):
+                    if isinstance(sub, dict):
+                        self._on_reply(sub)
+            else:
+                self._on_reply(item)
+
+    def _on_reply(self, item: dict) -> None:
+        pend = self._pending.get(str(item.get("rid")))
+        if pend is None:
+            return
+        kind = item.get("type")
+        if kind == "serve_token":
+            idx, tok = int(item["index"]), int(item["token"])
+            if idx == len(pend.tokens):
+                pend.tokens.append(tok)
+            elif idx < len(pend.tokens):
+                pend.tokens[idx] = tok  # preemption re-emission
+                self.re_emitted_tokens += 1
+            pend.stream.put(("token", (idx, tok)))
+        elif kind == "serve_done":
+            if pend.done.is_set():
+                # Hedged pair: the first terminal report won; the
+                # loser's later "cancelled" (or duplicate
+                # "completed") must not overwrite it.
+                return
+            pend.status = item.get("status")
+            pend.reason = item.get("reason")
+            pend.error = item.get("error")
+            if item.get("tokens"):
+                pend.tokens = [int(t) for t in item["tokens"]]
+            pend.stream.put(("done", None))
+            pend.done.set()
 
     def close(self) -> None:
         self._closed.set()

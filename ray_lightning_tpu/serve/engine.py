@@ -35,6 +35,7 @@ the identical sampling stream.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 import uuid
@@ -117,6 +118,17 @@ class ServeConfig:
     prefill_chunk: Optional[int] = None
     # Sampling seed for temperature>0 requests.
     seed: int = 0
+    # One reply frame a tick per reply address (``serve_batch``, the
+    # tick's ``serve_token`` / ``serve_done`` items in order) instead of
+    # one acknowledged frame a token.  OFF by default: the consumer has
+    # to understand ``serve_batch`` (``ServeClient`` does).
+    coalesce_replies: bool = False
+    # Dispatch the next decode before emitting this one's tokens, so the
+    # device runs while the host replies: on a tick whose slots all go
+    # on (none at its last token or at eos), with nothing waiting to be
+    # admitted.  OFF by default; not with spec_k, adapters, prefix_cache
+    # or prefill_chunk (ValueError).
+    decode_lookahead: bool = False
     # Background-thread idle sleep between polls when no work exists.
     idle_wait_s: float = 0.002
     # Live-export refresh cadence (prom textfile / serve-live.json).
@@ -208,7 +220,9 @@ class ServeEngine:
         from ray_lightning_tpu.models.quant import (
             dequantize_decode_params, is_quantized,
         )
-        from ray_lightning_tpu.serve.kv_cache import PagedKVCache
+        from ray_lightning_tpu.serve.kv_cache import (
+            GPTServeFamily, PagedKVCache,
+        )
         from ray_lightning_tpu.serve.metrics import ServeStats
         from ray_lightning_tpu.serve.scheduler import (
             Scheduler, derive_geometry,
@@ -226,6 +240,28 @@ class ServeEngine:
         self.module = module
         self.cfg = module.config
         self.config = cfg = config or ServeConfig()
+        # The one seam to the model family: pool layout, prefill and
+        # decode programs.  A module without ``serve_family`` is GPT,
+        # served by serve/kv_cache.py's functions as it always was.
+        self.family = (module.serve_family()
+                       if hasattr(module, "serve_family")
+                       else GPTServeFamily(self.cfg))
+        if self.family.two_kind:
+            refused = [
+                name for name, on in (
+                    ("prefix_cache", cfg.prefix_cache),
+                    ("spec_k > 0", cfg.spec_k > 0),
+                    ("a draft model", draft_module is not None),
+                    ("LoRA adapters (max_adapters / adapters=)",
+                     cfg.max_adapters > 0 or bool(adapters)),
+                    ("prefill_chunk", cfg.prefill_chunk is not None),
+                ) if on]
+            if refused:
+                raise ValueError(
+                    f"the {self.family.name} family is served with two "
+                    f"kinds of cache state (block tables and window "
+                    f"rings) and does not support: {', '.join(refused)}"
+                )
         _reject_unmerged_lora(params)
         self.params = _prep(params)
         self._c = module._compute_dtype()
@@ -307,8 +343,8 @@ class ServeEngine:
                 f"num_blocks {num_blocks} cannot hold even one "
                 f"max-length sequence ({blocks_per_seq} blocks)"
             )
-        self.cache = PagedKVCache(
-            self.cfg, num_blocks, cfg.block_size, dtype=self._c
+        self.cache = self.family.make_cache(
+            num_blocks, cfg.block_size, cfg.num_slots, self._c
         )
         # The longest RETAINED bucket bounds the admissible prompt
         # length — submit() enforces it, so Scheduler.bucket_for can
@@ -318,6 +354,8 @@ class ServeEngine:
             cfg.num_slots, self.cache.allocator, cfg.block_size,
             blocks_per_seq, buckets, max_queue=cfg.max_queue,
             max_queue_per_adapter=cfg.max_queue_per_adapter,
+            window_allocator=getattr(self.cache, "window_allocator", None),
+            window_blocks=getattr(self.cache, "window_blocks", 0),
         )
         # Prefix-aware KV reuse + chunked prefill (docs/SERVING.md
         # "Prefix caching & chunked prefill").  All host-side wiring:
@@ -355,6 +393,21 @@ class ServeEngine:
         # inactive slot (writes trashed, sampled token ignored), so the
         # job needs no change to the compiled decode graph.
         self._chunk_jobs: Dict[int, "_PrefillJob"] = {}
+        if cfg.decode_lookahead:
+            refused = [name for name, on in (
+                ("spec_k / draft model", draft_module is not None),
+                ("max_adapters", cfg.max_adapters > 0),
+                ("prefix_cache", cfg.prefix_cache),
+                ("prefill_chunk", cfg.prefill_chunk is not None),
+            ) if on]
+            if refused:
+                raise ValueError(
+                    "decode_lookahead dispatches a tick before the last "
+                    "one's tokens are booked and does not combine with: "
+                    + ", ".join(refused))
+        # The decode dispatched ahead of its tick (decode_lookahead), as
+        # ``_dispatch_decode`` returned it, or None.
+        self._ahead: Optional[tuple] = None
         # Adapter names whose cached chains must be dropped before the
         # next admission poll: add/remove_adapter run on OTHER threads,
         # and every PrefixIndex mutation belongs to the serve thread —
@@ -437,6 +490,11 @@ class ServeEngine:
         # dispatch can outlive — so it shares the lock.
         # guarded by self._lock
         self._reply_handles: Dict[Tuple[str, int], Any] = {}
+        # Open only inside a tick's emit loop under
+        # ``ServeConfig.coalesce_replies``: (owner thread, replies by
+        # address, in order).
+        self._reply_batch: Optional[
+            Tuple[int, Dict[Tuple[str, int], List[dict]]]] = None
         self._exporter = None
         self._live_path = None
         self._last_export = 0.0
@@ -500,7 +558,7 @@ class ServeEngine:
         )
         from ray_lightning_tpu.telemetry.program_ledger import ledgered_jit
 
-        cfg, c = self.cfg, self._c
+        cfg, c, fam = self.cfg, self._c, self.family
         base_key = jax.random.PRNGKey(self.config.seed)
         # Donation keeps the pool update in place on TPU; XLA:CPU cannot
         # donate and would warn on every dispatch.
@@ -514,18 +572,21 @@ class ServeEngine:
 
         def _decode(params, pool, block_tables, seq_lens, tokens, temps,
                     seeds, top_ks, ad, ad_ids):
-            logits, pool = paged_decode_step(
-                cfg, params, pool, block_tables, seq_lens, tokens,
+            # (logits, pool) and, from a family that counts them, the
+            # program's own integer sums (routed assignments): handed
+            # on beside the tokens.
+            logits, pool, *sums = fam.decode(
+                params, pool, block_tables, seq_lens, tokens,
                 compute_dtype=c, adapters=ad, adapter_ids=ad_ids,
                 lora_impl=lora_impl,
             )
             keys = make_slot_keys(base_key, seeds, seq_lens)
-            return sample_tokens(logits, keys, temps, top_ks), pool
+            return (sample_tokens(logits, keys, temps, top_ks), pool, *sums)
 
         def _prefill(params, pool, tokens, prompt_len, block_ids, temp,
                      seed, top_k, ad, ad_id):
-            logits, pool = paged_prefill(
-                cfg, params, pool, tokens, prompt_len, block_ids,
+            logits, pool, *sums = fam.prefill(
+                params, pool, tokens, prompt_len, block_ids,
                 compute_dtype=c, adapters=ad, adapter_id=ad_id,
                 lora_impl=lora_impl,
             )
@@ -535,7 +596,7 @@ class ServeEngine:
             first = sample_tokens(
                 logits[None], keys, temp[None], top_k[None]
             )[0]
-            return first, pool
+            return (first, pool, *sums)
 
         def _first(logits, prompt_len, temp, seed, top_k):
             # Disaggregated admission: the prefill worker shipped the
@@ -769,7 +830,7 @@ class ServeEngine:
                 f"to a multiple of block_size, pass prefill_buckets, "
                 f"or enable chunked prefill (ServeConfig.prefill_chunk)"
             )
-        if any(not 0 <= t < self.cfg.vocab_size for t in prompt):
+        if any(not 0 <= t < self.family.vocab_size for t in prompt):
             raise ValueError("prompt token outside the vocab")
         if self._error is not None:
             raise RuntimeError(
@@ -793,6 +854,7 @@ class ServeEngine:
         )
         req._trace_local = trace_local
         if _handoff is not None:
+            self._refuse_block_transfer("import_blocks")
             req._handoff = _handoff
         handle = ServeHandle(rid, req)
         with self._lock:
@@ -893,7 +955,9 @@ class ServeEngine:
         tr = self.tracer
         for slot, req, bucket in admissions:
             ph.then("admit_dispatch", rid=req.rid, bucket=bucket,
-                    prompt_len=req.prompt_len)
+                    prompt_len=req.prompt_len,
+                    full_blocks=len(self.scheduler._blocks[slot]),
+                    window_blocks=len(self.scheduler._window[slot]))
             wait = now - req.arrival_t
             self.stats.note_admitted(
                 wait, None if req.recv_t is None else now - req.recv_t)
@@ -927,6 +991,10 @@ class ServeEngine:
                     np.int32,
                 )
                 ids = jnp.asarray(ids)
+                if self.family.two_kind:
+                    # (full blocks of the bucket, the slot's window ring)
+                    ids = (ids, jnp.asarray(
+                        self.scheduler.window_tables[slot]))
                 handoff = getattr(req, "_handoff", None)
                 padded = None
                 if handoff is None or self.draft_module is not None:
@@ -966,7 +1034,7 @@ class ServeEngine:
                     else self.adapters.buffers
                 ad_id = None if self.adapters is None \
                     else np.int32(req._adapter_slot)
-                first, self._pool = self._prefill_fn(
+                first, self._pool, *_ = self._prefill_fn(
                     self.params, self._pool, padded,
                     np.int32(req.prompt_len), ids,
                     np.float32(req.temperature),
@@ -1303,18 +1371,21 @@ class ServeEngine:
             req.adapter, req.prompt, self.scheduler._blocks[slot][:n]
         )
 
-    def _decode_tick(self, active: List[int], ph) -> None:
-        """One token for every active slot — the non-speculative path
-        (and the fallback when no active slot drafts this tick).
-        ``ph`` is the tick's open phase (``decode_dispatch``)."""
+    def _dispatch_decode(self, active: List[int]) -> tuple:
+        """Dispatch one decode for the slots ``active`` and return what
+        the tick that reads it needs: dispatch time, the (slot, request)
+        pairs it computes for, the tokens and the family's sums (still
+        on the device), and the tick's block counters."""
         import jax.numpy as jnp
 
         t0 = time.monotonic()
         seq_lens = jnp.asarray(self.scheduler.seq_lens)
         cur = jnp.asarray(self._cur_tokens)
         tables = jnp.asarray(self.scheduler.block_tables)
+        if self.family.two_kind:
+            tables = (tables, jnp.asarray(self.scheduler.window_tables))
         ad, ad_ids = self._lora_operands()
-        toks, self._pool = self._decode_fn(
+        toks, self._pool, *sums = self._decode_fn(
             self.params, self._pool, tables, seq_lens, cur,
             jnp.asarray(self.scheduler.temperatures),
             jnp.asarray(self.scheduler.sample_seeds),
@@ -1333,21 +1404,68 @@ class ServeEngine:
                 seq_lens + 1,
             )
             self.stats.bump("draft_steps")
+        # What the paged decode kernel has to read against what the
+        # slots' tables span: host integers, before the lengths advance.
+        # A slot at seq_len holds positions [0, seq_len] in
+        # seq_len // Bs + 1 blocks, the one being written included.
+        resident = self.scheduler.seq_lens[active] // self.config.block_size
+        # rlt: noqa[RLT002] host ints (scheduler.seq_lens), no device value
+        read = int(resident.sum()) + len(active)
+        kv_blocks = {"read": read, "table": self.scheduler.block_tables.size}
+        if self.family.two_kind:
+            # Per layer, by kind of state: a full layer reads the
+            # resident blocks of its table, a sliding layer the slot's
+            # whole ring; the unsuffixed counters stay the sums.
+            ring = self.scheduler.window_tables
+            n_full, n_ring = self.family.n_full, self.family.n_ring
+            by_kind = {
+                "read_full": read * n_full,
+                "table_full": kv_blocks["table"] * n_full,
+                "read_window": len(active) * ring.shape[1] * n_ring,
+                "table_window": ring.size * n_ring,
+            }
+            kv_blocks = {
+                "read": by_kind["read_full"] + by_kind["read_window"],
+                "table": by_kind["table_full"] + by_kind["table_window"],
+                **by_kind,
+            }
+        kv_blocks = {f"decode_kv_blocks_{k}": v for k, v in kv_blocks.items()}
+        if sums:
+            kv_blocks["moe_tokens_routed"] = len(active) * self.family.n_sparse
+        held = [(slot, self.scheduler.slots[slot]) for slot in active]
+        return t0, held, toks, sums, kv_blocks
+
+    def _decode_tick(self, active: List[int], ph) -> None:
+        """One token for every active slot — the non-speculative path
+        (and the fallback when no active slot drafts this tick).
+        ``ph`` is the tick's open phase (``decode_dispatch``).
+
+        Under ``decode_lookahead`` the tick may find its decode already
+        dispatched (by the tick before, which then covers the slots of
+        THAT moment: a slot admitted since waits one tick, a slot
+        cancelled, expired or preempted since is skipped), and may
+        dispatch the next one before it emits."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is not None:
+            live = [slot for slot, req in ahead[1]
+                    if self.scheduler.slots[slot] is req]
+            if live:
+                active = live
+            else:
+                ahead = None    # every slot it computed for is gone
+        t0, _, toks, sums, counts = ahead or self._dispatch_decode(active)
         ph.then("decode_wait")
         # rlt: noqa[RLT002] deliberate: the tick must emit tokens
         toks = np.asarray(toks)
         ph.then("emit")
         dt = time.monotonic() - t0
         self.stats.bump("decode_steps")
-        # What the paged decode kernel had to read against what the
-        # slots' tables span: host integers, before the lengths advance.
-        # A slot at seq_len holds positions [0, seq_len] in
-        # seq_len // Bs + 1 blocks, the one being written included.
-        resident = self.scheduler.seq_lens[active] // self.config.block_size
-        self.stats.bump_many({  # rlt: noqa[RLT002] host ints, no device value
-            "decode_kv_blocks_read": int(resident.sum()) + len(active),
-            "decode_kv_blocks_table": self.scheduler.block_tables.size,
-        })
+        if sums:
+            # rlt: noqa[RLT002] the same program's output as the tokens above
+            local, hit = (int(v) for v in np.asarray(sums[0]))
+            counts.update(moe_local_assignments=local,
+                          moe_local_experts_hit=hit)
+        self.stats.bump_many(counts)  # rlt: noqa[RLT002] host ints, no device value
         # Tick wall in µs — with decode_steps/tokens_out it gives the
         # capacity oracle per-bin (busy slots, tick cost) pairs, the
         # data its affine tick-cost fit needs (serve/capacity.py).
@@ -1357,14 +1475,44 @@ class ServeEngine:
         for slot in active:
             self.scheduler.seq_lens[slot] += 1
             self.scheduler.draft_lens[slot] = self.scheduler.seq_lens[slot]
-            tok = int(toks[slot])  # rlt: noqa[RLT002] host np after the tick fetch
-            self._cur_tokens[slot] = tok
-            req = self.scheduler.slots[slot]
-            if req is not None and req.adapter is not None:
-                self.stats.note_adapter(req.adapter, tokens=1)
-            done = self.scheduler.append_token(slot, tok)
-            if done:
-                self._complete(slot)
+            # rlt: noqa[RLT002] host np after the tick fetch
+            self._cur_tokens[slot] = int(toks[slot])
+        if self.config.decode_lookahead and self._all_go_on(active):
+            ph.then("decode_dispatch", slots=len(active), ahead=1)
+            self._ahead = self._dispatch_decode([
+                s for s, r in enumerate(self.scheduler.slots)
+                if r is not None])
+            self._ahead[-1]["decode_ahead"] = 1     # counted when read
+            ph.then("emit")
+        with self._coalesced_replies():
+            for slot in active:
+                tok = int(self._cur_tokens[slot])  # rlt: noqa[RLT002] host np
+                req = self.scheduler.slots[slot]
+                if req is not None and req.adapter is not None:
+                    self.stats.note_adapter(req.adapter, tokens=1)
+                done = self.scheduler.append_token(slot, tok)
+                if done:
+                    self._complete(slot)
+
+    def _all_go_on(self, active: List[int]) -> bool:
+        """Whether the next decode can be dispatched now, before this
+        tick's tokens (already in ``_cur_tokens``, lengths advanced) are
+        booked: every slot goes on after its token, nothing waits for a
+        slot, and every next position has its block."""
+        sched = self.scheduler
+        if not active or sched.queue or (
+                self._inbox is not None and not self._inbox.empty()):
+            return False
+        for slot in active:
+            req = sched.slots[slot]
+            if len(req.generated) + 1 >= req.max_new_tokens or (
+                    req.eos_token_id is not None
+                    and int(self._cur_tokens[slot]) == req.eos_token_id):
+                return False
+        for slot in active:
+            if sched.needs_block(slot) and not sched.grow(slot):
+                return False    # pool dry: the loop's grow phase preempts
+        return True
 
     def _spec_tick(self, active: List[int], widths: List[int],
                    ph) -> None:
@@ -1469,33 +1617,34 @@ class ServeEngine:
             "decode_us", int(dt * 1e6))
 
         total_emitted = 0
-        for slot in active:
-            w = widths[slot]
-            drafts = window[slot, 1: w + 1]
-            target = sampled[slot, : w + 1]
-            accepted = 0
-            while accepted < w and drafts[accepted] == target[accepted]:
-                accepted += 1
-            emit = [int(t) for t in drafts[:accepted]]  # rlt: noqa[RLT002] host np
-            emit.append(int(target[accepted]))  # rlt: noqa[RLT002] host np
-            seq_was = int(sched.seq_lens[slot])  # rlt: noqa[RLT002] host np state
-            draft_was = int(sched.draft_lens[slot])  # rlt: noqa[RLT002] host np state
-            n, done = sched.append_tokens(slot, emit)
-            new_len = seq_was + n
-            # Roll BOTH caches back to the emitted frontier: the target
-            # wrote the whole window, the draft chain wrote K+1
-            # positions from its own frontier; everything past new_len
-            # is rejected garbage whose blocks return to the pool.
-            sched.truncate_slot_to(slot, new_len)
-            sched.draft_lens[slot] = min(draft_was + K + 1, new_len)
-            self._cur_tokens[slot] = emit[n - 1]
-            total_emitted += n
-            self.stats.note_spec_slot(w, min(accepted, n), n)
-            req = sched.slots[slot]
-            if req is not None and req.adapter is not None:
-                self.stats.note_adapter(req.adapter, tokens=n)
-            if done:
-                self._complete(slot)
+        with self._coalesced_replies():
+            for slot in active:
+                w = widths[slot]
+                drafts = window[slot, 1: w + 1]
+                target = sampled[slot, : w + 1]
+                accepted = 0
+                while accepted < w and drafts[accepted] == target[accepted]:
+                    accepted += 1
+                emit = [int(t) for t in drafts[:accepted]]  # rlt: noqa[RLT002] host np
+                emit.append(int(target[accepted]))  # rlt: noqa[RLT002] host np
+                seq_was = int(sched.seq_lens[slot])  # rlt: noqa[RLT002] host np state
+                draft_was = int(sched.draft_lens[slot])  # rlt: noqa[RLT002] host np state
+                n, done = sched.append_tokens(slot, emit)
+                new_len = seq_was + n
+                # Roll BOTH caches back to the emitted frontier: the target
+                # wrote the whole window, the draft chain wrote K+1
+                # positions from its own frontier; everything past new_len
+                # is rejected garbage whose blocks return to the pool.
+                sched.truncate_slot_to(slot, new_len)
+                sched.draft_lens[slot] = min(draft_was + K + 1, new_len)
+                self._cur_tokens[slot] = emit[n - 1]
+                total_emitted += n
+                self.stats.note_spec_slot(w, min(accepted, n), n)
+                req = sched.slots[slot]
+                if req is not None and req.adapter is not None:
+                    self.stats.note_adapter(req.adapter, tokens=n)
+                if done:
+                    self._complete(slot)
         self.stats.bump("spec_ticks")
         self.stats.note_token_latency(dt, n_tokens=total_emitted)
 
@@ -1717,6 +1866,15 @@ class ServeEngine:
             self._thread.join(timeout=30)
             self._thread = None
 
+    def _refuse_block_transfer(self, what: str) -> None:
+        if self.family.two_kind:
+            raise ValueError(
+                f"{what} is not supported for the {self.family.name} "
+                f"family: a sequence's state is a block table and a "
+                f"window ring (KV handoff and live migration move one "
+                f"kind of block)"
+            )
+
     def export_resident(self) -> List[dict]:
         """Export every resident decoding sequence's KV blocks plus
         scheduler position — the planned-drain live-migration payload
@@ -1726,6 +1884,7 @@ class ServeEngine:
         Queued requests and chunked prefills mid-flight are NOT
         exported: they have no emitted position worth moving, so the
         router's ordinary recompute failover covers them."""
+        self._refuse_block_transfer("export_blocks")
         out = []
         sched = self.scheduler
         Bs = self.config.block_size
@@ -1873,6 +2032,7 @@ class ServeEngine:
             self.cancel(str(item["rid"]))
             return
         if kind in ("serve_kv_handoff", "serve_migration"):
+            self._refuse_block_transfer("import_blocks")
             fields = dict(item["req"])
             adapter = fields.get("adapter")
             if (adapter is not None and self.adapters is not None
@@ -2234,9 +2394,31 @@ class ServeEngine:
             "tokens": [int(t) for t in req.generated],
         })
 
+    @contextlib.contextmanager
+    def _coalesced_replies(self):
+        """Under ``ServeConfig.coalesce_replies``, hold the replies this
+        thread makes inside the block and send them on leaving it: one
+        ``serve_batch`` frame per reply address, items in order."""
+        if not self.config.coalesce_replies or self._reply_batch is not None:
+            yield
+            return
+        held: Dict[Tuple[str, int], List[dict]] = {}
+        self._reply_batch = (threading.get_ident(), held)
+        try:
+            yield
+        finally:
+            self._reply_batch = None
+            for addr, items in held.items():
+                self._reply(addr, items[0] if len(items) == 1 else
+                            {"type": "serve_batch", "items": items})
+
     def _reply(self, addr: Tuple[str, int], item: dict) -> None:
         from ray_lightning_tpu.cluster.queue import QueueHandle
 
+        batch = self._reply_batch
+        if batch is not None and batch[0] == threading.get_ident():
+            batch[1].setdefault(addr, []).append(item)
+            return
         with self._lock:
             handle = self._reply_handles.get(addr)
             if handle is None:

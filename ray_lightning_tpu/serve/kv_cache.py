@@ -50,6 +50,7 @@ static path's causal mask hides, and keep scores/softmax/PV in f32
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -926,6 +927,28 @@ def paged_verify_step(
     )
     logits = _head_logits(params, x, c)
     return logits, {"k": k_new, "v": v_new}
+
+
+class GPTServeFamily:
+    """The seam :class:`~ray_lightning_tpu.serve.engine.ServeEngine`
+    builds on: a model family's pool layout and its prefill and decode
+    programs.  This is ``GPT``'s (one kind of cache state, the functions
+    of this file); a module with a ``serve_family()`` method brings its
+    own (``models/exaone_moe.py``: window and full layers in one cache
+    manager), and the engine asks nothing else of a family."""
+
+    name = "gpt"
+    two_kind = False     # one block table a slot, one pool a tensor
+
+    def __init__(self, cfg: GPTConfig):
+        self.cfg = cfg
+        self.vocab_size = cfg.vocab_size
+        self.prefill = functools.partial(paged_prefill, cfg)
+        self.decode = functools.partial(paged_decode_step, cfg)
+
+    def make_cache(self, num_blocks: int, block_size: int, num_slots: int,
+                   dtype) -> "PagedKVCache":
+        return PagedKVCache(self.cfg, num_blocks, block_size, dtype=dtype)
 
 
 def make_slot_keys(

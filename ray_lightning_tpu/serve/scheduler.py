@@ -195,6 +195,8 @@ class Scheduler:
         buckets: Sequence[int],
         max_queue: int = 64,
         max_queue_per_adapter: Optional[int] = None,
+        window_allocator: Optional[BlockAllocator] = None,
+        window_blocks: int = 0,
     ):
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
@@ -220,6 +222,17 @@ class Scheduler:
         # The compiled step's operands (value-only mutation).
         self.block_tables = np.full(
             (num_slots, max_blocks_per_seq), TRASH_BLOCK, np.int32
+        )
+        # A second kind of cache state (models with sliding-window
+        # layers): every slot also holds a ring of ``window_blocks``
+        # blocks of the window pool, claimed at admission from that
+        # pool's own allocator and freed with the slot.  None = one
+        # kind of state, the scheduler as it always was.
+        self.window_allocator = window_allocator
+        self.window_blocks = window_blocks if window_allocator else 0
+        self._window: List[List[int]] = [[] for _ in range(num_slots)]
+        self.window_tables = None if window_allocator is None else np.full(
+            (num_slots, window_blocks), TRASH_BLOCK, np.int32
         )
         self.seq_lens = np.zeros((num_slots,), np.int32)
         self.temperatures = np.zeros((num_slots,), np.float32)
@@ -373,7 +386,10 @@ class Scheduler:
                 bucket = self.bucket_for(req.prompt_len)
                 need = bucket // self.block_size
             ids = self._alloc(need)
-            if ids is None:
+            ring = None if ids is None else self._alloc_window()
+            if ids is None or ring is None:
+                if ids:
+                    self.allocator.free(ids)
                 if claimed:
                     self.allocator.free(claimed)  # drop the claim refs
                 break  # pool dry: wait for evictions, keep grant order
@@ -399,6 +415,9 @@ class Scheduler:
             row = self.block_tables[slot]
             row[:] = TRASH_BLOCK
             row[: len(ids)] = ids
+            if ring:
+                self._window[slot] = ring
+                self.window_tables[slot, :] = ring
             self.seq_lens[slot] = req.prompt_len
             self.temperatures[slot] = req.temperature
             self.top_ks[slot] = req.top_k or 0
@@ -492,6 +511,13 @@ class Scheduler:
             self.reclaim(n - self.allocator.free_blocks)
             ids = self.allocator.alloc(n)
         return ids
+
+    def _alloc_window(self) -> Optional[List[int]]:
+        """The slot's ring of window blocks ([] when the model has one
+        kind of state), or None when the window pool is dry."""
+        if self.window_allocator is None:
+            return []
+        return self.window_allocator.alloc(self.window_blocks)
 
     def grow(self, slot: int) -> bool:
         """Allocate the next block for ``slot``.  False = pool dry."""
@@ -686,6 +712,10 @@ class Scheduler:
     def _release(self, slot: int) -> None:
         self.allocator.free(self._blocks[slot])
         self._blocks[slot] = []
+        if self._window[slot]:
+            self.window_allocator.free(self._window[slot])
+            self._window[slot] = []
+            self.window_tables[slot, :] = TRASH_BLOCK
         self.slots[slot] = None
         self.block_tables[slot, :] = TRASH_BLOCK
         self.seq_lens[slot] = 0
@@ -704,4 +734,8 @@ class Scheduler:
             "blocks_free": self.allocator.free_blocks,
             "blocks_live": self.allocator.live_blocks,
             "num_blocks": self.allocator.num_blocks,
+            **({} if self.window_allocator is None else {
+                "window_blocks_free": self.window_allocator.free_blocks,
+                "window_blocks_live": self.window_allocator.live_blocks,
+            }),
         }

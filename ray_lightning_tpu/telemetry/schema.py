@@ -618,6 +618,19 @@ def validate_serve_reply(item: Any, where: str = "serve_reply") -> List[str]:
             item, "serve_done", _SERVE_DONE_REQUIRED,
             _SERVE_DONE_OPTIONAL, where,
         )
+    if kind == "serve_batch":
+        # One tick's replies to one address in one frame
+        # (ServeConfig.coalesce_replies): tokens and completions only.
+        items = item.get("items")
+        if not isinstance(items, list) or not items:
+            return [f"{where}: serve_batch without items"]
+        problems = []
+        for i, sub in enumerate(items):
+            if isinstance(sub, dict) and sub.get("type") == "serve_batch":
+                problems.append(f"{where}.items[{i}]: nested serve_batch")
+            else:
+                problems += validate_serve_reply(sub, f"{where}.items[{i}]")
+        return problems
     return [f"{where}: unknown serve reply type {kind!r}"]
 
 

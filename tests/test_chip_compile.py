@@ -350,3 +350,145 @@ def test_serve_decode_step_moves_no_pool_layer(serve_decode, opcode):
              and int(np.prod([int(d) for d in m.group(1).split(",")]))
              in whole]
     assert not found, found
+
+
+# -- the expert-parallel share (k-exaone-236b-a23b-ep8.serve-mixed) ---------
+
+# benchmarks/workloads/k-exaone-236b-a23b-ep8.serve-mixed.json: 32 slots x
+# 4096 positions in blocks of 32, the engine's default pool of 33 x 128 + 1
+# blocks, rings of 5 blocks; published widths, 16 of 128 experts held.
+MOE = dict(W=32, M=128, Bs=32, N=33 * 128 + 1, d=6144, f=2048, E=16, k=8)
+
+
+def _moe_cfg():
+    from ray_lightning_tpu.models.exaone_moe import ExaoneMoEConfig
+
+    return ExaoneMoEConfig(n_layer=8, experts_held=(0, 16),
+                           vocab_held=(0, 19200), seq_len=4096)
+
+
+@pytest.mark.parametrize("rows", [MOE["W"], 3072], ids=["decode", "prefill"])
+def test_moe_kernels_compile_at_serve_cell_shapes(one_chip, rows):
+    """The grouped matmuls at a decode tick's 32 x 8 assignments (tiles of
+    16 rows, weight-streaming) and at the largest prefill bucket's 3072 x
+    8 (tiles of 128)."""
+    from ray_lightning_tpu.ops.moe import dropless_moe
+
+    w = _sds((MOE["E"], MOE["d"], MOE["f"]), jnp.bfloat16, one_chip)
+    text = _compile(
+        lambda x, idx, gates, wg, wu, wd: dropless_moe(
+            x, idx, gates, wg, wu, wd, 0, impl="pallas"),
+        _sds((rows, MOE["d"]), jnp.bfloat16, one_chip),
+        _sds((rows, MOE["k"]), jnp.int32, one_chip),
+        _sds((rows, MOE["k"]), jnp.float32, one_chip), w, w,
+        _sds((MOE["E"], MOE["f"], MOE["d"]), jnp.bfloat16, one_chip))
+    assert "%rlt_moe_gate_up" in text and "%rlt_moe_down" in text
+
+
+def test_gqa_paged_decode_kernel_compiles_at_serve_cell_shapes(one_chip):
+    """64 query heads on 8 K/V heads of 128 over the two full layers'
+    pool."""
+    from ray_lightning_tpu.ops.paged_attention import paged_decode_attention
+
+    row = _sds((MOE["W"], 1024), jnp.bfloat16, one_chip)
+    kv = _sds((2, MOE["N"], MOE["Bs"], 1024), jnp.bfloat16, one_chip)
+    assert "rlt_paged_decode" in _compile(
+        lambda *a: paged_decode_attention(
+            *a, n_head=64, n_kv_head=8, scale=128 ** -0.5),
+        _sds((MOE["W"], 8192), jnp.bfloat16, one_chip), row, row, kv, kv,
+        _sds((), jnp.int32, one_chip),
+        _sds((MOE["W"], MOE["M"]), jnp.int32, one_chip),
+        _sds((MOE["W"],), jnp.int32, one_chip),
+    )
+
+
+def _moe_programs(one_chip):
+    from ray_lightning_tpu.models import exaone_moe as em
+
+    cfg = _moe_cfg()
+    module = em.ExaoneMoE(cfg)
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda l: _sds(l.shape, l.dtype, one_chip), tree)
+
+    params = abstract(jax.eval_shape(module.init_params,
+                                     jax.random.PRNGKey(0)))
+    cache = em.TwoKindKVCache(cfg, MOE["N"], MOE["Bs"], MOE["W"],
+                              jnp.bfloat16)
+    pool = abstract(jax.eval_shape(cache.init_pool))
+    return module.serve_family(), params, pool, cache.window_blocks
+
+
+@pytest.fixture(scope="module")
+def moe_decode(one_chip):
+    """The family's decode program as the engine jits it (pool donated)."""
+    fam, params, pool, ring = _moe_programs(one_chip)
+    i32 = lambda *shape: _sds(shape, jnp.int32, one_chip)  # noqa: E731
+
+    def step(params, pool, full, rings, seq_lens, tokens):
+        return fam.decode(params, pool, (full, rings), seq_lens, tokens)
+
+    return jax.jit(step, donate_argnums=1).lower(
+        params, pool, i32(MOE["W"], MOE["M"]), i32(MOE["W"], ring),
+        i32(MOE["W"]), i32(MOE["W"])).compile()
+
+
+def _footprint(compiled) -> float:
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+
+
+def test_moe_decode_step_names_its_kernels_and_fits(moe_decode):
+    """Both kernels' names are in the program and it leaves the chip
+    (15.75 GB usable) over 2 GB: 13.33 GB at PR 26, 11.96 of it weights."""
+    text = moe_decode.as_text()
+    for kernel in ("%rlt_moe_gate_up", "%rlt_moe_down", "%rlt_paged_decode"):
+        assert kernel in text
+    assert 12e9 < _footprint(moe_decode) < 13.7e9
+
+
+@pytest.mark.parametrize("opcode", ["copy", "convert", "gather",
+                                    "dynamic-slice"])
+def test_moe_decode_step_moves_no_pool_layer_and_no_expert_tensor(
+        moe_decode, opcode):
+    """No operation's result is a pool layer, a pool, every slot's whole
+    table of positions, or an expert-stacked weight tensor."""
+    import re
+
+    row, rings = 1024, MOE["W"] * 5 + 1
+    N, Bs, W, M = MOE["N"], MOE["Bs"], MOE["W"], MOE["M"]
+    d, f, E = MOE["d"], MOE["f"], MOE["E"]
+    # By shape, not by size: W == Bs here, so a slot-major gather of the
+    # rings (W, 161, 8, 128: 10 MB, wanted) is as large as a ring layer.
+    moved = {(N, Bs, row), (2, N, Bs, row), (rings, Bs, row),
+             (6, rings, Bs, row), (W, M, Bs, row), (W, M * Bs, row),
+             (W, M * Bs, 8, 128), (E, d, f), (E, f, d), (d, f), (f, d)}
+    # A result laid out in S(1) is XLA's own prefetch of an operand into
+    # VMEM (the shared expert's down matrix), not a copy in HBM.
+    rx = re.compile(
+        r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\](\S*) ([\w\-]+)\(", re.M)
+    found = [m.group(0).strip()[:160]
+             for m in rx.finditer(moe_decode.as_text())
+             if m.group(3).startswith(opcode) and "S(1)" not in m.group(2)
+             and tuple(int(x) for x in m.group(1).split(",") if x != "1")
+             in moved]
+    assert not found, found
+
+
+def test_moe_prefill_fits_at_the_largest_bucket(one_chip):
+    """Bucket 3072 (the cell's largest) leaves over 1 GB of the chip:
+    14.45 GB at PR 26 (bucket 4096 needs 14.85 GB and is not used)."""
+    fam, params, pool, ring = _moe_programs(one_chip)
+    i32 = lambda *shape: _sds(shape, jnp.int32, one_chip)  # noqa: E731
+
+    def prefill(params, pool, tokens, prompt_len, full, rings):
+        return fam.prefill(params, pool, tokens, prompt_len, (full, rings))
+
+    compiled = jax.jit(prefill, donate_argnums=1).lower(
+        params, pool, i32(3072), i32(), i32(3072 // MOE["Bs"]), i32(ring),
+    ).compile()
+    text = compiled.as_text()
+    assert "%rlt_flash_fwd" in text and "%rlt_moe_gate_up" in text
+    assert _footprint(compiled) < 14.75e9

@@ -377,6 +377,7 @@ KERNELS = {
     "layer_norm.py": {"rlt_ln_fwd", "rlt_ln_bwd"},
     "lora.py": {"rlt_lora_bgmv"},
     "paged_attention.py": {"rlt_paged_decode"},
+    "moe.py": {"rlt_moe_gate_up", "rlt_moe_down"},
 }
 
 

@@ -680,6 +680,161 @@ class TestClientPlane:
             client.close()
 
 
+class TestCoalescedReplies:
+    """``ServeConfig.coalesce_replies``: one ``serve_batch`` frame a
+    tick per reply address; what a caller is served does not change."""
+
+    def _serve(self, model, coalesce):
+        from ray_lightning_tpu.cluster.queue import QueueHandle
+        from ray_lightning_tpu.serve.client import ServeClient
+
+        m, params = model
+        eng = ServeEngine(m, params, ServeConfig(
+            num_slots=3, block_size=8, coalesce_replies=coalesce,
+        ))
+        frames = []
+        orig = QueueHandle.put
+
+        def spy(handle, item):
+            if isinstance(item, dict) and str(
+                    item.get("type", "")).startswith("serve_") and (
+                    item["type"] != "serve_request"):
+                frames.append(item)
+            orig(handle, item)
+
+        client = ServeClient(eng.queue_handle())
+        prompts = [_rand_prompt(20 + i, 4 + i, m.config.vocab_size)
+                   for i in range(3)]
+        news = [6, 4, 5]
+        try:
+            QueueHandle.put = spy
+            rids = [client.submit(p, n) for p, n in zip(prompts, news)]
+            deadline = time.monotonic() + 10
+            while time.monotonic() < deadline and eng.step():
+                pass
+            eng.run_until_idle()
+            served = [client.result(r, 10) for r in rids]
+        finally:
+            QueueHandle.put = orig
+            eng.stop()
+            client.close()
+        want = [_ref_tokens(m, params, p, n)
+                for p, n in zip(prompts, news)]
+        return served, want, frames
+
+    @pytest.mark.parametrize("coalesce", [False, True])
+    def test_served_tokens_are_the_reference(self, model, coalesce):
+        served, want, _ = self._serve(model, coalesce)
+        assert served == want
+
+    def test_one_frame_a_tick_in_order_and_schema_valid(self, model):
+        from ray_lightning_tpu.telemetry.schema import validate_serve_reply
+
+        _, _, plain = self._serve(model, False)
+        _, _, frames = self._serve(model, True)
+        assert all(f["type"] != "serve_batch" for f in plain)
+        batches = [f for f in frames if f["type"] == "serve_batch"]
+        assert batches, "no tick coalesced its replies"
+        for f in frames:
+            assert validate_serve_reply(f) == [], f
+        # Fewer frames, the same items: 15 tokens and 3 completions.
+        items = [i for f in frames
+                 for i in (f["items"] if f["type"] == "serve_batch" else [f])]
+        assert len(frames) < len(plain) == len(items) == 18
+        for rid in {i["rid"] for i in items}:
+            mine = [i for i in items if i["rid"] == rid]
+            assert [i["index"] for i in mine[:-1]] == list(
+                range(len(mine) - 1))
+            assert mine[-1]["type"] == "serve_done"
+
+    def test_schema_refuses_empty_and_nested_batches(self):
+        from ray_lightning_tpu.telemetry.schema import validate_serve_reply
+
+        tok = {"type": "serve_token", "rid": "r", "index": 0, "token": 1}
+        assert validate_serve_reply(
+            {"type": "serve_batch", "items": [tok, tok]}) == []
+        assert validate_serve_reply({"type": "serve_batch", "items": []})
+        assert validate_serve_reply({"type": "serve_batch", "items": [
+            {"type": "serve_batch", "items": [tok]}]})
+        assert validate_serve_reply({"type": "serve_batch", "items": [
+            {**tok, "index": -1}]})
+
+
+class TestDecodeLookahead:
+    """``ServeConfig.decode_lookahead``: the next decode is dispatched
+    before this tick's tokens are booked; what is served does not
+    change, whatever happens to a slot while a decode is in flight."""
+
+    def _engine(self, model, **kw):
+        m, params = model
+        return ServeEngine(m, params, ServeConfig(
+            num_slots=3, block_size=8, decode_lookahead=True, **kw))
+
+    @pytest.mark.parametrize("coalesce", [False, True])
+    def test_served_tokens_are_the_reference(self, model, coalesce):
+        """More requests than slots, lengths that end on different
+        ticks: admissions and completions interleave with ticks
+        dispatched ahead."""
+        m, params = model
+        eng = self._engine(model, coalesce_replies=coalesce)
+        prompts = [_rand_prompt(40 + i, 3 + (5 * i) % 11,
+                                m.config.vocab_size) for i in range(7)]
+        news = [9, 2, 12, 1, 7, 5, 10]
+        handles = [eng.submit(p, n) for p, n in zip(prompts, news)]
+        eng.run_until_idle()
+        for h, p, n in zip(handles, prompts, news):
+            assert h.result(1) == _ref_tokens(m, params, p, n)
+        c = eng.stats.counters
+        assert 0 < c["decode_ahead"] < c["decode_steps"]
+        assert eng._ahead is None      # nothing left in flight at idle
+        assert c["tokens_out"] == sum(news)
+
+    def test_over_the_client_plane_with_eos(self, model):
+        from ray_lightning_tpu.serve.client import ServeClient
+
+        m, params = model
+        prompt = _rand_prompt(61, 6, m.config.vocab_size)
+        want = _ref_tokens(m, params, prompt, 10)
+        eos = want[4]
+        cut = want[:want.index(eos) + 1]
+        eng = self._engine(model, coalesce_replies=True).start()
+        client = ServeClient(eng.queue_handle())
+        try:
+            other = client.submit(_rand_prompt(62, 5, m.config.vocab_size),
+                                  12)
+            got = list(client.stream(prompt, 10, eos_token_id=eos,
+                                     timeout=60))
+            assert got == cut
+            assert len(client.result(other, 60)) == 12
+        finally:
+            eng.stop()
+            client.close()
+
+    def test_slot_cancelled_while_a_decode_is_in_flight(self, model):
+        m, params = model
+        eng = self._engine(model)
+        p1 = _rand_prompt(71, 5, m.config.vocab_size)
+        p2 = _rand_prompt(72, 7, m.config.vocab_size)
+        p3 = _rand_prompt(73, 4, m.config.vocab_size)
+        h1, h2 = eng.submit(p1, 12), eng.submit(p2, 12)
+        while eng._ahead is None:
+            assert eng.step()
+        assert eng.cancel(h2.rid)
+        h3 = eng.submit(p3, 6)          # takes a slot mid-flight
+        eng.run_until_idle()
+        assert h1.result(1) == _ref_tokens(m, params, p1, 12)
+        assert h3.result(1) == _ref_tokens(m, params, p3, 6)
+        assert h2.done() and len(h2.tokens) < 12
+        assert eng.stats.counters["cancelled"] == 1
+
+    @pytest.mark.parametrize("kw", [
+        {"prefix_cache": True}, {"prefill_chunk": 8},
+        {"max_adapters": 2, "adapter_rank": 4}])
+    def test_refused_with_what_it_does_not_combine_with(self, model, kw):
+        with pytest.raises(ValueError, match="decode_lookahead"):
+            self._engine(model, **kw)
+
+
 def test_bench_serve_block_in_artifacts_gated():
     """A drifted serve block in a committed BENCH artifact fails the
     format.sh layer-4 gate (scan wired into check_telemetry_schema)."""
