@@ -547,6 +547,14 @@ class ServeFamily:
         return TwoKindKVCache(self.cfg, num_blocks, block_size, num_slots,
                               dtype)
 
+    def prepare_params(self, tree: Dict[str, Any],
+                       compute_dtype) -> Dict[str, Any]:
+        """The tree as it came, no leaf copied: ``init_params`` makes
+        the weights in ``param_dtype``, which is the compute dtype, and
+        the router in float32, which it has to stay; a second copy of
+        any of it would not fit beside the first."""
+        return tree
+
 
 # ---------------------------------------------------------------------------
 # the module

@@ -67,7 +67,8 @@ class PrefillRunner:
         from ray_lightning_tpu.cluster.queue import DriverQueue
         from ray_lightning_tpu.models.generate import _reject_unmerged_lora
         from ray_lightning_tpu.serve.kv_cache import (
-            PagedKVCache, PrefixIndex, paged_prefill, paged_verify_step,
+            GPTServeFamily, PagedKVCache, PrefixIndex, paged_prefill,
+            paged_verify_step,
         )
         from ray_lightning_tpu.serve.scheduler import derive_geometry
 
@@ -76,8 +77,11 @@ class PrefillRunner:
         self.cfg = module.config
         self.serve_cfg = serve_cfg
         _reject_unmerged_lora(params)
-        self.params = jax.tree.map(jnp.asarray, params)
         self._c = module._compute_dtype()
+        # Held as the engine holds it (the same method of the same
+        # family): a worker and its replicas read equal dtypes.
+        self.params = GPTServeFamily(self.cfg).prepare_params(
+            jax.tree.map(jnp.asarray, params), self._c)
         self.max_model_len, self.buckets = derive_geometry(
             serve_cfg, self.cfg
         )

@@ -228,14 +228,22 @@ class ServeEngine:
             Scheduler, derive_geometry,
         )
 
-        def _prep(tree):
+        def _prep(tree, family, c):
+            """The tree the programs are called with, and how many of
+            its leaves changed dtype on the way.  The caller's tree is
+            not kept: it can be dropped after ``ServeEngine(...)``."""
             tree = jax.tree.map(jnp.asarray, tree)
             # Same backend gate as generate(): off-TPU, per-token
             # dequant inside the decode program costs more than the
             # weight-bandwidth it saves — hoist it once at engine build.
             if is_quantized(tree) and jax.default_backend() != "tpu":
                 tree = dequantize_decode_params(tree)
-            return tree
+            # The family whose programs read the tree holds it in the
+            # dtype they read it in: no weight is converted per call.
+            was = [leaf.dtype for leaf in jax.tree.leaves(tree)]
+            tree = family.prepare_params(tree, c)
+            now = [leaf.dtype for leaf in jax.tree.leaves(tree)]
+            return tree, sum(a != b for a, b in zip(was, now))
 
         self.module = module
         self.cfg = module.config
@@ -263,8 +271,8 @@ class ServeEngine:
                     f"rings) and does not support: {', '.join(refused)}"
                 )
         _reject_unmerged_lora(params)
-        self.params = _prep(params)
         self._c = module._compute_dtype()
+        self.params, cast_leaves = _prep(params, self.family, self._c)
         if (draft_module is None) != (draft_params is None):
             raise ValueError(
                 "draft_module and draft_params come as a pair"
@@ -318,8 +326,11 @@ class ServeEngine:
                     f"tokens would not be target tokens"
                 )
             _reject_unmerged_lora(draft_params)
-            self.draft_params = _prep(draft_params)
             self._draft_c = draft_module._compute_dtype()
+            # The draft's programs are GPT's (serve/kv_cache.py).
+            self.draft_params, _ = _prep(
+                draft_params, GPTServeFamily(draft_module.config),
+                self._draft_c)
         self.spec_k = cfg.spec_k if draft_module is not None else 0
 
         if (cfg.max_model_len or 0) > self.cfg.seq_len:
@@ -417,6 +428,14 @@ class ServeEngine:
         # factors.
         self._prefix_drops: List[str] = []
         self.stats = ServeStats()
+        # Set once: the bytes of the target's tree as the programs are
+        # called with it, and the leaves whose dtype the preparation
+        # changed (0 where the tree came in the dtype it is read in).
+        self.stats.bump_many({
+            "weights_resident_bytes": sum(
+                leaf.nbytes for leaf in jax.tree.leaves(self.params)),
+            "weights_cast_leaves": cast_leaves,
+        })
         self._pool = self.cache.init_pool()
         self._draft_pool = None
         if draft_module is not None:

@@ -935,10 +935,24 @@ class GPTServeFamily:
     programs.  This is ``GPT``'s (one kind of cache state, the functions
     of this file); a module with a ``serve_family()`` method brings its
     own (``models/exaone_moe.py``: window and full layers in one cache
-    manager), and the engine asks nothing else of a family."""
+    manager), and the engine asks nothing else of a family:
+    ``make_cache``, ``prefill``, ``decode``, ``prepare_params``,
+    ``vocab_size``, ``two_kind``."""
 
     name = "gpt"
     two_kind = False     # one block table a slot, one pool a tensor
+
+    # What the programs of this file read through ``.astype(c)``: the
+    # matmul weights that pass through ``resolve_weight`` with their
+    # biases and, in an int8 tree, their scales (``*_q8`` stays int8),
+    # and the two tables.  Everything else is read in its own dtype:
+    # LayerNorm gains and biases in float32, and a routed GPT's
+    # ``gate_w`` / ``moe_*`` leaves, whose compute dtype IS
+    # ``gate_w.dtype`` (``models/gpt.py`` ``_moe_residual``).
+    _CAST_TABLES = ("wte", "wpe", "wte_sc")
+    _CAST_BLOCK_LEAVES = tuple(
+        f"{w}{suffix}" for w in ("qkv", "proj", "mlp_in", "mlp_out")
+        for suffix in ("_w", "_b", "_w_sc"))
 
     def __init__(self, cfg: GPTConfig):
         self.cfg = cfg
@@ -949,6 +963,32 @@ class GPTServeFamily:
     def make_cache(self, num_blocks: int, block_size: int, num_slots: int,
                    dtype) -> "PagedKVCache":
         return PagedKVCache(self.cfg, num_blocks, block_size, dtype=dtype)
+
+    def prepare_params(self, tree: Dict[str, Any],
+                       compute_dtype) -> Dict[str, Any]:
+        """The tree the programs are called with, made once at build:
+        the leaves they would cast to ``compute_dtype`` on every call,
+        held in it.  ``bf16(W)`` once is bit for bit ``bf16(W)`` per
+        call, and the casts inside the programs become no-ops.  A leaf
+        already in its dtype is handed on as it is, a tree with nothing
+        to cast (float32 compute) is returned itself; the cast runs a
+        leaf at a time, so the transient is one leaf."""
+        c = jnp.dtype(compute_dtype)
+
+        def stale(leaves, names):
+            return [k for k in names if k in leaves and leaves[k].dtype != c]
+
+        tables = stale(tree, self._CAST_TABLES)
+        in_blocks = stale(tree["blocks"], self._CAST_BLOCK_LEAVES)
+        if not tables and not in_blocks:
+            return tree
+        blocks = dict(tree["blocks"])
+        for k in in_blocks:
+            blocks[k] = blocks[k].astype(c)
+        out = {**tree, "blocks": blocks}
+        for k in tables:
+            out[k] = out[k].astype(c)
+        return out
 
 
 def make_slot_keys(

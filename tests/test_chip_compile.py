@@ -289,11 +289,12 @@ def test_step_program_names_its_kernels(small_step, kernel):
 @pytest.fixture(scope="module")
 def serve_decode(one_chip):
     """``paged_decode_step`` as the engine jits it (pool donated) for
-    GPT-2-large at the serve cell's shapes: float32 params, bf16
-    compute, bf16 pool."""
+    GPT-2-large at the serve cell's shapes: the float32 params as the
+    engine holds them for bf16 compute (``prepare_params``), bf16
+    pool."""
     from ray_lightning_tpu.models import GPT, GPTConfig
     from ray_lightning_tpu.serve.kv_cache import (
-        PagedKVCache, paged_decode_step,
+        GPTServeFamily, PagedKVCache, paged_decode_step,
     )
 
     cfg = GPTConfig(n_layer=SERVE["n_layer"], n_head=SERVE["n_head"],
@@ -304,7 +305,9 @@ def serve_decode(one_chip):
             lambda l: _sds(l.shape, l.dtype, one_chip), tree)
 
     params = abstract(jax.eval_shape(
-        GPT(cfg, attn_impl="auto").init_params, jax.random.PRNGKey(0)))
+        lambda key: GPTServeFamily(cfg).prepare_params(
+            GPT(cfg, attn_impl="auto").init_params(key), jnp.bfloat16),
+        jax.random.PRNGKey(0)))
     pool = abstract(jax.eval_shape(
         PagedKVCache(cfg, SERVE["N"], SERVE["Bs"], jnp.bfloat16).init_pool))
 
@@ -328,6 +331,27 @@ def test_serve_decode_step_compiles_names_its_kernel_and_fits(serve_decode):
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert total < 12.9e9, f"decode needs {total / 1e9:.2f} GB"
+
+
+def test_serve_decode_step_converts_no_weight(serve_decode):
+    """The engine's tree is in the dtype the program reads: the program
+    holds 1.55 GB of bf16 weights and no float32 copy of them (with the
+    caller's float32 tree it needed 7.73 GB: 3.1 GB of arguments and
+    1.4 GB of converted temporaries more), and no ``convert`` makes a
+    whole stack of a weight or a table."""
+    import re
+
+    total = _footprint(serve_decode)
+    assert total < 5.2e9, f"decode needs {total / 1e9:.2f} GB"
+    L, d = SERVE["n_layer"], SERVE["n_head"] * DH
+    stacks = {L * d * 3 * d, L * d * d, L * d * 4 * d, V * d, 1024 * d}
+    rx = re.compile(
+        r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* convert\(", re.M)
+    found = [m.group(0).strip()[:160]
+             for m in rx.finditer(serve_decode.as_text())
+             if int(np.prod([int(n) for n in m.group(1).split(",")]))
+             in stacks]
+    assert not found, found
 
 
 @pytest.mark.parametrize("opcode", ["copy", "convert", "gather",

@@ -764,7 +764,7 @@ class TestHandoffAdmission:
     half of the split, without the fleet around it."""
 
     def _handoff_via_worker(self, model, req, serve_cfg, kv_to,
-                            same_host=True):
+                            same_host=True, check=None):
         from ray_lightning_tpu.serve.dist.prefill import PrefillRunner
 
         m, params = model
@@ -772,6 +772,8 @@ class TestHandoffAdmission:
         worker = PrefillRunner("pw", m, params, serve_cfg,
                                beats.handle, beat_s=60.0)
         try:
+            if check is not None:
+                check(worker)
             worker._inbox.handle.put(make_dispatch_item(
                 req, kv_to, same_host=same_host))
             assert worker.step(timeout=5)
@@ -807,6 +809,56 @@ class TestHandoffAdmission:
             assert done[0]["tokens"] == ref[0]
             assert eng.stats.counters["kv_imports"] == 1
             assert eng.stats.counters["prefills"] == 0
+        finally:
+            eng.stop()
+            replies.shutdown()
+
+    @pytest.mark.parametrize("temperature", [0.0, 0.8])
+    def test_bf16_worker_and_engine_hold_equal_trees(self, dist_model,
+                                                     temperature):
+        """One float32 tree, bf16 compute: the worker and the engine
+        prepare it through the same method of the same family, so they
+        hold equal dtypes leaf for leaf (the weights in bf16, LayerNorm
+        in float32), and a handed-off prefill decodes to the monolith's
+        tokens."""
+        import jax
+        import jax.numpy as jnp
+
+        from ray_lightning_tpu.models.gpt import GPT
+        from ray_lightning_tpu.serve.engine import ServeEngine
+
+        m32, params = dist_model
+        m = GPT(m32.config, attn_impl="xla")
+        m.precision = "bf16"
+        prompt = list(range(1, 11))
+        ref = _reference_tokens((m, params), [prompt], [temperature])
+        eng = ServeEngine(m, params, _serve_cfg())
+        replies = DriverQueue()
+
+        def same_dtypes(worker):
+            held = jax.tree.map(lambda a: a.dtype, eng.params)
+            assert held == jax.tree.map(lambda a: a.dtype, worker.params)
+            assert held["blocks"]["qkv_w"] == jnp.bfloat16
+            assert held["wte"] == jnp.bfloat16
+            assert held["blocks"]["ln1_g"] == jnp.float32
+
+        try:
+            req = request_fields(
+                "h1", prompt, 8,
+                reply=(replies.handle.host, replies.handle.port),
+                sample_seed=0, temperature=temperature,
+            )
+            self._handoff_via_worker(
+                (m, params), req, _serve_cfg(),
+                (eng.queue_handle().host, eng.queue_handle().port),
+                check=same_dtypes,
+            )
+            eng.run_until_idle()
+            done = [i for i in _drain(replies, timeout=5)
+                    if i["type"] == "serve_done"]
+            assert done and done[0]["status"] == "finished"
+            assert done[0]["tokens"] == ref[0]
+            assert eng.stats.counters["kv_imports"] == 1
         finally:
             eng.stop()
             replies.shutdown()
