@@ -76,7 +76,7 @@ def check(cond: bool, msg: str) -> None:
 def model_config(size: str) -> GPTConfig:
     if size == "full":
         # Published GPT-2-small widths, untouched; the 10-step LR
-        # warm-up (bench.py's) lets 27 steps show a falling loss.
+        # warm-up lets 27 steps show a falling loss.
         return dataclasses.replace(GPTConfig.gpt2_small(), warmup_steps=10)
     return GPTConfig(vocab_size=512, n_layer=2, n_head=4, d_model=256,
                      seq_len=128, warmup_steps=10)

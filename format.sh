@@ -13,10 +13,9 @@
 #   2. flake8 (pinned below, when importable) — the CI lint gate;
 #   3. yapf --diff/--in-place (pinned below, when importable) with the
 #      repo .style.yapf;
-#   4. telemetry artifact schema gate (tools/check_telemetry_schema.py,
-#      no deps beyond the package) — exporter/schema drift fails fast;
-#      self-tests cover spans, the live plane, flight bundles AND the
-#      bench host_overhead block (megastep dispatch accounting);
+#   4. wire-schema gate (tests/test_wire_schemas.py, also part of
+#      tier-1) — every real producer of an item that crosses a process
+#      or machine boundary against its validator in telemetry/schema.py;
 #   5. chaos-plane smoke (tools/chaos_sweep.py --selftest, no
 #      subprocesses/fits) — the RLT_FAULT grammar, deterministic
 #      matching, exactly-once markers and the file corruptors vs the
@@ -122,17 +121,13 @@ else
   echo "format.sh: yapf not installed (pip install yapf==${YAPF_VERSION}) — skipped"
 fi
 
-# -- layer 4: telemetry artifact schemas (zero extra deps) -------------------
-# Gates producer/schema drift: exporter self-test (spans, Chrome traces,
-# heartbeat/event/log stream items, crash flight bundles, the bench
-# host_overhead block), the committed flight-bundle fixture
-# (tests/data/flight_bundle.json), and BENCH_*.json telemetry/fault/
-# host_overhead blocks (tools/check_telemetry_schema.py).
-python tools/check_telemetry_schema.py || fail=1
-# Cross-round regression diff self-check (tools/rlt_bench_diff.py):
-# the gated-key table + direction rules stay honest, so a drifted key
-# path can't silently drop a metric from the trajectory diff.
-python tools/rlt_bench_diff.py --selftest || fail=1
+# -- layer 4: wire schemas (zero extra deps) ----------------------------------
+# Gates producer/schema drift: spans, Chrome traces, heartbeat/event/log
+# stream items, crash flight bundles (and the committed fixture
+# tests/data/flight_bundle.json), program-ledger rows, the serving
+# plane's frames and the MPMD transfer frames, each built by its real
+# producer and held to its validator.
+python -m pytest tests/test_wire_schemas.py -q -p no:cacheprovider || fail=1
 
 # -- layer 5: chaos-plane smoke (zero extra deps, no subprocess fits) --------
 # Gates the fault-injection grammar + deterministic matching + the
@@ -140,8 +135,8 @@ python tools/rlt_bench_diff.py --selftest || fail=1
 # turn the recovery acceptance suite into a no-op.
 python tools/chaos_sweep.py --selftest || fail=1
 # Serving-plane sibling (tools/chaos_serve_sweep.py --selftest): the
-# serve fault templates, the brownout ladder's hysteresis/probe logic,
-# client retry backoff maths, and the scorecard->bench-block contract.
+# serve fault templates, the brownout ladder's hysteresis/probe logic
+# and client retry backoff maths.
 # The full serving matrix lives in "python tools/chaos_serve_sweep.py".
 python tools/chaos_serve_sweep.py --selftest || fail=1
 

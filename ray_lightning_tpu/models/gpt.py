@@ -844,10 +844,10 @@ def residual_save_bytes(
     policy: str,
     precision: str = "bf16",
 ) -> int:
-    """Analytic bytes the remat backward SAVES per step under a policy —
-    the accounting behind the bench's ``residual_policy`` block (chip
-    truth comes from the profiler's dynamic-update-slice lines; this is
-    the model that says which arm to expect to win and by how much).
+    """Analytic bytes the remat backward SAVES per step under a policy
+    (chip truth comes from the profiler's dynamic-update-slice lines;
+    this is the model that says which arm to expect to win and by how
+    much).
 
     Per layer, the saved set is: the scan CARRY (the block's residual-
     stream input, stacked across layers by the scan — the top profiler

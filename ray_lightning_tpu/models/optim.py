@@ -7,8 +7,8 @@ adam-family ``optax.GradientTransformation`` so its moments are STORED
 in bf16 or block-scaled int8 (``ops/optim_quant.py``) while the update
 math stays f32 — dequant → f32 update → requant runs inside the donated
 train step, so the f32 moments never persist in HBM.
-:func:`opt_state_bytes` is the analytic accounting the bench's
-``opt_state`` block reports.
+:func:`opt_state_bytes` is the analytic accounting of that state's
+bytes (``tests/test_opt_state.py``).
 """
 
 from __future__ import annotations
@@ -217,7 +217,7 @@ def opt_state_bytes(
     min_quant_size: int = MIN_QUANT_SIZE,
 ) -> int:
     """Analytic HBM bytes of the PERSISTENT AdamW moment state under a
-    precision policy — the bench ``opt_state`` block's accounting.
+    precision policy.
     Counts both moments per parameter leaf; scalars/counts are noise
     and ignored.  ``dtype=None`` models the GPT legacy default (bf16
     first moment via ``mu_dtype``, f32 second)."""

@@ -1,10 +1,10 @@
-"""ResNet-18 for CIFAR-scale images (BASELINE config #3: ResNet-18 /
-CIFAR-10 over a multi-host data-parallel mesh).
+"""ResNet-18 for CIFAR-scale images (the reference's third example
+config: ResNet-18 / CIFAR-10 over a multi-host data-parallel mesh).
 
 The reference framework has no vision model of its own — its examples lean
 on torchvision/pl_bolts (reference ``examples/ray_ddp_example.py``,
 ``ray_ddp_sharded_example.py:62``); this module provides the in-framework
-equivalent so the BASELINE grid is runnable end to end.
+equivalent so the reference's example grid is runnable end to end.
 
 TPU-first design choices (not a torch translation):
 

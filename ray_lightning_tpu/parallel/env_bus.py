@@ -20,7 +20,7 @@ Two consumers, which is the point:
 
 Adding a knob: one :class:`EnvKnob` line here.  ``forward=True`` puts
 it on the worker bridge; ``forward=False`` documents why it is
-driver-, agent-, or bench-local.  The linter parses this file with
+driver-, agent-, or entry-point-local.  The linter parses this file with
 ``ast`` (no import), so keep entries as plain ``EnvKnob("NAME", ...)``
 calls with a literal first argument.
 """
@@ -91,16 +91,7 @@ KNOBS: Tuple[EnvKnob, ...] = (
     EnvKnob("RLT_MONITOR_DIR", False, "monitor artifact directory"),
     EnvKnob("RLT_PROM_FILE", False, "OpenMetrics textfile path"),
     EnvKnob("RLT_PROM_PORT", False, "OpenMetrics localhost port"),
-    # -- bench / entry-point knobs (never reach workers by design) -------
-    EnvKnob("RLT_OPT_STATE_DTYPE", False, "bench opt-state arm"),
-    EnvKnob("RLT_REMAT_POLICY", False, "bench remat arm"),
-    EnvKnob("RLT_SPEC_K", False, "bench speculative width"),
-    EnvKnob("RLT_PREFIX_CACHE", False, "bench prefix-cache arm gate"),
-    EnvKnob("RLT_PREFIX_SHARE", False, "bench shared-prefix mix %"),
-    EnvKnob("RLT_PREFILL_CHUNK", False, "bench chunked-prefill width"),
-    EnvKnob("RLT_DISAGG_REPLICAS", False, "bench fleet width"),
-    EnvKnob("RLT_DISAGG_PREFILL", False, "bench prefill workers"),
-    EnvKnob("RLT_MAX_ADAPTERS", False, "bench multi-LoRA tenant count"),
+    # -- entry-point knobs (never reach workers by design) ---------------
     EnvKnob("RLT_DRYRUN_MPMD", False, "graft-entry mpmd flavor gate"),
     # -- SLO & capacity plane (serve entry points + router) --------------
     EnvKnob("RLT_SLO", False, "serve SLO burn-rate evaluator gate"),
@@ -124,9 +115,6 @@ KNOBS: Tuple[EnvKnob, ...] = (
             "client retry attempts on typed rejections (client-local)"),
     EnvKnob("RLT_RETRY_BACKOFF_S", False,
             "client retry backoff base seconds (client-local)"),
-    EnvKnob("RLT_SERVE_CHAOS", False,
-            "bench_serve: skip the migration-vs-failover serve_chaos "
-            "phase when 0 (bench-process-local gate)"),
 )
 
 

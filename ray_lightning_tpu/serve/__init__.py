@@ -27,15 +27,15 @@ compiled ONCE and re-dispatched forever:
   stacked in resident device buffers, applied per-slot via a gathered
   BGMV with an int32 ``adapter_ids`` operand — any tenant mix shares
   the compiled-once program set (zero steady-state recompiles);
-* :mod:`.metrics` — the jax-free SLO stats engine the bench and the
+* :mod:`.metrics` — the jax-free SLO stats engine the engine and the
   exporters share;
 * :mod:`.dist` — **disaggregated multi-replica serving**: prefill
   workers shipping paged-KV blocks over the queue plane to N decode
   replicas behind a load-aware router with heartbeat failover
   (imported lazily — ``from ray_lightning_tpu.serve.dist import ...``).
 
-See ``docs/SERVING.md`` for architecture, knobs and the bench
-methodology (``bench_serve.py``).
+See ``docs/SERVING.md`` for architecture and knobs; the serve cells of
+``benchmarks/`` (``PERF.md``) are what is measured on the chip.
 """
 
 from ray_lightning_tpu.serve.client import ServeClient, ServeRejected

@@ -20,8 +20,8 @@ export tick produces, then derives:
 - **saturation prediction** — ``predict_saturation_rps(max_new)``
   balances the engine-time budget (one measured admission cost plus
   ``max_new−1`` full-width tick shares per request) into a
-  request-rate knee, gated against the measured Poisson-sweep knee
-  in bench_serve's ``slo`` block (±20%).
+  request-rate knee (not yet held against a knee measured on the chip:
+  no open-loop cell).
 - **KV-exhaustion ETA** — the free-block trend extrapolated to zero.
 - **queue-wait slope / rejection rate** — leading indicators the
   burn-rate alerts and the router's headroom tie-break consume.

@@ -122,8 +122,7 @@ class ServeClient:
         self._lock = threading.Lock()
         self._closed = threading.Event()
         # Tokens whose index had already streamed (preemption, router
-        # failover, or hedged-duplicate re-emissions, deduped below) —
-        # the disagg bench's re-emission accounting.
+        # failover, or hedged-duplicate re-emissions, deduped below).
         self.re_emitted_tokens = 0
         # Resilience accounting + the p99 estimate hedging adapts to.
         self.retry = retry if retry is not None else RetryPolicy.from_env()

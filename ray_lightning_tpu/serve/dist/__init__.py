@@ -21,8 +21,9 @@ sliding-window restart governor for prefill workers.
   ``router-live.json`` / per-replica OpenMetrics export.
 
 See docs/SERVING.md "Disaggregated serving" for the dataflow diagram,
-wire format and failover semantics; ``bench_serve.py`` carries the
-disagg-vs-monolith A/B and the kill-a-replica chaos arm.
+wire format and failover semantics; ``tools/chaos_serve_sweep.py``
+carries the kill-a-replica chaos matrix.  The fleet has not run on the
+chip (``PERF.md`` section 7).
 """
 
 from ray_lightning_tpu.serve.dist.handoff import (

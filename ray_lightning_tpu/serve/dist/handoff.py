@@ -24,7 +24,7 @@ outside the schema like MPMD activation bytes):
   placement decisions run on.
 
 Everything here is jax-free given payload bytes, so the schema gate
-(``tools/check_telemetry_schema.py``) drives the REAL producers.
+(``tests/test_wire_schemas.py``) drives the REAL producers.
 """
 
 from __future__ import annotations

@@ -1,14 +1,14 @@
 """SLO stats for the serving plane: TTFT, token latency, occupancy.
 
-jax-free so the bench, the schema gate and the exporters can use it
-without a backend.  Latency families are bounded reservoirs (newest-N):
+jax-free so the schema gate and the exporters can use it without a
+backend.  Latency families are bounded reservoirs (newest-N):
 a serving process runs for days; unbounded lists would be a slow leak,
 and SLO percentiles over the recent window are what an operator acts
 on anyway.
 
 Snapshot schema is pinned in ``telemetry/schema.py``
-(``validate_serve_snapshot``) and self-tested by
-``tools/check_telemetry_schema.py`` — ``rlt_top`` and the OpenMetrics
+(``validate_serve_snapshot``) and held to it by
+``tests/test_wire_schemas.py`` — ``rlt_top`` and the OpenMetrics
 exporter parse these dicts long after this producer moves on.
 """
 
@@ -111,8 +111,8 @@ class ServeStats:
         self._phases: Dict[str, _Reservoir] = {}
         # Per-adapter (tenant) accounting — lazily created by
         # note_adapter, so engines without an adapter pool keep
-        # snapshots byte-identical to pre-LoRA rounds.  The bench's
-        # fairness spread and the rlt_top tenant pane read these.
+        # snapshots byte-identical to pre-LoRA rounds.  The fairness
+        # spread gauge and the rlt_top tenant pane read these.
         self._adapters: Dict[str, Dict[str, int]] = {}
         # Prefix-cache block — lazily set by set_prefix, so engines
         # without the cache keep snapshots byte-identical to pre-cache

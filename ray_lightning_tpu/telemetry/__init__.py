@@ -18,8 +18,9 @@ The observability subsystem (ISSUEs 2 + 3).  One import surface:
   ``trainer.monitor_report``), :class:`FlightRecorder` (crash bundles),
   :class:`RankLogHandler` (rank-tagged log ring + forwarding), and
   :mod:`.export_prom` (OpenMetrics textfile/HTTP export);
-* :mod:`.schema` — the artifact-schema validators ``format.sh`` gates
-  on (device traces are read by ``benchmarks/lib/xplane.py``);
+* :mod:`.schema` — the wire-schema validators
+  ``tests/test_wire_schemas.py`` holds every producer to (device traces
+  are read by ``benchmarks/lib/xplane.py``);
 * the **SLO & capacity plane** (ISSUE 18): :class:`TimeSeriesStore`
   (bounded fixed-interval ring store with windowed rate/percentile/
   slope/ETA queries), :class:`SloSpec` / :class:`SloEvaluator`

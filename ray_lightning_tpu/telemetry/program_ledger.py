@@ -33,9 +33,8 @@ Dispatch discipline (why this is safe on hot paths):
 * **Fast path** is one attribute load and a direct ``Compiled`` call
   inside ``try/except`` — no per-call fingerprinting.  A signature
   mismatch surfaces as the executable's own ``TypeError``/
-  ``ValueError``, which routes to the slow path.  Measured overhead vs
-  a bare jit call is tens of nanoseconds (``bench.py`` publishes the
-  A/B as ``programs.ledger_overhead_pct``).
+  ``ValueError``, which routes to the slow path.  Its overhead over
+  a bare jit call has not been measured on the chip.
 * AOT compiles do NOT populate the normal jit call cache, so the
   wrapper never falls back to the plain jitted callable for concrete
   arguments — that would silently double every compile.  The one

@@ -15,7 +15,7 @@ Tiers (``TelemetryConfig.tier``):
 * ``cheap`` — **the default**: counters (the loop's phases among them,
   ``<phase>_us``) + step stats + headline metrics in
   ``callback_metrics``.  Budget: <1% per-step overhead (asserted by
-  the overhead smoke test, measured precisely in ``BENCH_*``);
+  the overhead smoke test; not measured on the chip);
 * ``full``  — cheap + span recording + JSONL/Chrome export at fit end.
 
 Config sources, strongest first: an explicit ``telemetry=`` on the

@@ -1,18 +1,19 @@
-"""Telemetry artifact schemas + validators (the drift gate).
+"""Telemetry wire schemas + validators (the drift gate).
 
-Artifact families leaving this subsystem: JSONL span dumps, Chrome
-``trace_event`` documents, the ``telemetry`` block inside
-``BENCH_*.json``, and — since the live-monitor round — the stream items
-the worker→driver queue carries (``heartbeat``, ``event``, ``log``,
-``metrics``) plus the crash flight bundle ``flight_recorder.py``
-persists.  Downstream consumers (Perfetto, the trace-summary tool,
-``rlt_top``, round-over-round bench comparison, post-mortem tooling)
-parse them long after the producing code has moved on — so the schema
-is written down HERE, and ``tools/check_telemetry_schema.py`` (wired
-into ``format.sh``) fails fast when a producer drifts.
+What crosses a process or machine boundary from this subsystem: JSONL
+span dumps, Chrome ``trace_event`` documents, the stream items the
+worker→driver queue carries (``heartbeat``, ``event``, ``log``,
+``metrics``), the crash flight bundle ``flight_recorder.py`` persists,
+program-ledger rows, the serving plane's request / reply / snapshot /
+handoff frames and the MPMD transfer frames.  Downstream consumers
+(Perfetto, ``rlt_top``, the router, post-mortem tooling) parse them
+long after the producing code has moved on — so the schema is written
+down HERE, and ``tests/test_wire_schemas.py`` (tier-1, and layer 4 of
+``format.sh``) drives every real producer through its validator and
+fails when the two drift apart.
 
 Validators return a list of problem strings (empty = valid) instead of
-raising, so the CLI can report every problem in one pass.  jax-free.
+raising, so a caller can report every problem in one pass.  jax-free.
 """
 
 from __future__ import annotations
@@ -24,12 +25,6 @@ __all__ = [
     "validate_span_jsonl",
     "validate_chrome_trace",
     "validate_trace_context",
-    "validate_bench_trace",
-    "validate_bench_telemetry",
-    "validate_bench_fault",
-    "validate_bench_host_overhead",
-    "validate_bench_opt_state",
-    "validate_bench_residual_policy",
     "validate_heartbeat",
     "validate_event",
     "validate_log_item",
@@ -42,26 +37,15 @@ __all__ = [
     "validate_serve_adapter_load",
     "validate_serve_migration",
     "validate_router_snapshot",
-    "validate_bench_serve",
-    "validate_bench_spec_decode",
-    "validate_bench_prefix_cache",
-    "validate_bench_chunked_prefill",
-    "validate_bench_serve_disagg",
-    "validate_bench_serve_chaos",
-    "validate_bench_multi_lora",
     "validate_mpmd_stage_item",
     "validate_mpmd_xfer",
     "validate_mpmd_snapshot",
-    "validate_bench_mpmd",
-    "validate_bench_comm_overlap",
     "validate_program_row",
     "validate_recompile_record",
     "validate_program_snapshot",
-    "validate_bench_programs",
     "validate_timeseries_point",
     "validate_slo_alert",
     "validate_capacity_snapshot",
-    "validate_bench_slo",
     "FLIGHT_BUNDLE_SCHEMA_ID",
 ]
 
@@ -383,8 +367,7 @@ def validate_flight_bundle(doc: Any, where: str = "bundle") -> List[str]:
 
 # ---------------------------------------------------------------------------
 # Program ledger (telemetry/program_ledger.py): the compiled-executable
-# observatory — per-program cost/memory rows, recompile forensics, and
-# the bench ``programs`` block
+# observatory — per-program cost/memory rows and recompile forensics
 # ---------------------------------------------------------------------------
 
 # One compiled executable: identity + the XLA accounting captured at
@@ -438,23 +421,6 @@ _PROGRAM_SNAPSHOT_OPTIONAL = {
     "dropped": int,       # rows past the ring cap
 }
 
-# The bench ``programs`` block: ledger coverage + the dispatch-overhead
-# A/B (``ledger_overhead_pct`` nullable — the probe is best-effort).
-_BENCH_PROGRAMS_REQUIRED = {
-    "n_programs": int,
-    "compile_time_total_s": (int, float),
-    "recompile_events": int,
-    "ledger_overhead_pct": (int, float, type(None)),
-}
-_BENCH_PROGRAMS_OPTIONAL = {
-    "rows": list,         # program rows (validate_program_row each)
-    "hbm": dict,          # program_ledger.hbm_report()
-    "roofline": dict,     # program_ledger.roofline(...)
-    "mfu_basis": str,     # "analytic" | "measured"
-    "dropped": int,
-}
-
-
 def validate_program_row(row: Any, where: str = "program") -> List[str]:
     problems = _check_fields(
         row, _PROGRAM_ROW_REQUIRED, _PROGRAM_ROW_OPTIONAL, where
@@ -504,29 +470,8 @@ def validate_program_snapshot(snap: Any,
     return problems
 
 
-def validate_bench_programs(block: Any,
-                            where: str = "programs") -> List[str]:
-    """Validate the ``programs`` block of a ``BENCH_*.json`` artifact
-    (absent on pre-ledger rounds)."""
-    problems = _check_fields(
-        block, _BENCH_PROGRAMS_REQUIRED, _BENCH_PROGRAMS_OPTIONAL, where
-    )
-    if problems:
-        return problems
-    if block["n_programs"] < 0:
-        problems.append(f"{where}: negative n_programs")
-    if block["recompile_events"] < 0:
-        problems.append(f"{where}: negative recompile_events")
-    basis = block.get("mfu_basis")
-    if basis is not None and basis not in ("analytic", "measured"):
-        problems.append(f"{where}: invalid mfu_basis {basis!r}")
-    for i, row in enumerate(block.get("rows", [])):
-        problems += validate_program_row(row, f"{where}.rows[{i}]")
-    return problems
-
-
 # ---------------------------------------------------------------------------
-# Serving plane (serve/): wire items, live snapshot, bench block
+# Serving plane (serve/): wire items, live snapshot
 # ---------------------------------------------------------------------------
 
 # The client → engine submission item (serve/client.py → engine inbox).
@@ -908,7 +853,7 @@ def validate_capacity_snapshot(snap: Any,
 
 # ---------------------------------------------------------------------------
 # Disaggregated serving (serve/dist/): KV handoff envelope, router
-# snapshot, bench block
+# snapshot
 # ---------------------------------------------------------------------------
 
 # The prefill worker → decode replica handoff envelope.  Like the MPMD
@@ -1192,513 +1137,9 @@ def validate_router_snapshot(doc: Any,
     return problems
 
 
-# The bench_serve.py artifact block: serving rounds become comparable
-# only if every round spells the SLO numbers the same way.  The A/B
-# ratio and sweep arms are nullable (best-effort probes), the headline
-# latency/throughput numbers are not — a serve bench that cannot
-# measure them has failed.
-_BENCH_SERVE_REQUIRED = {
-    "requests_per_sec": (int, float),
-    "p50_token_latency_ms": (int, float),
-    "p99_token_latency_ms": (int, float),
-    "recompiles_steady_state": int,
-}
-_BENCH_SERVE_OPTIONAL = {
-    "tokens_per_sec": (int, float, type(None)),
-    "p50_ttft_ms": (int, float, type(None)),
-    "p99_ttft_ms": (int, float, type(None)),
-    "continuous_vs_sequential": (int, float, type(None)),
-    "sequential_requests_per_sec": (int, float, type(None)),
-    "sequential_tokens_per_sec": (int, float, type(None)),
-    "num_slots": int,
-    "block_size": int,
-    "num_blocks": int,
-    "completed": int,
-    "preempted": int,
-    "rejected": int,
-    "expired": int,
-    "rate_sweep": list,       # per-offered-rate open-loop arms
-}
-_BENCH_SERVE_SWEEP_REQUIRED = {
-    "offered_rps": (int, float),
-    "requests_per_sec": (int, float),
-    "p50_token_latency_ms": (int, float, type(None)),
-    "p99_token_latency_ms": (int, float, type(None)),
-}
-_BENCH_SERVE_SWEEP_OPTIONAL = {
-    "p50_ttft_ms": (int, float, type(None)),
-    "p99_ttft_ms": (int, float, type(None)),
-    "completed": int,
-    "expired": int,
-    "rejected": int,
-    "queue_depth_max": int,
-}
-
-
-def validate_bench_serve(block: Any, where: str = "serve") -> List[str]:
-    """Validate the ``serve`` block of a bench artifact (absent on
-    pre-serving rounds)."""
-    problems = _check_fields(
-        block, _BENCH_SERVE_REQUIRED, _BENCH_SERVE_OPTIONAL, where
-    )
-    if problems:
-        return problems
-    if block["recompiles_steady_state"] < 0:
-        problems.append(f"{where}: negative recompiles_steady_state")
-    for i, arm in enumerate(block.get("rate_sweep", [])):
-        problems += _check_fields(
-            arm, _BENCH_SERVE_SWEEP_REQUIRED, _BENCH_SERVE_SWEEP_OPTIONAL,
-            f"{where}.rate_sweep[{i}]",
-        )
-    return problems
-
-
-# The bench_serve.py SLO/capacity-plane block: the oracle-calibration
-# gate (predicted saturation knee vs the measured Poisson-sweep knee),
-# the burn-rate alert discrimination check (fires hot, silent cold),
-# the zero-recompile pin and the plane-overhead A/B.  Headline numbers
-# are non-nullable — a round that cannot calibrate has failed; the
-# overhead ratio is best-effort (CPU noise floor).
-_BENCH_SLO_REQUIRED = {
-    "predicted_saturation_rps": (int, float),
-    "measured_saturation_rps": (int, float),
-    "prediction_error_pct": (int, float),
-    "alerts_hot": int,        # slo_alert events in the 1.5x arm
-    "alerts_cold": int,       # slo_alert events in the 0.5x arm
-    "recompiles_steady_state": int,
-}
-_BENCH_SLO_OPTIONAL = {
-    "overhead_pct": (int, float, type(None)),
-    "capacity_tokens_per_s": (int, float, type(None)),
-    "service_rate_per_slot": (int, float, type(None)),
-    "hot_rps": (int, float),
-    "cold_rps": (int, float),
-    "hot_utilization": (int, float, type(None)),
-    "ts_points": int,         # persisted timeseries_point count
-}
-
-
-def validate_bench_slo(block: Any, where: str = "slo") -> List[str]:
-    """Validate the ``slo`` block of a bench artifact (absent on
-    pre-capacity-plane rounds)."""
-    problems = _check_fields(
-        block, _BENCH_SLO_REQUIRED, _BENCH_SLO_OPTIONAL, where
-    )
-    if problems:
-        return problems
-    for key in ("predicted_saturation_rps", "measured_saturation_rps"):
-        if block[key] <= 0:
-            problems.append(f"{where}: {key} must be > 0")
-    if block["prediction_error_pct"] < 0:
-        problems.append(f"{where}: negative prediction_error_pct")
-    for key in ("alerts_hot", "alerts_cold",
-                "recompiles_steady_state"):
-        if block[key] < 0:
-            problems.append(f"{where}: negative {key}")
-    return problems
-
-
-# The bench_serve.py speculative-decoding A/B block: the spec arm and
-# its non-spec baseline must both pin their recompile counters (the
-# zero-recompile steady state is the contract, not a best-effort), and
-# the acceptance sweep scans tokens/s across draft quality.
-_BENCH_SPEC_REQUIRED = {
-    "spec_k": int,
-    "tokens_per_sec": (int, float),            # spec arm, emitted
-    "baseline_tokens_per_sec": (int, float),   # non-spec decode arm
-    "vs_baseline": (int, float),               # the >= 1.5x headline
-    "acceptance_rate": (int, float),
-    "recompiles_steady_state": int,
-    "baseline_recompiles_steady_state": int,
-}
-_BENCH_SPEC_OPTIONAL = {
-    "draft_layers": int,
-    "target_layers": int,
-    "drafted": int,
-    "accepted": int,
-    "emitted": int,
-    "greedy_parity": bool,        # spec tokens == non-spec tokens
-    "requests": int,
-    "max_new_tokens": int,
-    "acceptance_sweep": list,     # per-noise arms
-}
-_BENCH_SPEC_SWEEP_REQUIRED = {
-    "noise": (int, float),        # identity-tail perturbation scale
-    "acceptance_rate": (int, float),
-    "tokens_per_sec": (int, float),
-    "vs_baseline": (int, float),
-}
-
-
-def validate_bench_spec_decode(block: Any,
-                               where: str = "spec_decode") -> List[str]:
-    """Validate the ``spec_decode`` block of a bench artifact (absent
-    on pre-speculation rounds)."""
-    problems = _check_fields(
-        block, _BENCH_SPEC_REQUIRED, _BENCH_SPEC_OPTIONAL, where
-    )
-    if problems:
-        return problems
-    if block["spec_k"] < 1:
-        problems.append(f"{where}: spec_k must be >= 1")
-    if not 0.0 <= block["acceptance_rate"] <= 1.0:
-        problems.append(
-            f"{where}: acceptance_rate {block['acceptance_rate']} "
-            "outside [0, 1]"
-        )
-    for key in ("recompiles_steady_state",
-                "baseline_recompiles_steady_state"):
-        if block[key] < 0:
-            problems.append(f"{where}: negative {key}")
-    for i, arm in enumerate(block.get("acceptance_sweep", [])):
-        arm_problems = _check_fields(
-            arm, _BENCH_SPEC_SWEEP_REQUIRED, {},
-            f"{where}.acceptance_sweep[{i}]",
-        )
-        # Per-arm guard: an earlier arm's failure must not suppress
-        # THIS arm's range check.
-        if not arm_problems and not 0.0 <= arm["acceptance_rate"] <= 1.0:
-            arm_problems.append(
-                f"{where}.acceptance_sweep[{i}]: acceptance_rate "
-                "outside [0, 1]"
-            )
-        problems += arm_problems
-    return problems
-
-
-# The bench_serve.py prefix-cache A/B block: the cached arm serves a
-# shared-prefix workload mix against its cache-off baseline.  Both
-# arms must pin recompiles_steady_state (sharing is operand-only by
-# construction — a recompile would mean the claim leaked into a
-# shape), and the parity flag asserts the cached arm's tokens are
-# bitwise the baseline's.
-_BENCH_PREFIX_REQUIRED = {
-    "prefix_share": (int, float),       # fraction of prompt in the shared prefix
-    "requests": int,
-    "hit_rate": (int, float),
-    "blocks_claimed": int,
-    "ttft_p50_ms": (int, float),                # cached arm
-    "baseline_ttft_p50_ms": (int, float),       # cache-off arm
-    "ttft_speedup": (int, float),               # the >= 1.5x headline
-    "tokens_per_sec": (int, float),
-    "baseline_tokens_per_sec": (int, float),
-    "recompiles_steady_state": int,
-    "baseline_recompiles_steady_state": int,
-}
-_BENCH_PREFIX_OPTIONAL = {
-    "token_parity": bool,       # cached tokens == baseline tokens
-    "blocks_inserted": int,
-    "cached_blocks": int,
-    "prefill_chunks": int,
-    "max_new_tokens": int,
-}
-
-
-def validate_bench_prefix_cache(block: Any,
-                                where: str = "prefix_cache") -> List[str]:
-    """Validate the ``prefix_cache`` block of a bench artifact (absent
-    on pre-cache rounds)."""
-    problems = _check_fields(
-        block, _BENCH_PREFIX_REQUIRED, _BENCH_PREFIX_OPTIONAL, where
-    )
-    if problems:
-        return problems
-    if not 0.0 <= block["hit_rate"] <= 1.0:
-        problems.append(
-            f"{where}: hit_rate {block['hit_rate']} outside [0, 1]"
-        )
-    if not 0.0 <= block["prefix_share"] <= 1.0:
-        problems.append(
-            f"{where}: prefix_share {block['prefix_share']} "
-            "outside [0, 1]"
-        )
-    for key in ("recompiles_steady_state",
-                "baseline_recompiles_steady_state"):
-        if block[key] < 0:
-            problems.append(f"{where}: negative {key}")
-    if block["requests"] < 1:
-        problems.append(f"{where}: requests < 1")
-    return problems
-
-
-# The bench_long_context.py serving-side chunked-prefill block: a long
-# prompt admitted against resident decode traffic, with the no-stall
-# contract surfaced as the max per-step emission gap of the resident
-# slots (1 = a token landed every step; the acceptance bound).
-_BENCH_CHUNKED_REQUIRED = {
-    "prompt_len": int,
-    "chunk_width": int,
-    "chunks": int,
-    "resident_max_stall_ticks": int,
-    "recompiles_steady_state": int,
-}
-_BENCH_CHUNKED_OPTIONAL = {
-    "ttft_ms": (int, float, type(None)),
-    "resident_requests": int,
-    "tokens_per_sec": (int, float, type(None)),
-}
-
-
-def validate_bench_chunked_prefill(block: Any,
-                                   where: str = "chunked_prefill"
-                                   ) -> List[str]:
-    """Validate the ``chunked_prefill`` block of a bench artifact."""
-    problems = _check_fields(
-        block, _BENCH_CHUNKED_REQUIRED, _BENCH_CHUNKED_OPTIONAL, where
-    )
-    if problems:
-        return problems
-    if block["chunk_width"] < 1:
-        problems.append(f"{where}: chunk_width < 1")
-    if block["chunks"] < 1:
-        problems.append(f"{where}: chunks < 1")
-    if block["prompt_len"] < 1:
-        problems.append(f"{where}: prompt_len < 1")
-    if block["resident_max_stall_ticks"] < 0:
-        problems.append(f"{where}: negative resident_max_stall_ticks")
-    if block["recompiles_steady_state"] < 0:
-        problems.append(f"{where}: negative recompiles_steady_state")
-    return problems
-
-
-# The bench_serve.py disaggregated-serving block: the disagg-vs-
-# monolith A/B plus the kill-a-replica chaos arm.  The chaos arm's
-# loss accounting is required when the arm ran — a chaos block that
-# cannot say how many requests survived has failed — and
-# lost_requests is the zero-lost acceptance surface.
-_BENCH_DISAGG_REQUIRED = {
-    "replicas": int,
-    "prefill_workers": int,
-    "requests_per_sec": (int, float),
-    "recompiles_steady_state": int,
-}
-_BENCH_DISAGG_OPTIONAL = {
-    "requests": int,
-    "tokens_per_sec": (int, float, type(None)),
-    "monolith_requests_per_sec": (int, float, type(None)),
-    "vs_monolith": (int, float, type(None)),
-    "kv_imports": int,
-    "prefill_dispatches": int,
-    "p50_ttft_ms": (int, float, type(None)),
-    "p99_ttft_ms": (int, float, type(None)),
-    "chaos": dict,
-}
-_BENCH_DISAGG_CHAOS_REQUIRED = {
-    "killed_replica": str,
-    "submitted": int,
-    "completed": int,
-    "lost_requests": int,
-    "failed_over_requests": int,
-}
-_BENCH_DISAGG_CHAOS_OPTIONAL = {
-    "failover_detect_s": (int, float, type(None)),
-    "re_emitted_tokens": int,
-    "survivor_recompiles_steady_state": int,
-    "offered_rps": (int, float),
-}
-
-
-def validate_bench_serve_disagg(block: Any,
-                                where: str = "serve_disagg") -> List[str]:
-    """Validate the ``serve_disagg`` block of a bench artifact (absent
-    on pre-disaggregation rounds)."""
-    problems = _check_fields(
-        block, _BENCH_DISAGG_REQUIRED, _BENCH_DISAGG_OPTIONAL, where
-    )
-    if problems:
-        return problems
-    if block["replicas"] < 1:
-        problems.append(f"{where}: replicas must be >= 1")
-    if block["prefill_workers"] < 0:
-        problems.append(f"{where}: negative prefill_workers")
-    if block["recompiles_steady_state"] < 0:
-        problems.append(f"{where}: negative recompiles_steady_state")
-    chaos = block.get("chaos")
-    if chaos is not None:
-        chaos_problems = _check_fields(
-            chaos, _BENCH_DISAGG_CHAOS_REQUIRED,
-            _BENCH_DISAGG_CHAOS_OPTIONAL, f"{where}.chaos",
-        )
-        if not chaos_problems:
-            if chaos["lost_requests"] < 0:
-                chaos_problems.append(
-                    f"{where}.chaos: negative lost_requests"
-                )
-            if chaos["completed"] + chaos["lost_requests"] \
-                    > chaos["submitted"]:
-                chaos_problems.append(
-                    f"{where}.chaos: completed + lost > submitted"
-                )
-        problems += chaos_problems
-    return problems
-
-
-# The bench_serve.py serving-chaos block (ISSUE 19): the
-# migration-vs-failover A/B.  Both arms drain/kill a replica
-# mid-stream; the migration arm must lose zero requests, re-emit zero
-# tokens (the KV moved, nothing was recomputed), and keep token parity
-# with the uninterrupted engine — the failover arm is the recompute
-# baseline it beats on time-to-recover.  Both arms pin steady-state
-# recompiles.
-_BENCH_SERVE_CHAOS_REQUIRED = {
-    "migrations": int,                      # migration frames landed
-    "migration_ttr_s": (int, float),        # drain -> stream resumed
-    "failover_ttr_s": (int, float),         # kill -> stream resumed
-    "migration_vs_failover": (int, float),  # failover_ttr / migration_ttr
-    "lost_requests": int,
-    "migration_re_emitted_tokens": int,     # MUST be 0 (no recompute)
-    "recompiles_steady_state": int,
-}
-_BENCH_SERVE_CHAOS_OPTIONAL = {
-    # bool keys ride the optional dict (the required-path bool guard
-    # exists to catch True-as-int); presence is enforced below.
-    "parity": bool,                         # tokens == uninterrupted run
-    "failover_re_emitted_tokens": int,
-    "requests": int,
-    "shed": int,                 # brownout arm: typed shed replies
-    "brownout_level_max": int,
-    "hedges": int,
-    "hedge_cancels": int,
-}
-
-
-def validate_bench_serve_chaos(block: Any,
-                               where: str = "serve_chaos") -> List[str]:
-    """Validate the ``serve_chaos`` block of a bench artifact (absent
-    on pre-chaos rounds)."""
-    problems = _check_fields(
-        block, _BENCH_SERVE_CHAOS_REQUIRED, _BENCH_SERVE_CHAOS_OPTIONAL,
-        where,
-    )
-    if problems:
-        return problems
-    if "parity" not in block:
-        problems.append(f"{where}: missing required key 'parity'")
-    for key in ("migrations", "lost_requests",
-                "migration_re_emitted_tokens",
-                "recompiles_steady_state"):
-        if block[key] < 0:
-            problems.append(f"{where}: negative {key}")
-    for key in ("migration_ttr_s", "failover_ttr_s",
-                "migration_vs_failover"):
-        if block[key] < 0:
-            problems.append(f"{where}: negative {key}")
-    lvl = block.get("brownout_level_max")
-    if lvl is not None and not 0 <= lvl <= 3:
-        problems.append(
-            f"{where}: brownout_level_max {lvl} outside [0, 3]"
-        )
-    return problems
-
-
-# The bench_serve.py multi-tenant LoRA block: N adapters multiplexed
-# over ONE resident base engine vs the merge-and-swap-per-tenant
-# baseline (fold tenant k's factors into the weights, serve its batch,
-# swap for the next tenant — the pre-pool serving shape).  Both arms
-# pin their steady-state recompile counters (the zero-recompile
-# contract covers adapter joins and hot-adds); fairness_spread is
-# min/max lifetime tokens across tenants under uniform offered load
-# (1.0 = perfectly fair, the DRR grant surface); greedy_parity pins
-# every tenant's multiplexed stream token-for-token against its
-# merged-model baseline.
-_BENCH_MULTI_LORA_REQUIRED = {
-    "adapters": int,                           # tenant count (N)
-    "rank": int,                               # stacked-buffer rank
-    "tokens_per_sec": (int, float),            # multiplexed arm
-    "baseline_tokens_per_sec": (int, float),   # merge-and-swap arm
-    "vs_baseline": (int, float),               # the >= 3x headline
-    "fairness_spread": (int, float),
-    "recompiles_steady_state": int,
-    "baseline_recompiles_steady_state": int,
-}
-_BENCH_MULTI_LORA_OPTIONAL = {
-    "requests": int,
-    "max_new_tokens": int,
-    "requests_per_sec": (int, float, type(None)),
-    "greedy_parity": bool,
-    "hot_adds": int,              # tenants joined AFTER warmup
-    "pool_loads": int,
-    "bgmv_impl": str,             # "xla" | "pallas" (engine-resolved)
-    "completed": int,
-}
-
-
-def validate_bench_multi_lora(block: Any,
-                              where: str = "multi_lora") -> List[str]:
-    """Validate the ``multi_lora`` block of a bench artifact (absent on
-    pre-multi-tenant rounds)."""
-    problems = _check_fields(
-        block, _BENCH_MULTI_LORA_REQUIRED, _BENCH_MULTI_LORA_OPTIONAL,
-        where,
-    )
-    if problems:
-        return problems
-    if block["adapters"] < 1:
-        problems.append(f"{where}: adapters must be >= 1")
-    if block["rank"] < 1:
-        problems.append(f"{where}: rank must be >= 1")
-    if not 0.0 <= block["fairness_spread"] <= 1.0:
-        problems.append(
-            f"{where}: fairness_spread {block['fairness_spread']} "
-            "outside [0, 1]"
-        )
-    for key in ("recompiles_steady_state",
-                "baseline_recompiles_steady_state"):
-        if block[key] < 0:
-            problems.append(f"{where}: negative {key}")
-    impl = block.get("bgmv_impl")
-    if impl is not None and impl not in ("xla", "pallas"):
-        problems.append(f"{where}: unknown bgmv_impl {impl!r}")
-    return problems
-
-
-# The bench_serve.py distributed-tracing block: the stitch-coverage /
-# per-phase-percentile / overhead acceptance surface.  ``coverage`` is
-# the fraction of COMPLETED requests whose stitched trace carries a
-# complete queue_wait→…→first_token phase chain (the >=0.95 bar);
-# ``overhead_pct`` is the measured closed-loop headline cost of
-# cheap-tier tracing (the <2% bar); ``phases`` maps each critical-path
-# phase to its p50/p95 over the traced run.
-_BENCH_TRACE_REQUIRED = {
-    "coverage": (int, float),
-    "requests": int,
-    "phases": dict,
-    "overhead_pct": (int, float, type(None)),
-}
-_BENCH_TRACE_OPTIONAL = {
-    "complete_chains": int,
-    "spans": int,
-    "traced_requests_per_sec": (int, float, type(None)),
-    "baseline_requests_per_sec": (int, float, type(None)),
-    "replicas": int,
-    "prefill_workers": int,
-}
-
-
-def validate_bench_trace(block: Any, where: str = "trace") -> List[str]:
-    """Validate the ``trace`` block of a bench artifact (absent on
-    pre-tracing rounds)."""
-    problems = _check_fields(
-        block, _BENCH_TRACE_REQUIRED, _BENCH_TRACE_OPTIONAL, where
-    )
-    if problems:
-        return problems
-    if not 0.0 <= block["coverage"] <= 1.0:
-        problems.append(
-            f"{where}: coverage {block['coverage']} outside [0, 1]"
-        )
-    if block["requests"] < 0:
-        problems.append(f"{where}: negative requests")
-    for phase, summary in block["phases"].items():
-        problems += _check_fields(
-            summary, _SERVE_PHASE_FIELDS, {}, f"{where}.phases.{phase}"
-        )
-    return problems
-
-
 # ---------------------------------------------------------------------------
 # MPMD pipeline plane (mpmd/): stream items, transfer frames, live
-# snapshot, bench block
+# snapshot
 # ---------------------------------------------------------------------------
 
 # Per-optimizer-step stage beat on the worker→driver queue (the MPMD
@@ -1791,268 +1232,3 @@ def validate_mpmd_snapshot(doc: Any,
             item, f"{where}.stages[{i}]"
         )
     return problems
-
-
-# The bench mpmd block: the pipeline A/B becomes round-over-round
-# comparable only if bubble/throughput are spelled the same way.
-# Headline identification is required; each probe arm is nullable.
-_BENCH_MPMD_REQUIRED = {
-    "schedule": str,
-    "n_stages": int,
-    "n_micro": int,
-}
-_BENCH_MPMD_OPTIONAL = {
-    "interleave": int,
-    "bubble_fraction": (int, float, type(None)),
-    "gpipe_bubble_fraction": (int, float, type(None)),
-    "stage_occupancy": (int, float, type(None)),
-    "stage_skew_ms": (int, float, type(None)),
-    "tokens_per_sec": (int, float, type(None)),
-    "single_mesh_tokens_per_sec": (int, float, type(None)),
-    "vs_single_mesh": (int, float, type(None)),
-    "loss_parity_max_diff": (int, float, type(None)),
-    "op_costs_ms": dict,
-}
-
-
-def validate_bench_mpmd(block: Any, where: str = "mpmd") -> List[str]:
-    """Validate the ``mpmd`` block of a ``BENCH_*.json`` artifact
-    (absent on pre-MPMD rounds)."""
-    problems = _check_fields(
-        block, _BENCH_MPMD_REQUIRED, _BENCH_MPMD_OPTIONAL, where
-    )
-    if problems:
-        return problems
-    if block["n_stages"] < 1:
-        problems.append(f"{where}: n_stages must be >= 1")
-    if block["n_micro"] < 1:
-        problems.append(f"{where}: n_micro must be >= 1")
-    for key in ("bubble_fraction", "gpipe_bubble_fraction"):
-        value = block.get(key)
-        if isinstance(value, (int, float)) and not 0 <= value <= 1:
-            problems.append(f"{where}: {key} {value} outside [0, 1]")
-    return problems
-
-
-# The bench comm_overlap block: the backward-overlapped grad-sync A/B
-# (round 25).  Both arms run the SAME int8_ef grad-comm config on the
-# same mesh; only `segments` differs (0 = step-end sync, G >= 1 =
-# tapped backward).  ``loss_rel_diff`` is the A/B fit parity at the EF
-# tolerance; ``bytes_ratio`` = overlap grad_sync_bytes / step-end
-# (bucket re-planning pads per group, so ~1.0 within 10%);
-# ``collectives_before_last_dot_*`` is the HLO-structural proof that
-# the overlapped arm's bucket collectives are data-dependence-ordered
-# INTO the backward rather than appended after it (step-end arm: 0).
-# ``mpmd_*`` keys record the quantized-DCN-wire probe.  Probe keys are
-# nullable — each arm is best-effort.
-_BENCH_COMM_OVERLAP_REQUIRED = {
-    "segments": int,
-    "mode": str,
-    "loss_rel_diff": (int, float),
-}
-_BENCH_COMM_OVERLAP_OPTIONAL = {
-    "devices": (int, type(None)),
-    "loss_step_end": (int, float, type(None)),
-    "loss_overlap": (int, float, type(None)),
-    "grad_sync_bytes_step_end": (int, float, type(None)),
-    "grad_sync_bytes_overlap": (int, float, type(None)),
-    "bytes_ratio": (int, float, type(None)),
-    "dispatches_per_opt_step_step_end": (int, float, type(None)),
-    "dispatches_per_opt_step_overlap": (int, float, type(None)),
-    "recompiles_step_end": (int, type(None)),
-    "recompiles_overlap": (int, type(None)),
-    "collectives_before_last_dot_step_end": (int, type(None)),
-    "collectives_before_last_dot_overlap": (int, type(None)),
-    "hlo_gate": (bool, type(None)),
-    "mpmd_wire_enc": (str, type(None)),
-    "mpmd_wire_ratio": (int, float, type(None)),
-    "mpmd_loss_rel_diff": (int, float, type(None)),
-}
-
-
-def validate_bench_comm_overlap(
-    block: Any, where: str = "comm_overlap"
-) -> List[str]:
-    """Validate the ``comm_overlap`` block of a ``BENCH_*.json``
-    artifact (absent on pre-overlap rounds)."""
-    problems = _check_fields(
-        block, _BENCH_COMM_OVERLAP_REQUIRED,
-        _BENCH_COMM_OVERLAP_OPTIONAL, where,
-    )
-    if problems:
-        return problems
-    if block["segments"] < 1:
-        problems.append(
-            f"{where}: segments must be >= 1 (the overlapped arm), got "
-            f"{block['segments']}"
-        )
-    if block["loss_rel_diff"] < 0:
-        problems.append(f"{where}: negative loss_rel_diff")
-    ratio = block.get("bytes_ratio")
-    if isinstance(ratio, (int, float)) and not 0.9 <= ratio <= 1.1:
-        problems.append(
-            f"{where}: bytes_ratio {ratio} outside [0.9, 1.1] — "
-            "overlap bucketing must not change the wire volume"
-        )
-    if block.get("hlo_gate") is True:
-        before = block.get("collectives_before_last_dot_overlap")
-        if not isinstance(before, int) or before < 1:
-            problems.append(
-                f"{where}: hlo_gate claims interleaving but "
-                "collectives_before_last_dot_overlap is not a positive "
-                "count"
-            )
-    wire = block.get("mpmd_wire_ratio")
-    if isinstance(wire, (int, float)) and wire < 1.0:
-        problems.append(
-            f"{where}: mpmd_wire_ratio {wire} < 1 (codec inflated the "
-            "payload)"
-        )
-    return problems
-
-
-# The bench telemetry block contract: BENCH_*.json rounds become
-# machine-comparable only if every round spells these the same way.
-_BENCH_REQUIRED = {
-    "tier": str,
-}
-_BENCH_OPTIONAL = {
-    "overhead_pct": (int, float, type(None)),
-    "heartbeat_overhead_pct": (int, float, type(None)),
-    "monitor_events": int,
-    "report": dict,
-    "headline": dict,
-    "probe": dict,
-}
-
-
-def validate_bench_telemetry(block: Any,
-                             where: str = "telemetry") -> List[str]:
-    """Validate the ``telemetry`` block of a ``BENCH_*.json`` artifact
-    (absence of the block entirely is the caller's call — pre-telemetry
-    rounds legitimately lack it)."""
-    return _check_fields(block, _BENCH_REQUIRED, _BENCH_OPTIONAL, where)
-
-
-# The bench fault block: recovery cost lands in the perf trajectory
-# (crash → resumed wall time, drain checkpoint write time, the backoff
-# actually slept; since the elastic-world round: lost worker → resumed
-# -at-smaller-world wall delta).  Every key is nullable — each probe is
-# best-effort.
-_BENCH_FAULT_OPTIONAL = {
-    "time_to_recover_s": (int, float, type(None)),
-    "drain_checkpoint_s": (int, float, type(None)),
-    "backoff_s": (int, float, type(None)),
-    "resize_time_to_recover_s": (int, float, type(None)),
-    "resize_old_world": (int, type(None)),
-    "resize_new_world": (int, type(None)),
-}
-
-
-def validate_bench_fault(block: Any, where: str = "fault") -> List[str]:
-    """Validate the ``fault`` block of a ``BENCH_*.json`` artifact
-    (absent on pre-recovery-plane rounds)."""
-    problems = _check_fields(block, {}, _BENCH_FAULT_OPTIONAL, where)
-    if not problems and isinstance(block, dict):
-        for key in ("resize_old_world", "resize_new_world"):
-            value = block.get(key)
-            if isinstance(value, int) and value < 0:
-                problems.append(f"{where}: negative {key}")
-    return problems
-
-
-# The bench host_overhead block: how much of the step the HOST costs
-# (the megastep round's acceptance surface).  ``fit_vs_raw`` is the
-# Trainer-path overhead budget; ``dispatches_per_opt_step`` counts jit
-# dispatches per optimizer update on the headline (per-step) fit;
-# ``megastep_*`` record the K-fused A/B arm.  Nullable per probe — each
-# arm is best-effort, a failed probe must never cost the headline line.
-_BENCH_HOST_OVERHEAD_OPTIONAL = {
-    "fit_vs_raw": (int, float, type(None)),
-    "dispatches_per_opt_step": (int, float, type(None)),
-    "megastep_k": (int, type(None)),
-    "megastep_dispatches_per_opt_step": (int, float, type(None)),
-    "megastep_tokens_per_sec": (int, float, type(None)),
-    "megastep_speedup": (int, float, type(None)),
-}
-
-
-def validate_bench_host_overhead(block: Any,
-                                 where: str = "host_overhead") -> List[str]:
-    """Validate the ``host_overhead`` block of a ``BENCH_*.json``
-    artifact (absent on pre-megastep rounds)."""
-    problems = _check_fields(block, {}, _BENCH_HOST_OVERHEAD_OPTIONAL, where)
-    k = block.get("megastep_k") if isinstance(block, dict) else None
-    if not problems and isinstance(k, int) and k < 1:
-        problems.append(f"{where}: megastep_k must be >= 1, got {k}")
-    return problems
-
-
-# The bench opt_state block: the HBM-traffic diet's acceptance surface.
-# ``bytes_*`` are ANALYTIC persistent AdamW moment bytes
-# (models/optim.py:opt_state_bytes — the chip truth is the optimizer
-# line in the per-op profile); ``hbm_ratio`` =
-# bytes_f32 / bytes_int8 (the >= 3.5x acceptance bar);
-# ``loss_rel_diff_vs_f32`` is the measured A/B fit parity at the int8_ef
-# grad-comm tolerance; ``update_sharding`` records the resolved
-# cross-replica sharded-update arm.  Measured keys nullable per probe.
-_BENCH_OPT_STATE_REQUIRED = {
-    "dtype": str,
-    "block_size": int,
-    "bytes_f32": (int, float),
-    "bytes_int8": (int, float),
-    "bytes_active": (int, float),
-    "hbm_ratio": (int, float),
-}
-_BENCH_OPT_STATE_OPTIONAL = {
-    "loss_rel_diff_vs_f32": (int, float, type(None)),
-    "tokens_per_sec": (int, float, type(None)),
-    "vs_baseline": (int, float, type(None)),
-    "update_sharding": (str, type(None)),
-}
-
-
-def validate_bench_opt_state(block: Any,
-                             where: str = "opt_state") -> List[str]:
-    """Validate the ``opt_state`` block of a ``BENCH_*.json`` artifact
-    (absent on pre-round-15 artifacts)."""
-    problems = _check_fields(
-        block, _BENCH_OPT_STATE_REQUIRED, _BENCH_OPT_STATE_OPTIONAL, where
-    )
-    if not problems:
-        if block["hbm_ratio"] <= 0:
-            problems.append(f"{where}: hbm_ratio must be > 0")
-        if block["block_size"] < 1:
-            problems.append(f"{where}: block_size must be >= 1")
-    return problems
-
-
-# The bench residual_policy block: scan-residual compression A/B.
-# ``*_bytes_per_step`` are ANALYTIC remat-saved residual bytes
-# (models/gpt.py:residual_save_bytes; the chip truth is the profiler's
-# dynamic-update-slice lines); ``vs_baseline`` is the measured
-# tokens/sec ratio of the active arm against the baseline policy when
-# the probe ran (remat fits measure nothing on the CPU container —
-# nullable off-chip).
-_BENCH_RESIDUAL_REQUIRED = {
-    "policy": str,
-    "baseline_policy": str,
-    "residual_bytes_per_step": (int, float),
-    "baseline_residual_bytes_per_step": (int, float),
-    "bytes_saved_pct": (int, float),
-}
-_BENCH_RESIDUAL_OPTIONAL = {
-    "tokens_per_sec": (int, float, type(None)),
-    "vs_baseline": (int, float, type(None)),
-    "loss_rel_diff_vs_baseline": (int, float, type(None)),
-}
-
-
-def validate_bench_residual_policy(
-    block: Any, where: str = "residual_policy"
-) -> List[str]:
-    """Validate the ``residual_policy`` block of a ``BENCH_*.json``
-    artifact (absent on pre-round-15 artifacts)."""
-    return _check_fields(
-        block, _BENCH_RESIDUAL_REQUIRED, _BENCH_RESIDUAL_OPTIONAL, where
-    )

@@ -20,7 +20,7 @@ Alerts are schema-valid ``slo_alert`` events on the existing event
 plane (``make_event`` shape, SLO specifics riding the ``detail``
 dict — ``telemetry/schema.py::validate_slo_alert``), deduplicated
 until the spec re-arms (burn drops below threshold).  ``snapshot()``
-feeds the ``rlt_slo_*`` OpenMetrics family and the bench gate.
+feeds the ``rlt_slo_*`` OpenMetrics family.
 jax-free; clock injectable per RLT004.
 """
 

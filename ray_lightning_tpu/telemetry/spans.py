@@ -5,8 +5,8 @@ ships zero observability).  A :class:`SpanTracer` records named phases
 (:data:`PHASES`) into a bounded ring buffer, one tracer per rank.  Two
 export formats:
 
-* **JSONL** — one span object per line (the machine-diffable form the
-  schema checker validates, ``tools/check_telemetry_schema.py``);
+* **JSONL** — one span object per line (the machine-diffable form
+  ``tests/test_wire_schemas.py`` holds to ``telemetry/schema.py``);
 * **Chrome ``trace_event``** — a ``{"traceEvents": [...]}`` document of
   ``ph == "X"`` complete events, loadable in Perfetto / ``chrome://tracing``
   next to the ``jax.profiler`` traces ``ProfilerCallback`` captures.

@@ -15,8 +15,8 @@ Per-step wall time is split into three host-observable phases:
   the overhead this subsystem promises not to add).
 
 On top of the split: examples/sec + tokens/sec throughput, an analytic
-FLOPs MFU estimate for the GPT/ViT model families (the same accounting
-``bench.py`` publishes, now computed live inside any fit), recompile
+FLOPs MFU estimate for the GPT/ViT model families (computed live
+inside any fit), recompile
 counters hooked via ``jax.monitoring`` event listeners, and
 ``jax.local_devices()`` memory stats where the backend exposes them
 (TPU yes, CPU no — best-effort by design).
@@ -44,7 +44,7 @@ __all__ = [
 
 
 # ---------------------------------------------------------------------------
-# Analytic FLOPs (the published-MFU accounting, shared with bench.py)
+# Analytic FLOPs (the published-MFU accounting)
 # ---------------------------------------------------------------------------
 
 def model_flops_per_token(cfg: Any, attn: str = "full") -> float:

@@ -248,7 +248,7 @@ def _completed(trace_spans: Sequence[Dict[str, Any]]) -> bool:
 def coverage(spans: Sequence[Dict[str, Any]]
              ) -> Tuple[int, int, float]:
     """``(complete, completed_total, fraction)`` over COMPLETED
-    requests — the bench's stitch-coverage acceptance number.  Expired/
+    requests — the stitch-coverage number.  Expired/
     rejected requests legitimately have truncated chains and are not
     counted against coverage."""
     groups = request_traces(spans)
@@ -270,8 +270,7 @@ def phase_percentiles(
     spans: Sequence[Dict[str, Any]]
 ) -> Dict[str, Dict[str, float]]:
     """Corpus-wide per-phase latency summary (p50/p95 ms) — the same
-    spelling ``ServeStats`` exports live and the bench trace block
-    commits."""
+    spelling ``ServeStats`` exports live."""
     from ray_lightning_tpu.serve.metrics import percentile
 
     durs: Dict[str, List[float]] = collections.defaultdict(list)

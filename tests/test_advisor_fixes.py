@@ -285,18 +285,17 @@ def test_logged_lr_is_last_applied(tmp_path):
     )
 
 
-# -- (r2-c) dual-convention MFU fields in bench ------------------------------
+# -- (r2-c) dual-convention MFU accounting -----------------------------------
 
-def test_bench_reports_both_mfu_conventions():
-    import bench
+def test_flops_accounting_has_both_mfu_conventions():
+    from ray_lightning_tpu.models.gpt import GPTConfig
+    from ray_lightning_tpu.telemetry.step_stats import model_flops_per_token
 
-    cfg_flops_full = bench.model_flops_per_token(
-        bench.GPTConfig.tiny(), attn="full")
-    cfg_flops_causal = bench.model_flops_per_token(
-        bench.GPTConfig.tiny(), attn="causal")
+    cfg_flops_full = model_flops_per_token(GPTConfig.tiny(), attn="full")
+    cfg_flops_causal = model_flops_per_token(GPTConfig.tiny(), attn="causal")
     assert cfg_flops_causal < cfg_flops_full
     # Attention term is exactly halved; everything else is identical.
-    cfg = bench.GPTConfig.tiny()
+    cfg = GPTConfig.tiny()
     attn_full = 3.0 * 4 * cfg.n_layer * cfg.seq_len * cfg.d_model
     assert cfg_flops_full - cfg_flops_causal == pytest.approx(attn_full / 2)
 

@@ -643,10 +643,7 @@ def test_governor_flap_guard_not_preseeded_by_scratch(tmp_path):
 
 def test_resize_events_validate():
     from ray_lightning_tpu.telemetry.monitor import make_event
-    from ray_lightning_tpu.telemetry.schema import (
-        validate_bench_fault,
-        validate_event,
-    )
+    from ray_lightning_tpu.telemetry.schema import validate_event
 
     ev = make_event("resize", -1, old_world=4, new_world=2,
                     recover_s=1.5, ckpt="/tmp/x.ckpt", message="m")
@@ -654,11 +651,6 @@ def test_resize_events_validate():
     rej = make_event("resize_rejected", -1, old_world=4, new_world=0,
                      message="below min")
     assert validate_event(rej) == []
-    assert validate_bench_fault(
-        {"resize_time_to_recover_s": 2.0, "resize_old_world": 2,
-         "resize_new_world": 1}
-    ) == []
-    assert validate_bench_fault({"resize_old_world": -1})
 
 
 # ---------------------------------------------------------------------------

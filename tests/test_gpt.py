@@ -352,7 +352,7 @@ def test_remat_bf16_resid_without_remat_is_exact():
 
 
 def test_residual_save_bytes_accounting():
-    """The analytic model behind the bench ``residual_policy`` block:
+    """The analytic model of what each remat policy saves per step:
     arm ordering must match the design — dots+flash (double-save) >
     dots+flash-out > bf16-resid(f32 run) > dots — and the bf16 carry
     must save exactly half the carry bytes of an f32 run."""

@@ -436,7 +436,7 @@ def test_mpmd_wire_dtype_pipeline_parity_and_ratio():
     spec, full, data, steps = _pipeline_setup()
 
     def run(wire):
-        # Meshless per-stage devices (like the bench probe): the wire
+        # Meshless per-stage devices: the wire
         # codec is transport-layer, orthogonal to stage sharding.
         return run_inproc_pipeline_fit(
             spec, full, spec.tx_factory, lambda s: data[s], steps,

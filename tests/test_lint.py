@@ -201,10 +201,9 @@ def test_committed_baseline_is_well_formed_and_documented():
 # Scoping
 # ---------------------------------------------------------------------------
 
-def test_in_scope_covers_package_tools_bench_not_tests():
+def test_in_scope_covers_package_tools_entry_not_tests():
     assert in_scope("ray_lightning_tpu/serve/engine.py")
     assert in_scope("tools/rlt_top.py")
-    assert in_scope("bench_serve.py")
     assert in_scope("__graft_entry__.py")
     assert in_scope("examples/tpu_serve_example.py")
     assert not in_scope("tests/test_lint.py")
@@ -395,7 +394,7 @@ def test_unregistered_env_knob_fails_lint():
 
 def test_schema_producer_key_drift_fails_lint():
     """A key added to make_beat without a schema entry fails RLT006
-    (the static complement to tools/check_telemetry_schema.py)."""
+    (the static complement to tests/test_wire_schemas.py)."""
     rel = "ray_lightning_tpu/telemetry/heartbeat.py"
     cfg = repo_config(REPO)
     src = _read(rel)

@@ -369,7 +369,7 @@ def test_csv_cadence_stays_aligned_across_resume(tmp_path):
 
 def test_dispatch_counters(tmp_path):
     """16 micro-steps in 4 stride dispatches — the counter behind the
-    bench's dispatches_per_opt_step acceptance number."""
+    benchmark's ``dispatches_per_step.train``."""
     t = _fit(tmp_path, K)
     counters = t.telemetry_report["counters"]
     assert counters["megastep_dispatches"]["mean"] == K
@@ -628,22 +628,3 @@ def test_strategy_knob_fills_unset_trainer_default(tmp_path):
     t = _fit(tmp_path, 2)  # via LocalStrategy(megastep=2)
     assert t.telemetry_report["counters"]["megastep_dispatches"][
         "mean"] == BATCHES / 2
-
-
-# -- schema ------------------------------------------------------------------
-
-def test_host_overhead_schema():
-    from ray_lightning_tpu.telemetry.schema import (
-        validate_bench_host_overhead,
-    )
-
-    good = {
-        "fit_vs_raw": 0.95, "dispatches_per_opt_step": 1.0,
-        "megastep_k": 8, "megastep_dispatches_per_opt_step": 0.125,
-        "megastep_tokens_per_sec": None, "megastep_speedup": 1.1,
-    }
-    assert validate_bench_host_overhead(good) == []
-    assert validate_bench_host_overhead({}) == []  # all-optional block
-    assert validate_bench_host_overhead({"surprise": 1})
-    assert validate_bench_host_overhead({"megastep_k": 0})
-    assert validate_bench_host_overhead({"megastep_k": "8"})

@@ -1,4 +1,4 @@
-"""MNIST classifier convergence (BASELINE.md config #1 analogue;
+"""MNIST classifier convergence (the reference's first example config;
 ≙ reference predict_test accuracy>=0.5, tests/utils.py:256-272)."""
 
 import pytest

@@ -195,7 +195,7 @@ def test_interleaved_bubble_beats_gpipe_structurally():
         transfer_s=0.1, interleave=2,
     )
     assert i["bubble_fraction"] < g["bubble_fraction"]
-    # And through the measured-cost entry point the dryrun/bench use:
+    # And through the measured-cost entry point the dryrun uses:
     mi = measured_schedule_bubble(
         "1f1b", 2, 8, 2, {"FWD": 0.5, "BWD": 1.0, "SEND": 0.05}
     )
@@ -573,7 +573,6 @@ def test_prom_and_rlt_top_render_mpmd():
 
 def test_mpmd_schema_validators():
     from ray_lightning_tpu.telemetry.schema import (
-        validate_bench_mpmd,
         validate_mpmd_xfer,
         validate_stream_item,
     )
@@ -588,10 +587,6 @@ def test_mpmd_schema_validators():
             "chunk": 0, "data": b"x"}
     assert validate_mpmd_xfer(xfer) == []
     assert validate_mpmd_xfer({**xfer, "kind": "weird"})
-    assert validate_bench_mpmd(
-        {"schedule": "gpipe", "n_stages": 2, "n_micro": 8}
-    ) == []
-    assert validate_bench_mpmd({"schedule": "gpipe"})
 
 
 # ---------------------------------------------------------------------------

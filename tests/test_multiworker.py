@@ -87,7 +87,7 @@ def test_zero3_restart_checkpoint_sharded_per_host(tmp_path):
     the 2 processes writes only its addressable shards, and the set
     reassembles to the full shapes."""
     from ray_lightning_tpu.utils.sharded_ckpt import (
-        is_sharded_ckpt, load_sharded,
+        _read_shard_header, is_sharded_ckpt, load_sharded,
     )
 
     rs = tmp_path / "restarts"
@@ -101,12 +101,23 @@ def test_zero3_restart_checkpoint_sharded_per_host(tmp_path):
     )
     tags = [p for p in rs.iterdir() if p.name.endswith(".ckpt")]
     assert len(tags) == 1 and is_sharded_ckpt(str(tags[0]))
-    shards = sorted(tags[0].glob("shard-*"))
-    assert len(shards) == 2  # one file per process, not one gathered blob
+    # One file per process (each beside its ``.crc32`` sidecar), not one
+    # gathered blob.
+    shards = sorted(tags[0].glob("shard-*.ckpt"))
+    assert len(shards) == 2
     sizes = [s.stat().st_size for s in shards]
     # ZeRO-3: each host holds ~half the (w, m, v) state; neither file
     # may contain the whole thing.
     assert max(sizes) < 0.75 * sum(sizes), sizes
+    # No process wrote the sharded weight whole: each file holds the rows
+    # its own devices own, and the two sets are disjoint and complete.
+    rows = []
+    for shard in shards:
+        header, _ = _read_shard_header(str(shard))
+        (w,) = [leaf for leaf in header["leaves"] if leaf["s"] == [256, 128]]
+        rows.append({r for e in w["e"] for r in range(*e["i"][0])})
+    assert len(rows[0]) == len(rows[1]) == 128
+    assert rows[0] | rows[1] == set(range(256))
     payload = load_sharded(str(tags[0]))
     state = payload["state"]
     assert np.asarray(

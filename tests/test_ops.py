@@ -448,7 +448,7 @@ def test_zigzag_indices_partition():
 class TestKernelDisableSwitch:
     """RLT_DISABLE_KERNELS: the on-hardware A/B switch must force the
     XLA path per family and be reflected by the selection predicates
-    (bench.py records kernel_path from exactly these)."""
+    (``GPT.kernel_paths`` reports the path from exactly these)."""
 
     def test_family_disable_forces_fallback(self, monkeypatch):
         from ray_lightning_tpu.ops import kernel_probe

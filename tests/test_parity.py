@@ -1,5 +1,5 @@
 """Gradient/loss parity across execution flavors — the north-star metric's
-second half (BASELINE.md: "DDP↔pmap gradient parity").
+second half ("DDP↔pmap gradient parity").
 
 Single-device vs GSPMD-sharded vs shard_map-explicit must produce the same
 gradients and the same training trajectory on a fixed seed/batch, within

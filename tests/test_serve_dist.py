@@ -28,8 +28,8 @@ from ray_lightning_tpu.serve.dist.handoff import (
 )
 from ray_lightning_tpu.serve.dist.router import RestartGovernor, Router
 from ray_lightning_tpu.telemetry.schema import (
-    validate_bench_serve_disagg, validate_router_snapshot,
-    validate_serve_kv_handoff, validate_serve_request,
+    validate_router_snapshot, validate_serve_kv_handoff,
+    validate_serve_request,
 )
 
 pytestmark = pytest.mark.serve
@@ -87,19 +87,6 @@ class TestWireItems:
         assert item["type"] == "serve_prefill_dispatch"
         assert item["kv_to"] == ["127.0.0.1", 5]
 
-    def test_bench_disagg_block_schema(self):
-        block = {"replicas": 2, "prefill_workers": 1,
-                 "requests_per_sec": 1.5, "recompiles_steady_state": 0}
-        assert validate_bench_serve_disagg(block) == []
-        assert validate_bench_serve_disagg({**block, "replicas": 0})
-        chaos = {"killed_replica": "r0", "submitted": 10,
-                 "completed": 10, "lost_requests": 0,
-                 "failed_over_requests": 2}
-        assert validate_bench_serve_disagg(
-            {**block, "chaos": chaos}) == []
-        assert validate_bench_serve_disagg(
-            {**block, "chaos": {**chaos, "completed": 11}})
-
 
 class _StubHandle:
     def __init__(self, member_id, alive=True):
@@ -114,14 +101,16 @@ class _StubHandle:
         self.killed = True
 
 
-def _drain(q, timeout=2.0):
+def _drain(q, timeout=2.0, want=1):
+    """What is on ``q``: returns once it is empty and holds ``want``
+    items (frames of one poll arrive one by one), or at the timeout."""
     items = []
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:
         try:
             items.append(q.get_nowait())
         except _pyqueue.Empty:
-            if items:
+            if len(items) >= want:
                 return items
             time.sleep(0.01)
     return items
@@ -339,7 +328,7 @@ class TestRouterPolicy:
                      if t.replica == victim]
             rig.replicas[victim][0]._alive = False
             rig.router.poll()
-            re_routed = _drain(rig.replicas[survivor][1])
+            re_routed = _drain(rig.replicas[survivor][1], want=len(moved))
             assert sorted(i["rid"] for i in re_routed) == sorted(moved)
             # The re-submission carries the ORIGINAL fleet seed — the
             # bitwise-stream guarantee's transport half.
@@ -1275,7 +1264,7 @@ class TestActorFleet:
     def test_actor_chaos_kill_replica_zero_lost(self, dist_model):
         """SIGKILL one of two decode actors under load: every request
         still completes (failover onto the survivor), bitwise-equal to
-        the monolith run — the bench chaos arm's shape as a test."""
+        the monolith run."""
         from ray_lightning_tpu.serve.client import ServeClient
         from ray_lightning_tpu.serve.dist import launch_actor_fleet
 

@@ -4,13 +4,13 @@ burn-rate alerting (fire / dedup / re-arm / fast-spike silence), the
 headroom oracle's measured-phase-cost tick model with its sampled-gauge
 fallback, the fleet fold, the engine integration (plane on → schema-
 valid ``capacity`` block on every snapshot + ``rlt_capacity_*`` /
-``rlt_slo_*`` prom families), the rlt_top capacity pane with its
-staleness tag, and the bench-diff tool's self-test.
+``rlt_slo_*`` prom families) and the rlt_top capacity pane with its
+staleness tag.
 
 Everything below the engine class is jax-free and clock-driven
-(RLT004): no sleeps, no wall-clock flake.  The saturation-calibration
-truth test (predicted vs measured Poisson knee) lives in
-bench_serve.py phase 9 — here we pin the math on synthetic counters.
+(RLT004): no sleeps, no wall-clock flake.  The saturation prediction
+against a measured Poisson knee has not been measured on the chip (no
+open-loop cell yet) — here we pin the math on synthetic counters.
 """
 
 import time
@@ -525,26 +525,3 @@ class TestRltTopPane:
         }
         text = rlt_top.render(snap, "test", now=1001.0)
         assert "ceiling 300.0" in text
-
-
-# ---------------------------------------------------------------------------
-# tools/rlt_bench_diff.py: the regression differ's own contract
-# ---------------------------------------------------------------------------
-
-class TestBenchDiff:
-    def test_self_test_passes(self):
-        from tools.rlt_bench_diff import self_test
-
-        assert self_test() == 0
-
-    def test_lookup_and_direction(self):
-        from tools.rlt_bench_diff import diff_docs, lookup
-
-        doc = {"serve": {"requests_per_sec": 12.5}}
-        assert lookup(doc, "serve.requests_per_sec") == 12.5
-        assert lookup(doc, "serve.missing") is None
-        rows = {r["key"]: r for r in diff_docs(
-            {"serve": {"requests_per_sec": 10.0}},
-            {"serve": {"requests_per_sec": 8.0}},
-        )}
-        assert rows["serve.requests_per_sec"]["status"] == "regression"

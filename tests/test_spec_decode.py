@@ -648,7 +648,7 @@ class TestClientVariableWidth:
 
 
 # ---------------------------------------------------------------------------
-# Telemetry: snapshot schema, prom family, bench block
+# Telemetry: snapshot schema, prom family
 # ---------------------------------------------------------------------------
 
 class TestSpecTelemetry:
@@ -700,22 +700,3 @@ class TestSpecTelemetry:
         )
         assert out.returncode == 0, out.stderr
         assert "spec acc" in out.stdout
-
-    def test_bench_spec_block_schema(self):
-        from ray_lightning_tpu.telemetry.schema import (
-            validate_bench_spec_decode,
-        )
-
-        good = {
-            "spec_k": 4, "tokens_per_sec": 100.0,
-            "baseline_tokens_per_sec": 50.0, "vs_baseline": 2.0,
-            "acceptance_rate": 0.9, "recompiles_steady_state": 0,
-            "baseline_recompiles_steady_state": 0,
-            "acceptance_sweep": [{"noise": 0.01, "acceptance_rate": 0.7,
-                                  "tokens_per_sec": 80.0,
-                                  "vs_baseline": 1.6}],
-        }
-        assert validate_bench_spec_decode(good) == []
-        assert validate_bench_spec_decode({"spec_k": 4})
-        assert validate_bench_spec_decode({**good, "acceptance_rate": 2})
-        assert validate_bench_spec_decode({**good, "surprise": 1})

@@ -32,7 +32,6 @@ from ray_lightning_tpu.telemetry import (
     straggler_ranks,
 )
 from ray_lightning_tpu.telemetry.schema import (
-    validate_bench_telemetry,
     validate_chrome_trace,
     validate_span_jsonl,
 )
@@ -325,7 +324,7 @@ def test_gpt_fit_records_tokens_and_mfu(tmp_path, monkeypatch):
 
 def test_off_tier_records_nothing_and_overhead_smoke(tmp_path):
     """telemetry="off" leaves callback_metrics clean; the default cheap
-    tier's overhead is loosely bounded (precise number in BENCH_*)."""
+    tier's overhead is loosely bounded (not measured on the chip)."""
     def run(tier, sub):
         t0 = time.perf_counter()
         trainer = get_trainer(
@@ -401,16 +400,6 @@ def test_telemetry_callback_upgrades_cheap_fit(tmp_path):
     assert (tmp_path / "cbtel" / "spans-rank0.jsonl").exists()
     assert cb.report.get("step_stats", {}).get("steps") == 2
     assert cb.export_paths
-
-
-def test_bench_telemetry_block_schema():
-    block = {
-        "tier": "cheap",
-        "overhead_pct": 0.4,
-        "report": {"step_stats": {}, "counters": {}},
-    }
-    assert validate_bench_telemetry(block) == []
-    assert validate_bench_telemetry({"overhead_pct": 1}) != []  # no tier
 
 
 # ---------------------------------------------------------------------------

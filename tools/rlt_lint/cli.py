@@ -40,11 +40,11 @@ DEFAULT_BASELINE = os.path.join(
     "tools", "rlt_lint", "baseline.json"
 )
 
-#: Scanned universe: the package, tooling, bench drivers and examples.
+#: Scanned universe: the package, tooling, the entry point and examples.
 #: Tests are exempt (they deliberately poke invariants), and the
 #: fixture corpus is lint-bait by construction.
 _SCAN_PREFIXES = ("ray_lightning_tpu/", "tools/", "examples/")
-_SCAN_ROOT_FILES = re.compile(r"^(bench[\w]*|__graft_entry__)\.py$")
+_SCAN_ROOT_FILES = re.compile(r"^__graft_entry__\.py$")
 _EXCLUDE_PREFIXES = ("tools/rlt_lint/fixtures/",)
 
 
