@@ -328,8 +328,8 @@ class ServeClient:
             if not isinstance(item, dict):
                 continue
             if item.get("type") == "serve_batch":
-                # One tick's replies in one frame
-                # (``ServeConfig.coalesce_replies``), in order.
+                # One tick's replies in one frame, in order: what the
+                # engine sends when a tick has several for this address.
                 for sub in item.get("items", ()):
                     if isinstance(sub, dict):
                         self._on_reply(sub)

@@ -118,17 +118,24 @@ class ServeConfig:
     prefill_chunk: Optional[int] = None
     # Sampling seed for temperature>0 requests.
     seed: int = 0
+    # The next two are what the engine does, not options: they are kept
+    # for the tests' reference path (``False`` is the serial loop with a
+    # frame a token; served tokens are equal on both) and go with the
+    # next ``benchmark`` PR that drops the two keys from
+    # ``benchmarks/workloads/k-exaone-236b-a23b-ep8.serve-mixed.json``
+    # (ROADMAP.md D3).
     # One reply frame a tick per reply address (``serve_batch``, the
-    # tick's ``serve_token`` / ``serve_done`` items in order) instead of
-    # one acknowledged frame a token.  OFF by default: the consumer has
-    # to understand ``serve_batch`` (``ServeClient`` does).
-    coalesce_replies: bool = False
-    # Dispatch the next decode before emitting this one's tokens, so the
-    # device runs while the host replies: on a tick whose slots all go
-    # on (none at its last token or at eos), with nothing waiting to be
-    # admitted.  OFF by default; not with spec_k, adapters, prefix_cache
-    # or prefill_chunk (ValueError).
-    decode_lookahead: bool = False
+    # tick's ``serve_token`` / ``serve_done`` items in order; a tick with
+    # one item for an address sends the bare item).
+    coalesce_replies: bool = True
+    # The decode loop runs one tick ahead of the host: on a tick whose
+    # slots all go on, the next decode is dispatched on this one's tokens
+    # where they lie on the device, before they are fetched; where an
+    # active request carries an ``eos_token_id``, after the fetch and
+    # before the replies.  An engine with a draft model, an adapter pool,
+    # ``prefix_cache`` or ``prefill_chunk`` books state between ticks
+    # that this path does not handle and runs the serial loop.
+    decode_lookahead: bool = True
     # Background-thread idle sleep between polls when no work exists.
     idle_wait_s: float = 0.002
     # Live-export refresh cadence (prom textfile / serve-live.json).
@@ -404,21 +411,19 @@ class ServeEngine:
         # inactive slot (writes trashed, sampled token ignored), so the
         # job needs no change to the compiled decode graph.
         self._chunk_jobs: Dict[int, "_PrefillJob"] = {}
-        if cfg.decode_lookahead:
-            refused = [name for name, on in (
-                ("spec_k / draft model", draft_module is not None),
-                ("max_adapters", cfg.max_adapters > 0),
-                ("prefix_cache", cfg.prefix_cache),
-                ("prefill_chunk", cfg.prefill_chunk is not None),
-            ) if on]
-            if refused:
-                raise ValueError(
-                    "decode_lookahead dispatches a tick before the last "
-                    "one's tokens are booked and does not combine with: "
-                    + ", ".join(refused))
-        # The decode dispatched ahead of its tick (decode_lookahead), as
-        # ``_dispatch_decode`` returned it, or None.
+        # Depth of the decode loop against the host, from the engine's
+        # build: 1 (``_decode_tick`` dispatches a tick ahead where the
+        # tick's slots allow it) unless the engine books state between
+        # ticks that a decode in flight would not see.
+        self._pipelined = cfg.decode_lookahead and not (
+            draft_module is not None or cfg.max_adapters > 0
+            or cfg.prefix_cache or cfg.prefill_chunk is not None)
+        # The decode dispatched ahead of its tick, as ``_dispatch_decode``
+        # returned it, or None; and when the last tick's tokens reached
+        # the host (``time.monotonic()``): a decode dispatched behind
+        # another starts on the device no earlier than that.
         self._ahead: Optional[tuple] = None
+        self._fetched_t = 0.0
         # Adapter names whose cached chains must be dropped before the
         # next admission poll: add/remove_adapter run on OTHER threads,
         # and every PrefixIndex mutation belongs to the serve thread —
@@ -509,8 +514,7 @@ class ServeEngine:
         # dispatch can outlive — so it shares the lock.
         # guarded by self._lock
         self._reply_handles: Dict[Tuple[str, int], Any] = {}
-        # Open only inside a tick's emit loop under
-        # ``ServeConfig.coalesce_replies``: (owner thread, replies by
+        # Open only inside a tick's emit loop: (owner thread, replies by
         # address, in order).
         self._reply_batch: Optional[
             Tuple[int, Dict[Tuple[str, int], List[dict]]]] = None
@@ -1167,6 +1171,8 @@ class ServeEngine:
                 self._spec_tick(active, widths, ph)
             else:
                 self._decode_tick(active, ph)
+        else:
+            self._ahead = None  # every slot it computed for is gone
         ph.then("housekeep")
         self._refresh_gauges()
         self._maybe_export()
@@ -1211,8 +1217,10 @@ class ServeEngine:
 
         if self.adapters is None:
             return None, None
+        # (a copy: the upload may alias the host's memory, and a slot's
+        # release rewrites it while a decode may still be queued)
         return self.adapters.buffers, jnp.asarray(
-            self.scheduler.adapter_slots
+            self.scheduler.adapter_slots.copy()
         )
 
     def _tick_top_ks(self):
@@ -1225,7 +1233,7 @@ class ServeEngine:
 
         if not np.any(self.scheduler.top_ks > 0):
             return None
-        return jnp.asarray(self.scheduler.top_ks)
+        return jnp.asarray(self.scheduler.top_ks.copy())  # as above
 
     # -- prefix cache + chunked prefill -------------------------------------
     def _claim_prefix(self, req) -> List[int]:
@@ -1390,26 +1398,49 @@ class ServeEngine:
             req.adapter, req.prompt, self.scheduler._blocks[slot][:n]
         )
 
-    def _dispatch_decode(self, active: List[int]) -> tuple:
+    def _dispatch_decode(self, active: List[int], tokens=None) -> tuple:
         """Dispatch one decode for the slots ``active`` and return what
         the tick that reads it needs: dispatch time, the (slot, request)
         pairs it computes for, the tokens and the family's sums (still
-        on the device), and the tick's block counters."""
+        on the device), and the tick's block counters.  ``tokens`` is
+        the last decode's output where it lies on the device, for a tick
+        dispatched before that output is fetched; None takes the host's
+        ``_cur_tokens``."""
         import jax.numpy as jnp
 
+        from ray_lightning_tpu.serve.kv_cache import TRASH_BLOCK
+
         t0 = time.monotonic()
-        seq_lens = jnp.asarray(self.scheduler.seq_lens)
-        cur = jnp.asarray(self._cur_tokens)
-        tables = jnp.asarray(self.scheduler.block_tables)
+        sched = self.scheduler
+        # A slot that holds a request and is not among ``active`` ends
+        # with the tick in hand (or is mid chunked prefill, and reads so
+        # already): this decode computes it as the empty slot it is about
+        # to be (length 0, every block the trash block).
+        off = None
+        if len(active) < sched.active_slots:
+            off = np.ones((self.config.num_slots,), bool)
+            off[active] = False
+
+        def up(host, empty=None):
+            # A copy: the host goes on to change these arrays while the
+            # decode that reads them may still be queued on the device,
+            # and an upload may alias the host's memory (on the CPU it
+            # does).
+            host = host.copy()
+            if off is not None and empty is not None:
+                host[off] = empty
+            return jnp.asarray(host)
+
+        seq_lens = up(sched.seq_lens, 0)
+        cur = up(self._cur_tokens) if tokens is None else tokens
+        tables = up(sched.block_tables, TRASH_BLOCK)
         if self.family.two_kind:
-            tables = (tables, jnp.asarray(self.scheduler.window_tables))
+            tables = (tables, up(sched.window_tables, TRASH_BLOCK))
         ad, ad_ids = self._lora_operands()
         toks, self._pool, *sums = self._decode_fn(
             self.params, self._pool, tables, seq_lens, cur,
-            jnp.asarray(self.scheduler.temperatures),
-            jnp.asarray(self.scheduler.sample_seeds),
-            self._tick_top_ks(),
-            ad, ad_ids,
+            up(sched.temperatures), up(sched.sample_seeds),
+            self._tick_top_ks(), ad, ad_ids,
         )
         if self.draft_module is not None:
             # Mirror the write into the draft cache so its frontier
@@ -1459,25 +1490,44 @@ class ServeEngine:
         (and the fallback when no active slot drafts this tick).
         ``ph`` is the tick's open phase (``decode_dispatch``).
 
-        Under ``decode_lookahead`` the tick may find its decode already
-        dispatched (by the tick before, which then covers the slots of
-        THAT moment: a slot admitted since waits one tick, a slot
-        cancelled, expired or preempted since is skipped), and may
-        dispatch the next one before it emits."""
+        A pipelined engine (``_pipelined``) runs one tick ahead of the
+        host where the tick's slots allow it: the tick may find its
+        decode already dispatched (by the tick before, which then covers
+        the slots of THAT moment: a slot admitted since waits one tick, a
+        slot cancelled, expired or preempted since is skipped), and
+        dispatches the next one on this one's tokens where they lie on
+        the device, before it fetches them; where it cannot know without
+        the tokens that every slot goes on (an ``eos_token_id``, a slot
+        admitted since), after the fetch and before the replies."""
+        sched = self.scheduler
         ahead, self._ahead = self._ahead, None
         if ahead is not None:
             live = [slot for slot, req in ahead[1]
-                    if self.scheduler.slots[slot] is req]
+                    if sched.slots[slot] is req]
             if live:
                 active = live
             else:
                 ahead = None    # every slot it computed for is gone
         t0, _, toks, sums, counts = ahead or self._dispatch_decode(active)
+        # The lengths advance without the tokens: the host knows them.
+        for slot in active:
+            sched.seq_lens[slot] += 1
+            sched.draft_lens[slot] = sched.seq_lens[slot]
+        going = self._going_on(active, fetched=False)
+        if going:
+            ph.then("decode_dispatch", slots=len(going), ahead=1)
+            self._ahead = self._dispatch_decode(going, tokens=toks)
+            # counted when read
+            self._ahead[-1].update(decode_ahead=1, decode_fed_on_device=1)
         ph.then("decode_wait")
         # rlt: noqa[RLT002] deliberate: the tick must emit tokens
         toks = np.asarray(toks)
         ph.then("emit")
-        dt = time.monotonic() - t0
+        # A decode dispatched behind another queued on the device until
+        # that one's tokens were out: its cost counts from there.
+        now = time.monotonic()
+        dt = now - max(t0, self._fetched_t)
+        self._fetched_t = now
         self.stats.bump("decode_steps")
         if sums:
             # rlt: noqa[RLT002] the same program's output as the tokens above
@@ -1492,46 +1542,62 @@ class ServeEngine:
             "decode_us", int(dt * 1e6))
         self.stats.note_token_latency(dt, n_tokens=len(active))
         for slot in active:
-            self.scheduler.seq_lens[slot] += 1
-            self.scheduler.draft_lens[slot] = self.scheduler.seq_lens[slot]
             # rlt: noqa[RLT002] host np after the tick fetch
             self._cur_tokens[slot] = int(toks[slot])
-        if self.config.decode_lookahead and self._all_go_on(active):
-            ph.then("decode_dispatch", slots=len(active), ahead=1)
-            self._ahead = self._dispatch_decode([
-                s for s, r in enumerate(self.scheduler.slots)
-                if r is not None])
-            self._ahead[-1]["decode_ahead"] = 1     # counted when read
-            ph.then("emit")
+        if not going:
+            going = self._going_on(active, fetched=True)
+            if going:
+                ph.then("decode_dispatch", slots=len(going), ahead=1)
+                self._ahead = self._dispatch_decode(going)
+                self._ahead[-1]["decode_ahead"] = 1     # counted when read
+                ph.then("emit")
         with self._coalesced_replies():
             for slot in active:
                 tok = int(self._cur_tokens[slot])  # rlt: noqa[RLT002] host np
-                req = self.scheduler.slots[slot]
+                req = sched.slots[slot]
                 if req is not None and req.adapter is not None:
                     self.stats.note_adapter(req.adapter, tokens=1)
-                done = self.scheduler.append_token(slot, tok)
+                done = sched.append_token(slot, tok)
                 if done:
                     self._complete(slot)
 
-    def _all_go_on(self, active: List[int]) -> bool:
-        """Whether the next decode can be dispatched now, before this
-        tick's tokens (already in ``_cur_tokens``, lengths advanced) are
-        booked: every slot goes on after its token, nothing waits for a
-        slot, and every next position has its block."""
+    def _going_on(self, active: List[int], fetched: bool) -> List[int]:
+        """The slots the next decode can be dispatched for now, before
+        this tick's tokens (lengths advanced already) are booked: those
+        that go on after their token, if nothing waits for a slot it
+        could have and every next position has its block; else none.
+        Before the tokens are ``fetched`` into ``_cur_tokens`` an
+        ``eos_token_id`` cannot be tested, and a slot that is not among
+        ``active`` (admitted since the decode in hand was dispatched) has
+        its token on the host only: either holds the tick back until
+        they are."""
         sched = self.scheduler
-        if not active or sched.queue or (
-                self._inbox is not None and not self._inbox.empty()):
-            return False
-        for slot in active:
+        if not self._pipelined or not active:
+            return []
+        held = [s for s, r in enumerate(sched.slots) if r is not None]
+        if not fetched and len(held) != len(active):
+            return []
+        ticked, going = set(active), []
+        for slot in held:
             req = sched.slots[slot]
-            if len(req.generated) + 1 >= req.max_new_tokens or (
-                    req.eos_token_id is not None
-                    and int(self._cur_tokens[slot]) == req.eos_token_id):
-                return False
-        for slot in active:
+            if slot in ticked:
+                if len(req.generated) + 1 >= req.max_new_tokens:
+                    continue    # ends with this tick's token, by count
+                if req.eos_token_id is not None:
+                    if not fetched:
+                        return []
+                    if int(self._cur_tokens[slot]) == req.eos_token_id:
+                        continue    # ends with this tick's token, at eos
+            going.append(slot)
+        if not going:
+            return []
+        if len(going) < len(sched.slots) and (sched.queue or (
+                self._inbox is not None and not self._inbox.empty())):
+            return []   # a slot is, or falls, free for what waits
+        for slot in going:
             if sched.needs_block(slot) and not sched.grow(slot):
-                return False    # pool dry: the loop's grow phase preempts
-        return True
+                return []   # pool dry: the loop's grow phase preempts
+        return going
 
     def _spec_tick(self, active: List[int], widths: List[int],
                    ph) -> None:
@@ -2415,9 +2481,10 @@ class ServeEngine:
 
     @contextlib.contextmanager
     def _coalesced_replies(self):
-        """Under ``ServeConfig.coalesce_replies``, hold the replies this
-        thread makes inside the block and send them on leaving it: one
-        ``serve_batch`` frame per reply address, items in order."""
+        """Hold the replies this thread makes inside the block (a
+        tick's emit loop) and send them on leaving it: one
+        ``serve_batch`` frame per reply address, items in order; a lone
+        item goes bare."""
         if not self.config.coalesce_replies or self._reply_batch is not None:
             yield
             return
@@ -2445,6 +2512,7 @@ class ServeEngine:
                 self._reply_handles[addr] = handle
         try:
             handle.put(item)
+            self.stats.bump("reply_frames")
         except (OSError, ConnectionError):
             # Client went away: drop its stream, keep serving others.
             with self._lock:

@@ -39,6 +39,11 @@ _COUNTER_KEYS = (
     # Per decode tick: blocks that hold the active slots' visible
     # positions, and the W x M entries of the block tables.
     "decode_kv_blocks_read", "decode_kv_blocks_table",
+    # Of ``decode_steps``: ticks read from a decode dispatched ahead of
+    # them, and those of them whose ``tokens`` operand was the tick
+    # before's output where it lay on the device.  Reply frames sent
+    # (a tick's replies to one address are one frame).
+    "decode_ahead", "decode_fed_on_device", "reply_frames",
 )
 
 

@@ -564,8 +564,8 @@ def validate_serve_reply(item: Any, where: str = "serve_reply") -> List[str]:
             _SERVE_DONE_OPTIONAL, where,
         )
     if kind == "serve_batch":
-        # One tick's replies to one address in one frame
-        # (ServeConfig.coalesce_replies): tokens and completions only.
+        # One tick's replies to one address in one frame (what the
+        # engine sends): tokens and completions only.
         items = item.get("items")
         if not isinstance(items, list) or not items:
             return [f"{where}: serve_batch without items"]

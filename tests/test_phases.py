@@ -217,6 +217,45 @@ def test_tick_phases_sum_to_the_tick(ticked):
     assert counters["decode_us"] > 0 and counters["admit_us"] > 0
 
 
+def test_a_tick_dispatched_ahead_tiles_its_iteration():
+    """On an iteration whose tick dispatches the next decode before it
+    fetches its own, the phases still tile the iteration, and that
+    dispatch is booked as ``decode_dispatch``, not inside the tick's
+    ``decode_wait``: with a dispatch slowed to 20 ms the wait stays the
+    device's."""
+    eng = _tiny_engine()
+    slow_s, calls = 0.02, []
+    dispatch = eng._dispatch_decode
+
+    def slow(*a, **kw):
+        calls.append(kw.get("tokens") is not None)
+        time.sleep(slow_s)
+        return dispatch(*a, **kw)
+
+    eng._dispatch_decode = slow
+    eng.submit(list(range(1, 7)), 8)
+    ahead_iterations = 0
+    for _ in range(50):
+        before = dict(eng.stats.counters)
+        eng.step()
+        delta = {k: v - before.get(k, 0)
+                 for k, v in eng.stats.counters.items()}
+        if eng._ahead is not None:
+            ahead_iterations += 1
+            parts = sum(delta[f"tick_{p}_us"] for p in TICK)
+            assert parts == pytest.approx(delta["tick_us"], abs=50)
+            assert delta["tick_decode_dispatch_us"] >= 0.9e6 * slow_s
+            assert delta["tick_decode_wait_us"] < 0.5e6 * slow_s
+        if not eng.scheduler.has_work():
+            break
+    counters = eng.stats.counters
+    # 7 decodes for 8 tokens, all but the first fed on the device.
+    assert calls == [False] + [True] * 6 and ahead_iterations == 6
+    assert counters["decode_fed_on_device"] == 6 == counters["decode_ahead"]
+    assert counters["tick_decode_dispatch_us"] >= 0.9e6 * slow_s * 6
+    assert counters["tick_decode_wait_us"] < 0.5e6 * slow_s * 6
+
+
 def test_queue_wait_grows_with_admissions(ticked):
     early, late = ticked
     assert 0 < early["admitted"] < late["admitted"] == 6

@@ -666,17 +666,18 @@ class TestClientPlane:
 
 
 class TestCoalescedReplies:
-    """``ServeConfig.coalesce_replies``: one ``serve_batch`` frame a
-    tick per reply address; what a caller is served does not change."""
+    """One ``serve_batch`` frame a tick per reply address (what the
+    engine does; ``coalesce_replies=False`` is the tests' reference, a
+    frame a token); what a caller is served does not change."""
 
     def _serve(self, model, coalesce):
         from ray_lightning_tpu.cluster.queue import QueueHandle
         from ray_lightning_tpu.serve.client import ServeClient
 
         m, params = model
+        kw = {} if coalesce else {"coalesce_replies": False}
         eng = ServeEngine(m, params, ServeConfig(
-            num_slots=3, block_size=8, coalesce_replies=coalesce,
-        ))
+            num_slots=3, block_size=8, **kw))
         frames = []
         orig = QueueHandle.put
 
@@ -703,6 +704,7 @@ class TestCoalescedReplies:
             QueueHandle.put = orig
             eng.stop()
             client.close()
+        assert eng.stats.counters["reply_frames"] == len(frames)
         want = [_ref_tokens(m, params, p, n)
                 for p, n in zip(prompts, news)]
         return served, want, frames
@@ -745,34 +747,100 @@ class TestCoalescedReplies:
             {**tok, "index": -1}]})
 
 
+SERIAL = {"coalesce_replies": False, "decode_lookahead": False}
+
+
 class TestDecodeLookahead:
-    """``ServeConfig.decode_lookahead``: the next decode is dispatched
-    before this tick's tokens are booked; what is served does not
-    change, whatever happens to a slot while a decode is in flight."""
+    """The decode loop runs one tick ahead of the host: the next decode
+    is dispatched on this tick's tokens where they lie on the device,
+    before they are fetched.  What is served does not change, whatever
+    happens to a slot while a decode is in flight; ``SERIAL`` is the
+    reference path."""
 
     def _engine(self, model, **kw):
         m, params = model
         return ServeEngine(m, params, ServeConfig(
-            num_slots=3, block_size=8, decode_lookahead=True, **kw))
+            num_slots=3, block_size=8, **kw))
 
-    @pytest.mark.parametrize("coalesce", [False, True])
-    def test_served_tokens_are_the_reference(self, model, coalesce):
+    def _served(self, model, temperature, **kw):
         """More requests than slots, lengths that end on different
         ticks: admissions and completions interleave with ticks
         dispatched ahead."""
-        m, params = model
-        eng = self._engine(model, coalesce_replies=coalesce)
+        m, _ = model
+        eng = self._engine(model, **kw)
         prompts = [_rand_prompt(40 + i, 3 + (5 * i) % 11,
                                 m.config.vocab_size) for i in range(7)]
         news = [9, 2, 12, 1, 7, 5, 10]
-        handles = [eng.submit(p, n) for p, n in zip(prompts, news)]
+        handles = [eng.submit(p, n, temperature=temperature)
+                   for p, n in zip(prompts, news)]
         eng.run_until_idle()
-        for h, p, n in zip(handles, prompts, news):
-            assert h.result(1) == _ref_tokens(m, params, p, n)
-        c = eng.stats.counters
-        assert 0 < c["decode_ahead"] < c["decode_steps"]
         assert eng._ahead is None      # nothing left in flight at idle
-        assert c["tokens_out"] == sum(news)
+        assert eng.stats.counters["tokens_out"] == sum(news)
+        return prompts, [h.result(1) for h in handles], eng.stats.counters
+
+    @pytest.mark.parametrize("temperature", [0.0, 0.8])
+    @pytest.mark.parametrize("kw", [{}, SERIAL], ids=["ahead", "serial"])
+    def test_served_tokens_are_the_reference(self, model, kw, temperature):
+        """Sampling is keyed by position, so the tokens are the same
+        under any order of dispatch, at any temperature: greedy against
+        the static path, sampled against the serial loop's tokens
+        (engine seed and submission order fixed)."""
+        m, params = model
+        prompts, served, c = self._served(model, temperature, **kw)
+        greedy = [_ref_tokens(m, params, p, len(s))
+                  for p, s in zip(prompts, served)]
+        if temperature == 0.0:
+            want = greedy
+        else:
+            want = self._served(model, temperature, **SERIAL)[1]
+            assert want != greedy
+        assert served == want
+        if kw:
+            assert c["decode_ahead"] == 0 == c["decode_fed_on_device"]
+        else:
+            assert (0 < c["decode_fed_on_device"] <= c["decode_ahead"]
+                    < c["decode_steps"])
+
+    def test_a_slot_ending_by_count_does_not_hold_the_others_back(
+            self, model):
+        """The host knows a slot's last token by count before it has
+        it: the next decode is dispatched for the slots that go on, the
+        one that ends computed as the empty slot it is about to be."""
+        m, params = model
+        eng = self._engine(model)
+        p1 = _rand_prompt(65, 5, m.config.vocab_size)
+        p2 = _rand_prompt(66, 7, m.config.vocab_size)
+        h1, h2 = eng.submit(p1, 4), eng.submit(p2, 9)
+        eng.run_until_idle()
+        assert h1.result(1) == _ref_tokens(m, params, p1, 4)
+        assert h2.result(1) == _ref_tokens(m, params, p2, 9)
+        c = eng.stats.counters
+        # Every tick but the first was fed on the device, the one after
+        # h1's last token too.
+        assert c["decode_steps"] == 8
+        assert c["decode_fed_on_device"] == 7 == c["decode_ahead"]
+
+    def test_a_request_with_eos_is_never_fed_past_it(self, model):
+        """The host cannot test an eos before it has the token: while a
+        request carries one, the next decode waits for the fetch (and
+        is still dispatched before the replies)."""
+        m, params = model
+        prompt = _rand_prompt(61, 6, m.config.vocab_size)
+        want = _ref_tokens(m, params, prompt, 10)
+        eos = want[4]
+        cut = want[:want.index(eos) + 1]
+        eng = self._engine(model)
+        dispatched = []
+        decode = eng._decode_fn
+        eng._decode_fn = lambda *a: dispatched.append(1) or decode(*a)
+        assert eng.generate(prompt, 10, eos_token_id=eos) == cut
+        c = eng.stats.counters
+        # A decode a token after the first, none after the eos.
+        assert len(dispatched) == len(cut) - 1 == c["decode_steps"]
+        assert c["decode_fed_on_device"] == 0
+        assert c["decode_ahead"] == (c["decode_steps"] - 1 if len(cut) > 2
+                                     else 0)
+        assert eng._ahead is None
 
     def test_over_the_client_plane_with_eos(self, model):
         from ray_lightning_tpu.serve.client import ServeClient
@@ -782,7 +850,7 @@ class TestDecodeLookahead:
         want = _ref_tokens(m, params, prompt, 10)
         eos = want[4]
         cut = want[:want.index(eos) + 1]
-        eng = self._engine(model, coalesce_replies=True).start()
+        eng = self._engine(model).start()
         client = ServeClient(eng.queue_handle())
         try:
             other = client.submit(_rand_prompt(62, 5, m.config.vocab_size),
@@ -812,9 +880,76 @@ class TestDecodeLookahead:
         assert h2.done() and len(h2.tokens) < 12
         assert eng.stats.counters["cancelled"] == 1
 
+    def test_every_slot_cancelled_while_a_decode_is_in_flight(self, model):
+        m, params = model
+        eng = self._engine(model)
+        h1 = eng.submit(_rand_prompt(74, 5, m.config.vocab_size), 12)
+        while eng._ahead is None:
+            assert eng.step()
+        assert eng.cancel(h1.rid)
+        eng.step()
+        assert eng._ahead is None       # dropped with its last slot
+        p2 = _rand_prompt(75, 6, m.config.vocab_size)
+        assert eng.generate(p2, 5) == _ref_tokens(m, params, p2, 5)
+
+    def test_a_tick_behind_another_books_no_queueing_as_its_cost(self,
+                                                                 model):
+        """``decode_us`` feeds the capacity oracle's tick-cost fit: a
+        decode dispatched while the one before it still ran counts from
+        when that one's tokens were out, not from its own dispatch."""
+        m, _ = model
+        eng = self._engine(model)
+        eng.submit(_rand_prompt(76, 5, m.config.vocab_size), 12)
+        while eng._ahead is None:
+            assert eng.step()
+        # As if dispatched 10 s before the tick it queued behind ended.
+        eng._ahead = (eng._ahead[0] - 10.0, *eng._ahead[1:])
+        before = eng.stats.counters["decode_us"]
+        assert eng.step()
+        assert 0 < eng.stats.counters["decode_us"] - before < 5_000_000
+        eng.run_until_idle()
+
+    def test_slot_preempted_while_a_decode_is_in_flight(self, model):
+        """A pool too small for two whole sequences: the younger is
+        preempted while ticks are dispatched ahead, and both still match
+        the static path."""
+        m, params = model
+        eng = ServeEngine(m, params, ServeConfig(
+            num_slots=2, block_size=4, num_blocks=8, max_model_len=24))
+        p1, p2 = [3, 1, 4, 1], [2, 7, 1]
+        h1, h2 = eng.submit(p1, 16), eng.submit(p2, 16)
+        eng.run_until_idle()
+        assert h1.result(5) == _ref_tokens(m, params, p1, 16)
+        assert h2.result(5) == _ref_tokens(m, params, p2, 16)
+        c = eng.stats.counters
+        assert c["preempted"] >= 1 and c["decode_ahead"] > 0
+        assert eng._ahead is None
+
     @pytest.mark.parametrize("kw", [
         {"prefix_cache": True}, {"prefill_chunk": 8},
-        {"max_adapters": 2, "adapter_rank": 4}])
-    def test_refused_with_what_it_does_not_combine_with(self, model, kw):
-        with pytest.raises(ValueError, match="decode_lookahead"):
-            self._engine(model, **kw)
+        {"max_adapters": 2, "adapter_rank": 4}, {"spec_k": 2}],
+        ids=["prefix_cache", "prefill_chunk", "adapters", "draft"])
+    def test_serial_loop_where_state_is_booked_between_ticks(self, model,
+                                                             kw):
+        """Such an engine builds with the default fields, runs the
+        serial loop and serves the reference tokens."""
+        from ray_lightning_tpu.serve.draft import early_exit_draft
+
+        m, params = model
+        draft = {}
+        if "spec_k" in kw:
+            dm, dp = early_exit_draft(m, params, 1)
+            draft = {"draft_module": dm, "draft_params": dp}
+        eng = ServeEngine(m, params, ServeConfig(
+            num_slots=3, block_size=8, **kw), **draft)
+        assert eng.config.decode_lookahead and not eng._pipelined
+        prompts = [_rand_prompt(80 + i, 4 + 3 * i, m.config.vocab_size)
+                   for i in range(4)]
+        news = [9, 3, 7, 5]
+        handles = [eng.submit(p, n) for p, n in zip(prompts, news)]
+        eng.run_until_idle()
+        for h, p, n in zip(handles, prompts, news):
+            assert h.result(1) == _ref_tokens(m, params, p, n)
+        c = eng.stats.counters
+        assert c["decode_ahead"] == 0 == c["decode_fed_on_device"]
+        assert eng._ahead is None

@@ -103,12 +103,17 @@ class _StubHandle:
 
 def _drain(q, timeout=2.0, want=1):
     """What is on ``q``: returns once it is empty and holds ``want``
-    items (frames of one poll arrive one by one), or at the timeout."""
+    items (frames of one poll arrive one by one), or at the timeout.
+    An engine's tick sends its replies to one address as one
+    ``serve_batch`` frame: its items are unpacked, as the client does."""
     items = []
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:
         try:
-            items.append(q.get_nowait())
+            item = q.get_nowait()
+            batch = isinstance(item, dict) and item.get(
+                "type") == "serve_batch"
+            items.extend(item["items"] if batch else [item])
         except _pyqueue.Empty:
             if len(items) >= want:
                 return items

@@ -570,8 +570,10 @@ class TestHandoffLoadRace:
                 except Exception:  # noqa: BLE001 - empty queue
                     _time.sleep(0.01)
                     continue
-                if item.get("type") == "serve_done":
-                    done = item
+                # The last token and the completion share a tick's frame.
+                for sub in item.get("items", [item]):
+                    if sub.get("type") == "serve_done":
+                        done = sub
             assert done is not None and done["status"] == "finished"
             assert done["tokens"] == _ref_tokens(
                 m, merged["t0"], prompt, 8

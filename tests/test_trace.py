@@ -490,9 +490,15 @@ class TestFleetTracing:
 
         m, params = model
         trace_dir = str(tmp_path / "tel")
+        # The death below is a hard kill, which an in-process fleet sees
+        # at once (``handle.is_alive()``), not by beat age: lost_after_s
+        # only has to stay clear of the beat threads' starvation under
+        # full-suite load while both replicas compile (at 0.5 s both
+        # were once read as lost, 0.7 s after their last beat, and the
+        # request never streamed).
         fleet = launch_inproc_fleet(m, params, _serve_cfg(),
                                     n_replicas=2, n_prefill=0,
-                                    lost_after_s=0.5,
+                                    lost_after_s=5.0,
                                     trace_dir=trace_dir)
         client = ServeClient(fleet.queue_handle())
         try:
