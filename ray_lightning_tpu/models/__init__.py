@@ -1,6 +1,7 @@
 from .boring import BoringModel, BoringDataModule, XORModel, XORDataModule
 from .data_text import ByteLMDataModule, decode_bytes
 from .exaone_moe import ExaoneMoE, ExaoneMoEConfig
+from .sarvam_mla import SarvamMLA, SarvamMLAConfig
 from .generate import decode_step, generate, init_kv_cache, prefill
 from .gpt import (
     GPT,
@@ -33,6 +34,8 @@ __all__ = [
     "GPTConfig",
     "ExaoneMoE",
     "ExaoneMoEConfig",
+    "SarvamMLA",
+    "SarvamMLAConfig",
     "SyntheticLMDataModule",
     "add_lora_adapters",
     "extract_lora",

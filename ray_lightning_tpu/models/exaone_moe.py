@@ -74,6 +74,10 @@ class ExaoneMoEConfig:
     vocab_held: Optional[Tuple[int, int]] = None
     param_dtype: str = "bfloat16"
 
+    # The family's norm placement (:func:`decoder_block`): exaone4's, on
+    # each branch's output.  Not a field: no configuration changes it.
+    pre_norm = False
+
     def __post_init__(self):
         L = self.n_layer
         if self.layer_types is None:
@@ -151,14 +155,22 @@ def rms_norm(x: jax.Array, gain: jax.Array, eps: float) -> jax.Array:
     return (y * gain.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         inv_freq: Optional[jax.Array] = None,
+         factor: float = 1.0) -> jax.Array:
     """Rotary positions, half-split form.  x ``(..., S, H, Dh)``,
-    positions ``(..., S)``."""
+    positions ``(..., S)``.  The ``Dh / 2`` frequencies are
+    ``theta ** (-2i / Dh)`` unless a family hands its own as data
+    (``inv_freq``, YaRN's blend of scaled and unscaled ones) with the
+    ``factor`` its cos and sin are multiplied by."""
     dh = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
+    inv = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
     ang = positions.astype(jnp.float32)[..., None] * inv     # (..., S, Dh/2)
     cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[..., None, :]
     sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[..., None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x32 = x.astype(jnp.float32)
     x1, x2 = x32[..., : dh // 2], x32[..., dh // 2:]
     rot = jnp.concatenate([-x2, x1], -1)
@@ -300,6 +312,28 @@ def _residual(cfg, x, branch, gain):
     return x + rms_norm(branch.astype(x.dtype), gain, cfg.rms_eps)
 
 
+def decoder_block(cfg, p, x, mixer, mlp: str, row_valid, moe_impl: str,
+                  routing: Optional[list] = None):
+    """One layer: ``x`` plus its mixer's branch, then plus its
+    feed-forward's.  ``mixer(h)`` is the layer's attention of whatever
+    kind with its output projection; the feed-forward sees rows ``(S,
+    d)``.  Where the norms sit belongs to the family (``cfg.pre_norm``):
+    on each branch's output (``x + norm(branch(x))``, gains
+    ``attn_out_norm`` / ``ffn_out_norm``) or on its input (``x +
+    branch(norm(x))``, gains ``attn_norm`` / ``ffn_norm``).  Returns
+    ``(x, counts int32[2])``."""
+    if cfg.pre_norm:
+        x = x + mixer(rms_norm(x, p["attn_norm"], cfg.rms_eps)).astype(x.dtype)
+        h = rms_norm(x, p["ffn_norm"], cfg.rms_eps)
+        f, c = feed_forward(cfg, mlp, p, h.reshape(-1, h.shape[-1]),
+                            row_valid, moe_impl, routing)
+        return x + f.reshape(x.shape).astype(x.dtype), c
+    x = _residual(cfg, x, mixer(x), p["attn_out_norm"])
+    f, c = feed_forward(cfg, mlp, p, x.reshape(-1, x.shape[-1]), row_valid,
+                        moe_impl, routing)
+    return _residual(cfg, x, f.reshape(x.shape), p["ffn_out_norm"]), c
+
+
 def _resolve_attn(attn_impl: str, T: int) -> str:
     if attn_impl != "auto":
         return attn_impl
@@ -325,14 +359,14 @@ def sequence_forward(cfg: ExaoneMoEConfig, params, tokens: jax.Array,
     kv, counts = [], jnp.zeros((2,), jnp.int32)
     for p, kind, mlp in zip(params["layers"], cfg.layer_types,
                             cfg.mlp_types):
-        q, k, v = qkv(cfg, p, x, positions, kind)
-        kv.append((k.reshape(B, T, cfg.kv_width),
-                   v.reshape(B, T, cfg.kv_width)))
-        att = attend_sequence(cfg, kind, q, k, v, attn_impl)
-        x = _residual(cfg, x, _mm(att, p["wo"]), p["attn_out_norm"])
-        f, c = feed_forward(cfg, mlp, p, x.reshape(B * T, -1), valid,
-                            moe_impl, routing)
-        x = _residual(cfg, x, f.reshape(x.shape), p["ffn_out_norm"])
+        def mixer(h, p=p, kind=kind):
+            q, k, v = qkv(cfg, p, h, positions, kind)
+            kv.append((k.reshape(B, T, cfg.kv_width),
+                       v.reshape(B, T, cfg.kv_width)))
+            return _mm(attend_sequence(cfg, kind, q, k, v, attn_impl),
+                       p["wo"])
+
+        x, c = decoder_block(cfg, p, x, mixer, mlp, valid, moe_impl, routing)
         counts = counts + c
     return x, kv, counts
 
@@ -386,8 +420,8 @@ class TwoKindKVCache:
 
     def export_blocks(self, pool, ids):
         raise ValueError(
-            f"export_blocks is not supported for the {FAMILY} family: a "
-            "sequence's state is a block table and a window ring")
+            f"export_blocks is not supported for the {FAMILY} family: "
+            f"{ServeFamily.refuses_why}")
 
 
 def paged_prefill(cfg: ExaoneMoEConfig, params, pool, tokens, prompt_len,
@@ -467,46 +501,41 @@ def paged_decode_step(cfg: ExaoneMoEConfig, params, pool, block_tables,
     n_full = n_ring = 0
     for p, kind, mlp in zip(params["layers"], cfg.layer_types,
                             cfg.mlp_types):
-        q, k, v = qkv(cfg, p, x[:, None], pos[:, None], kind)
-        q, k, v = q[:, 0], k[:, 0], v[:, 0]
-        k_row = k.reshape(W, cfg.kv_width).astype(pool["k"].dtype)
-        v_row = v.reshape(W, cfg.kv_width).astype(pool["v"].dtype)
-        if kind == "full":
-            if attn_impl == "pallas":
+        def mixer(h, p=p, kind=kind, n_full=n_full, n_ring=n_ring):
+            q, k, v = qkv(cfg, p, h[:, None], pos[:, None], kind)
+            q, k, v = q[:, 0], k[:, 0], v[:, 0]
+            k_row = k.reshape(W, cfg.kv_width).astype(pool["k"].dtype)
+            v_row = v.reshape(W, cfg.kv_width).astype(pool["v"].dtype)
+            if kind == "full" and attn_impl == "pallas":
                 att = paged_decode_attention(
                     q.reshape(W, -1), k_row, v_row, pool["k"], pool["v"],
                     jnp.int32(n_full), full_tables, pos,
                     n_head=cfg.n_head, n_kv_head=cfg.n_kv_head,
                     scale=cfg.head_dim ** -0.5)
             else:
+                names, n, tables, S, vis = (
+                    (("k", "v"), n_full, full_tables, M * Bs, full_vis)
+                    if kind == "full" else
+                    (("wk", "wv"), n_ring, ring_tables, RB, ring_vis))
+
                 def ctx(t, row):
-                    got = t[n_full][full_tables].reshape(W, M * Bs, -1)
+                    got = t[n][tables].reshape(W, S, -1)
                     got = jnp.concatenate([got, row[:, None]], axis=1)
-                    return got.reshape(W, M * Bs + 1, cfg.n_kv_head, -1)
+                    return got.reshape(W, S + 1, cfg.n_kv_head, -1)
 
                 att = attend_one(
-                    cfg, q, ctx(pool["k"], k_row), ctx(pool["v"], v_row),
-                    jnp.concatenate([full_vis, one], axis=1))
-            new["k"].append(k_row)
-            new["v"].append(v_row)
-            n_full += 1
-        else:
-            def ctx(t, row):
-                got = t[n_ring][ring_tables].reshape(W, RB, -1)
-                got = jnp.concatenate([got, row[:, None]], axis=1)
-                return got.reshape(W, RB + 1, cfg.n_kv_head, -1)
+                    cfg, q, ctx(pool[names[0]], k_row),
+                    ctx(pool[names[1]], v_row),
+                    jnp.concatenate([vis, one], axis=1))
+            pre = "" if kind == "full" else "w"
+            new[pre + "k"].append(k_row)
+            new[pre + "v"].append(v_row)
+            return _mm(att.astype(h.dtype), p["wo"])
 
-            att = attend_one(
-                cfg, q, ctx(pool["wk"], k_row), ctx(pool["wv"], v_row),
-                jnp.concatenate([ring_vis, one], axis=1))
-            new["wk"].append(k_row)
-            new["wv"].append(v_row)
-            n_ring += 1
-        x = _residual(cfg, x, _mm(att.astype(x.dtype), p["wo"]),
-                      p["attn_out_norm"])
-        f, c = feed_forward(cfg, mlp, p, x, active, moe_impl)
-        x = _residual(cfg, x, f, p["ffn_out_norm"])
+        x, c = decoder_block(cfg, p, x, mixer, mlp, active, moe_impl)
         counts = counts + c
+        n_full += kind == "full"
+        n_ring += kind != "full"
     logits = head_logits(cfg, params, x)
     # Every index explicit, one row a slot a layer (PERF.md, PR 25: a
     # slice over the layer axis makes XLA re-lay the pool).
@@ -530,7 +559,13 @@ class ServeFamily:
     (``serve/kv_cache.py`` ``gpt_family`` is the same seam for ``GPT``.)"""
 
     name = FAMILY
-    two_kind = True
+    two_kind = True      # a window ring beside the block table
+    refuses = ("prefix_cache", "spec_k", "draft", "adapters",
+               "prefill_chunk", "block_transfer")
+    refuses_why = (
+        "it is served with two kinds of cache state, block tables and "
+        "window rings: a sequence's state is a block table and a "
+        "window ring")
 
     def __init__(self, module: "ExaoneMoE"):
         self.cfg = module.config
@@ -556,6 +591,69 @@ class ServeFamily:
         return tree
 
 
+def init_tree(cfg, rng: jax.Array, mixer_leaves,
+              router_bias_std: float = 0.0) -> Dict[str, Any]:
+    """A pattern-described decoder's weights in ``param_dtype``, made on
+    the device, one jitted call a layer and inside it one expert at a
+    time: no float32 copy of the whole model ever exists.
+    ``mixer_leaves(w, keys, kind)`` gives a layer's mixer tensors and
+    the block's norm gains (``w(key, shape)`` draws a matrix); the
+    feed-forward's, the tables and the final norm are every family's.
+    The router scores in float32 (a choice must not flip on rounding);
+    its selection bias is a float32 buffer, zero unless a family draws
+    it (``router_bias_std``)."""
+    dt = jnp.dtype(cfg.param_dtype)
+    keys = jax.random.split(rng, cfg.n_layer + 2)
+    d = cfg.d_model
+
+    def w(key, shape, std=0.02):
+        return (jax.random.normal(key, shape, jnp.float32)
+                * std).astype(dt)
+
+    @functools.partial(jax.jit, static_argnums=(1,))
+    def table(key, shape):
+        return w(key, shape)
+
+    @functools.partial(jax.jit, static_argnums=(1, 2))
+    def layer(key, kind, mlp):
+        ks = jax.random.split(key, 12)
+        p = mixer_leaves(w, ks[:4], kind)
+        if mlp == "dense":
+            p.update(w_gate=w(ks[4], (d, cfg.d_ff)),
+                     w_up=w(ks[5], (d, cfg.d_ff)),
+                     w_down=w(ks[6], (cfg.d_ff, d)))
+            return p
+        f, eh = cfg.d_expert, cfg.n_experts_held
+
+        def experts(key, shape):
+            return jax.lax.map(lambda k: w(k, shape),
+                               jax.random.split(key, eh))
+
+        bias = jnp.zeros((cfg.n_experts,), jnp.float32)
+        if router_bias_std:
+            bias = jax.random.normal(
+                ks[11], (cfg.n_experts,), jnp.float32) * router_bias_std
+        p.update(
+            router=jax.random.normal(ks[4], (d, cfg.n_experts),
+                                     jnp.float32) * 0.02,
+            router_bias=bias,
+            e_gate=experts(ks[5], (d, f)), e_up=experts(ks[6], (d, f)),
+            e_down=experts(ks[7], (f, d)),
+            s_gate=w(ks[8], (d, f)), s_up=w(ks[9], (d, f)),
+            s_down=w(ks[10], (f, d)))
+        return p
+
+    vh = cfg.n_vocab_held
+    return {
+        "embed": table(keys[0], (vh, d)),
+        "head": table(keys[1], (d, vh)),
+        "final_norm": jnp.ones((d,), jnp.float32),
+        "layers": [layer(keys[i + 2], kind, mlp)
+                   for i, (kind, mlp) in enumerate(
+                       zip(cfg.layer_types, cfg.mlp_types))],
+    }
+
+
 # ---------------------------------------------------------------------------
 # the module
 # ---------------------------------------------------------------------------
@@ -576,6 +674,10 @@ class ExaoneMoE(TpuModule):
         self.precision = ("bf16" if config.param_dtype == "bfloat16"
                           else "f32")
 
+    # The family's trunk over whole sequences (a subclass of another
+    # family names its own).
+    _sequence_forward = staticmethod(sequence_forward)
+
     def _compute_dtype(self):
         return jnp.dtype(self.config.param_dtype)
 
@@ -584,28 +686,13 @@ class ExaoneMoE(TpuModule):
 
     # -- parameters ---------------------------------------------------------
     def init_params(self, rng: jax.Array) -> Dict[str, Any]:
-        """Weights in ``param_dtype`` made on the device, one jitted
-        call a layer and inside it one expert at a time: no float32 copy
-        of the whole model ever exists."""
         cfg = self.config
-        dt = jnp.dtype(cfg.param_dtype)
-        keys = jax.random.split(rng, cfg.n_layer + 2)
         d = cfg.d_model
 
-        def w(key, shape, std=0.02):
-            return (jax.random.normal(key, shape, jnp.float32)
-                    * std).astype(dt)
-
-        @functools.partial(jax.jit, static_argnums=(1,))
-        def table(key, shape):
-            return w(key, shape)
-
-        @functools.partial(jax.jit, static_argnums=(1, 2))
-        def layer(key, kind, mlp):
+        def mixer_leaves(w, ks, kind):
             del kind        # both mixer kinds hold the same tensors
-            ks = jax.random.split(key, 12)
             hq, hkv = cfg.n_head * cfg.head_dim, cfg.kv_width
-            p = {
+            return {
                 "wq": w(ks[0], (d, hq)), "wk": w(ks[1], (d, hkv)),
                 "wv": w(ks[2], (d, hkv)), "wo": w(ks[3], (hq, d)),
                 "q_norm": jnp.ones((cfg.head_dim,), jnp.float32),
@@ -613,44 +700,14 @@ class ExaoneMoE(TpuModule):
                 "attn_out_norm": jnp.ones((d,), jnp.float32),
                 "ffn_out_norm": jnp.ones((d,), jnp.float32),
             }
-            if mlp == "dense":
-                p.update(w_gate=w(ks[4], (d, cfg.d_ff)),
-                         w_up=w(ks[5], (d, cfg.d_ff)),
-                         w_down=w(ks[6], (cfg.d_ff, d)))
-                return p
-            f, eh = cfg.d_expert, cfg.n_experts_held
 
-            def experts(key, shape):
-                return jax.lax.map(lambda k: w(k, shape),
-                                   jax.random.split(key, eh))
-
-            p.update(
-                # The router scores in float32 (a choice must not flip
-                # on rounding); its selection bias is a buffer, zero.
-                router=jax.random.normal(ks[4], (d, cfg.n_experts),
-                                         jnp.float32) * 0.02,
-                router_bias=jnp.zeros((cfg.n_experts,), jnp.float32),
-                e_gate=experts(ks[5], (d, f)), e_up=experts(ks[6], (d, f)),
-                e_down=experts(ks[7], (f, d)),
-                s_gate=w(ks[8], (d, f)), s_up=w(ks[9], (d, f)),
-                s_down=w(ks[10], (f, d)))
-            return p
-
-        vh = cfg.n_vocab_held
-        return {
-            "embed": table(keys[0], (vh, d)),
-            "head": table(keys[1], (d, vh)),
-            "final_norm": jnp.ones((d,), jnp.float32),
-            "layers": [layer(keys[i + 2], kind, mlp)
-                       for i, (kind, mlp) in enumerate(
-                           zip(cfg.layer_types, cfg.mlp_types))],
-        }
+        return init_tree(cfg, rng, mixer_leaves)
 
     # -- forward ------------------------------------------------------------
     def forward(self, params, tokens: jax.Array) -> jax.Array:
         """tokens ``(B, T)`` -> logits ``(B, T, V_held)`` float32, by
         the mixer and feed-forward code the serving prefill runs."""
-        x, _, _ = sequence_forward(
+        x, _, _ = self._sequence_forward(
             self.config, params, tokens, attn_impl=self.attn_impl,
             moe_impl=self.moe_impl)
         return head_logits(self.config, params, x)

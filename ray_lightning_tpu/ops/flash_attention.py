@@ -141,13 +141,14 @@ def _flash_fwd_bhsd(q, k, v, scale, block_q, block_k, want_lse=True):
     O(BH·S·lane) f32 lse tensor is allocated or written.
     """
     bh, s, d = q.shape
+    dv = v.shape[-1]    # the values' own width (the accumulator's)
     grid = (bh, s // block_q)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
-        head_dim=d,
+        head_dim=dv,
     )
-    out_shape = jax.ShapeDtypeStruct((bh, s, d), q.dtype)
-    out_spec = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))
+    out_shape = jax.ShapeDtypeStruct((bh, s, dv), q.dtype)
+    out_spec = pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0))
     lse_spec = pl.BlockSpec((1, block_q, _STAT_W), lambda b, i: (b, i, 0))
     result = pl.pallas_call(
         kernel,
@@ -159,7 +160,7 @@ def _flash_fwd_bhsd(q, k, v, scale, block_q, block_k, want_lse=True):
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, s, dv), lambda b, i: (b, 0, 0)),
         ],
         out_specs=(out_spec, lse_spec) if want_lse else out_spec,
         interpret=_interpret(),
@@ -307,20 +308,25 @@ def _flash_bwd_bhsd(q, k, v, out, lse, g, scale, block_q, block_k):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
 def _flash(scale, block_q, block_k, q, k, v):
-    b, s, h, d = q.shape
+    b, s, h, _ = q.shape
 
     def to_bhsd(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+        return x.transpose(0, 2, 1, 3).reshape(b * h, s, x.shape[-1])
 
     out, _ = _flash_fwd_bhsd(
         to_bhsd(q), to_bhsd(k), to_bhsd(v), scale, block_q, block_k,
         want_lse=False,
     )
-    return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    return out.reshape(b, h, s, v.shape[-1]).transpose(0, 2, 1, 3)
 
 
 def _flash_vjp_fwd(scale, block_q, block_k, q, k, v):
     b, s, h, d = q.shape
+    if v.shape[-1] != d:
+        raise NotImplementedError(
+            f"flash attention's backward kernels take one head width; "
+            f"values of {v.shape[-1]} beside queries of {d} run forward "
+            f"only (serving prefill)")
 
     def to_bhsd(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
@@ -367,7 +373,10 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
 ) -> jax.Array:
-    """Causal flash attention, (B, S, H, D) -> (B, S, H, D)."""
+    """Causal flash attention, (B, S, H, D) -> (B, S, H, D).  The
+    forward takes values of another width than the queries' and keys'
+    (``v (B, S, H, Dv)`` -> ``(B, S, H, Dv)``: latent attention's 128
+    beside 192); the backward does not."""
     _, s, _, d = q.shape
     scale = (d ** -0.5) if scale is None else scale
     if block_q is None:

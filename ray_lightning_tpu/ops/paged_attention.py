@@ -28,8 +28,17 @@ afterwards; this kernel
   (exact: 3 x 8 significand bits), stacked on the rows of ONE matmul, so
   the probabilities are never rounded to bfloat16 before they meet V.
 
-Off-TPU the kernel runs under the Pallas interpreter (tests only: the
-serving path takes it on TPU alone, see :func:`paged_decode_supported`).
+A second kernel, ``rlt_mla_decode``, serves latent (MLA) attention
+(``models/sarvam_mla.py``): one cached row ``[c | k_r | 0]`` a position
+shared by all heads, scored against the heads' absorbed queries as one
+matmul of ``H`` rows; the tile is read from HBM once and serves as keys
+(every column) and as values (its first ``rank`` columns).  The walk of
+the block table, the chunking, the double-buffered copies and the
+online softmax are the helpers both kernels call.
+
+Off-TPU the kernels run under the Pallas interpreter (tests only: the
+serving path takes them on TPU alone, see :func:`paged_decode_supported`
+and :func:`mla_decode_supported`).
 """
 
 from __future__ import annotations
@@ -47,7 +56,8 @@ from ray_lightning_tpu.ops.kernel_probe import (
     _interpret, kernel_family_disabled,
 )
 
-__all__ = ["paged_decode_attention", "paged_decode_supported"]
+__all__ = ["paged_decode_attention", "paged_decode_supported",
+           "mla_decode_attention", "mla_decode_supported"]
 
 # Cache positions one compute step covers (whole blocks): wide enough
 # that the score tile fills the 128 lanes, narrow enough that a slot's
@@ -81,11 +91,11 @@ def paged_decode_tiles(pool_k: jax.Array) -> bool:
     return hd % 128 == 0 and bs % _sublane(pool_k.dtype) == 0
 
 
-def _pages_per_chunk(m: int, bs: int) -> int:
+def _pages_per_chunk(m: int, bs: int, positions: int = CHUNK_POSITIONS) -> int:
     """Largest divisor of the table width ``m`` whose blocks cover at
-    most ``CHUNK_POSITIONS`` positions (a chunk never straddles the end
-    of a table row)."""
-    p = max(1, min(m, CHUNK_POSITIONS // bs))
+    most ``positions`` positions (a chunk never straddles the end of a
+    table row)."""
+    p = max(1, min(m, positions // bs))
     while m % p:
         p -= 1
     return p
@@ -121,17 +131,14 @@ def _dot_f32(a: jax.Array, b: jax.Array, dims) -> jax.Array:
     return (parts[2 * r:] + parts[r:2 * r]) + parts[:r]
 
 
-def _kernel(layer_ref, tables_ref, lens_ref,            # scalar prefetch
-            q_ref, kc_ref, vc_ref, k_hbm, v_hbm,        # inputs
-            o_ref,                                      # output
-            kbuf, vbuf, sems, buf_ref,                  # scratch
-            *, n_head, n_kv_head, head_dim, rows, scale, pages,
-            table_width, block_size):
-    w = pl.program_id(0)
-    n_slots = pl.num_programs(0)
+def _table_walk(tables_ref, lens_ref, layer, streams, sems, *, pages,
+                table_width, block_size):
+    """The walk of a slot's block table that both decode kernels share.
+    ``streams`` pairs each pool in HBM with its VMEM buffer ``(2, P*Bs,
+    row)``; every stream follows the same table.  Returns ``(n_pages,
+    chunk_dma)``: the resident pages of a slot, and the start or wait
+    of the copies of one of its chunks."""
     P, M, Bs = pages, table_width, block_size
-    T = P * Bs
-    layer = layer_ref[0]
 
     def n_pages(slot):
         return jnp.minimum(pl.cdiv(lens_ref[slot], Bs), M)
@@ -147,7 +154,7 @@ def _kernel(layer_ref, tables_ref, lens_ref,            # scalar prefetch
             @pl.when(j < n)
             def _():
                 blk = tables_ref[slot * M + j]
-                for hbm, vmem, s in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                for s, (hbm, vmem) in enumerate(streams):
                     copy = pltpu.make_async_copy(
                         hbm.at[layer, blk],
                         vmem.at[buf, pl.ds(i * Bs, Bs)],
@@ -155,14 +162,77 @@ def _kernel(layer_ref, tables_ref, lens_ref,            # scalar prefetch
                     )
                     getattr(copy, op)()
 
+    return n_pages, chunk_dma
+
+
+def _open_walk(w, buffers, buf_ref, chunk_dma):
+    """Before the first slot: the buffers finite, the first chunk in
+    flight."""
     @pl.when(w == 0)
     def _():
         # Rows no copy has filled yet must be finite: a masked position
         # contributes 0 x row.
-        kbuf[...] = jnp.zeros_like(kbuf)
-        vbuf[...] = jnp.zeros_like(vbuf)
+        for b in buffers:
+            b[...] = jnp.zeros_like(b)
         buf_ref[0] = 0
         chunk_dma(0, 0, 0, "start")
+
+
+def _walk_chunks(w, n_slots, n_chunks, chunk_dma, buf0, step, carry):
+    """``carry = step(c, buf, carry)`` over the slot's chunks, double-
+    buffered: while chunk ``c`` is computed the next one is in flight,
+    this slot's or the first of the next slot.  Returns ``(carry, the
+    buffer the next slot starts on)``."""
+    def body(c, state):
+        carry, buf = state
+        chunk_dma(w, c, buf, "wait")
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            chunk_dma(w, c + 1, 1 - buf, "start")
+
+        @pl.when((c + 1 == n_chunks) & (w + 1 < n_slots))
+        def _():
+            chunk_dma(w + 1, 0, 1 - buf, "start")
+
+        return step(c, buf, carry), 1 - buf
+
+    return jax.lax.fori_loop(0, n_chunks, body, (carry, buf0))
+
+
+def _softmax_step(s, visible, values, carry, round_probs=False):
+    """One chunk of the online softmax: scores ``s (rows, T)`` float32,
+    ``values (T, C)`` as stored; statistics and accumulator float32.
+    ``round_probs``: the probabilities meet the values in the values'
+    own dtype (one matmul, as the flash kernels do) and not as exact
+    float32 terms."""
+    acc, m, l = carry
+    s = jnp.where(visible, s, _NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m - m_new)
+    l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
+    if round_probs:
+        p = p.astype(values.dtype)
+    acc_new = acc * corr + _dot_f32(p, values, (((1,), (0,)), ((), ())))
+    return acc_new, m_new, l_new
+
+
+def _kernel(layer_ref, tables_ref, lens_ref,            # scalar prefetch
+            q_ref, kc_ref, vc_ref, k_hbm, v_hbm,        # inputs
+            o_ref,                                      # output
+            kbuf, vbuf, sems, buf_ref,                  # scratch
+            *, n_head, n_kv_head, head_dim, rows, scale, pages,
+            table_width, block_size):
+    w = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    P, Bs = pages, block_size
+    T = P * Bs
+    n_pages, chunk_dma = _table_walk(
+        tables_ref, lens_ref, layer_ref[0],
+        ((k_hbm, kbuf), (v_hbm, vbuf)), sems, pages=pages,
+        table_width=table_width, block_size=block_size)
+    _open_walk(w, (kbuf, vbuf), buf_ref, chunk_dma)
 
     n_vis = lens_ref[w]
     n_chunks = jnp.maximum(pl.cdiv(n_pages(w), P), 1)
@@ -191,33 +261,13 @@ def _kernel(layer_ref, tables_ref, lens_ref,            # scalar prefetch
     l0 = jnp.ones((rows, 1), jnp.float32)
     acc0 = jnp.broadcast_to(vc_ref[0].astype(jnp.float32), (rows, hd))
 
-    def body(c, carry):
-        acc, m, l, buf = carry
-        chunk_dma(w, c, buf, "wait")
-
-        @pl.when(c + 1 < n_chunks)
-        def _():
-            chunk_dma(w, c + 1, 1 - buf, "start")
-
-        @pl.when((c + 1 == n_chunks) & (w + 1 < n_slots))
-        def _():
-            chunk_dma(w + 1, 0, 1 - buf, "start")
-
+    def step(c, buf, carry):
         s = _dot_f32(q_bd, kbuf[buf], (((1,), (1,)), ((), ()))) * scale
         pos = c * T + jax.lax.broadcasted_iota(jnp.int32, (rows, T), 1)
-        s = jnp.where(pos < n_vis, s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_new = acc * corr + _dot_f32(
-            p, vbuf[buf], (((1,), (0,)), ((), ()))
-        )
-        return acc_new, m_new, l_new, 1 - buf
+        return _softmax_step(s, pos < n_vis, vbuf[buf], carry)
 
-    acc, _, l, buf = jax.lax.fori_loop(
-        0, n_chunks, body, (acc0, m0, l0, buf_ref[0])
-    )
+    (acc, _, l), buf = _walk_chunks(
+        w, n_slots, n_chunks, chunk_dma, buf_ref[0], step, (acc0, m0, l0))
     buf_ref[0] = buf
     out = jnp.where(diag, acc / l, 0.0)
     if group == 1:
@@ -330,3 +380,180 @@ def paged_decode_attention(
         v_pool,
     )
     return out.reshape(W, qd)
+
+
+# ---------------------------------------------------------------------------
+# latent (MLA) decode: one shared row a position, absorbed form
+# ---------------------------------------------------------------------------
+
+# Cache positions one step of ``rlt_mla_decode`` covers.  Its tile is a
+# matmul operand for all heads at once, so a step's fixed cost (eight
+# copies waited and started, the accumulator rescaled) is worth
+# amortising over more positions than ``CHUNK_POSITIONS``: 8 layers x 64
+# slots of ~2600 positions took 7.76 / 5.03 / 4.20 ms at 128 / 256 / 512
+# (my chip run, PR 30; PERF.md section 6).
+MLA_CHUNK_POSITIONS = 512
+
+
+def _mla_kernel(layer_ref, tables_ref, lens_ref,        # scalar prefetch
+                q_ref, cur_ref, pool_hbm,               # inputs
+                o_ref,                                  # output
+                buf, sems, buf_ref,                     # scratch
+                *, n_head, rank, scale, pages, table_width, block_size):
+    w = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    P, Bs = pages, block_size
+    T = P * Bs
+    n_pages, chunk_dma = _table_walk(
+        tables_ref, lens_ref, layer_ref[0], ((pool_hbm, buf),), sems,
+        pages=pages, table_width=table_width, block_size=block_size)
+    _open_walk(w, (buf,), buf_ref, chunk_dma)
+
+    n_vis = lens_ref[w]
+    n_chunks = jnp.maximum(pl.cdiv(n_pages(w), P), 1)
+
+    q = q_ref[0]                                        # (H, row)
+    q32 = q.astype(jnp.float32)
+    cur = cur_ref[0].astype(jnp.float32)                # (1, row)
+    # The current token's own row is position seq_len and opens the
+    # running softmax; its first ``rank`` columns are its value.
+    m0 = jnp.sum(q32 * cur, axis=1, keepdims=True) * scale
+    l0 = jnp.ones((n_head, 1), jnp.float32)
+    acc0 = jnp.broadcast_to(cur[:, :rank], (n_head, rank))
+
+    def step(c, b, carry):
+        # One tile, read once from HBM: every column is a key column
+        # (the padding lanes are zero in q), the first ``rank`` are the
+        # values.  All heads share it: a matmul of H rows.
+        s = _dot_f32(q, buf[b], (((1,), (1,)), ((), ()))) * scale
+        pos = c * T + jax.lax.broadcasted_iota(jnp.int32, (n_head, T), 1)
+        # The probabilities meet the values in the pool's dtype, as in
+        # the prefill's flash kernel: one matmul of H rows, not three.
+        return _softmax_step(s, pos < n_vis, buf[b, :, :rank], carry,
+                             round_probs=True)
+
+    (acc, _, l), b = _walk_chunks(
+        w, n_slots, n_chunks, chunk_dma, buf_ref[0], step, (acc0, m0, l0))
+    buf_ref[0] = b
+    o_ref[0] = acc / l
+
+
+def mla_decode_tiles(pool: jax.Array, n_head: int, rank: int) -> bool:
+    """What ``rlt_mla_decode`` tiles: a pool :func:`paged_decode_tiles`
+    takes, the value columns whole lanes of it, the heads whole sublane
+    tiles."""
+    return (paged_decode_tiles(pool) and rank % 128 == 0
+            and rank <= pool.shape[3]
+            and n_head % _sublane(jnp.bfloat16) == 0)
+
+
+def mla_decode_supported(pool: jax.Array, n_head: int, rank: int) -> bool:
+    """Whether ``attn_impl="auto"`` takes ``rlt_mla_decode`` (as
+    :func:`paged_decode_supported`: backend, shapes, the ``paged``
+    kernel family's switch)."""
+    return (not kernel_family_disabled("paged")
+            and jax.default_backend() == "tpu"
+            and mla_decode_tiles(pool, n_head, rank))
+
+
+def mla_decode_attention(
+    q: jax.Array,
+    row_cur: jax.Array,
+    pool: jax.Array,
+    layer: jax.Array,
+    block_tables: jax.Array,
+    seq_lens: jax.Array,
+    *,
+    rank: int,
+    scale: float,
+    impl: str = "pallas",
+) -> jax.Array:
+    """Latent attention of one new token per slot, absorbed form.
+
+    Every cached position of a layer is ONE row ``[c | k_r | 0]`` shared
+    by all heads: the compressed latent (``rank`` columns, keys and
+    values both), the rotary key, lanes of zero padding.  A head's
+    query is ``[q_n W_uk | q_r | 0]``, its score the dot product with
+    the row, its output ``P @ c`` (the caller applies ``W_uv``).
+
+    Args:
+        q: ``(W, H, row)`` absorbed queries, zero in the padding lanes.
+        row_cur: ``(W, row)`` the new token's own row in the pool's
+            dtype (what the caller will store at ``seq_lens[w]``).
+        pool: ``(L, N, Bs, row)``, read at ``layer`` only.
+        block_tables: ``(W, M)`` int32; seq_lens ``(W,)`` positions
+            already in the pool (0 = an idle slot: its own row alone).
+        impl: ``"pallas"`` (the ``rlt_mla_decode`` kernel: resident
+            blocks only, each tile read once for scores and values) or
+            ``"xla"`` (the whole table gathered and masked; the same
+            arithmetic: scores' products accumulated in float32, softmax
+            statistics in float32, the probabilities rounded to the
+            pool's dtype where they meet the values, their products
+            accumulated in float32).
+
+    Returns:
+        ``(W, H, rank)`` float32.
+    """
+    W, H, row = q.shape
+    _, _, Bs, _ = pool.shape
+    M = block_tables.shape[1]
+    lens = jnp.minimum(seq_lens.astype(jnp.int32), M * Bs)
+    if impl == "xla":
+        ctx = pool[layer][block_tables].reshape(W, M * Bs, row)
+        ctx = jnp.concatenate([ctx, row_cur[:, None]], axis=1).astype(
+            jnp.float32)
+        s = jnp.einsum("whc,wsc->whs", q.astype(jnp.float32), ctx,
+                       precision=jax.lax.Precision.HIGHEST) * scale
+        vis = jnp.arange(M * Bs + 1)[None, :] < lens[:, None]
+        vis = vis.at[:, -1].set(True)
+        probs = jax.nn.softmax(
+            jnp.where(vis[:, None, :], s, _NEG_INF), axis=-1)
+        probs = probs.astype(pool.dtype).astype(jnp.float32)
+        return jnp.einsum("whs,wsr->whr", probs, ctx[..., :rank],
+                          precision=jax.lax.Precision.HIGHEST)
+    if impl != "pallas":
+        raise ValueError(f"Unknown latent decode impl {impl!r} (xla|pallas)")
+    if pool.shape[3] != row or not mla_decode_tiles(pool, H, rank):
+        raise ValueError(
+            f"rlt_mla_decode does not tile a {pool.dtype} pool of shape "
+            f"{pool.shape} for {H} heads on rows of {row} with {rank} "
+            f"value columns: take the XLA path (attn_impl='xla' or 'auto')"
+        )
+    P = _pages_per_chunk(M, Bs, MLA_CHUNK_POSITIONS)
+    kernel = functools.partial(
+        _mla_kernel, n_head=H, rank=rank, scale=scale, pages=P,
+        table_width=M, block_size=Bs,
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(W,),
+        in_specs=[
+            pl.BlockSpec((1, H, row), lambda w, *_: (w, 0, 0)),
+            pl.BlockSpec((1, 1, row), lambda w, *_: (w, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, H, rank), lambda w, *_: (w, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, P * Bs, row), pool.dtype),
+            pltpu.SemaphoreType.DMA((1, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((W, H, rank), jnp.float32),
+        # Slots run in order, as in rlt_paged_decode.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        interpret=_interpret(),
+        name="rlt_mla_decode",
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        block_tables.astype(jnp.int32).reshape(-1),
+        lens,
+        q,
+        row_cur.reshape(W, 1, row),
+        pool,
+    )

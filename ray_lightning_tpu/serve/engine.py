@@ -247,10 +247,13 @@ class ServeEngine:
                 tree = dequantize_decode_params(tree)
             # The family whose programs read the tree holds it in the
             # dtype they read it in: no weight is converted per call.
-            was = [leaf.dtype for leaf in jax.tree.leaves(tree)]
+            # (By path: a family may also rearrange a leaf into others.)
+            was = {path: leaf.dtype for path, leaf in
+                   jax.tree_util.tree_leaves_with_path(tree)}
             tree = family.prepare_params(tree, c)
-            now = [leaf.dtype for leaf in jax.tree.leaves(tree)]
-            return tree, sum(a != b for a, b in zip(was, now))
+            return tree, sum(
+                was.get(path, leaf.dtype) != leaf.dtype for path, leaf in
+                jax.tree_util.tree_leaves_with_path(tree))
 
         self.module = module
         self.cfg = module.config
@@ -261,22 +264,23 @@ class ServeEngine:
         self.family = (module.serve_family()
                        if hasattr(module, "serve_family")
                        else GPTServeFamily(self.cfg))
-        if self.family.two_kind:
-            refused = [
-                name for name, on in (
-                    ("prefix_cache", cfg.prefix_cache),
-                    ("spec_k > 0", cfg.spec_k > 0),
-                    ("a draft model", draft_module is not None),
-                    ("LoRA adapters (max_adapters / adapters=)",
-                     cfg.max_adapters > 0 or bool(adapters)),
-                    ("prefill_chunk", cfg.prefill_chunk is not None),
-                ) if on]
-            if refused:
-                raise ValueError(
-                    f"the {self.family.name} family is served with two "
-                    f"kinds of cache state (block tables and window "
-                    f"rings) and does not support: {', '.join(refused)}"
-                )
+        # A family says itself what it cannot serve (``refuses``, with
+        # its reason; GPT's is empty).
+        refused = [
+            text for key, text, on in (
+                ("prefix_cache", "prefix_cache", cfg.prefix_cache),
+                ("spec_k", "spec_k > 0", cfg.spec_k > 0),
+                ("draft", "a draft model", draft_module is not None),
+                ("adapters", "LoRA adapters (max_adapters / adapters=)",
+                 cfg.max_adapters > 0 or bool(adapters)),
+                ("prefill_chunk", "prefill_chunk",
+                 cfg.prefill_chunk is not None),
+            ) if on and key in self.family.refuses]
+        if refused:
+            raise ValueError(
+                f"the {self.family.name} family does not support: "
+                f"{', '.join(refused)} ({self.family.refuses_why})"
+            )
         _reject_unmerged_lora(params)
         self._c = module._compute_dtype()
         self.params, cast_leaves = _prep(params, self.family, self._c)
@@ -441,6 +445,13 @@ class ServeEngine:
                 leaf.nbytes for leaf in jax.tree.leaves(self.params)),
             "weights_cast_leaves": cast_leaves,
         })
+        # Latent layers (a family whose cache row is one compressed
+        # latent a position, shared by all heads): how many, and the
+        # bytes of data in one cached position of one of them.
+        self._n_latent = getattr(self.family, "n_latent", 0)
+        if self._n_latent:
+            self.stats.bump(
+                "latent_row_bytes", self.family.latent_row_bytes(self._c))
         self._pool = self.cache.init_pool()
         self._draft_pool = None
         if draft_module is not None:
@@ -1480,6 +1491,12 @@ class ServeEngine:
                 **by_kind,
             }
         kv_blocks = {f"decode_kv_blocks_{k}": v for k, v in kv_blocks.items()}
+        if self._n_latent:
+            # Positions the latent decode attends this tick, the current
+            # token's own included, over the latent layers.
+            # rlt: noqa[RLT002] host ints (scheduler.seq_lens), no device value
+            kv_blocks["decode_latent_positions"] = self._n_latent * int(
+                self.scheduler.seq_lens[active].sum() + len(active))
         if sums:
             kv_blocks["moe_tokens_routed"] = len(active) * self.family.n_sparse
         held = [(slot, self.scheduler.slots[slot]) for slot in active]
@@ -1952,12 +1969,11 @@ class ServeEngine:
             self._thread = None
 
     def _refuse_block_transfer(self, what: str) -> None:
-        if self.family.two_kind:
+        if "block_transfer" in self.family.refuses:
             raise ValueError(
                 f"{what} is not supported for the {self.family.name} "
-                f"family: a sequence's state is a block table and a "
-                f"window ring (KV handoff and live migration move one "
-                f"kind of block)"
+                f"family: {self.family.refuses_why} (KV handoff and "
+                f"live migration move GPT's kind of block)"
             )
 
     def export_resident(self) -> List[dict]:

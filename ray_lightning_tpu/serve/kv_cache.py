@@ -937,10 +937,16 @@ class GPTServeFamily:
     own (``models/exaone_moe.py``: window and full layers in one cache
     manager), and the engine asks nothing else of a family:
     ``make_cache``, ``prefill``, ``decode``, ``prepare_params``,
-    ``vocab_size``, ``two_kind``."""
+    ``vocab_size``, ``two_kind`` (ring tables beside the block tables),
+    ``refuses`` / ``refuses_why``."""
 
     name = "gpt"
     two_kind = False     # one block table a slot, one pool a tensor
+    # What the family cannot serve, of ``prefix_cache``, ``spec_k``,
+    # ``draft``, ``adapters``, ``prefill_chunk``, ``block_transfer``,
+    # and why (the engine's refusals name both): nothing.
+    refuses: Tuple[str, ...] = ()
+    refuses_why = ""
 
     # What the programs of this file read through ``.astype(c)``: the
     # matmul weights that pass through ``resolve_weight`` with their

@@ -516,3 +516,106 @@ def test_moe_prefill_fits_at_the_largest_bucket(one_chip):
     text = compiled.as_text()
     assert "%rlt_flash_fwd" in text and "%rlt_moe_gate_up" in text
     assert _footprint(compiled) < 14.75e9
+
+
+# -- the latent share (sarvam-105b-ep8.serve-longctx) -------------------------
+
+# benchmarks/workloads/sarvam-105b-ep8.serve-longctx.json: 64 slots x 7168
+# positions in blocks of 32, the engine's default pool of 65 x 224 + 1
+# blocks of rows of 640 (576 of data); published widths, 16 of 128 experts.
+MLA = dict(W=64, M=224, Bs=32, N=65 * 224 + 1, H=64, r=512, row=640)
+
+
+def test_mla_decode_kernel_compiles_at_serve_cell_shapes(one_chip):
+    """64 heads' absorbed queries against rows of 640, tables of 224
+    blocks a slot by scalar prefetch (57 KB of SMEM)."""
+    from ray_lightning_tpu.ops.paged_attention import mla_decode_attention
+
+    assert "rlt_mla_decode" in _compile(
+        lambda *a: mla_decode_attention(*a, rank=MLA["r"], scale=0.135234),
+        _sds((MLA["W"], MLA["H"], MLA["row"]), jnp.bfloat16, one_chip),
+        _sds((MLA["W"], MLA["row"]), jnp.bfloat16, one_chip),
+        _sds((8, MLA["N"], MLA["Bs"], MLA["row"]), jnp.bfloat16, one_chip),
+        _sds((), jnp.int32, one_chip),
+        _sds((MLA["W"], MLA["M"]), jnp.int32, one_chip),
+        _sds((MLA["W"],), jnp.int32, one_chip),
+    )
+
+
+def _mla_programs(one_chip):
+    from ray_lightning_tpu.models import sarvam_mla as sm
+
+    cfg = sm.SarvamMLAConfig(n_layer=8, experts_held=(0, 16),
+                             vocab_held=(0, 32768))
+    module = sm.SarvamMLA(cfg)
+    fam = module.serve_family()
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda l: _sds(l.shape, l.dtype, one_chip), tree)
+
+    raw = jax.eval_shape(module.init_params, jax.random.PRNGKey(0))
+    params = abstract(jax.eval_shape(
+        lambda tree: fam.prepare_params(tree, jnp.bfloat16), raw))
+    cache = fam.make_cache(MLA["N"], MLA["Bs"], MLA["W"], jnp.bfloat16)
+    return fam, params, abstract(jax.eval_shape(cache.init_pool))
+
+
+@pytest.fixture(scope="module")
+def mla_decode(one_chip):
+    """The family's decode program as the engine jits it (pool donated)."""
+    fam, params, pool = _mla_programs(one_chip)
+    i32 = lambda *shape: _sds(shape, jnp.int32, one_chip)  # noqa: E731
+    return jax.jit(fam.decode, donate_argnums=1).lower(
+        params, pool, i32(MLA["W"], MLA["M"]), i32(MLA["W"]),
+        i32(MLA["W"])).compile()
+
+
+def test_mla_decode_step_names_its_kernels_and_fits(mla_decode):
+    """The three kernels' names are in the program and it leaves the
+    chip (15.75 GiB = 16.91 GB usable) 3.6 GB: 13.26 GB at PR 30, 8.45 of
+    it weights and 4.77 the latent pool."""
+    text = mla_decode.as_text()
+    for kernel in ("%rlt_moe_gate_up", "%rlt_moe_down", "%rlt_mla_decode"):
+        assert kernel in text
+    assert "%rlt_paged_decode" not in text
+    assert 13.0e9 < _footprint(mla_decode) < 13.5e9
+
+
+@pytest.mark.parametrize("opcode", ["copy", "convert", "gather",
+                                    "dynamic-slice"])
+def test_mla_decode_step_moves_no_pool_layer_and_no_weight(mla_decode,
+                                                           opcode):
+    """No operation's result is a pool layer, the pool, every slot's
+    whole table of rows, an up-projection (``W_uk`` / ``W_uv`` are the
+    tree's own leaves, rearranged once at the engine's build) or an
+    expert-stacked weight tensor."""
+    import re
+
+    N, Bs, W, M, row = (MLA[k] for k in ("N", "Bs", "W", "M", "row"))
+    moved = {(N, Bs, row), (8, N, Bs, row), (W, M, Bs, row),
+             (W, M * Bs, row), (64, 128, 512), (64, 512, 128),
+             (512, 16384), (16, 4096, 2048), (16, 2048, 4096),
+             (4096, 2048), (2048, 4096)}
+    rx = re.compile(
+        r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\](\S*) ([\w\-]+)\(", re.M)
+    found = [m.group(0).strip()[:160]
+             for m in rx.finditer(mla_decode.as_text())
+             if m.group(3).startswith(opcode) and "S(1)" not in m.group(2)
+             and tuple(int(x) for x in m.group(1).split(",") if x != "1")
+             in moved]
+    assert not found, found
+
+
+def test_mla_prefill_fits_at_the_largest_bucket(one_chip):
+    """Bucket 6144 (the cell's largest, past YaRN's original 4096)
+    through the flash forward at head width 192 with values of 128:
+    14.93 GB at PR 30, 2 GB of the chip left (the driver's reference
+    check runs beside the engine, not beside this program)."""
+    fam, params, pool = _mla_programs(one_chip)
+    i32 = lambda *shape: _sds(shape, jnp.int32, one_chip)  # noqa: E731
+    compiled = jax.jit(fam.prefill, donate_argnums=1).lower(
+        params, pool, i32(6144), i32(), i32(6144 // MLA["Bs"])).compile()
+    text = compiled.as_text()
+    assert "%rlt_flash_fwd" in text and "%rlt_moe_gate_up" in text
+    assert _footprint(compiled) < 15.05e9

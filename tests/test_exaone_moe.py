@@ -255,28 +255,44 @@ def test_engine_serves_the_family_through_the_client_plane(tiny, overlap):
 
 # -- the share ------------------------------------------------------------------
 
-def test_all_shares_add_up_to_the_uncut_layer(tiny):
-    """The parts all four shares give, the shared expert counted once,
-    are the uncut layer (16 of 16 experts)."""
-    cfg, _, params = tiny
+def _sarvam_share_case():
+    from benchmarks.reference import sarvam_mla_ref
+    from ray_lightning_tpu.models.sarvam_mla import (
+        SarvamMLA, sarvam_mla_tiny,
+    )
+
+    return sarvam_mla_tiny(), SarvamMLA, sarvam_mla_ref, 8
+
+
+@pytest.mark.parametrize("family", ["exaone_moe", "sarvam_mla"])
+def test_all_shares_add_up_to_the_uncut_layer(tiny, family):
+    """The parts all the shares give (four of 4 experts for
+    ``exaone_moe``; eight of 2, under a drawn selection bias, for
+    ``sarvam_mla``), the shared expert counted once, are the uncut layer
+    (16 of 16 experts)."""
+    cfg, Module, ref_mod, shares = (tiny[0], ExaoneMoE, ref, 4) \
+        if family == "exaone_moe" else _sarvam_share_case()
     whole_cfg = dataclasses.replace(cfg, experts_held=(0, 16))
-    module = ExaoneMoE(whole_cfg)
+    module = Module(whole_cfg)
     p = module.init_params(jax.random.PRNGKey(3))["layers"][2]
+    assert (float(jnp.abs(p["router_bias"]).max()) > 0) == (
+        family == "sarvam_mla")
     x = jax.random.normal(jax.random.PRNGKey(4), (24, cfg.d_model))
     whole, counts = em.feed_forward(whole_cfg, "sparse", p, x, None, "xla")
     assert int(counts[0]) == 24 * cfg.top_k
     shared = em.swiglu(x, p, "s_")
     routed = jnp.zeros_like(x)
-    for lo in range(0, 16, 4):
-        part_cfg = dataclasses.replace(cfg, experts_held=(lo, lo + 4))
-        part = dict(p, **{k: p[k][lo:lo + 4]
+    held = 16 // shares
+    for lo in range(0, 16, held):
+        part_cfg = dataclasses.replace(cfg, experts_held=(lo, lo + held))
+        part = dict(p, **{k: p[k][lo:lo + held]
                           for k in ("e_gate", "e_up", "e_down")})
         f, _ = em.feed_forward(part_cfg, "sparse", part, x, None, "xla")
         routed = routed + (f - shared)
     assert float(jnp.abs(routed + shared - whole).max()) < F32_TOL
     # And the uncut layer is the reference's.
-    rcfg = dict(ref.config_of(whole_cfg))
-    want, _ = ref.sparse_ffn(rcfg, p, x, "float32")
+    rcfg = dict(ref_mod.config_of(whole_cfg))
+    want, _ = ref_mod.sparse_ffn(rcfg, p, x, "float32")
     assert float(jnp.abs(whole - want).max()) < F32_TOL
 
 

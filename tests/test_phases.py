@@ -415,7 +415,7 @@ KERNELS = {
     "cross_entropy.py": {"rlt_ce_fwd", "rlt_ce_bwd_dx", "rlt_ce_bwd_dw"},
     "layer_norm.py": {"rlt_ln_fwd", "rlt_ln_bwd"},
     "lora.py": {"rlt_lora_bgmv"},
-    "paged_attention.py": {"rlt_paged_decode"},
+    "paged_attention.py": {"rlt_paged_decode", "rlt_mla_decode"},
     "moe.py": {"rlt_moe_gate_up", "rlt_moe_down"},
 }
 

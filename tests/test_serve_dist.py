@@ -391,7 +391,7 @@ class TestRouterPolicy:
             c = rig.router.counters
             assert c["replica_drains"] == 1
             assert c["replica_deaths"] == 0 and c["failovers"] == 0
-            re_routed = _drain(rig.replicas[survivor][1])
+            re_routed = _drain(rig.replicas[survivor][1], want=len(moved))
             assert sorted(i["rid"] for i in re_routed) == sorted(moved)
             snap = rig.router.snapshot()
             entry = next(r for r in snap["replicas"]
