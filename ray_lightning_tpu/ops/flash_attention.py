@@ -3,31 +3,66 @@
 The hot op of the transformer family, written TPU-first per the Pallas
 playbook (``/opt/skills/guides/pallas_guide.md``):
 
-* grid ``(batch*heads, seq/block_q)`` — one program per query block;
-* K/V live in VMEM per (batch,head) and are walked in ``block_k`` slices
-  with online softmax (running max/denominator in float32 scratch carries)
-  — memory is O(seq · head_dim) instead of the O(seq²) logits tensor;
-* the causal structure bounds the inner loop: query block ``i`` visits only
-  key blocks ``<= i`` (the upper half of the score matrix is never
-  computed, ~2× fewer MXU ops than mask-and-discard);
-* logits/accumulators in float32, inputs/outputs in the caller's dtype
-  (bfloat16 in the mixed-precision recipe).
+* K/V of a head live in VMEM and are walked with online softmax — memory
+  is O(seq · head_dim) instead of the O(seq²) logits tensor, in both
+  directions, at any context length;
+* **score tiles are held transposed, ``(keys, queries)``**: the queries
+  lie along the lanes, so the per-query statistics (running max ``m``,
+  denominator ``l``, ``lse``, ``delta``) are lane-dense ``(1, queries)``
+  rows, a reduction over the keys is an elementwise max / add down the
+  sublanes, and no statistic is ever broadcast across lanes.  In the
+  backward ``dv += p-tile @ dO`` and ``dk += ds-tile @ q`` are plain
+  matmuls of the tile as it lies (the ``(queries, keys)`` form transposes
+  ``p`` and ``ds`` for them);
+* the causal structure bounds the walk twice: key tiles wholly above a
+  query tile are never visited, and the square ON the diagonal is walked
+  in 128-wide strips bounded by the diagonal, so that of a 1024 x 1024
+  square 36 of 64 sub-squares are multiplied and 8 take the mask;
+* a backward program is one head and as many whole key tiles as fit
+  VMEM: ``dq`` then accumulates in float32 inside the program and the
+  partials output is one plane a program (ONE plane, and no sum outside,
+  at the fit cell's shapes);
+* logits, statistics and accumulators in float32, matmul operands in the
+  caller's dtype (bfloat16 in the mixed-precision recipe), probabilities
+  rounded to it where they meet ``v`` / ``dO``; a scale that is a power
+  of two (0.125 at head width 64) is folded into q, exactly;
+* the forward emits ``lse = m + log l`` as ``(BH, S, 8)`` float32;
+  ``delta = rowsum(dO · O)`` is computed in-kernel from the O block.
 
-Backward pass (FlashAttention-2 style, two kernels):
+Block shapes come from what the kernel can observe (``_pick_walk``: the
+sequence, the widths, the dtype, the VMEM the blocks need): no argument,
+field or environment variable chooses them.
 
-* the forward additionally emits the per-row log-sum-exp ``lse = m +
-  log l``, broadcast across a 128-lane minor dim (the TPU-native layout
-  for per-row scalars — same trick as jax.experimental.pallas.ops.tpu);
-* ``delta = rowsum(dO · O)`` is computed in-kernel from the O block (a
-  few VPU ops on resident data — no O(S·lane) HBM round-trip);
-* **dq kernel**: one program per query block, walks key blocks ``<= i``,
-  recomputes ``p = exp(s − lse)`` and accumulates ``ds @ K``;
-* **dk/dv kernel**: one program per key block, walks query blocks
-  ``>= floor(k/block_q)``, accumulating ``pᵀ @ dO`` and ``dsᵀ @ Q``.
+Measured, kernel alone on one TPU v5e, device time a call from a trace,
+q / k / v bf16 ``(128, 1024, 64)`` (the ``gpt2-medium.fit`` cell's; my chip
+runs, PR 31; ``tools/flash_sweep.py``; the whole table is in ``PERF.md``
+section 6):
 
-So the O(S²) logits tensor is never materialized in either direction —
-memory stays O(S·D) at any context length, which is what makes long-
-context (ring/sequence-parallel) training viable.
+====================================================  =======  ========
+walk                                                  forward  backward
+====================================================  =======  ========
+before PR 31, ``(queries, keys)`` tiles, 512 x 512    669.5    1024.1
+  the same, 256 x 512 / 256 x 256                     657.6 /  1128.8 /
+                                                      925.5    1732.4
+transposed, 512 tiles, 128-wide strips as below       484.8    772.8
+transposed, 1024 tiles, 128-row key strips            390.1    738.0
+transposed, 1024 tiles, 128-lane query strips         608.1    719.2
+transposed, 1024 tiles, no strips (whole square)      480.4    not run
+====================================================  =======  ========
+
+(us a call; 87 and 174 us of FLOPs at the chip's peak, causal half.)  The
+forward walks the diagonal square in key strips (a key sub-block against
+the query lanes at or past it: wide tiles, whose reductions down the
+sublanes run as many independent chains as there are lanes), the backward
+in query strips (a query sub-block against the keys at or above it: long
+streams through the same weights for four of its five matmuls).  Four
+heads unrolled in a forward program measured 344.5, but every process
+lowers a kernel's whole text at set-up, compile cache or not, and the two
+serve cells' warm ``setup_s`` rose 14-20% with it; four heads as a loop
+measured 392.9, no better than one: so a program is one head.  At the
+serve cells' prefill shapes the primal forward measured 1500 us against
+1760 (64 heads, 3072, width 128) and 7066 against 7688 (64, 6144, 192 with
+values of 128).
 
 (The reference framework has no analogue — its compute is opaque torch
 modules; this file exists because the TPU build owns its model math.)
@@ -36,16 +71,48 @@ modules; this file exists because the TPU build owns its model math.)
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "DEFAULT_BLOCK_Q", "DEFAULT_BLOCK_K"]
 
-DEFAULT_BLOCK_Q = 512  # tuned on v5e: 512² beats 256² by ~30% fwd+bwd
-DEFAULT_BLOCK_K = 512
+# Tiles of 1024 where they divide the sequence: at the fit cell's shapes the
+# kernels measured 390.1 us forward / 719.2 backward a call against 484.8 /
+# 772.8 with tiles of 512 (my chip runs, PR 31; the table above).
+DEFAULT_BLOCK_Q = 1024
+DEFAULT_BLOCK_K = 1024
+
+_NEG_INF = -1e30
+# Lane quantum: a score tile is (keys, queries), queries along the lanes,
+# so both block sizes are whole multiples of it.
+_LANE = 128
+# Width of one strip of the diagonal square: key rows in the forward,
+# query lanes in the backward (see the kernels).
+_SUB = 128
+# HBM width of the per-row lse stat.  In VMEM the tile is lane-padded
+# anyway, but the HBM array is (BH, S, _STAT_W) — at 128 the saved-
+# residual traffic was ~100 MB/layer of 128x-redundant f32 (the single
+# largest line in the step profile); 8 keeps a legal f32 tile while
+# cutting that 16x.
+_STAT_W = 8
+# VMEM a kernel may take, and what of it a program's blocks and scratch
+# may take by ``_tile_bytes``' count (the rest is for the score tiles'
+# temporaries).  The compiler's scoped default of 16 MiB is 0.5 MiB short
+# of a 1024 x 1024 tile beside sarvam's K and V of 6144 x 192; XLA plans
+# its own use of VMEM round the largest limit a program's kernels ask
+# for, and the fit cell's step grows in HBM with it (13.78 GB at 16 MiB,
+# 13.81 at 32, 13.95 at 64: sandbox compiles, PR 31), so no more than
+# the walks need.
+_VMEM_LIMIT = 32 * 2**20
+_VMEM_BLOCKS = 16 * 2**20
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
 
 
 def pick_block(seq_len: int, prefer: int = DEFAULT_BLOCK_Q) -> Optional[int]:
@@ -60,71 +127,163 @@ def pick_block(seq_len: int, prefer: int = DEFAULT_BLOCK_Q) -> Optional[int]:
             return block
         block //= 2
     return None
-_NEG_INF = -1e30
-# Lane quantum for block_k (per-row stats are broadcast across lanes in
-# VMEM, and the backward tiles them in block_k-wide sweeps).
-_LANE = 128
-# HBM width of the per-row lse stat.  In VMEM the tile is lane-padded
-# anyway, but the HBM array is (BH, S, _STAT_W) — at 128 the saved-
-# residual traffic was ~100 MB/layer of 128x-redundant f32 (the single
-# largest line in the step profile); 8 keeps a legal f32 tile while
-# cutting that 16x.
-_STAT_W = 8
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, scale, block_q,
-                block_k, head_dim):
-    # MXU discipline: dot inputs stay in the CALLER's dtype (bf16 in the
-    # mixed-precision recipe — f32 inputs would run the MXU at a fraction
-    # of peak); accumulation is always f32 via preferred_element_type, and
-    # the softmax statistics never leave f32.  ``scale`` is folded into
-    # the f32 scores, not pre-multiplied into q (no bf16 rounding of q).
-    q = q_ref[0]  # (block_q, d)
-    qi = pl.program_id(1)
-    q_base = qi * block_q
+class _Walk(NamedTuple):
+    """The tile walk of one call, chosen from its shapes alone."""
+    block_q: int    # forward: the query tile of a program
+    block_k: int    # backward: a key tile
+    step: int       # the other side's step below the diagonal square:
+    #                 keys in the forward, queries in the backward
+    sub: int        # width of a strip of the diagonal square
+    span: int       # backward: keys to a program (a multiple of block_k)
+    fold: bool      # scale is a power of two: folded into q, exactly
 
-    def make_body(masked):
-        def body(kb, carry):
-            acc, m, l = carry
-            k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :]
-            v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :]
-            s = jax.lax.dot_general(
-                q, k_blk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # (block_q, block_k) f32
-            if masked:
-                q_pos = q_base + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0
-                )
-                k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1
-                )
-                s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m - m_new)
-            l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
-            acc_new = acc * corr + jax.lax.dot_general(
-                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            return acc_new, m_new, l_new
-        return body
 
-    # Causal structure: key blocks entirely below the diagonal need no
-    # mask (saves the iota/compare/where VPU passes on ~all blocks); only
-    # blocks straddling the diagonal mask.  Last visible block index:
-    # cdiv(q_base + block_q, block_k).
-    num_full = q_base // block_k            # fully-visible blocks
-    num_kb = pl.cdiv(q_base + block_q, block_k)
-    acc0 = jnp.zeros((block_q, head_dim), jnp.float32)
-    m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    carry = jax.lax.fori_loop(0, num_full, make_body(False), (acc0, m0, l0))
-    acc, m, l = jax.lax.fori_loop(num_full, num_kb, make_body(True), carry)
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    if lse_ref is not None:
-        lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), (block_q, _STAT_W))
+def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a (rows, cols) block: lanes padded to 128, sublanes
+    to a whole packed tile."""
+    sublanes = 8 * (4 // itemsize)
+    return (-(-rows // sublanes) * sublanes) * (-(-cols // _LANE) * _LANE) \
+        * itemsize
+
+
+def _pick_walk(s: int, d: int, itemsize: int, scale: float,
+               block_q: Optional[int], block_k: Optional[int]) -> _Walk:
+    """Block shapes from what the kernel can observe: the sequence, the
+    q / k width, the dtype, and the VMEM the backward's blocks need (the
+    forward's are K and V of one head and a query tile: whatever fits the
+    backward fits it, and serving shapes are compiled by
+    ``tests/test_chip_compile.py``)."""
+    if block_q is None:
+        block_q = pick_block(s) or min(DEFAULT_BLOCK_Q, s)
+    if block_k is None:
+        block_k = pick_block(s, DEFAULT_BLOCK_K) or min(DEFAULT_BLOCK_K, s)
+    if s % block_q or s % block_k:
+        raise ValueError(
+            f"seq_len {s} must be divisible by block_q={block_q} and "
+            f"block_k={block_k}"
+        )
+    if block_k % _LANE or block_q % _LANE:
+        raise ValueError(
+            f"block_q={block_q} and block_k={block_k} must each be a "
+            f"multiple of {_LANE} (lane quantum of the score tiles)"
+        )
+    # Each side's step divides the other's tile, so that a diagonal square
+    # is whole steps.
+    step = math.gcd(block_q, block_k)
+    # Backward: q, dO, O, lse and the dq plane of a head stay resident
+    # (double buffered) beside the float32 dq scratch; a program takes as
+    # many whole key tiles (k, v, dk, dv) as fit, four at most (the walk
+    # over them is unrolled).
+    whole = 2 * (4 * _tile_bytes(s, d, itemsize)
+                 + _tile_bytes(s, _STAT_W, 4)) + _tile_bytes(d, s, 4)
+    span = block_k
+    for n in (4, 3, 2):
+        if (s % (n * block_k) == 0 and whole + 8 * _tile_bytes(
+                n * block_k, d, itemsize) <= _VMEM_BLOCKS):
+            span = n * block_k
+            break
+    fold = math.frexp(scale)[0] == 0.5
+    return _Walk(block_q, block_k, step, min(_SUB, step), span, fold)
+
+
+def _diag_bias(sub: int) -> jax.Array:
+    """(keys, queries) additive mask of a diagonal sub-square."""
+    key = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+    qry = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+    return jnp.where(qry >= key, 0.0, _NEG_INF).astype(jnp.float32)
+
+
+def _masked(s, bias, axis):
+    """Add the diagonal square's mask to a strip of scores ``(keys,
+    queries)``: to its first query lanes where the strip is a key
+    sub-block with the lanes to its right (``axis`` 1), to its last key
+    rows where it is a query sub-block with the keys above it (0)."""
+    sub, size = bias.shape[0], s.shape[axis]
+    if size == sub:
+        return s + bias
+    cut = sub if axis == 1 else size - sub
+    a = jax.lax.slice_in_dim(s, 0, cut, axis=axis)
+    b = jax.lax.slice_in_dim(s, cut, size, axis=axis)
+    return jax.lax.concatenate(
+        [a + bias, b] if axis == 1 else [a, b + bias], axis)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, walk, want_lse):
+    """One program: one head x one query tile.
+
+    A score tile is held TRANSPOSED, ``(keys, queries)``: the queries lie
+    along the lanes, so the running max ``m``, the denominator ``l`` and
+    their corrections are ``(1, block_q)`` lane-dense rows, every
+    reduction over the keys is an elementwise max / add down the
+    sublanes, and the accumulator ``(dv, block_q)`` fills its lanes at
+    any value width.  The tile is transposed back once, at the store.
+
+    Keys wholly below the query tile are walked in ``block_k`` rows,
+    unmasked.  The diagonal square is walked in key sub-blocks of
+    ``sub`` rows, each against only the query lanes at or past it: what
+    lies wholly above the diagonal is never multiplied, and only the
+    ``sub x sub`` square on the diagonal takes the mask.
+
+    MXU discipline: dot inputs stay in the CALLER's dtype (bf16 in the
+    mixed-precision recipe); accumulation is f32 via
+    preferred_element_type, and the statistics never leave f32.  A scale
+    that is a power of two is folded into q (exact in any float dtype);
+    any other multiplies the f32 scores.
+    """
+    if want_lse:
+        lse_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        m_ref, l_ref, acc_ref = rest
+    block_q, block_k, sub = walk.block_q, walk.step, walk.sub
+    q_base = pl.multiple_of(pl.program_id(1) * block_q, block_q)
+    bias = _diag_bias(sub)
+
+    q = q_ref[0]                                      # (block_q, d)
+    if walk.fold:
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def visit(start, rows, lo, n, masked):
+        """Keys [start, start + rows) against query lanes [lo, lo + n)."""
+        k_blk = k_ref[0, pl.ds(start, rows), :]
+        v_blk = v_ref[0, pl.ds(start, rows), :]
+        s = jax.lax.dot_general(
+            k_blk, jax.lax.slice_in_dim(q, lo, lo + n), _NT,
+            preferred_element_type=jnp.float32)
+        if not walk.fold:
+            s = s * scale
+        if masked:
+            s = _masked(s, bias, 1)
+        m_prev = m_ref[:, lo:lo + n]
+        m_new = jax.lax.max(m_prev, jnp.max(s, axis=0, keepdims=True))
+        p = jax.lax.exp(s - m_new)
+        corr = jax.lax.exp(m_prev - m_new)
+        l_ref[:, lo:lo + n] = l_ref[:, lo:lo + n] * corr + jnp.sum(
+            p, axis=0, keepdims=True)
+        acc_ref[:, lo:lo + n] = (
+            acc_ref[:, lo:lo + n] * corr + jax.lax.dot_general(
+                v_blk, p.astype(v_blk.dtype), _TN,
+                preferred_element_type=jnp.float32))      # (dv, n)
+        m_ref[:, lo:lo + n] = m_new
+
+    def below(kb, carry):
+        visit(pl.multiple_of(kb * block_k, block_k), block_k, 0, block_q,
+              False)
+        return carry
+
+    jax.lax.fori_loop(0, q_base // block_k, below, 0)
+    for c in range(block_q // sub):
+        visit(q_base + c * sub, sub, c * sub, block_q - c * sub, True)
+
+    l = l_ref[...]
+    o_ref[0] = (acc_ref[...] / l).T.astype(o_ref.dtype)
+    if want_lse:
+        lse_ref[0] = jnp.broadcast_to(
+            m_ref[...] + jnp.log(l), (_STAT_W, block_q)).T
 
 
 def _interpret() -> bool:
@@ -133,8 +292,14 @@ def _interpret() -> bool:
     return shared()
 
 
-def _flash_fwd_bhsd(q, k, v, scale, block_q, block_k, want_lse=True):
+@functools.partial(  # rlt: noqa[RLT008] traced into the caller's program, never an executable of its own
+    jax.jit, static_argnames=("scale", "walk", "want_lse"))
+def _flash_fwd_bhsd(q, k, v, scale, walk, want_lse=True):
     """q/k/v: (BH, S, D) merged batch-heads layout -> (out, lse|None).
+
+    Jitted so that the layers of an unrolled model share ONE lowered
+    kernel (a model of 8 layers lowered 8 kernels a program, seconds of
+    every process's set-up whether or not its compile cache is warm).
 
     ``want_lse=False`` (the primal, non-differentiated path — eval/
     predict) compiles a forward-only kernel with a single output, so no
@@ -142,27 +307,30 @@ def _flash_fwd_bhsd(q, k, v, scale, block_q, block_k, want_lse=True):
     """
     bh, s, d = q.shape
     dv = v.shape[-1]    # the values' own width (the accumulator's)
-    grid = (bh, s // block_q)
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
-        head_dim=dv,
-    )
+    block_q = walk.block_q
     out_shape = jax.ShapeDtypeStruct((bh, s, dv), q.dtype)
     out_spec = pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0))
     lse_spec = pl.BlockSpec((1, block_q, _STAT_W), lambda b, i: (b, i, 0))
     result = pl.pallas_call(
-        kernel,
+        functools.partial(
+            _fwd_kernel, scale=scale, walk=walk, want_lse=want_lse),
         out_shape=(
             out_shape,
             jax.ShapeDtypeStruct((bh, s, _STAT_W), jnp.float32),
         ) if want_lse else out_shape,
-        grid=grid,
+        grid=(bh, s // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, s, dv), lambda b, i: (b, 0, 0)),
         ],
         out_specs=(out_spec, lse_spec) if want_lse else out_spec,
+        scratch_shapes=[
+            pltpu.VMEM((1, block_q), jnp.float32),    # m
+            pltpu.VMEM((1, block_q), jnp.float32),    # l
+            pltpu.VMEM((dv, block_q), jnp.float32),   # out, transposed
+        ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
         name="rlt_flash_fwd",
     )(q, k, v)
@@ -170,157 +338,188 @@ def _flash_fwd_bhsd(q, k, v, scale, block_q, block_k, want_lse=True):
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, dk_ref,
-                dv_ref, dqp_ref, *, scale, block_q, block_k, head_dim,
-                seq_len):
-    """One program per KEY block: dk/dv accumulate in registers across the
-    query-block walk, and dq contributions are written as a per-key-block
-    PARTIAL plane (summed by one cheap XLA reduction afterwards).
+                dv_ref, dqp_ref, stat_ref, dq_ref, dk_acc, dv_acc, kt_ref,
+                *, scale, walk, seq_len):
+    """One program: one head x ``walk.span`` keys (whole key tiles).
 
-    Fusing dq into the dk/dv walk shares the s/p/dp/ds recomputation both
-    would otherwise do independently — 5 MXU dots per block pair instead
-    of 7 across two kernels.
+    Score tiles are held transposed, ``(keys, queries)``, as in the
+    forward: ``lse`` and ``delta = rowsum(dO · O)`` are transposed once a
+    program into lane-dense rows; ``dv += p^T-tile @ dO`` and ``dk +=
+    ds^T-tile @ q`` are plain matmuls of the tile as it lies (no
+    transposed copy of the probabilities), and ``dq`` accumulates
+    transposed, ``(d, queries)`` in float32 across the program's key
+    tiles, and is transposed back once.  One recomputation of s / p / dp
+    / ds serves all three gradients: 5 MXU dots a tile pair.
+
+    Each key tile walks its diagonal square in query strips of ``sub``
+    lanes, each against only the tile's keys at or above it (the mask on
+    the strip's last ``sub`` rows), then the query tiles wholly below it,
+    unmasked.  The program's dq is ONE plane of the partials output: the
+    planes of a head's programs are summed outside (one plane, no sum,
+    where a head is one program).  ``kt_ref`` holds the key tile
+    transposed for the dq matmul: the TPU compiler refuses an operand
+    that one matmul takes as it lies and another transposed.
     """
-    ki = pl.program_id(1)
-    k_base = ki * block_k
-    k = k_ref[0]                                      # (block_k, d)
-    v = v_ref[0]
-    # Query blocks before the causal frontier contribute nothing — zero
-    # exactly those rows (the walk below rewrites everything from the
-    # frontier on; zeroing the whole plane would double-write ~half of it
-    # on this bandwidth-sensitive path).
-    zero_blk = jnp.zeros((block_q, head_dim), dqp_ref.dtype)
+    block_q, block_k, sub = walk.step, walk.block_k, walk.sub
+    span, fold = walk.span, walk.fold
+    d = q_ref.shape[-1]
+    k_base = pl.multiple_of(pl.program_id(1) * span, span)
+    n_q = seq_len // block_q
+    first_q = k_base // block_q
+    bias = _diag_bias(sub)
 
-    def _zero_dead(qb, _):
-        dqp_ref[0, 0, pl.ds(qb * block_q, block_q), :] = zero_blk
-        return 0
+    def rows_of(i):
+        return pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
 
-    jax.lax.fori_loop(0, k_base // block_q, _zero_dead, 0)
+    def prepare(i, carry):
+        # lse and delta of query tile i as (1, block_q) rows; delta in-
+        # kernel: a few VPU ops on resident data instead of an
+        # O(S·lane) f32 HBM round-trip per layer.
+        rows = rows_of(i)
+        delta = jnp.sum(
+            do_ref[0, rows, :].astype(jnp.float32)
+            * o_ref[0, rows, :].astype(jnp.float32), axis=1, keepdims=True)
+        stat_ref[i, 0:1, :] = lse_ref[0, rows, :].T[:1]
+        stat_ref[i, 1:2, :] = jnp.broadcast_to(
+            delta, (block_q, _STAT_W)).T[:1]
+        dq_ref[i] = jnp.zeros((d, block_q), jnp.float32)
+        return carry
 
-    def make_body(masked):
-        def body(qb, carry):
-            dk_acc, dv_acc = carry
-            q_blk = q_ref[0, pl.ds(qb * block_q, block_q), :]
-            do_blk = do_ref[0, pl.ds(qb * block_q, block_q), :]
-            lse = jnp.broadcast_to(
-                lse_ref[0, pl.ds(qb * block_q, block_q), :1],
-                (block_q, block_k),
-            )
-            o_blk = o_ref[0, pl.ds(qb * block_q, block_q), :]
-            # delta = rowsum(dO · O) in-kernel: a few VPU ops on resident
-            # data instead of an O(S·lane) f32 HBM round-trip per layer.
-            delta = jnp.sum(
-                do_blk.astype(jnp.float32) * o_blk.astype(jnp.float32),
-                axis=1, keepdims=True,
-            )
-            di = jnp.broadcast_to(delta, (block_q, block_k))
-            s = jax.lax.dot_general(
-                q_blk, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                                 # (block_q, block_k)
-            if masked:
-                q_pos = qb * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0
-                )
-                k_pos = k_base + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1
-                )
-                s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-            p = jnp.exp(s - lse)
-            dv_new = dv_acc + jax.lax.dot_general(
-                p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )                                         # (block_k, d)
-            dp = jax.lax.dot_general(
-                do_blk, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            # scale folded into ds: dk = (ds*scale)^T @ Q, dq = (ds*scale) @ K.
-            ds = (p * (dp - di) * scale).astype(q_blk.dtype)
-            dk_new = dk_acc + jax.lax.dot_general(
-                ds, q_blk, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            dq_part = jax.lax.dot_general(
-                ds, k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )                                         # (block_q, d)
-            dqp_ref[0, 0, pl.ds(qb * block_q, block_q), :] = (
-                dq_part.astype(dqp_ref.dtype)
-            )
-            return dk_new, dv_new
-        return body
+    jax.lax.fori_loop(first_q, n_q, prepare, 0)
 
-    # Causal bound from below: query blocks before this key block see
-    # nothing here; blocks straddling the diagonal mask, later blocks see
-    # the whole key block and skip the mask.
-    qb_start = k_base // block_q
-    qb_mask_end = pl.cdiv(k_base + block_k, block_q)
-    zeros = jnp.zeros((block_k, head_dim), jnp.float32)
-    carry = jax.lax.fori_loop(
-        qb_start, qb_mask_end, make_body(True), (zeros, zeros)
-    )
-    dk, dv = jax.lax.fori_loop(
-        qb_mask_end, seq_len // block_q, make_body(False), carry
-    )
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    def load_q(i):
+        q = q_ref[0, rows_of(i), :]
+        if fold:
+            q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+        return q
+
+    def pair(k_c, v_c, i, q_t, do_t, lo, n, masked):
+        """The tile's first key rows (those of ``k_c``) against lanes
+        [lo, lo + n) of query tile ``i``."""
+        rows = k_c.shape[0]
+        q_sl = jax.lax.slice_in_dim(q_t, lo, lo + n)
+        do_sl = jax.lax.slice_in_dim(do_t, lo, lo + n)
+        s = jax.lax.dot_general(
+            k_c, q_sl, _NT, preferred_element_type=jnp.float32)
+        if not fold:
+            s = s * scale
+        if masked:
+            s = _masked(s, bias, 0)
+        p = jax.lax.exp(s - stat_ref[i, 0:1, lo:lo + n])  # (rows, n)
+        dv_acc[:rows] += jnp.dot(
+            p.astype(do_sl.dtype), do_sl,
+            preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(
+            v_c, do_sl, _NT, preferred_element_type=jnp.float32)
+        ds = p * (dp - stat_ref[i, 1:2, lo:lo + n])
+        if not fold:
+            ds = ds * scale
+        ds = ds.astype(q_sl.dtype)
+        # Folded: q_sl carries the scale, so dk is exact as it stands and
+        # dq takes its scale in float32 at the store.
+        dk_acc[:rows] += jnp.dot(
+            ds, q_sl, preferred_element_type=jnp.float32)
+        dq_ref[i, :, lo:lo + n] += jnp.dot(
+            kt_ref[:, :rows], ds, preferred_element_type=jnp.float32)
+
+    for jj in range(span // block_k):
+        k_t = k_ref[0, jj * block_k:(jj + 1) * block_k, :]
+        v_t = v_ref[0, jj * block_k:(jj + 1) * block_k, :]
+        kt_ref[...] = k_t.T
+        dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+        tile_q = (k_base + jj * block_k) // block_q   # first query tile
+        for t in range(block_k // block_q):
+            i = tile_q + t
+            q_t, do_t = load_q(i), do_ref[0, rows_of(i), :]
+            for c in range(block_q // sub):
+                rows = t * block_q + (c + 1) * sub    # keys the strip sees
+                pair(jax.lax.slice_in_dim(k_t, 0, rows),
+                     jax.lax.slice_in_dim(v_t, 0, rows), i, q_t, do_t,
+                     c * sub, sub, True)
+
+        def below(i, carry, k_t=k_t, v_t=v_t):
+            pair(k_t, v_t, i, load_q(i), do_ref[0, rows_of(i), :], 0,
+                 block_q, False)
+            return carry
+
+        jax.lax.fori_loop(tile_q + block_k // block_q, n_q, below, 0)
+        dk_ref[0, jj * block_k:(jj + 1) * block_k, :] = dk_acc[...].astype(
+            dk_ref.dtype)
+        dv_ref[0, jj * block_k:(jj + 1) * block_k, :] = dv_acc[...].astype(
+            dv_ref.dtype)
+
+    # Query tiles before the causal frontier take nothing from these keys.
+    def dead(i, carry):
+        dqp_ref[0, 0, rows_of(i), :] = jnp.zeros((block_q, d), dqp_ref.dtype)
+        return carry
+
+    def store(i, carry):
+        dq = dq_ref[i] * scale if fold else dq_ref[i]
+        dqp_ref[0, 0, rows_of(i), :] = dq.T.astype(dqp_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, first_q, dead, 0)
+    jax.lax.fori_loop(first_q, n_q, store, 0)
 
 
-def _flash_bwd_bhsd(q, k, v, out, lse, g, scale, block_q, block_k):
-    """Backward over (BH, S, D) tensors; returns (dq, dk, dv)."""
+@functools.partial(  # rlt: noqa[RLT008] traced into the caller's program, as the forward
+    jax.jit, static_argnames=("scale", "walk"))
+def _flash_bwd_bhsd(q, k, v, out, lse, g, scale, walk):
+    """Backward over (BH, S, D) tensors; returns (dq, dk, dv).  Jitted
+    for the same reason as the forward."""
     bh, s, d = q.shape
-    nkb = s // block_k
+    step, span = walk.step, walk.span
+    n = s // span
+    whole = lambda width: pl.BlockSpec((1, s, width), lambda b, i: (b, 0, 0))
+    keys = pl.BlockSpec((1, span, d), lambda b, i: (b, i, 0))
     dk, dv, dqp = pl.pallas_call(
-        functools.partial(
-            _bwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
-            head_dim=d, seq_len=s,
-        ),
+        functools.partial(_bwd_kernel, scale=scale, walk=walk, seq_len=s),
         out_shape=(
             jax.ShapeDtypeStruct((bh, s, d), k.dtype),
             jax.ShapeDtypeStruct((bh, s, d), v.dtype),
-            # dq partials per key block, in the input dtype: each partial
-            # is one f32-accumulated dot rounded once (same rounding the
-            # two-kernel design paid), and the few-term cross-block sum
-            # below runs in f32 — while the partial plane's HBM round-trip
-            # is half the width.
-            jax.ShapeDtypeStruct((bh, nkb, s, d), q.dtype),
+            # dq partials, a plane a program, in the input dtype: a plane
+            # is a float32 accumulation over its program's keys rounded
+            # once, and the cross-program sum below runs in f32.
+            jax.ShapeDtypeStruct((bh, n, s, d), q.dtype),
         ),
-        grid=(bh, nkb),
-        in_specs=[
-            pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s, _STAT_W), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
-        ],
+        grid=(bh, n),
+        in_specs=[whole(d), keys, keys, whole(d), whole(_STAT_W), whole(d)],
         out_specs=(
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
+            keys, keys,
             pl.BlockSpec((1, 1, s, d), lambda b, i: (b, i, 0, 0)),
         ),
+        scratch_shapes=[
+            pltpu.VMEM((s // step, 2, step), jnp.float32),  # lse, delta
+            pltpu.VMEM((s // step, d, step), jnp.float32),  # dq, transposed
+            pltpu.VMEM((walk.block_k, d), jnp.float32),     # dk of a tile
+            pltpu.VMEM((walk.block_k, d), jnp.float32),     # dv of a tile
+            pltpu.VMEM((d, walk.block_k), k.dtype),         # key tile^T
+        ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
         name="rlt_flash_bwd",
     )(q, k, v, g, lse, out)
+    if n == 1:
+        return dqp[:, 0], dk, dv
     dq = jnp.sum(dqp.astype(jnp.float32), axis=1).astype(q.dtype)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _flash(scale, block_q, block_k, q, k, v):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _flash(scale, walk, q, k, v):
     b, s, h, _ = q.shape
 
     def to_bhsd(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, s, x.shape[-1])
 
     out, _ = _flash_fwd_bhsd(
-        to_bhsd(q), to_bhsd(k), to_bhsd(v), scale, block_q, block_k,
-        want_lse=False,
+        to_bhsd(q), to_bhsd(k), to_bhsd(v), scale, walk, want_lse=False,
     )
     return out.reshape(b, h, s, v.shape[-1]).transpose(0, 2, 1, 3)
 
 
-def _flash_vjp_fwd(scale, block_q, block_k, q, k, v):
+def _flash_vjp_fwd(scale, walk, q, k, v):
     b, s, h, d = q.shape
     if v.shape[-1] != d:
         raise NotImplementedError(
@@ -340,7 +539,7 @@ def _flash_vjp_fwd(scale, block_q, block_k, q, k, v):
     qm = checkpoint_name(to_bhsd(q), "flash_q")
     km = checkpoint_name(to_bhsd(k), "flash_k")
     vm = checkpoint_name(to_bhsd(v), "flash_v")
-    out, lse = _flash_fwd_bhsd(qm, km, vm, scale, block_q, block_k)
+    out, lse = _flash_fwd_bhsd(qm, km, vm, scale, walk)
     out = checkpoint_name(out, "flash_out")
     lse = checkpoint_name(lse, "flash_lse")
     return (
@@ -349,12 +548,10 @@ def _flash_vjp_fwd(scale, block_q, block_k, q, k, v):
     )
 
 
-def _flash_vjp_bwd(scale, block_q, block_k, residuals, g):
+def _flash_vjp_bwd(scale, walk, residuals, g):
     qm, km, vm, out, lse, (b, s, h, d) = residuals
     gm = g.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    dq, dk, dv = _flash_bwd_bhsd(
-        qm, km, vm, out, lse, gm, scale, block_q, block_k
-    )
+    dq, dk, dv = _flash_bwd_bhsd(qm, km, vm, out, lse, gm, scale, walk)
 
     def from_bhsd(x):
         return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
@@ -377,20 +574,7 @@ def flash_attention(
     forward takes values of another width than the queries' and keys'
     (``v (B, S, H, Dv)`` -> ``(B, S, H, Dv)``: latent attention's 128
     beside 192); the backward does not."""
-    _, s, _, d = q.shape
-    scale = (d ** -0.5) if scale is None else scale
-    if block_q is None:
-        block_q = pick_block(s) or min(DEFAULT_BLOCK_Q, s)
-    if block_k is None:
-        block_k = pick_block(s, DEFAULT_BLOCK_K) or min(DEFAULT_BLOCK_K, s)
-    if s % block_q or s % block_k:
-        raise ValueError(
-            f"seq_len {s} must be divisible by block_q={block_q} and "
-            f"block_k={block_k}"
-        )
-    if block_k % _LANE:
-        raise ValueError(
-            f"block_k={block_k} must be a multiple of {_LANE} (lane "
-            f"quantum of the blocked score sweeps)"
-        )
-    return _flash(scale, block_q, block_k, q, k, v)
+    b, s, h, d = q.shape
+    scale = float((d ** -0.5) if scale is None else scale)
+    walk = _pick_walk(s, d, q.dtype.itemsize, scale, block_q, block_k)
+    return _flash(scale, walk, q, k, v)

@@ -221,17 +221,15 @@ def test_sharded_ce_island_compiles_under_data4_mesh(data4):
 
 # -- the whole single-chip train step ---------------------------------------
 
-@pytest.fixture(scope="module")
-def small_step(one_chip):
-    """``Trainer.fit``'s single-device step program for GPT-2-small
-    (bf16, remat, B=16), compiled once for the file."""
+def _train_step(one_chip, cfg, batch_size):
+    """``Trainer.fit``'s single-device step program (bf16, remat) for a
+    configuration and batch, compiled for the described chip."""
     from types import SimpleNamespace
 
     from ray_lightning_tpu.core.module import TrainState
-    from ray_lightning_tpu.models import GPT, GPTConfig
+    from ray_lightning_tpu.models import GPT
     from ray_lightning_tpu.parallel.step_fns import _single_device_raw_step
 
-    cfg = GPTConfig.gpt2_small()
     module = GPT(cfg, attn_impl="auto", remat=True)
     module.precision = "bf16"
     module.trainer = SimpleNamespace(mesh=None, step_mode="gspmd")
@@ -245,11 +243,20 @@ def small_step(one_chip):
     state = jax.tree_util.tree_map(
         lambda l: _sds(l.shape, l.dtype, one_chip), abstract
     )
-    batch = {"tokens": _sds((B, cfg.seq_len + 1), jnp.int32, one_chip)}
+    batch = {"tokens": _sds((batch_size, cfg.seq_len + 1), jnp.int32,
+                            one_chip)}
     rng = _sds((2,), jnp.uint32, one_chip)
     return jax.jit(
         _single_device_raw_step(module, tx), donate_argnums=0
     ).lower(state, batch, rng).compile()
+
+
+@pytest.fixture(scope="module")
+def small_step(one_chip):
+    """GPT-2-small at B=16, compiled once for the file."""
+    from ray_lightning_tpu.models import GPTConfig
+
+    return _train_step(one_chip, GPTConfig.gpt2_small(), B)
 
 
 def test_gpt2_small_train_step_compiles_and_fits(small_step):
@@ -282,6 +289,80 @@ def test_step_program_names_its_kernels(small_step, kernel):
              if "tpu_custom_call" in line]
     rx = re.compile(rf"^(ROOT )?%\w*{kernel}[_.\d]*$")
     assert any(rx.match(h) for h in heads), (kernel, sorted(set(heads)))
+
+
+# -- the fit cell's step: what the benchmark's flash metrics read -----------
+
+def _bench_file(rel):
+    import json
+
+    root = os.path.join(os.path.dirname(__file__), os.pardir, "benchmarks")
+    with open(os.path.join(root, rel)) as fh:
+        return json.load(fh)
+
+
+@pytest.fixture(scope="module")
+def fit_step(one_chip):
+    """The ``gpt2-medium.fit`` cell's step program (its configuration and
+    batch read from the benchmark's own files), compiled once for the
+    file, and the shapes its flash calls have."""
+    from ray_lightning_tpu.models import GPTConfig
+
+    cfg = GPTConfig(**_bench_file("configs/gpt2-medium.json")["fields"])
+    batch_size = _bench_file("traffic/fit.json")["batch_size"]
+    return _train_step(one_chip, cfg, batch_size), dict(
+        bh=batch_size * cfg.n_head, seq=cfg.seq_len,
+        head_dim=cfg.d_model // cfg.n_head)
+
+
+@pytest.mark.parametrize("metric,kernel", [
+    ("flash_fwd_roofline.train", "rlt_flash_fwd"),
+    ("flash_bwd_roofline.train", "rlt_flash_bwd"),
+])
+def test_fit_step_flash_calls_match_the_benchmarks_patterns(
+        fit_step, metric, kernel):
+    """``benchmarks/readers/attention_roofline.py`` finds the flash
+    kernels by their RESULT TUPLE (the pattern in the metric's own file)
+    and divides one call's cost by the mean time of the matches: the
+    layers' scanned body must hold exactly one custom call that matches,
+    and it must be the kernel the metric is named for."""
+    import re
+
+    compiled, shapes = fit_step
+    pattern = _bench_file(f"layer_metrics/{metric}.json")["args"]["pattern"]
+    rx = re.compile(pattern.format(**shapes))
+    calls = [line.strip() for line in compiled.as_text().splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    hits = [c for c in calls if rx.search(c.removeprefix("ROOT "))]
+    assert len(hits) == 1, (metric, [c[:160] for c in calls])
+    assert kernel in hits[0].split(" = ")[0]
+    named = [c for c in calls if kernel in c.split(" = ")[0]]
+    assert named == hits, "a call of the kernel the pattern does not match"
+
+
+def test_fit_step_fits_where_the_parent_did(fit_step):
+    """The cell's step stood at 13.83 GB of 15.75 before PR 31; the walk
+    may not cost it memory (the HBM lse stays 8 lanes wide)."""
+    from ray_lightning_tpu.ops.flash_attention import _STAT_W
+
+    assert _STAT_W == 8
+    assert _footprint(fit_step[0]) <= 13.84e9
+
+
+@pytest.mark.parametrize("heads,seq,width,value_width,scale", [
+    (64, 3072, 128, 128, None),      # K-EXAONE's full-attention layers
+    (64, 6144, 192, 128, 0.1),       # sarvam's: q/k 192, values 128
+], ids=["k-exaone-3072", "sarvam-6144"])
+def test_flash_forward_compiles_at_serve_prefill_shapes(
+        one_chip, heads, seq, width, value_width, scale):
+    """The primal forward at the two prefills' largest buckets: K and V of
+    a head resident, inside VMEM."""
+    from ray_lightning_tpu.ops.flash_attention import flash_attention
+
+    q = _sds((1, seq, heads, width), jnp.bfloat16, one_chip)
+    v = _sds((1, seq, heads, value_width), jnp.bfloat16, one_chip)
+    text = _compile(lambda q, k, v: flash_attention(q, k, v, scale), q, q, v)
+    assert "%rlt_flash_fwd" in text
 
 
 # -- the serve cell's decode program ----------------------------------------
