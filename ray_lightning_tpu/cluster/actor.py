@@ -468,6 +468,7 @@ class ProcessActor:
         self._pending: Dict[int, Future] = {}
         self._lock = threading.Lock()
         self._closed = False
+        self._exit_sent = False
         self._conn_dead = False
         self._recv_thread = threading.Thread(
             target=self._receive_loop, name=f"{self.name}-recv", daemon=True
@@ -613,17 +614,28 @@ class ProcessActor:
             and self._proc.poll() is None
         )
 
+    def request_exit(self) -> None:
+        """Ask the child to exit and do not wait: :meth:`kill` waits.
+        Whoever kills a set of actors asks every one first.  Workers of
+        one ``jax.distributed`` job leave through a barrier at exit, so
+        one asked alone waits out its whole grace for the others and is
+        then terminated (5 s a teardown, PR 32)."""
+        if self._exit_sent:
+            return
+        self._exit_sent = True
+        try:
+            with self._send_lock:
+                rpc.send_frame(self._conn, rpc.dumps(("exit",)))
+        except (OSError, ValueError):
+            pass
+
     def kill(self, timeout: float = 5.0) -> None:
         """Tear down the actor (≙ ``ray.kill(w, no_restart=True)``,
         reference ``ray_ddp.py:398-400``)."""
         if self._closed:
             return
         self._closed = True
-        try:
-            with self._send_lock:
-                rpc.send_frame(self._conn, rpc.dumps(("exit",)))
-        except (OSError, ValueError):
-            pass
+        self.request_exit()
         deadline = time.monotonic() + timeout
         while self._proc.poll() is None and time.monotonic() < deadline:
             time.sleep(0.05)

@@ -177,6 +177,8 @@ class LocalBackend(ClusterBackend):
 
     def shutdown(self) -> None:
         for a in self._actors:
+            a.request_exit()
+        for a in self._actors:
             try:
                 a.kill()
             except Exception:  # noqa: BLE001 - best-effort teardown
@@ -237,6 +239,8 @@ class RemoteBackend(ClusterBackend):
 
     def shutdown(self) -> None:
         for a in self._actors:
+            a.request_exit()
+        for a in self._actors:
             try:
                 a.kill()
             except Exception:  # noqa: BLE001 - best-effort teardown
@@ -280,6 +284,9 @@ class _RayActorAdapter:
 
     def is_alive(self) -> bool:
         return True
+
+    def request_exit(self) -> None:
+        """``ray.kill`` gives no grace: nothing to ask ahead of it."""
 
     def kill(self, timeout: float = 5.0) -> None:
         import ray

@@ -541,6 +541,8 @@ class TpuStrategy:
         """Kill every current worker.  Failures are expected (some are
         already dead) but never SILENT: an unkillable worker is a zombie
         holding TPU chips, and the debug log must say which rank."""
+        for w in self._workers:
+            w.request_exit()
         for rank, w in enumerate(self._workers):
             try:
                 if timeout is None:
@@ -746,6 +748,13 @@ class TpuStrategy:
                 # respawning would retrain epochs just to re-raise it.
                 except ActorDiedError as err:
                     self._capture_attempt_events()
+                    # Whatever follows, a raise or a respawn, this set
+                    # is done, and the dead rank's peers are wedged in
+                    # its collective, where neither the exit message
+                    # nor SIGTERM (the drain handler takes it) ends
+                    # them: the monitor abort's grace, not kill()'s 5 s
+                    # twice over for each in turn at the teardown.
+                    self._kill_workers(timeout=1.0, why="worker-death")
                     # A death supersedes any in-flight grow drain (the
                     # restart below is itself a grow opportunity); a
                     # stale flag would mislabel the NEXT preemption as

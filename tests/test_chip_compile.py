@@ -473,20 +473,23 @@ def _moe_cfg():
 
 
 @pytest.mark.parametrize("rows", [MOE["W"], 3072], ids=["decode", "prefill"])
-def test_moe_kernels_compile_at_serve_cell_shapes(one_chip, rows):
+def test_moe_kernels_compile_at_serve_cell_shapes(one_chip, rows, request):
     """The grouped matmuls at a decode tick's 32 x 8 assignments (tiles of
     16 rows, weight-streaming) and at the largest prefill bucket's 3072 x
-    8 (tiles of 128)."""
+    8 (tiles of 128), there in the prefill program they run in."""
     from ray_lightning_tpu.ops.moe import dropless_moe
 
-    w = _sds((MOE["E"], MOE["d"], MOE["f"]), jnp.bfloat16, one_chip)
-    text = _compile(
-        lambda x, idx, gates, wg, wu, wd: dropless_moe(
-            x, idx, gates, wg, wu, wd, 0, impl="pallas"),
-        _sds((rows, MOE["d"]), jnp.bfloat16, one_chip),
-        _sds((rows, MOE["k"]), jnp.int32, one_chip),
-        _sds((rows, MOE["k"]), jnp.float32, one_chip), w, w,
-        _sds((MOE["E"], MOE["f"], MOE["d"]), jnp.bfloat16, one_chip))
+    if rows == 3072:
+        text = request.getfixturevalue("moe_prefill").as_text()
+    else:
+        w = _sds((MOE["E"], MOE["d"], MOE["f"]), jnp.bfloat16, one_chip)
+        text = _compile(
+            lambda x, idx, gates, wg, wu, wd: dropless_moe(
+                x, idx, gates, wg, wu, wd, 0, impl="pallas"),
+            _sds((rows, MOE["d"]), jnp.bfloat16, one_chip),
+            _sds((rows, MOE["k"]), jnp.int32, one_chip),
+            _sds((rows, MOE["k"]), jnp.float32, one_chip), w, w,
+            _sds((MOE["E"], MOE["f"], MOE["d"]), jnp.bfloat16, one_chip))
     assert "%rlt_moe_gate_up" in text and "%rlt_moe_down" in text
 
 
@@ -582,21 +585,26 @@ def test_moe_decode_step_moves_no_pool_layer_and_no_expert_tensor(
     assert not found, found
 
 
-def test_moe_prefill_fits_at_the_largest_bucket(one_chip):
-    """Bucket 3072 (the cell's largest) leaves over 1 GB of the chip:
-    14.45 GB at PR 26 (bucket 4096 needs 14.85 GB and is not used)."""
+@pytest.fixture(scope="module")
+def moe_prefill(one_chip):
+    """The prefill at the cell's largest bucket, the pool donated."""
     fam, params, pool, ring = _moe_programs(one_chip)
     i32 = lambda *shape: _sds(shape, jnp.int32, one_chip)  # noqa: E731
 
     def prefill(params, pool, tokens, prompt_len, full, rings):
         return fam.prefill(params, pool, tokens, prompt_len, (full, rings))
 
-    compiled = jax.jit(prefill, donate_argnums=1).lower(
+    return jax.jit(prefill, donate_argnums=1).lower(
         params, pool, i32(3072), i32(), i32(3072 // MOE["Bs"]), i32(ring),
     ).compile()
-    text = compiled.as_text()
+
+
+def test_moe_prefill_fits_at_the_largest_bucket(moe_prefill):
+    """Bucket 3072 leaves over 1 GB of the chip: 14.45 GB at PR 26
+    (bucket 4096 needs 14.85 GB and is not used)."""
+    text = moe_prefill.as_text()
     assert "%rlt_flash_fwd" in text and "%rlt_moe_gate_up" in text
-    assert _footprint(compiled) < 14.75e9
+    assert _footprint(moe_prefill) < 14.75e9
 
 
 # -- the latent share (sarvam-105b-ep8.serve-longctx) -------------------------

@@ -53,8 +53,11 @@ def _exit_hard():
     os._exit(17)
 
 
-@pytest.fixture
+@pytest.fixture(scope="module")
 def actor():
+    """One actor for every case that leaves it alive (a spawn is the
+    child's ``import jax``, seconds each); a case that kills its actor,
+    or sets its environment at the spawn, starts its own."""
     a = ProcessActor(name="test-actor")
     yield a
     a.kill()
@@ -115,16 +118,14 @@ class TestDriverQueue:
         assert q.get(timeout=10) == {"a": 1}
         q.shutdown()
 
-    def test_cross_process_streaming(self):
+    def test_cross_process_streaming(self, actor):
         q = DriverQueue()
-        a = ProcessActor(name="queue-actor")
         try:
-            result = a.execute(_put_through_queue, q.handle, 5)
+            result = actor.execute(_put_through_queue, q.handle, 5)
             assert result == "done"
             got = [q.get(timeout=10) for _ in range(5)]
             assert got == [{"step": i} for i in range(5)]
         finally:
-            a.kill()
             q.shutdown()
 
     def test_handle_repickles(self):
@@ -219,11 +220,10 @@ class TestDriverQueue:
 
 
 class TestProcessResults:
-    def test_pump_callback_raising_keeps_fit_result(self):
+    def test_pump_callback_raising_keeps_fit_result(self, actor):
         """A raising on_item observer must neither deadlock the pump
         nor drop the futures' results (satellite: driver resilience)."""
         q = DriverQueue()
-        a = ProcessActor(name="raising-pump-actor")
         seen = []
 
         def bad_observer(item):
@@ -231,28 +231,25 @@ class TestProcessResults:
             raise RuntimeError("observer blew up")
 
         try:
-            fut = a.submit(_put_through_queue, q.handle, 3)
+            fut = actor.submit(_put_through_queue, q.handle, 3)
             with pytest.warns(UserWarning, match="stream-item callback"):
                 out = process_results([fut], q, on_item=bad_observer)
             assert out == ["done"]
             assert seen == [{"step": i} for i in range(3)]
         finally:
-            a.kill()
             q.shutdown()
 
-    def test_pump_tick_callback_raising_is_survived(self):
+    def test_pump_tick_callback_raising_is_survived(self, actor):
         q = DriverQueue()
-        a = ProcessActor(name="tick-actor")
 
         def bad_tick():
             raise ValueError("tick broke")
 
         try:
-            fut = a.submit(_add, 2, 2)
+            fut = actor.submit(_add, 2, 2)
             with pytest.warns(UserWarning, match="tick callback"):
                 assert process_results([fut], q, on_tick=bad_tick) == [4]
         finally:
-            a.kill()
             q.shutdown()
 
     def test_multi_rank_producers_exactly_once_under_pump(self):
@@ -286,42 +283,34 @@ class TestProcessResults:
                 a.kill()
             q.shutdown()
 
-    def test_pump_drains_queue_and_returns_results(self):
+    def test_pump_drains_queue_and_returns_results(self, actor):
         q = DriverQueue()
-        a = ProcessActor(name="pump-actor")
         try:
-            fut = a.submit(_put_through_queue, q.handle, 3)
+            fut = actor.submit(_put_through_queue, q.handle, 3)
             seen = []
             out = process_results([fut], q, on_item=seen.append)
             assert out == ["done"]
             assert seen == [{"step": i} for i in range(3)]
         finally:
-            a.kill()
             q.shutdown()
 
-    def test_thunks_execute_in_driver(self):
+    def test_thunks_execute_in_driver(self, actor):
         q = DriverQueue()
-        a = ProcessActor(name="thunk-actor")
         try:
-            fut = a.submit(_put_thunk, q.handle, 21)
+            fut = actor.submit(_put_thunk, q.handle, 21)
             process_results([fut], q)
             # The thunk ran driver-side during the pump; verify by running
             # another and checking handle_queue_item directly.
-            a.execute(_put_thunk, q.handle, 5)
+            actor.execute(_put_thunk, q.handle, 5)
             item = q.get(timeout=10)
             assert callable(item) and item() == 10
         finally:
-            a.kill()
             q.shutdown()
 
-    def test_worker_failure_raises(self):
-        a = ProcessActor(name="fail-actor")
-        try:
-            fut = a.submit(_boom)
-            with pytest.raises(RemoteError):
-                process_results([fut], None)
-        finally:
-            a.kill()
+    def test_worker_failure_raises(self, actor):
+        fut = actor.submit(_boom)
+        with pytest.raises(RemoteError):
+            process_results([fut], None)
 
 
 class TestBackend:

@@ -20,6 +20,8 @@ import pytest
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ray_lightning_tpu.cluster.actor import ActorDiedError
+from ray_lightning_tpu.core.callbacks import Callback
 from ray_lightning_tpu.core.loop import (
     FitConfig,
     _elastic_resume_info,
@@ -28,7 +30,9 @@ from ray_lightning_tpu.core.loop import (
 )
 from ray_lightning_tpu.fault import inject
 from ray_lightning_tpu.models.boring import BoringDataModule, BoringModel
+from ray_lightning_tpu.core.trainer import Trainer
 from ray_lightning_tpu.parallel.mesh import MeshSpec, build_mesh
+from ray_lightning_tpu.parallel.strategies import RayStrategy
 from ray_lightning_tpu.utils import sharded_ckpt as sc
 
 
@@ -472,8 +476,6 @@ def test_lose_worker_grammar():
 
 
 def test_governor_resize_decisions():
-    from ray_lightning_tpu.parallel.strategies import RayStrategy
-
     cap = [4]
     s = RayStrategy(num_workers=4, max_restarts=1,
                     elastic_min_workers=2,
@@ -532,9 +534,7 @@ def test_governor_shrink_grow_simulation(tmp_path):
     """The whole shrink→grow trace without processes: attempt 1 dies
     with capacity 1 → shrink to 1 (budget-free); attempt 2 drains on
     the grow request → respawn at 2; attempt 3 completes."""
-    from ray_lightning_tpu.cluster.actor import ActorDiedError
     from ray_lightning_tpu.fault.drain import PreemptedError
-    from ray_lightning_tpu.parallel.strategies import RayStrategy
 
     cap = [1]
     s = RayStrategy(
@@ -578,9 +578,6 @@ def test_governor_shrink_grow_simulation(tmp_path):
 def test_governor_resize_flap_guard(tmp_path):
     """Consecutive shrinks resuming from the same point must raise (a
     flapping fleet cannot loop budget-free forever)."""
-    from ray_lightning_tpu.cluster.actor import ActorDiedError
-    from ray_lightning_tpu.parallel.strategies import RayStrategy
-
     cap = [3]
     s = RayStrategy(
         num_workers=4, max_restarts=1, elastic_min_workers=1,
@@ -611,9 +608,6 @@ def test_governor_flap_guard_not_preseeded_by_scratch(tmp_path):
     must get the same two-strike allowance as one with checkpoints —
     the initial sentinel must not make the first scratch shrink count
     as a repeat."""
-    from ray_lightning_tpu.cluster.actor import ActorDiedError
-    from ray_lightning_tpu.parallel.strategies import RayStrategy
-
     cap = [3]
     s = RayStrategy(
         num_workers=4, max_restarts=1, elastic_min_workers=1,
@@ -770,7 +764,6 @@ def test_session_resize_notifies_packer(tmp_path):
 def test_gang_packed_trials_get_disjoint_meshes(tmp_path):
     """Two concurrent LocalStrategy trials on one 8-device fleet train
     on DISJOINT 4-device sub-meshes."""
-    from ray_lightning_tpu.core.trainer import Trainer
     from ray_lightning_tpu.parallel.strategies import LocalStrategy
     from ray_lightning_tpu.tuning import tune_run
     from ray_lightning_tpu.tuning.session import (
@@ -818,9 +811,6 @@ def test_chaos_lose_worker_shrinks_and_completes(tmp_path, monkeypatch):
     shrink is budget-free, and the resize event records
     old/new world + recover_s (the scorecard's
     ``resize_time_to_recover_s``)."""
-    from ray_lightning_tpu.core.trainer import Trainer
-    from ray_lightning_tpu.parallel.strategies import RayStrategy
-
     monkeypatch.setenv("RLT_FAULT", "lose_worker@point:spawn,rank:1")
     monkeypatch.setenv("RLT_FAULT_STATE", str(tmp_path / "chaos"))
     strategy = RayStrategy(
@@ -852,10 +842,6 @@ def test_chaos_lose_worker_shrinks_and_completes(tmp_path, monkeypatch):
 @pytest.mark.chaos
 @pytest.mark.slow
 def test_chaos_shrink_below_min_rejects(tmp_path, monkeypatch):
-    from ray_lightning_tpu.cluster.actor import ActorDiedError
-    from ray_lightning_tpu.core.trainer import Trainer
-    from ray_lightning_tpu.parallel.strategies import RayStrategy
-
     monkeypatch.setenv("RLT_FAULT", "lose_worker@point:spawn,rank:1")
     monkeypatch.setenv("RLT_FAULT_STATE", str(tmp_path / "chaos"))
     strategy = RayStrategy(
@@ -872,3 +858,147 @@ def test_chaos_shrink_below_min_rejects(tmp_path, monkeypatch):
     assert strategy.active_workers == 2  # never resized
     kinds = [e["kind"] for e in strategy.recovery_events]
     assert "resize_rejected" in kinds
+
+
+# ---------------------------------------------------------------------------
+# Elastic restart after a rank's death (real fits; chaos: test_fault_tolerance)
+# ---------------------------------------------------------------------------
+
+class CrashOnce(Callback):
+    """Hard-kill one rank at a given epoch, only on the first attempt.
+
+    A marker file on the (shared) filesystem records that the crash
+    already happened, so the respawned worker set trains through.
+    """
+
+    def __init__(self, marker: str, crash_rank: int = 1, crash_epoch: int = 1):
+        self.marker = marker
+        self.crash_rank = crash_rank
+        self.crash_epoch = crash_epoch
+
+    def on_train_epoch_start(self, trainer, module) -> None:
+        if (
+            trainer.global_rank == self.crash_rank
+            and trainer.current_epoch == self.crash_epoch
+            and not os.path.exists(self.marker)
+        ):
+            with open(self.marker, "w") as f:
+                f.write("crashed")
+            os._exit(1)  # simulate hard worker death (OOM/preemption)
+
+
+class EpochRecorder(Callback):
+    def __init__(self):
+        self.epochs = []
+
+    def on_train_epoch_end(self, trainer, module) -> None:
+        self.epochs.append(trainer.current_epoch)
+
+    def state_dict(self):
+        return {"epochs": list(self.epochs)}
+
+    def load_state_dict(self, state):
+        self.epochs = list(state["epochs"])
+
+
+def _fit(tmp_path, max_restarts, crash=True, max_epochs=4, crash_epoch=1):
+    callbacks = []
+    if crash:
+        callbacks.append(CrashOnce(str(tmp_path / "crash-marker"),
+                                   crash_epoch=crash_epoch))
+    recorder = EpochRecorder()
+    callbacks.append(recorder)
+    strategy = RayStrategy(num_workers=2, max_restarts=max_restarts)
+    trainer = Trainer(
+        strategy=strategy,
+        max_epochs=max_epochs,
+        default_root_dir=str(tmp_path),
+        enable_checkpointing=False,
+        limit_train_batches=2,
+        limit_val_batches=1,
+        callbacks=callbacks,
+    )
+    trainer.fit(BoringModel(), BoringDataModule(batch_size=16))
+    return trainer, strategy, recorder
+
+
+def test_worker_death_fails_fast_without_elastic(tmp_path):
+    """max_restarts=0 keeps reference semantics: crash propagates."""
+    with pytest.raises(ActorDiedError):
+        _fit(tmp_path, max_restarts=0)
+
+
+def test_elastic_restart_completes_fit(tmp_path):
+    trainer, strategy, recorder = _fit(tmp_path, max_restarts=1)
+    assert strategy.restarts_used == 1
+    assert np.isfinite(trainer.callback_metrics["train_loss"])
+    # Completed all epochs: epoch 0 ran pre-crash, checkpointed, then the
+    # respawned set resumed at epoch 1 (<= restart_every_n_epochs lost).
+    assert trainer.epochs_run == 4
+    # Callback state rode the restart checkpoint: epoch 0 (pre-crash)
+    # survives, epochs 1-3 ran on the respawned set — no resets, no gaps.
+    assert recorder.epochs == [0, 1, 2, 3]
+    # Restart scratch dir is cleaned up after success.
+    leftovers = [d for d in os.listdir(tmp_path)
+                 if d.startswith(".rlt-restart-")]
+    assert not leftovers
+
+
+def test_elastic_budget_exhaustion_raises(tmp_path):
+    """Crashing more times than max_restarts still fails."""
+    marker = str(tmp_path / "never-written-marker")
+
+    class AlwaysCrash(CrashOnce):
+        def on_train_epoch_start(self, trainer, module) -> None:
+            if (trainer.global_rank == self.crash_rank
+                    and trainer.current_epoch == self.crash_epoch):
+                os._exit(1)
+
+    strategy = RayStrategy(num_workers=2, max_restarts=1)
+    trainer = Trainer(
+        strategy=strategy,
+        max_epochs=3,
+        default_root_dir=str(tmp_path),
+        enable_checkpointing=False,
+        limit_train_batches=2,
+        limit_val_batches=1,
+        callbacks=[AlwaysCrash(marker)],
+    )
+    with pytest.raises(ActorDiedError):
+        trainer.fit(BoringModel(), BoringDataModule(batch_size=16))
+    assert strategy.restarts_used == 1
+    # Scratch dir is reclaimed on failure too.
+    assert not [d for d in os.listdir(tmp_path)
+                if d.startswith(".rlt-restart-")]
+
+
+def test_user_exception_is_not_retried(tmp_path):
+    """Deterministic exceptions in user code must fail fast, not burn the
+    restart budget re-raising the same error."""
+    from ray_lightning_tpu.cluster.actor import RemoteError
+
+    class BadHook(Callback):
+        def on_train_epoch_start(self, trainer, module) -> None:
+            raise ValueError("deterministic user bug")
+
+    strategy = RayStrategy(num_workers=1, max_restarts=3)
+    trainer = Trainer(
+        strategy=strategy,
+        max_epochs=1,
+        default_root_dir=str(tmp_path),
+        enable_checkpointing=False,
+        limit_train_batches=1,
+        callbacks=[BadHook()],
+    )
+    with pytest.raises(RemoteError, match="deterministic user bug"):
+        trainer.fit(BoringModel(), BoringDataModule(batch_size=16))
+    assert strategy.restarts_used == 0
+
+
+def test_elastic_restart_without_checkpoint_restarts_from_scratch(tmp_path):
+    """Crash at epoch 0 (before any restart checkpoint exists): the
+    respawned set simply begins again."""
+    trainer, strategy, _ = _fit(tmp_path, max_restarts=1, max_epochs=2,
+                                crash_epoch=0)
+    assert strategy.restarts_used == 1
+    assert trainer.epochs_run == 2

@@ -1,5 +1,5 @@
-"""Failure detection + elastic restart + the recovery-plane acceptance
-matrix.
+"""Failure detection + elastic restart: the recovery-plane acceptance
+matrix (the restarts' own fits, no fault injected: ``test_elastic.py``).
 
 The reference only fails fast (worker death raises out of ``ray.get``,
 SURVEY §5 "failure detection: ABSENT"); this framework adds opt-in
@@ -13,11 +13,9 @@ to the previous good one.
 
 import os
 
-import numpy as np
 import pytest
 
 from ray_lightning_tpu.cluster.actor import ActorDiedError
-from ray_lightning_tpu.core.callbacks import Callback
 from ray_lightning_tpu.core.trainer import Trainer
 from ray_lightning_tpu.fault.drain import PreemptedError
 from ray_lightning_tpu.models.boring import BoringDataModule, BoringModel
@@ -36,144 +34,33 @@ def chaos_env(tmp_path, monkeypatch):
     return _arm
 
 
-class CrashOnce(Callback):
-    """Hard-kill one rank at a given epoch, only on the first attempt.
+def test_a_dead_ranks_peers_are_asked_at_once_and_waited_for_briefly():
+    """The survivors of a dead rank are wedged in its collective: the
+    exit message cannot end them and the drain handler takes their
+    SIGTERM, so each used to cost ``kill()``'s 5 s twice, one after the
+    other (10 s a restart or a teardown of two workers, PR 32).  Every
+    worker is asked before any is waited for, under the monitor abort's
+    grace, as soon as the death is known."""
+    from ray_lightning_tpu.core.loop import FitConfig
 
-    A marker file on the (shared) filesystem records that the crash
-    already happened, so the respawned worker set trains through.
-    """
+    strategy = RayStrategy(num_workers=2)
+    calls = []
 
-    def __init__(self, marker: str, crash_rank: int = 1, crash_epoch: int = 1):
-        self.marker = marker
-        self.crash_rank = crash_rank
-        self.crash_epoch = crash_epoch
+    class Worker:
+        def request_exit(self):
+            calls.append("asked")
 
-    def on_train_epoch_start(self, trainer, module) -> None:
-        if (
-            trainer.global_rank == self.crash_rank
-            and trainer.current_epoch == self.crash_epoch
-            and not os.path.exists(self.marker)
-        ):
-            with open(self.marker, "w") as f:
-                f.write("crashed")
-            os._exit(1)  # simulate hard worker death (OOM/preemption)
+        def kill(self, timeout=5.0):
+            calls.append(timeout)
 
+    def dies(*args, **kwargs):
+        raise ActorDiedError("rank 1 died")
 
-class EpochRecorder(Callback):
-    def __init__(self):
-        self.epochs = []
-
-    def on_train_epoch_end(self, trainer, module) -> None:
-        self.epochs.append(trainer.current_epoch)
-
-    def state_dict(self):
-        return {"epochs": list(self.epochs)}
-
-    def load_state_dict(self, state):
-        self.epochs = list(state["epochs"])
-
-
-def _fit(tmp_path, max_restarts, crash=True, max_epochs=4, crash_epoch=1):
-    callbacks = []
-    if crash:
-        callbacks.append(CrashOnce(str(tmp_path / "crash-marker"),
-                                   crash_epoch=crash_epoch))
-    recorder = EpochRecorder()
-    callbacks.append(recorder)
-    strategy = RayStrategy(num_workers=2, max_restarts=max_restarts)
-    trainer = Trainer(
-        strategy=strategy,
-        max_epochs=max_epochs,
-        default_root_dir=str(tmp_path),
-        enable_checkpointing=False,
-        limit_train_batches=2,
-        limit_val_batches=1,
-        callbacks=callbacks,
-    )
-    trainer.fit(BoringModel(), BoringDataModule(batch_size=16))
-    return trainer, strategy, recorder
-
-
-def test_worker_death_fails_fast_without_elastic(tmp_path):
-    """max_restarts=0 keeps reference semantics: crash propagates."""
+    strategy._backend, strategy._workers = object(), [Worker(), Worker()]
+    strategy._run_once = dies
     with pytest.raises(ActorDiedError):
-        _fit(tmp_path, max_restarts=0)
-
-
-def test_elastic_restart_completes_fit(tmp_path):
-    trainer, strategy, recorder = _fit(tmp_path, max_restarts=1)
-    assert strategy.restarts_used == 1
-    assert np.isfinite(trainer.callback_metrics["train_loss"])
-    # Completed all epochs: epoch 0 ran pre-crash, checkpointed, then the
-    # respawned set resumed at epoch 1 (<= restart_every_n_epochs lost).
-    assert trainer.epochs_run == 4
-    # Callback state rode the restart checkpoint: epoch 0 (pre-crash)
-    # survives, epochs 1-3 ran on the respawned set — no resets, no gaps.
-    assert recorder.epochs == [0, 1, 2, 3]
-    # Restart scratch dir is cleaned up after success.
-    leftovers = [d for d in os.listdir(tmp_path)
-                 if d.startswith(".rlt-restart-")]
-    assert not leftovers
-
-
-def test_elastic_budget_exhaustion_raises(tmp_path):
-    """Crashing more times than max_restarts still fails."""
-    marker = str(tmp_path / "never-written-marker")
-
-    class AlwaysCrash(CrashOnce):
-        def on_train_epoch_start(self, trainer, module) -> None:
-            if (trainer.global_rank == self.crash_rank
-                    and trainer.current_epoch == self.crash_epoch):
-                os._exit(1)
-
-    strategy = RayStrategy(num_workers=2, max_restarts=1)
-    trainer = Trainer(
-        strategy=strategy,
-        max_epochs=3,
-        default_root_dir=str(tmp_path),
-        enable_checkpointing=False,
-        limit_train_batches=2,
-        limit_val_batches=1,
-        callbacks=[AlwaysCrash(marker)],
-    )
-    with pytest.raises(ActorDiedError):
-        trainer.fit(BoringModel(), BoringDataModule(batch_size=16))
-    assert strategy.restarts_used == 1
-    # Scratch dir is reclaimed on failure too.
-    assert not [d for d in os.listdir(tmp_path)
-                if d.startswith(".rlt-restart-")]
-
-
-def test_user_exception_is_not_retried(tmp_path):
-    """Deterministic exceptions in user code must fail fast, not burn the
-    restart budget re-raising the same error."""
-    from ray_lightning_tpu.cluster.actor import RemoteError
-
-    class BadHook(Callback):
-        def on_train_epoch_start(self, trainer, module) -> None:
-            raise ValueError("deterministic user bug")
-
-    strategy = RayStrategy(num_workers=1, max_restarts=3)
-    trainer = Trainer(
-        strategy=strategy,
-        max_epochs=1,
-        default_root_dir=str(tmp_path),
-        enable_checkpointing=False,
-        limit_train_batches=1,
-        callbacks=[BadHook()],
-    )
-    with pytest.raises(RemoteError, match="deterministic user bug"):
-        trainer.fit(BoringModel(), BoringDataModule(batch_size=16))
-    assert strategy.restarts_used == 0
-
-
-def test_elastic_restart_without_checkpoint_restarts_from_scratch(tmp_path):
-    """Crash at epoch 0 (before any restart checkpoint exists): the
-    respawned set simply begins again."""
-    trainer, strategy, _ = _fit(tmp_path, max_restarts=1, max_epochs=2,
-                                crash_epoch=0)
-    assert strategy.restarts_used == 1
-    assert trainer.epochs_run == 2
+        strategy.run("validate", None, None, FitConfig(), [])
+    assert calls == ["asked", "asked", 1.0, 1.0]
 
 
 # ---------------------------------------------------------------------------

@@ -15,15 +15,12 @@ from ray_lightning_tpu.models.generate import (
     _sample, decode_step, generate, init_kv_cache, prefill,
 )
 from ray_lightning_tpu.models.gpt import GPT, GPTConfig
+from utils import tiny_gpt
 
 
 @pytest.fixture(scope="module")
 def model():
-    cfg = GPTConfig(vocab_size=128, n_layer=2, n_head=4, d_model=64,
-                    seq_len=32, warmup_steps=1)
-    m = GPT(cfg, attn_impl="xla")
-    params = m.init_params(jax.random.PRNGKey(0))
-    return m, params
+    return tiny_gpt(seq_len=32)
 
 
 def test_decode_logits_match_full_forward(model):

@@ -9,17 +9,24 @@ the oracle.
 import numpy as np
 import pytest
 
-torch = pytest.importorskip("torch")
-transformers = pytest.importorskip("transformers")
+import jax
+import jax.numpy as jnp
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-from ray_lightning_tpu.models.gpt import GPT  # noqa: E402
-from ray_lightning_tpu.utils.hf_import import (  # noqa: E402
+from ray_lightning_tpu.models.gpt import GPT
+from ray_lightning_tpu.utils.hf_import import (
     gpt_config_from_hf,
     import_gpt2,
 )
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _torch_stack():
+    """``torch`` and ``transformers`` are imported where these cases
+    run, not where every xdist worker collects them (7 s of import in
+    each of seven processes)."""
+    global torch, transformers
+    torch = pytest.importorskip("torch")
+    transformers = pytest.importorskip("transformers")
 
 
 def _tiny_hf(vocab=97, n_layer=2, n_head=4, d=64, seq=32):

@@ -13,7 +13,6 @@ self-tests — deleting a distributed tracer's ``clock=`` or moving a
 
 from __future__ import annotations
 
-import json
 import os
 import subprocess
 import threading
@@ -439,24 +438,25 @@ def test_guard_comment_on_use_site_is_not_a_suppression():
     assert findings[0].line == 7
 
 
-def test_explicit_absolute_path_is_normalized(tmp_path, capsys):
+def test_explicit_absolute_path_is_normalized(tmp_path, capsys,
+                                              monkeypatch):
     """Review fix: an absolute path to a registered file must hit the
-    same path-keyed rules as the repo-relative form (no false clean)."""
+    same path-keyed rules as the repo-relative form (no false clean).
+    The mutated file is a copy under ``tmp_path`` taken as the root:
+    other workers import the real one while this runs."""
+    from tools.rlt_lint import cli
+
     rel = "ray_lightning_tpu/serve/engine.py"
-    src = _read(rel)
     anchor = "    def step(self) -> bool:\n"
-    mutated = src.replace(
+    mutated = _read(rel).replace(
         anchor, anchor + "        _oops = jax.jit(lambda z: z)\n"
     )
-    scratch = os.path.join(REPO, rel + ".lintbak")
-    os.rename(os.path.join(REPO, rel), scratch)
-    try:
-        with open(os.path.join(REPO, rel), "w") as f:
-            f.write(mutated)
-        rc = run_lint([os.path.join(REPO, rel)],
-                      os.path.join("tools", "rlt_lint", "baseline.json"))
-    finally:
-        os.replace(scratch, os.path.join(REPO, rel))
+    baseline = os.path.join("tools", "rlt_lint", "baseline.json")
+    for path, text in ((rel, mutated), (baseline, _read(baseline))):
+        os.makedirs(tmp_path / os.path.dirname(path))
+        (tmp_path / path).write_text(text)
+    monkeypatch.setattr(cli, "_REPO_ROOT", str(tmp_path))
+    rc = run_lint([str(tmp_path / rel)], baseline, repo_config(REPO))
     out = capsys.readouterr().out
     assert rc == 1 and "RLT001" in out, out
 

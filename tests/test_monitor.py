@@ -13,7 +13,6 @@ import glob
 import json
 import logging
 import os
-import subprocess
 import sys
 import threading
 import time
@@ -42,6 +41,7 @@ from ray_lightning_tpu.telemetry.heartbeat import (
     make_beat,
 )
 from ray_lightning_tpu.telemetry.logs import RankLogHandler
+from utils import rlt_top_once
 from ray_lightning_tpu.telemetry.schema import (
     validate_event,
     validate_flight_bundle,
@@ -372,14 +372,7 @@ class TestPromExport:
     def test_rlt_top_renders_live_json(self, tmp_path):
         snap, _ = self._snapshot()
         (tmp_path / "live.json").write_text(json.dumps(snap, default=str))
-        out = subprocess.run(
-            [sys.executable,
-             os.path.join(os.path.dirname(__file__), "..", "tools",
-                          "rlt_top.py"),
-             "--once", str(tmp_path)],
-            capture_output=True, text=True, timeout=60,
-        )
-        assert out.returncode == 0, out.stderr
+        out = rlt_top_once(tmp_path)
         assert "rank" in out.stdout and "ok" in out.stdout
 
 
@@ -648,7 +641,9 @@ class TestLivePlaneIntegration:
         trainer = Trainer(
             strategy=RayStrategy(
                 num_workers=1,
-                telemetry={"tier": "cheap", "heartbeat_s": 0.1},
+                # Beats of 0.1 s: a worker starved for 0.3 s under
+                # tier-1's load read as a lost heartbeat (PR 32).
+                telemetry={"tier": "cheap", "heartbeat_s": 0.5},
             ),
             max_epochs=1,
             default_root_dir=str(tmp_path),

@@ -7,13 +7,15 @@ import pytest
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ray_lightning_tpu.utils.orbax_io import (
-    ORBAX_INSTALLED, load_orbax, save_orbax,
-)
 
-pytestmark = pytest.mark.skipif(
-    not ORBAX_INSTALLED, reason="orbax-checkpoint not installed"
-)
+@pytest.fixture(scope="module", autouse=True)
+def _orbax():
+    """``orbax.checkpoint`` (5 s of import) comes in where these cases
+    run, not where every xdist worker collects them."""
+    global load_orbax, save_orbax
+    pytest.importorskip("orbax.checkpoint",
+                        reason="orbax-checkpoint not installed")
+    from ray_lightning_tpu.utils.orbax_io import load_orbax, save_orbax
 
 
 def _tree():

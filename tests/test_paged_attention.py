@@ -4,8 +4,8 @@ The kernel runs under the Pallas interpreter here (``attn_impl="pallas"``
 on the CPU); ``tests/test_chip_compile.py`` compiles it for the chip at
 the serving cell's shapes.  Both paths see the same two-layer model, the
 same randomly filled pool and the same tables: the logits agree within
-the tolerance ``tests/test_ops.py`` holds flash to against XLA, and the
-pools agree after the step (the trash block aside: several slots may
+the tolerance ``tests/test_ops_flash.py`` holds flash to against XLA,
+and the pools agree after the step (the trash block aside: several slots may
 write it, and which write lands last is nobody's contract).
 """
 

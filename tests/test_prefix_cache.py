@@ -13,14 +13,10 @@ starves resident decode slots for more than one chunk tick — pinned
 via per-tick token emission), and adapter-drop invalidation.
 """
 
-import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
 
-from ray_lightning_tpu.models.generate import generate
-from ray_lightning_tpu.models.gpt import GPT, GPTConfig
 from ray_lightning_tpu.serve.engine import ServeConfig, ServeEngine
 from ray_lightning_tpu.serve.kv_cache import (
     TRASH_BLOCK, BlockAllocator, PrefixIndex,
@@ -28,26 +24,16 @@ from ray_lightning_tpu.serve.kv_cache import (
 from ray_lightning_tpu.serve.scheduler import Request, Scheduler
 from ray_lightning_tpu.telemetry import compile_event_count
 
+from utils import rand_prompt as _rand_prompt
+from utils import reference_tokens as _ref_tokens
+from utils import tiny_gpt
+
 pytestmark = pytest.mark.serve
 
 
 @pytest.fixture(scope="module")
 def model():
-    cfg = GPTConfig(vocab_size=128, n_layer=2, n_head=4, d_model=64,
-                    seq_len=64, warmup_steps=1)
-    m = GPT(cfg, attn_impl="xla")
-    params = m.init_params(jax.random.PRNGKey(0))
-    return m, params
-
-
-def _ref_tokens(m, params, prompt, n):
-    out = generate(m, params, jnp.asarray([prompt], jnp.int32), n)
-    return np.asarray(out)[0, len(prompt):].tolist()
-
-
-def _rand_prompt(seed, length, vocab=128):
-    rng = np.random.default_rng(seed)
-    return rng.integers(1, vocab, size=(length,)).tolist()
+    return tiny_gpt()
 
 
 # ---------------------------------------------------------------------------

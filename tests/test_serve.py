@@ -10,18 +10,14 @@ guarantee (via the telemetry recompile counter), scheduler policy
 units, SLO stats schema, and the DriverQueue client plane.
 """
 
-import os
-import subprocess
-import sys
 import time
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from ray_lightning_tpu.models.generate import generate
-from ray_lightning_tpu.models.gpt import GPT, GPTConfig
+from ray_lightning_tpu.serve.client import ServeClient
 from ray_lightning_tpu.serve.engine import (
     ServeConfig, ServeEngine, ServeRejected,
 )
@@ -35,27 +31,16 @@ from ray_lightning_tpu.serve.scheduler import (
 )
 from ray_lightning_tpu.telemetry import compile_event_count
 
+from utils import rand_prompt as _rand_prompt
+from utils import reference_tokens as _ref_tokens
+from utils import rlt_top_once, tiny_gpt
+
 pytestmark = pytest.mark.serve
 
 
 @pytest.fixture(scope="module")
 def model():
-    cfg = GPTConfig(vocab_size=128, n_layer=2, n_head=4, d_model=64,
-                    seq_len=64, warmup_steps=1)
-    m = GPT(cfg, attn_impl="xla")
-    params = m.init_params(jax.random.PRNGKey(0))
-    return m, params
-
-
-def _ref_tokens(m, params, prompt, n):
-    """Static-path greedy reference continuation."""
-    out = generate(m, params, jnp.asarray([prompt], jnp.int32), n)
-    return np.asarray(out)[0, len(prompt):].tolist()
-
-
-def _rand_prompt(seed, length, vocab):
-    rng = np.random.default_rng(seed)
-    return rng.integers(1, vocab, size=(length,)).tolist()
+    return tiny_gpt()
 
 
 # ---------------------------------------------------------------------------
@@ -541,14 +526,7 @@ class TestServeStats:
         )
         eng.generate([5, 6], 3)
         assert (tmp_path / "serve-live.json").exists()
-        out = subprocess.run(
-            [sys.executable,
-             os.path.join(os.path.dirname(__file__), "..", "tools",
-                          "rlt_top.py"),
-             "--once", str(tmp_path)],
-            capture_output=True, text=True, timeout=60,
-        )
-        assert out.returncode == 0, out.stderr
+        out = rlt_top_once(tmp_path)
         assert "serve:" in out.stdout and "slots" in out.stdout
 
 
@@ -558,8 +536,6 @@ class TestServeStats:
 
 class TestClientPlane:
     def test_generate_stream_and_backpressure_over_queue(self, model):
-        from ray_lightning_tpu.serve.client import ServeClient
-
         m, params = model
         eng = ServeEngine(m, params, ServeConfig(
             num_slots=1, block_size=8, max_queue=2,
@@ -638,8 +614,6 @@ class TestClientPlane:
             orig(addr, item)
 
         eng._reply = spy
-        from ray_lightning_tpu.serve.client import ServeClient
-
         client = ServeClient(eng.queue_handle())
         try:
             rid = client.submit([1, 2, 3], 3)
@@ -672,8 +646,6 @@ class TestCoalescedReplies:
 
     def _serve(self, model, coalesce):
         from ray_lightning_tpu.cluster.queue import QueueHandle
-        from ray_lightning_tpu.serve.client import ServeClient
-
         m, params = model
         kw = {} if coalesce else {"coalesce_replies": False}
         eng = ServeEngine(m, params, ServeConfig(
@@ -843,8 +815,6 @@ class TestDecodeLookahead:
         assert eng._ahead is None
 
     def test_over_the_client_plane_with_eos(self, model):
-        from ray_lightning_tpu.serve.client import ServeClient
-
         m, params = model
         prompt = _rand_prompt(61, 6, m.config.vocab_size)
         want = _ref_tokens(m, params, prompt, 10)

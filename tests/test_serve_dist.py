@@ -659,15 +659,9 @@ class TestSegmentSweep:
 
 @pytest.fixture(scope="module")
 def dist_model():
-    import jax
+    from utils import tiny_gpt
 
-    from ray_lightning_tpu.models.gpt import GPT, GPTConfig
-
-    cfg = GPTConfig(vocab_size=128, n_layer=2, n_head=4, d_model=64,
-                    seq_len=64, warmup_steps=1)
-    m = GPT(cfg, attn_impl="xla")
-    params = m.init_params(jax.random.PRNGKey(0))
-    return m, params
+    return tiny_gpt()
 
 
 def _prompts(n, seed=0, vocab=128, lo=3, hi=14):

@@ -17,9 +17,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ray_lightning_tpu.models.generate import generate
 from ray_lightning_tpu.models.gpt import (
-    GPT, GPTConfig, extract_lora, synthetic_lora_adapter,
+    extract_lora, synthetic_lora_adapter,
 )
 from ray_lightning_tpu.serve.engine import ServeConfig, ServeEngine
 from ray_lightning_tpu.serve.lora import (
@@ -27,19 +26,13 @@ from ray_lightning_tpu.serve.lora import (
 )
 from ray_lightning_tpu.telemetry import compile_event_count
 
+from utils import rand_prompt as _rand_prompt
+from utils import reference_tokens as _ref_tokens
+from utils import tiny_gpt
+
 pytestmark = pytest.mark.serve
 
 RANK = 4
-
-
-def _rand_prompt(seed, length, vocab=128):
-    rng = np.random.default_rng(seed)
-    return rng.integers(1, vocab, size=(length,)).tolist()
-
-
-def _ref_tokens(m, params, prompt, n):
-    out = generate(m, params, jnp.asarray([prompt], jnp.int32), n)
-    return np.asarray(out)[0, len(prompt):].tolist()
 
 
 def _make_tenant(params, lora_cfg, seed):
@@ -54,11 +47,8 @@ def model():
     """Base model + 5 tenants (4 preloaded in tests, 1 for hot-add)."""
     import dataclasses
 
-    cfg = GPTConfig(vocab_size=128, n_layer=2, n_head=4, d_model=64,
-                    seq_len=64, warmup_steps=1)
-    m = GPT(cfg, attn_impl="xla")
-    params = m.init_params(jax.random.PRNGKey(0))
-    lora_cfg = dataclasses.replace(cfg, lora_rank=RANK)
+    m, params = tiny_gpt()
+    lora_cfg = dataclasses.replace(m.config, lora_rank=RANK)
     tenants = {f"t{i}": _make_tenant(params, lora_cfg, seed=10 + i)
                for i in range(5)}
     adapters = {k: v[0] for k, v in tenants.items()}

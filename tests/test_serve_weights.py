@@ -7,6 +7,7 @@ is bit for bit ``bf16(W)`` per call, so every comparison here is exact
 handed on untouched and uncopied.
 """
 
+import functools
 import re
 
 import jax
@@ -65,6 +66,11 @@ def bf16_model():
     return m, m.init_params(jax.random.PRNGKey(0))
 
 
+def _jit(program, cfg):
+    """A serving program as the engine runs it: jitted, in bfloat16."""
+    return jax.jit(functools.partial(program, cfg, compute_dtype=BF16))
+
+
 def _assert_same(got, want):
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert a.dtype == b.dtype and np.array_equal(
@@ -81,9 +87,9 @@ class TestProgramsAreExactlyEqual:
     def _prefilled(self, cfg, params):
         toks = np.zeros((16,), np.int32)
         toks[:11] = np.arange(1, 12)
-        return paged_prefill(
-            cfg, params, self._pool(cfg), jnp.asarray(toks), jnp.int32(11),
-            jnp.asarray([3, 5], jnp.int32), compute_dtype=BF16)
+        return _jit(paged_prefill, cfg)(
+            params, self._pool(cfg), jnp.asarray(toks), jnp.int32(11),
+            jnp.asarray([3, 5], jnp.int32))
 
     def test_prefill(self, trees):
         _, cfg, params, prepared = trees
@@ -97,9 +103,8 @@ class TestProgramsAreExactlyEqual:
         tables[0, :2] = [3, 5]
         args = (jnp.asarray(tables), jnp.asarray([11, 0], jnp.int32),
                 jnp.asarray([7, 0], jnp.int32))
-        _assert_same(
-            paged_decode_step(cfg, prepared, pool, *args, compute_dtype=BF16),
-            paged_decode_step(cfg, params, pool, *args, compute_dtype=BF16))
+        step = _jit(paged_decode_step, cfg)
+        _assert_same(step(prepared, pool, *args), step(params, pool, *args))
 
     def test_verify_step(self, trees):
         _, cfg, params, prepared = trees
@@ -109,9 +114,8 @@ class TestProgramsAreExactlyEqual:
         args = (jnp.asarray(tables), jnp.asarray([11, 0], jnp.int32),
                 jnp.asarray([[7, 9, 4], [0, 0, 0]], jnp.int32),
                 jnp.asarray([14, 0], jnp.int32))
-        _assert_same(
-            paged_verify_step(cfg, prepared, pool, *args, compute_dtype=BF16),
-            paged_verify_step(cfg, params, pool, *args, compute_dtype=BF16))
+        step = _jit(paged_verify_step, cfg)
+        _assert_same(step(prepared, pool, *args), step(params, pool, *args))
 
 
 # -- what is cast and what is left alone ------------------------------------
@@ -217,23 +221,33 @@ def _requests(temperature):
 
 
 class TestEngine:
+    @pytest.fixture(scope="class")
+    def served(self, bf16_model):
+        """``served(prepared, temperature, together)``: the tokens of
+        ``_requests(temperature)``, each combination from a fresh engine
+        and served once for the cases that compare it."""
+        m, params = bf16_model
+        trees = {False: params,
+                 True: GPTServeFamily(m.config).prepare_params(params, BF16)}
+
+        @functools.cache
+        def serve(prepared, temperature, together):
+            return _serve(m, trees[prepared], _requests(temperature),
+                          together)
+
+        return serve
+
     @pytest.mark.parametrize("temperature", [0.0, 0.8],
                              ids=["greedy", "sampled"])
     @pytest.mark.parametrize("together", [False, True],
                              ids=["alone", "batched"])
     def test_float32_and_prepared_tree_serve_the_same_tokens(
-            self, bf16_model, temperature, together):
-        m, params = bf16_model
-        prepared = GPTServeFamily(m.config).prepare_params(params, BF16)
-        requests = _requests(temperature)
-        assert (_serve(m, params, requests, together)
-                == _serve(m, prepared, requests, together))
+            self, served, temperature, together):
+        assert (served(False, temperature, together)
+                == served(True, temperature, together))
 
-    def test_same_alone_and_batched(self, bf16_model):
-        m, params = bf16_model
-        requests = _requests(0.0)
-        assert (_serve(m, params, requests, False)
-                == _serve(m, params, requests, True))
+    def test_same_alone_and_batched(self, served):
+        assert served(False, 0.0, False) == served(False, 0.0, True)
 
     @pytest.mark.parametrize("kind,precision,cast", [
         ("dense", "bf16", 10), ("dense", "32", 0),

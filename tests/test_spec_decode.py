@@ -19,8 +19,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ray_lightning_tpu.models.generate import generate
 from ray_lightning_tpu.models.gpt import GPT, GPTConfig
+from ray_lightning_tpu.serve.client import ServeClient
 from ray_lightning_tpu.serve.draft import (
     early_exit_draft, pad_identity_layers,
 )
@@ -31,28 +31,19 @@ from ray_lightning_tpu.serve.kv_cache import (
 )
 from ray_lightning_tpu.telemetry import compile_event_count
 
+from utils import rand_prompt as _rand_prompt
+from utils import reference_tokens as _ref_tokens
+from utils import rlt_top_once, tiny_gpt
+
 pytestmark = pytest.mark.serve
 
 
 @pytest.fixture(scope="module")
 def model():
     """4-layer target whose 2-layer early-exit is the draft."""
-    cfg = GPTConfig(vocab_size=128, n_layer=4, n_head=4, d_model=64,
-                    seq_len=64, warmup_steps=1)
-    m = GPT(cfg, attn_impl="xla")
-    params = m.init_params(jax.random.PRNGKey(0))
+    m, params = tiny_gpt(n_layer=4)
     draft, draft_params = early_exit_draft(m, params, 2)
     return m, params, draft, draft_params
-
-
-def _ref_tokens(m, params, prompt, n, **kw):
-    out = generate(m, params, jnp.asarray([prompt], jnp.int32), n, **kw)
-    return np.asarray(out)[0, len(prompt):].tolist()
-
-
-def _rand_prompt(seed, length, vocab=128):
-    rng = np.random.default_rng(seed)
-    return rng.integers(1, vocab, size=(length,)).tolist()
 
 
 def _spec_engine(m, params, draft, draft_params, spec_k=3, **cfg_kw):
@@ -590,8 +581,6 @@ class TestClientVariableWidth:
     def test_stream_dedup_under_spec_and_preemption(self, model):
         """Index-based dedup holds when tokens arrive in multi-token
         bursts and re-emissions cross burst boundaries."""
-        from ray_lightning_tpu.serve.client import ServeClient
-
         m, params, draft, dparams = model
         # 7 usable blocks, two 20-token sequences needing 5 each plus
         # speculative coverage: exhaustion (hence preemption and
@@ -615,7 +604,6 @@ class TestClientVariableWidth:
             client.close()
 
     def test_client_spec_and_topk_fields_roundtrip(self, model):
-        from ray_lightning_tpu.serve.client import ServeClient
         from ray_lightning_tpu.telemetry.schema import (
             validate_serve_request,
         )
@@ -677,10 +665,6 @@ class TestSpecTelemetry:
         assert 'rlt_serve_requests_total{kind="spec_drafted"}' not in text
 
     def test_rlt_top_shows_acceptance(self, model, tmp_path):
-        import os
-        import subprocess
-        import sys
-
         m, params, draft, dparams = model
         eng = ServeEngine(
             m, params,
@@ -691,12 +675,5 @@ class TestSpecTelemetry:
         )
         eng.generate(_rand_prompt(22, 5), 6)
         assert (tmp_path / "serve-live.json").exists()
-        out = subprocess.run(
-            [sys.executable,
-             os.path.join(os.path.dirname(__file__), "..", "tools",
-                          "rlt_top.py"),
-             "--once", str(tmp_path)],
-            capture_output=True, text=True, timeout=60,
-        )
-        assert out.returncode == 0, out.stderr
+        out = rlt_top_once(tmp_path)
         assert "spec acc" in out.stdout
