@@ -428,6 +428,11 @@ class ServeEngine:
         # another starts on the device no earlier than that.
         self._ahead: Optional[tuple] = None
         self._fetched_t = 0.0
+        # Admissions of this iteration whose first token is not fetched
+        # yet, in the order their prefills were dispatched: ``(slot,
+        # request, the token on the device, the request's open span, host
+        # seconds its dispatch took)``.  Empty between iterations.
+        self._firsts: List[tuple] = []
         # Adapter names whose cached chains must be dropped before the
         # next admission poll: add/remove_adapter run on OTHER threads,
         # and every PrefixIndex mutation belongs to the serve thread —
@@ -663,6 +668,15 @@ class ServeEngine:
             donate_argnums=(0,) if jax.default_backend() == "tpu" else (),
         )
         self._first_fn = ledgered_jit(_first, site="serve/first_token")
+
+        def _feed(tokens, slot, first):
+            # An admission's first token written into the next decode's
+            # ``tokens`` operand where both lie on the device.  The slot
+            # is a traced scalar and the program is applied once an
+            # admission: one shape, however many admissions a tick.
+            return tokens.at[slot].set(first)
+
+        self._feed_fn = ledgered_jit(_feed, site="serve/feed_first")
 
         def _chunk_prefill(params, pool, table_row, start, tokens, limit,
                            sample_idx, temp, seed, top_k, ad, ad_ids):
@@ -1084,38 +1098,18 @@ class ServeEngine:
                     self.draft_params, self._draft_pool, padded,
                     np.int32(req.prompt_len), ids,
                 )
-            ph.then("admit_wait", rid=req.rid)
-            first = int(first)  # rlt: noqa[RLT002] deliberate TTFT sync at admission
-            ph.then("admit_emit", rid=req.rid)
-            t_first = time.monotonic()
-            # Per-admission wall in µs (host prep + prefill/import
-            # dispatch + the TTFT sync above).  Paired with the
-            # `admitted` counter it gives the capacity oracle the
-            # once-per-request admission cost its saturation model
-            # charges (serve/capacity.py).
-            self.stats.bump(  # rlt: noqa[RLT002] host float, no device value
-                "admit_us", int((t_first - t_adm) * 1e6))
-            t_adm = t_first
-            if rph is not None:
-                # The int() above synced the device, so this interval
-                # covers dispatch + device compute of the admission.
-                rph.__exit__(None, None, None)
-                self.stats.note_phase(rph.name, rph.dur)
-                rph = self._request_phase(ctx, "first_token", req,
-                                          token_index=0)
-            self.stats.note_first_token(t_first - req.arrival_t)
-            done = self.scheduler.append_token(slot, first, now=t_first)
-            if rph is not None:
-                rph.__exit__(None, None, None)
-                self.stats.note_phase("first_token", rph.dur)
-            self.stats.bump("tokens_out")
-            if req.adapter is not None:
-                self.stats.note_adapter(req.adapter, tokens=1)
-            self._cur_tokens[slot] = first
-            if self.prefix_cache is not None:
-                self._prefix_insert(slot, req)
-            if done:
-                self._complete(slot)
+            # The first token stays on the device.  Where the loop runs
+            # a tick ahead and needs no token's value to go on (no
+            # ``eos_token_id``, a bucketed admission), the next decode
+            # is fed it there and dispatched before the host blocks on
+            # it (``_decode_tick``); otherwise the sync sits here, as it
+            # always did, and the decode follows it.
+            self._firsts.append(
+                (slot, req, first, rph, time.monotonic() - t_adm))
+            if not (self._pipelined and bucket != 0
+                    and req.eos_token_id is None):
+                self._fetch_firsts(ph)
+            t_adm = time.monotonic()
 
         # One chunk for every in-flight chunked prefill BEFORE the
         # decode tick: both dispatches queue on the device each step,
@@ -1142,9 +1136,14 @@ class ServeEngine:
         # is a throughput bet, and a bet must never cost another
         # request its progress (two spec slots preempting each other's
         # windows would ping-pong without forward progress).
+        # A slot whose request ends with the first token it still waits
+        # for (by count) is never decoded for, nor grown.
+        skip = set(self._chunk_jobs).union(
+            slot for slot, req, *_ in self._firsts
+            if req.max_new_tokens <= 1)
         active = [
             s for s, r in enumerate(self.scheduler.slots)
-            if r is not None and s not in self._chunk_jobs
+            if r is not None and s not in skip
         ]
         for slot in list(active):
             if self.scheduler.slots[slot] is None:
@@ -1173,7 +1172,7 @@ class ServeEngine:
 
         active = [
             s for s, r in enumerate(self.scheduler.slots)
-            if r is not None and s not in self._chunk_jobs
+            if r is not None and s not in skip
         ]
         if active:
             worked = True
@@ -1184,6 +1183,7 @@ class ServeEngine:
                 self._decode_tick(active, ph)
         else:
             self._ahead = None  # every slot it computed for is gone
+            self._fetch_firsts(ph)  # no decode to dispatch before them
         ph.then("housekeep")
         self._refresh_gauges()
         self._maybe_export()
@@ -1199,6 +1199,62 @@ class ServeEngine:
             name, "request",
             **trace_args(child_context(ctx), rid=req.rid, **args),
         ).__enter__()
+
+    def _fetch_firsts(self, ph) -> None:
+        """Fetch and emit the first token of every admission that still
+        waits for it, in the order of their prefills (phases
+        ``admit_wait``, then ``admit_emit``, an admission).  A slot whose
+        request left since its prefill was dispatched (cancelled,
+        preempted) is skipped, as a slot that leaves while a decode is in
+        flight is."""
+        firsts, self._firsts = self._firsts, []
+        sched = self.scheduler
+        for slot, req, first, rph, spent in firsts:
+            if sched.slots[slot] is not req:
+                if rph is not None:
+                    rph.__exit__(None, None, None)
+                continue
+            ph.then("admit_wait", rid=req.rid)
+            t_wait = time.monotonic()
+            # The TTFT sync.  It sits at the admission where the decode
+            # needs the token's value first (the serial loop, a request
+            # with an eos); else behind the dispatch of the decode this
+            # token feeds, so that the device's queue is not empty while
+            # the host waits here.
+            # rlt: noqa[RLT002] deliberate TTFT sync, placed as said above
+            first = int(first)
+            ph.then("admit_emit", rid=req.rid)
+            t_first = time.monotonic()
+            # Per-admission wall in µs (host prep + prefill/import
+            # dispatch + the TTFT sync above).  Paired with the
+            # `admitted` counter it gives the capacity oracle the
+            # once-per-request admission cost its saturation model
+            # charges (serve/capacity.py).
+            self.stats.bump(  # rlt: noqa[RLT002] host float, no device value
+                "admit_us", int((spent + t_first - t_wait) * 1e6))
+            # A decode dispatched behind this prefill starts on the
+            # device no earlier than now.
+            self._fetched_t = t_first
+            if rph is not None:
+                # The int() above synced the device, so this interval
+                # covers dispatch + device compute of the admission.
+                rph.__exit__(None, None, None)
+                self.stats.note_phase(rph.name, rph.dur)
+                rph = self._request_phase(req.trace, "first_token", req,
+                                          token_index=0)
+            self.stats.note_first_token(t_first - req.arrival_t)
+            done = sched.append_token(slot, first, now=t_first)
+            if rph is not None:
+                rph.__exit__(None, None, None)
+                self.stats.note_phase("first_token", rph.dur)
+            self.stats.bump("tokens_out")
+            if req.adapter is not None:
+                self.stats.note_adapter(req.adapter, tokens=1)
+            self._cur_tokens[slot] = first
+            if self.prefix_cache is not None:
+                self._prefix_insert(slot, req)
+            if done:
+                self._complete(slot)
 
     def _tick_widths(self) -> List[int]:
         """Drafted tokens per slot this tick (0 = plain decode)."""
@@ -1416,7 +1472,9 @@ class ServeEngine:
         on the device), and the tick's block counters.  ``tokens`` is
         the last decode's output where it lies on the device, for a tick
         dispatched before that output is fetched; None takes the host's
-        ``_cur_tokens``."""
+        ``_cur_tokens``.  Either way the first token of an admission
+        among ``active`` that is not fetched yet (``_firsts``) is written
+        at its slot on the device (``_feed_fn``)."""
         import jax.numpy as jnp
 
         from ray_lightning_tpu.serve.kv_cache import TRASH_BLOCK
@@ -1444,6 +1502,11 @@ class ServeEngine:
 
         seq_lens = up(sched.seq_lens, 0)
         cur = up(self._cur_tokens) if tokens is None else tokens
+        fed = 0
+        for slot, req, first, *_ in self._firsts:
+            if slot in active and sched.slots[slot] is req:
+                cur = self._feed_fn(cur, np.int32(slot), first)
+                fed += 1
         tables = up(sched.block_tables, TRASH_BLOCK)
         if self.family.two_kind:
             tables = (tables, up(sched.window_tables, TRASH_BLOCK))
@@ -1499,6 +1562,8 @@ class ServeEngine:
                 self.scheduler.seq_lens[active].sum() + len(active))
         if sums:
             kv_blocks["moe_tokens_routed"] = len(active) * self.family.n_sparse
+        if fed:
+            kv_blocks["admit_fed_on_device"] = fed
         held = [(slot, self.scheduler.slots[slot]) for slot in active]
         return t0, held, toks, sums, kv_blocks
 
@@ -1510,12 +1575,22 @@ class ServeEngine:
         A pipelined engine (``_pipelined``) runs one tick ahead of the
         host where the tick's slots allow it: the tick may find its
         decode already dispatched (by the tick before, which then covers
-        the slots of THAT moment: a slot admitted since waits one tick, a
-        slot cancelled, expired or preempted since is skipped), and
-        dispatches the next one on this one's tokens where they lie on
-        the device, before it fetches them; where it cannot know without
-        the tokens that every slot goes on (an ``eos_token_id``, a slot
-        admitted since), after the fetch and before the replies."""
+        the slots of THAT moment: a slot cancelled, expired or preempted
+        since is skipped), and dispatches the next one on this one's
+        tokens where they lie on the device, before it fetches them;
+        where it cannot know without the tokens that every slot goes on
+        (an ``eos_token_id``), after the fetch and before the replies.
+
+        An admission joins that pipeline.  Its prefill was dispatched by
+        ``_step`` and its first token is still on the device
+        (``_firsts``): the next decode is dispatched for the admitted
+        slot too, fed that token there, and only then does the host
+        block, in the device's order: on the tokens of the decode in hand
+        (replies out), then on each first token (``_fetch_firsts``).
+        Where no decode was in hand the prefills come first on the
+        device, and so do their fetches.  So across an admission the
+        device's queue holds the decode behind the prefill before the
+        host waits for either."""
         sched = self.scheduler
         ahead, self._ahead = self._ahead, None
         if ahead is not None:
@@ -1526,6 +1601,9 @@ class ServeEngine:
             else:
                 ahead = None    # every slot it computed for is gone
         t0, _, toks, sums, counts = ahead or self._dispatch_decode(active)
+        if ahead is None:
+            # This decode was fed the first tokens: they are its slots'.
+            self._fetch_firsts(ph)
         # The lengths advance without the tokens: the host knows them.
         for slot in active:
             sched.seq_lens[slot] += 1
@@ -1577,26 +1655,30 @@ class ServeEngine:
                 done = sched.append_token(slot, tok)
                 if done:
                     self._complete(slot)
+        self._fetch_firsts(ph)
 
     def _going_on(self, active: List[int], fetched: bool) -> List[int]:
         """The slots the next decode can be dispatched for now, before
         this tick's tokens (lengths advanced already) are booked: those
         that go on after their token, if nothing waits for a slot it
-        could have and every next position has its block; else none.
+        could have (while no admission's prefill is on the device) and
+        every next position has its block; else none.
         Before the tokens are ``fetched`` into ``_cur_tokens`` an
-        ``eos_token_id`` cannot be tested, and a slot that is not among
-        ``active`` (admitted since the decode in hand was dispatched) has
-        its token on the host only: either holds the tick back until
-        they are."""
+        ``eos_token_id`` cannot be tested: that holds the tick back until
+        they are.  A slot that is not among ``active`` was admitted since
+        the decode in hand was dispatched.  It goes on with the others
+        when its first token still lies on the device (``_firsts``: the
+        decode is fed it there) and is not its last by count; a token
+        that is on the host only (an admission that synced) holds the
+        tick back until the fetch, like an eos."""
         sched = self.scheduler
         if not self._pipelined or not active:
             return []
-        held = [s for s, r in enumerate(sched.slots) if r is not None]
-        if not fetched and len(held) != len(active):
-            return []
         ticked, going = set(active), []
-        for slot in held:
-            req = sched.slots[slot]
+        on_device = {slot: req for slot, req, *_ in self._firsts}
+        for slot, req in enumerate(sched.slots):
+            if req is None:
+                continue
             if slot in ticked:
                 if len(req.generated) + 1 >= req.max_new_tokens:
                     continue    # ends with this tick's token, by count
@@ -1605,12 +1687,23 @@ class ServeEngine:
                         return []
                     if int(self._cur_tokens[slot]) == req.eos_token_id:
                         continue    # ends with this tick's token, at eos
+            elif on_device.get(slot) is req:
+                if req.max_new_tokens <= 1:
+                    continue    # ends with its first token, by count
+            elif not fetched:
+                return []
             going.append(slot)
         if not going:
             return []
-        if len(going) < len(sched.slots) and (sched.queue or (
-                self._inbox is not None and not self._inbox.empty())):
-            return []   # a slot is, or falls, free for what waits
+        if len(going) < len(sched.slots) and not on_device and (
+                sched.queue or (
+                    self._inbox is not None and not self._inbox.empty())):
+            # A slot is, or falls, free for what waits: the next
+            # iteration admits it into the very next decode.  Not while
+            # a prefill of this iteration is on the device: the decode
+            # goes behind it now, and what waits joins a tick later,
+            # rather than leave the device idle behind that prefill.
+            return []
         for slot in going:
             if sched.needs_block(slot) and not sched.grow(slot):
                 return []   # pool dry: the loop's grow phase preempts

@@ -44,6 +44,10 @@ _COUNTER_KEYS = (
     # before's output where it lay on the device.  Reply frames sent
     # (a tick's replies to one address are one frame).
     "decode_ahead", "decode_fed_on_device", "reply_frames",
+    # Of ``prefills`` + ``kv_imports``: admissions whose first token
+    # reached the next decode without leaving the device (fetched by the
+    # host only after that decode was dispatched).
+    "admit_fed_on_device",
 )
 
 

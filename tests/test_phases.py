@@ -238,6 +238,57 @@ def test_a_tick_dispatched_ahead_tiles_its_iteration():
     assert counters["tick_decode_wait_us"] < 0.5e6 * slow_s * 6
 
 
+def test_an_admitting_iteration_tiles_and_waits_in_admit_wait():
+    """An admission's first token is fetched after the dispatch of the
+    decode it feeds: on such an iteration the phases still tile, and a
+    first token that is slow to come (0.2 s each) is booked as
+    ``admit_wait``, not inside ``decode_dispatch`` or ``decode_wait``."""
+    eng = _tiny_engine()
+    eng.generate(list(range(1, 5)), 3)      # compiles outside the phases
+    slow_s = 0.2
+
+    class SlowFirst:
+        def __init__(self, token):
+            self.token = token
+
+        def __int__(self):
+            time.sleep(slow_s)
+            return int(self.token)
+
+    prefill, feed = eng._prefill_fn, eng._feed_fn
+
+    def slow_prefill(*a):
+        first, *rest = prefill(*a)
+        return (SlowFirst(first), *rest)
+
+    eng._prefill_fn = slow_prefill
+    eng._feed_fn = lambda cur, slot, first: feed(cur, slot, first.token)
+    eng.submit(list(range(1, 7)), 12)
+    admitting = []
+    for i in range(50):
+        if i == 3:          # two more while a decode is in flight
+            eng.submit(list(range(2, 9)), 4)
+            eng.submit(list(range(3, 8)), 5)
+        before = dict(eng.stats.counters)
+        eng.step()
+        delta = {k: v - before.get(k, 0)
+                 for k, v in eng.stats.counters.items()}
+        if delta["prefills"]:
+            admitting.append(delta["prefills"])
+            parts = sum(delta[f"tick_{p}_us"] for p in TICK)
+            assert parts == pytest.approx(delta["tick_us"], rel=0.02)
+            waited = 1e6 * slow_s * delta["prefills"]
+            assert 0.9 * waited <= delta["tick_admit_wait_us"] < 1.5 * waited
+            assert delta["tick_decode_dispatch_us"] < 0.5e6 * slow_s
+            assert delta["tick_decode_wait_us"] < 0.5e6 * slow_s
+        if not eng.scheduler.has_work():
+            break
+    assert admitting == [1, 2]
+    counters = eng.stats.counters
+    assert counters["admit_fed_on_device"] == 4 == counters["prefills"]
+    assert counters["admit_us"] >= 0.9e6 * slow_s * 3
+
+
 def test_queue_wait_grows_with_admissions(ticked):
     early, late = ticked
     assert 0 < early["admitted"] < late["admitted"] == 6

@@ -725,9 +725,13 @@ SERIAL = {"coalesce_replies": False, "decode_lookahead": False}
 class TestDecodeLookahead:
     """The decode loop runs one tick ahead of the host: the next decode
     is dispatched on this tick's tokens where they lie on the device,
-    before they are fetched.  What is served does not change, whatever
-    happens to a slot while a decode is in flight; ``SERIAL`` is the
-    reference path."""
+    before they are fetched, and an admission joins that pipeline: its
+    first token feeds the next decode on the device and is fetched after
+    that decode's dispatch.  What is served does not change, whatever
+    happens to a slot while a decode or a prefill is in flight;
+    ``SERIAL`` is the reference path."""
+
+    NEWS = [9, 2, 12, 1, 7, 5, 10]
 
     def _engine(self, model, **kw):
         m, params = model
@@ -742,11 +746,12 @@ class TestDecodeLookahead:
         eng = self._engine(model, **kw)
         prompts = [_rand_prompt(40 + i, 3 + (5 * i) % 11,
                                 m.config.vocab_size) for i in range(7)]
-        news = [9, 2, 12, 1, 7, 5, 10]
+        news = self.NEWS
         handles = [eng.submit(p, n, temperature=temperature)
                    for p, n in zip(prompts, news)]
         eng.run_until_idle()
         assert eng._ahead is None      # nothing left in flight at idle
+        assert eng._firsts == []
         assert eng.stats.counters["tokens_out"] == sum(news)
         return prompts, [h.result(1) for h in handles], eng.stats.counters
 
@@ -767,11 +772,17 @@ class TestDecodeLookahead:
             want = self._served(model, temperature, **SERIAL)[1]
             assert want != greedy
         assert served == want
+        assert c["prefills"] == len(self.NEWS)
         if kw:
             assert c["decode_ahead"] == 0 == c["decode_fed_on_device"]
+            assert c["admit_fed_on_device"] == 0
         else:
             assert (0 < c["decode_fed_on_device"] <= c["decode_ahead"]
                     < c["decode_steps"])
+            # Every first token reached the decode after it on the
+            # device, but the one that was its request's last.
+            assert c["admit_fed_on_device"] == (
+                c["prefills"] - self.NEWS.count(1))
 
     def test_a_slot_ending_by_count_does_not_hold_the_others_back(
             self, model):
@@ -809,7 +820,7 @@ class TestDecodeLookahead:
         c = eng.stats.counters
         # A decode a token after the first, none after the eos.
         assert len(dispatched) == len(cut) - 1 == c["decode_steps"]
-        assert c["decode_fed_on_device"] == 0
+        assert c["decode_fed_on_device"] == 0 == c["admit_fed_on_device"]
         assert c["decode_ahead"] == (c["decode_steps"] - 1 if len(cut) > 2
                                      else 0)
         assert eng._ahead is None
@@ -922,4 +933,221 @@ class TestDecodeLookahead:
             assert h.result(1) == _ref_tokens(m, params, p, n)
         c = eng.stats.counters
         assert c["decode_ahead"] == 0 == c["decode_fed_on_device"]
+        assert c["admit_fed_on_device"] == 0
         assert eng._ahead is None
+
+    # -- an admission in the pipeline -----------------------------------
+
+    def _logged(self, eng, monkeypatch):
+        """The engine's prefills, decodes (with their ``seq_lens``
+        operand) and phases, in the order the loop makes them."""
+        from ray_lightning_tpu.telemetry import spans
+
+        events, lens = [], []
+        prefill, decode, then = (eng._prefill_fn, eng._decode_fn,
+                                 spans._PhaseCtx.then)
+        eng._prefill_fn = lambda *a: events.append("prefill") or prefill(*a)
+
+        def logged_decode(*a):
+            events.append("decode")
+            lens.append(np.asarray(a[3]).tolist())
+            return decode(*a)
+
+        def logged_then(ph, name, **args):
+            events.append(name)
+            return then(ph, name, **args)
+
+        eng._decode_fn = logged_decode
+        monkeypatch.setattr(spans._PhaseCtx, "then", logged_then)
+        return events, lens
+
+    @pytest.mark.parametrize("case", ["ahead", "after_idle", "eos",
+                                      "serial"])
+    def test_order_of_dispatch_on_an_admitting_iteration(
+            self, model, monkeypatch, case):
+        """Prefill, then the decode for residents plus the admitted
+        slot, and only then the host blocks on the first token; an
+        ``eos_token_id`` or the serial loop keep the sync at the
+        admission, before the decode."""
+        m, params = model
+        eng = self._engine(model, **(SERIAL if case == "serial" else {}))
+        p1 = _rand_prompt(91, 5, m.config.vocab_size)
+        p2 = _rand_prompt(92, 7, m.config.vocab_size)
+        want2 = _ref_tokens(m, params, p2, 6)
+        never = next(t for t in range(m.config.vocab_size)
+                     if t not in want2)
+        kw = {"eos_token_id": never} if case == "eos" else {}
+        h1 = None
+        if case != "after_idle":
+            h1 = eng.submit(p1, 12)
+            for _ in range(3):
+                assert eng.step()
+            assert (eng._ahead is not None) == (case != "serial")
+        events, lens = self._logged(eng, monkeypatch)
+        fed_before = eng.stats.counters["admit_fed_on_device"]
+        h2 = eng.submit(p2, 6, **kw)
+        assert eng.step()
+        monkeypatch.undo()
+        ev = [e for e in events if e in (
+            "prefill", "decode", "admit_wait", "decode_wait")]
+        slot2 = 0 if case == "after_idle" else 1
+        if case == "ahead":
+            # The decode in hand was dispatched an iteration earlier.
+            assert ev == ["prefill", "decode", "decode_wait", "admit_wait"]
+            assert lens[0][slot2] == len(p2) and lens[0][0] > 0
+        elif case == "after_idle":
+            # No decode in hand: the prefill is first on the device, so
+            # its fetch is first, behind the dispatch of its decode.
+            assert ev == ["prefill", "decode", "admit_wait", "decode",
+                          "decode_wait"]
+            assert lens[0][slot2] == len(p2)
+        elif case == "eos":
+            assert ev == ["prefill", "admit_wait", "decode_wait", "decode"]
+        else:
+            assert ev == ["prefill", "admit_wait", "decode", "decode_wait"]
+        c = eng.stats.counters
+        eng.run_until_idle()
+        assert h2.result(1) == want2
+        if h1 is not None:
+            assert h1.result(1) == _ref_tokens(m, params, p1, 12)
+        fed = case in ("ahead", "after_idle")
+        assert c["admit_fed_on_device"] - fed_before == (1 if fed else 0)
+        assert c["prefills"] == (1 if h1 is None else 2)
+
+    @pytest.mark.parametrize("resident", [False, True],
+                             ids=["alone", "beside_a_resident"])
+    def test_a_request_of_one_token_is_never_decoded_for(
+            self, model, monkeypatch, resident):
+        m, params = model
+        eng = self._engine(model)
+        p1 = _rand_prompt(93, 5, m.config.vocab_size)
+        p2 = _rand_prompt(94, 6, m.config.vocab_size)
+        h1 = None
+        if resident:
+            h1 = eng.submit(p1, 8)
+            while eng._ahead is None:
+                assert eng.step()
+        events, lens = self._logged(eng, monkeypatch)
+        fed_before = eng.stats.counters["admit_fed_on_device"]
+        h2 = eng.submit(p2, 1)
+        assert eng.step()
+        assert h2.done() and h2.result(1) == _ref_tokens(m, params, p2, 1)
+        eng.run_until_idle()
+        monkeypatch.undo()
+        if resident:
+            assert h1.result(1) == _ref_tokens(m, params, p1, 8)
+            # Computed as the empty slot it was about to be, every time.
+            assert lens and all(row[1] == 0 for row in lens)
+        else:
+            assert "decode" not in events and "admit_wait" in events
+        assert eng.stats.counters["admit_fed_on_device"] == fed_before
+        assert eng.scheduler.active_slots == 0
+
+    @pytest.mark.parametrize("how", ["cancelled", "preempted",
+                                     "deadline_passed"])
+    def test_slot_leaves_between_its_prefill_and_its_first_token(
+            self, model, how):
+        """What happens to an admitted request while its first token is
+        still on the device: a cancel or a preemption is a slot that
+        left (skipped at the fetch, nothing emitted), and a deadline
+        that passes there was met at admission."""
+        m, params = model
+        eng = self._engine(model)
+        p1 = _rand_prompt(95, 5, m.config.vocab_size)
+        p2 = _rand_prompt(96, 7, m.config.vocab_size)
+        h1 = eng.submit(p1, 10)
+        while eng._ahead is None:
+            assert eng.step()
+        prefill, seen = eng._prefill_fn, []
+
+        def between(*a):
+            out = prefill(*a)
+            if not seen:
+                seen.append(1)
+                if how == "cancelled":
+                    assert eng.cancel(h2.rid)
+                elif how == "preempted":
+                    assert eng.scheduler.preempt_youngest() is h2.request
+                else:
+                    time.sleep(0.06)
+            return out
+
+        eng._prefill_fn = between
+        emitted = []
+        h2 = eng.submit(p2, 6, on_token=lambda i, t: emitted.append(i),
+                        **({"deadline_s": 0.05}
+                           if how == "deadline_passed" else {}))
+        assert eng.step() and seen
+        if how == "deadline_passed":
+            assert emitted == [0]
+        else:
+            assert emitted == [] and eng._firsts == []
+        eng.run_until_idle()
+        c = eng.stats.counters
+        assert h1.result(1) == _ref_tokens(m, params, p1, 10)
+        if how == "cancelled":
+            assert h2.done() and h2.tokens == [] and c["cancelled"] == 1
+        else:
+            assert h2.result(1) == _ref_tokens(m, params, p2, 6)
+            assert emitted == list(range(6)) and c["expired"] == 0
+            assert h2.request.preemptions == (how == "preempted")
+        assert eng._ahead is None and eng.scheduler.active_slots == 0
+
+    def test_two_admissions_in_one_iteration(self, model):
+        """Their prefills queue one behind the other, one decode takes
+        both first tokens on the device, and the scatter that writes
+        them compiled once, with the first admission ever."""
+        m, params = model
+        eng = self._engine(model)
+        prompts = [_rand_prompt(97 + i, 4 + i, m.config.vocab_size)
+                   for i in range(3)]      # one bucket
+        h1 = eng.submit(prompts[0], 14)
+        while eng._ahead is None:
+            assert eng.step()
+        feeds, feed = [], eng._feed_fn
+        eng._feed_fn = lambda *a: feeds.append(int(a[1])) or feed(*a)
+        compiles = compile_event_count()
+        h2, h3 = eng.submit(prompts[1], 5), eng.submit(prompts[2], 7)
+        assert eng.step()
+        assert sorted(feeds) == [1, 2]
+        assert {s for s, _ in eng._ahead[1]} == {0, 1, 2}
+        assert len(h2.tokens) == 1 == len(h3.tokens)
+        eng.run_until_idle()
+        assert compile_event_count() == compiles
+        for h, p, n in zip((h1, h2, h3), prompts, (14, 5, 7)):
+            assert h.result(1) == _ref_tokens(m, params, p, n)
+        c = eng.stats.counters
+        assert c["admit_fed_on_device"] == 3 == c["prefills"]
+
+    @pytest.mark.parametrize("admitting", [False, True])
+    def test_what_waits_holds_the_decode_back_unless_a_prefill_is_queued(
+            self, model, admitting):
+        """A request waits for the slot that falls free with this tick:
+        the decode is held back so that the next iteration admits it
+        into the very next one.  Not on an iteration that admits: its
+        prefill is on the device, the decode goes behind it now, and
+        what waits joins a tick later."""
+        m, params = model
+        eng = ServeEngine(m, params, ServeConfig(
+            num_slots=3 if admitting else 2, block_size=8))
+        prompts = [_rand_prompt(101 + i, 4 + i, m.config.vocab_size)
+                   for i in range(4)]
+        news = [12, 3, 6, 5]
+        handles = [eng.submit(p, n) for p, n in zip(prompts[:2], news)]
+        assert eng.step() and eng._ahead is not None
+        # The second request ends, by count, with the tick in hand.
+        if admitting:
+            handles.append(eng.submit(prompts[2], news[2]))
+        handles.append(eng.submit(prompts[3], news[3]))     # waits
+        fed = eng.stats.counters["admit_fed_on_device"]
+        assert eng.step() and eng.scheduler.queue_depth == 1
+        if admitting:
+            assert {s for s, _ in eng._ahead[1]} == {0, 2}
+        else:
+            assert eng._ahead is None
+        eng.run_until_idle()
+        assert eng.stats.counters["admit_fed_on_device"] - fed == (
+            2 if admitting else 1)
+        for h, p in zip(handles, [0, 1, 2, 3] if admitting else [0, 1, 3]):
+            assert h.result(1) == _ref_tokens(m, params, prompts[p],
+                                              news[p])

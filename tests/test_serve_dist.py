@@ -797,6 +797,11 @@ class TestHandoffAdmission:
             assert done[0]["tokens"] == ref[0]
             assert eng.stats.counters["kv_imports"] == 1
             assert eng.stats.counters["prefills"] == 0
+            # An imported admission joins the decode loop's pipeline as
+            # a local prefill does: its first token is sampled on the
+            # device and feeds the decode there.
+            assert eng._pipelined
+            assert eng.stats.counters["admit_fed_on_device"] == 1
         finally:
             eng.stop()
             replies.shutdown()
