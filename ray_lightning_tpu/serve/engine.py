@@ -48,10 +48,13 @@ import numpy as np
 from ray_lightning_tpu.fault.inject import (
     FaultBlackhole, FaultInjected, fire as _fault_fire, set_member,
 )
+from ray_lightning_tpu.serve.metrics import LoopWatch, ServeStats
 from ray_lightning_tpu.telemetry.propagate import (
     child_context, trace_args,
 )
+from ray_lightning_tpu.telemetry.runtime import TelemetryConfig
 from ray_lightning_tpu.telemetry.spans import SpanTracer, phase
+from ray_lightning_tpu.telemetry.step_stats import compile_event_count
 
 __all__ = ["ServeConfig", "ServeEngine", "ServeHandle", "ServeRejected"]
 
@@ -230,7 +233,6 @@ class ServeEngine:
         from ray_lightning_tpu.serve.kv_cache import (
             GPTServeFamily, PagedKVCache,
         )
-        from ray_lightning_tpu.serve.metrics import ServeStats
         from ray_lightning_tpu.serve.scheduler import (
             Scheduler, derive_geometry,
         )
@@ -489,7 +491,28 @@ class ServeEngine:
             enabled=trace_dir is not None, maxlen=16384, rank=0,
             clock=time.time,
         )
-        self._tick_us: Dict[str, int] = {}  # this iteration's phases
+        self._tick_us: Dict[str, int] = {}  # this turn's phases
+        # The loop's turns chain: ``between`` is opened on the clock read
+        # that closes a turn and closed on the one that opens the next
+        # (``_open_turn`` / ``_close_turn``), so the phases tile the
+        # thread's wall; None before the first turn and after the loop
+        # was left (``_drop_between``).  With it the thread that closed
+        # the turn, and the engine's facts of the turn in hand for a
+        # stall's record: active slots, the admissions' buckets, the
+        # decode tick's counts.
+        self._between = None
+        self._turn_thread = 0
+        self._turn_slots = 0
+        self._turn_buckets: Sequence[int] = ()
+        self._turn_counts: Dict[str, int] = {}
+        self._compiles_seen = compile_event_count()   # arms the listener
+        # What the loop's thread was doing, and a record of every turn
+        # that stalled (``serve/metrics.py`` ``LoopWatch``): at every
+        # telemetry tier but ``off`` (``RLT_TELEMETRY``, as a fit reads
+        # it).
+        self._watch = None
+        if TelemetryConfig.coerce(None).tier != "off":
+            self._watch = LoopWatch()
         self._build_programs()
 
         self._handles: Dict[str, ServeHandle] = {}  # guarded by self._lock
@@ -656,9 +679,12 @@ class ServeEngine:
         # One python callable; XLA compiles one executable per bucket
         # length (tokens/block_ids shapes) — the bucketed prefill set
         # lands in the program ledger as one site with a variant per
-        # bucket.
+        # bucket, each lowered under a name that carries its bucket
+        # (``jit__prefill_b<bucket>`` on a device trace).
         self._prefill_fn = ledgered_jit(
-            _prefill, site="serve/prefill", donate_argnums=donate
+            _prefill, site="serve/prefill", donate_argnums=donate,
+            name_of=lambda params, pool, tokens, *_:
+                f"_prefill_b{tokens.shape[0]}",
         )
         # Disaggregated KV import: one executable per bucket block
         # count (block_ids shape), mirroring the prefill set — fleet
@@ -950,16 +976,15 @@ class ServeEngine:
 
         The iteration is cut into the ``PHASES["serve"]`` phases
         (``telemetry/spans.py``), chained on single clock reads so they
-        tile it: each is a profiler annotation ``rlt:serve/<phase>``
-        and a counter ``tick_<phase>_us``, summed locally and handed to
-        the stats under one lock at the end."""
-        t0 = time.perf_counter()
-        ph = self._tick_phase("inbox").__enter__()
+        tile it, and with ``between`` the time since the turn before:
+        each is a profiler annotation ``rlt:serve/<phase>`` and a
+        counter ``tick_<phase>_us``, summed locally and handed to the
+        stats under one lock at the end."""
+        ph, t0 = self._open_turn("inbox")
         try:
             return self._step(ph)
         finally:
-            ph.__exit__(None, None, None)
-            self._flush_tick(time.perf_counter() - t0, ticks=1)
+            self._close_turn(ph, t0, ticks=1)
 
     def _tick_phase(self, name: str):
         """A phase of the loop's own (``PHASES["serve"]``).  It is a
@@ -972,12 +997,60 @@ class ServeEngine:
         return (self.tracer.phase if busy else phase)(
             name, "serve", self._tick_us, "tick_")
 
-    def _flush_tick(self, wall_s: float, ticks: int = 0) -> None:
-        tick = self._tick_us
-        tick["tick_us"] = round(wall_s * 1e6)
+    def _open_turn(self, name: str):
+        """Open a turn of the loop (an iteration, or an idle sleep) in
+        its first phase ``name``, on the clock read that closes the
+        ``between`` since the turn before, which this turn counts as its
+        own.  Returns the entered phase and the turn's start."""
+        if self._between is not None \
+                and self._turn_thread != threading.get_ident():
+            self._drop_between()    # another thread's turn: no chain
+        prev, self._between = self._between, None
+        ph = self._tick_phase(name)
+        if prev is not None:
+            return ph.after(prev), prev.t0
+        if self._watch is not None:
+            self._watch.rebase()
+        ph.__enter__()
+        return ph, ph.t0
+
+    def _close_turn(self, ph, t0: float, ticks: int) -> None:
+        """Close a turn on the clock read that opens ``between``, and
+        hand its phases to the stats under one lock, with what its
+        thread was doing and, where it stalled, its record."""
+        tick, self._tick_us = self._tick_us, {}
+        self._between = self._tick_phase("between").after(ph)
+        self._turn_thread = threading.get_ident()
+        tick["tick_us"] = wall_us = round((self._between.t0 - t0) * 1e6)
         tick["ticks"] = ticks
-        self.stats.bump_many(tick)
-        tick.clear()
+        stall = None
+        if self._watch is not None:
+            compiles = compile_event_count()
+            counts = self._turn_counts
+            stall = self._watch.turn(
+                tick, wall_us, compiles != self._compiles_seen,
+                self._turn_slots, self._turn_buckets,
+                "decode_ahead" in counts, "decode_fed_on_device" in counts)
+            self._compiles_seen = compiles
+            self._turn_slots, self._turn_buckets = 0, ()
+            self._turn_counts = {}
+        self.stats.bump_many(tick, stall)
+        if stall is not None:
+            # Where a trace holds the stall it shows where it ended; at
+            # tier ``full`` the record is a span in the requests' ring.
+            with phase("stall", "serve", wall_us=wall_us,
+                       phase=stall["phase"], verdict=stall["verdict"]):
+                pass
+            self.tracer.record("stall", stall["t_ns"] / 1e9, wall_us / 1e6,
+                               args=stall)
+
+    def _drop_between(self) -> None:
+        """Leave the loop: the ``between`` open since the last turn is
+        closed and not counted (the next turn starts its own clock)."""
+        prev, self._between = self._between, None
+        if prev is not None:
+            prev.__exit__(None, None, None)
+            self._tick_us = {}
 
     def _step(self, ph) -> bool:
         import jax.numpy as jnp
@@ -995,6 +1068,8 @@ class ServeEngine:
                 self._prefix_drops.clear()
             admissions, expired = self.scheduler.poll()
         worked = bool(admissions) or bool(expired)
+        if admissions:
+            self._turn_buckets = [bucket for _, _, bucket in admissions]
         for req in expired:
             self.stats.bump("expired")
             self._finish_handle(req)
@@ -1077,7 +1152,9 @@ class ServeEngine:
                     np.int32(req.sample_seed), np.int32(req.top_k or 0),
                 )
             elif bucket != 0:
-                self.stats.bump("prefills")
+                self.stats.bump_many({
+                    "prefills": 1, "prefill_bucket_positions": bucket,
+                    "prefill_prompt_positions": req.prompt_len})
                 ad = None if self.adapters is None \
                     else self.adapters.buffers
                 ad_id = None if self.adapters is None \
@@ -1176,6 +1253,7 @@ class ServeEngine:
         ]
         if active:
             worked = True
+            self._turn_slots = len(active)
             ph.then("decode_dispatch", slots=len(active))
             if any(widths[s] > 0 for s in active):
                 self._spec_tick(active, widths, ph)
@@ -1355,7 +1433,9 @@ class ServeEngine:
                 self.draft_params, self._draft_pool, table_row,
                 start_arr, tokens, limit,
             )
-        self.stats.bump("prefill_chunks")
+        self.stats.bump_many({
+            "prefill_chunks": 1, "prefill_bucket_positions": width,
+            "prefill_prompt_positions": suffix})
         return tok
 
     def _start_chunk_job(self, slot: int, req) -> None:
@@ -1427,7 +1507,9 @@ class ServeEngine:
                     self.draft_params, self._draft_pool, table_row,
                     start_arr, tokens, limit,
                 )
-            self.stats.bump("prefill_chunks")
+            self.stats.bump_many({
+                "prefill_chunks": 1, "prefill_bucket_positions": width,
+                "prefill_prompt_positions": end - start})
             job.next_pos = end
             worked = True
             if not last:
@@ -1601,6 +1683,7 @@ class ServeEngine:
             else:
                 ahead = None    # every slot it computed for is gone
         t0, _, toks, sums, counts = ahead or self._dispatch_decode(active)
+        self._turn_counts = counts
         if ahead is None:
             # This decode was fed the first tokens: they are its slots'.
             self._fetch_firsts(ph)
@@ -1845,10 +1928,13 @@ class ServeEngine:
 
     def run_until_idle(self, max_steps: int = 1_000_000) -> None:
         """Drive the loop synchronously until queue and slots drain."""
-        for _ in range(max_steps):
-            self.step()
-            if not self.scheduler.has_work():
-                return
+        try:
+            for _ in range(max_steps):
+                self.step()
+                if not self.scheduler.has_work():
+                    return
+        finally:
+            self._drop_between()
         raise RuntimeError(f"still busy after {max_steps} serve steps")
 
     def _complete(self, slot: int) -> None:
@@ -1918,7 +2004,6 @@ class ServeEngine:
                 # replace means the resident chain no longer matches
                 # the factors a future claim would decode through.
                 self._prefix_drops.append(name)
-        self.stats.bump("adapter_loads")
         return slot
 
     def remove_adapter(self, name: str) -> None:
@@ -1939,7 +2024,6 @@ class ServeEngine:
             self.adapters.remove(name)
             if self.prefix_cache is not None:
                 self._prefix_drops.append(name)
-        self.stats.bump("adapter_unloads")
 
     def adapter_names(self) -> List[str]:
         """Loaded tenant names (the replica beat advertises these for
@@ -2011,17 +2095,22 @@ class ServeEngine:
             # replica:-pinned faults fire here, not on whichever member
             # thread registered last (inproc fleets share one process).
             set_member(*self.fault_member)
-        while not self._stop.is_set():
-            try:
-                worked = self.step()
-            except Exception as e:  # noqa: BLE001 - a dying loop must
-                # fail its pending work loudly, never strand it
-                self._fail_pending(e)
-                return
-            if not worked:
-                with self._tick_phase("idle") as ph:
-                    time.sleep(self.config.idle_wait_s)
-                self._flush_tick(ph.dur)
+        try:
+            while not self._stop.is_set():
+                try:
+                    worked = self.step()
+                except Exception as e:  # noqa: BLE001 - a dying loop must
+                    # fail its pending work loudly, never strand it
+                    self._fail_pending(e)
+                    return
+                if not worked:
+                    ph, t0 = self._open_turn("idle")
+                    try:
+                        time.sleep(self.config.idle_wait_s)
+                    finally:
+                        self._close_turn(ph, t0, ticks=0)
+        finally:
+            self._drop_between()
 
     def _fail_pending(self, exc: BaseException) -> None:
         """The serve loop died: mark the engine dead (submit() refuses
@@ -2122,6 +2211,8 @@ class ServeEngine:
         if self._thread is not None:
             self._thread.join(timeout=30)
             self._thread = None
+        if self._watch is not None:
+            self._watch.close()     # the collector hook
         if self.prefix_cache is not None:
             self.prefix_cache.drop_all()
         if self._inbox is not None:
@@ -2434,9 +2525,7 @@ class ServeEngine:
             )
             adopted = False
         if adopted:
-            self.stats.bump("migrations_in")
             return
-        self.stats.bump("migration_fallbacks")
         try:
             handle = self.submit(
                 fields["prompt"], int(fields["max_new_tokens"]),

@@ -14,13 +14,37 @@ exporter parse these dicts long after this producer moves on.
 
 from __future__ import annotations
 
+import gc
+import logging
+import resource
 import threading
 import time
-from typing import Dict, List, Optional
+import weakref
+from typing import Any, Dict, List, Optional, Sequence
 
 from ray_lightning_tpu.telemetry.spans import PHASES
 
-__all__ = ["ServeStats", "percentile"]
+__all__ = ["LoopWatch", "ServeStats", "percentile"]
+
+# A stalled iteration of the serve loop (``LoopWatch``;
+# docs/OBSERVABILITY.md "The loop stalled").  Its host part (wall less
+# ``idle``, ``decode_wait`` and ``admit_wait``) a dispatch (the decode
+# and each admission: a burst of 31 admissions is 32 times the work,
+# not a stall) is over STALL_HOST_US and STALL_FACTOR times its running
+# median, or one of its two waits on the device (``admit_wait`` an
+# admission) is over STALL_WAIT_US and STALL_FACTOR times that wait's
+# running median.  STALL_STEP is how far
+# one iteration moves a running median (up when above it, down when
+# below: an outlier moves it no further than any other reading).
+STALL_HOST_US = 100_000
+STALL_WAIT_US = 1_000_000
+STALL_FACTOR = 8
+STALL_STEP = 1 / 16
+# How many records a ``ServeStats`` keeps: the longest by ``wall_us``.
+STALLS_KEPT = 8
+
+_PHASE_KEYS = frozenset(f"tick_{p}_us" for p in PHASES["serve"])
+_RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)   # Linux
 
 # Newest-N window per latency family.  4096 tokens at serving rates is
 # minutes of traffic — enough for a stable p99, small enough to forget.
@@ -48,6 +72,17 @@ _COUNTER_KEYS = (
     # reached the next decode without leaving the device (fetched by the
     # host only after that decode was dispatched).
     "admit_fed_on_device",
+    # Positions the prefill programs computed (a bucket, a suffix window
+    # or a chunk each) and the prompt positions among them.
+    "prefill_bucket_positions", "prefill_prompt_positions",
+    # What the loop's thread was doing (``LoopWatch``; every tier but
+    # ``off``), window sums like the phases: the thread on a CPU, its
+    # involuntary and voluntary context switches, the process's
+    # collector pauses (all, and generation 2), and the iterations that
+    # stalled with their wall.
+    "tick_cpu_us", "tick_invol_switches", "tick_vol_switches",
+    "gc_us", "gc_gen2_us", "gc_collections",
+    "ticks_stalled", "tick_stalled_us",
 )
 
 
@@ -99,6 +134,164 @@ class _Reservoir:
         }
 
 
+def _gc_hook(cell: List[int]):
+    """A ``gc.callbacks`` entry that sums the collector's pauses into
+    ``cell``: microseconds, microseconds of generation 2, collections,
+    and the start of the one in progress (collections do not nest)."""
+    def hook(phase: str, info: Dict[str, int]) -> None:
+        now = time.perf_counter_ns()
+        if phase == "start":
+            cell[3] = now
+        elif cell[3]:
+            us = (now - cell[3]) // 1000
+            cell[0] += us
+            if info.get("generation") == 2:
+                cell[1] += us
+            cell[2] += 1
+            cell[3] = 0
+    return hook
+
+
+def _uninstall(hook) -> None:
+    try:
+        gc.callbacks.remove(hook)
+    except ValueError:
+        pass
+
+
+def stall_verdict(wall_us: int, cpu_us: int, gc_us: int,
+                  invol_switches: int) -> str:
+    """In a word, what held a stalled iteration: ``collector`` (the
+    collector's pauses are over half its wall), ``running`` (its thread
+    was on a CPU for over half of it: the loop's own Python), else
+    ``preempted`` (the thread was switched out while it could run: the
+    host's scheduler), else ``blocked`` (it waited, switched out of its
+    own accord: a lock, the interpreter's among them, a socket, the
+    device)."""
+    if 2 * gc_us > wall_us:
+        return "collector"
+    if 2 * cpu_us > wall_us:
+        return "running"
+    return "preempted" if invol_switches else "blocked"
+
+
+class LoopWatch:
+    """What the serve loop's thread was doing, a turn at a time.
+
+    The engine closes every turn of its loop (an iteration with the
+    ``between`` before it, or an idle sleep) through :meth:`turn`, on
+    the clock read that opens the next.  That adds to the turn's
+    counters the thread's CPU time and context switches
+    (``time.thread_time_ns``, ``getrusage(RUSAGE_THREAD)``) and the
+    process's collector pauses (one ``gc.callbacks`` hook, installed
+    here and removed by :meth:`close` or with the object), and returns
+    the turn's record when it stalled (the module's constants say
+    when).  A turn in which the process compiled a program is never a
+    stall and moves no median: the program ledger names it already.
+    """
+
+    def __init__(self):
+        self._gc: List[int] = [0, 0, 0, 0]
+        hook = _gc_hook(self._gc)
+        gc.callbacks.append(hook)
+        self.close = weakref.finalize(self, _uninstall, hook)
+        # Running medians of the host part a dispatch, ``decode_wait``
+        # and ``admit_wait`` an admission, microseconds (None: no
+        # reading).
+        self._med: List[Optional[float]] = [None, None, None]
+        self._nv = self._niv = 0
+        self.rebase()
+
+    def rebase(self) -> None:
+        """Start from this thread's readings as they are now (a turn
+        that does not follow another on the same thread)."""
+        self._cpu_ns = time.thread_time_ns()
+        if _RUSAGE_THREAD is not None:
+            ru = resource.getrusage(_RUSAGE_THREAD)
+            self._nv, self._niv = ru.ru_nvcsw, ru.ru_nivcsw
+        self._gc_seen = tuple(self._gc[:3])
+        self._t_ns = time.time_ns()
+        self._prev: Optional[tuple] = None      # the turn before
+
+    def _over(self, i: int, x: float, floor: int) -> bool:
+        """Whether ``x`` is over ``floor`` and STALL_FACTOR times its
+        running median; the reading then moves the median."""
+        med = self._med[i]
+        if med is None:
+            self._med[i] = max(x, 1.0)
+            return False
+        self._med[i] = max(
+            med * (1 + STALL_STEP if x > med else 1 - STALL_STEP), 1.0)
+        return x > floor and x > STALL_FACTOR * med
+
+    def turn(self, tick: Dict[str, int], wall_us: int, compiled: bool,
+             slots: int, buckets: Sequence[int], ahead: bool,
+             fed: bool) -> Optional[Dict[str, Any]]:
+        """Close a turn whose phases are in ``tick``: add what the
+        thread did to ``tick`` and return the turn's record if it
+        stalled.  ``slots``, ``buckets`` (one an admission), ``ahead``
+        and ``fed`` are the engine's facts of the turn, kept for the
+        record."""
+        cpu_ns = time.thread_time_ns()
+        nv, niv = self._nv, self._niv
+        if _RUSAGE_THREAD is not None:
+            ru = resource.getrusage(_RUSAGE_THREAD)
+            nv, niv = ru.ru_nvcsw, ru.ru_nivcsw
+        gc_now = tuple(self._gc[:3])
+        t_ns = time.time_ns()
+        cpu_us = (cpu_ns - self._cpu_ns) // 1000
+        gc_us = gc_now[0] - self._gc_seen[0]
+        invol, vol = niv - self._niv, nv - self._nv
+        now = (self._t_ns, wall_us, tick, cpu_us, gc_us, invol, vol,
+               slots, buckets, ahead, fed)
+        tick["tick_cpu_us"] = cpu_us
+        tick["tick_invol_switches"] = invol
+        tick["tick_vol_switches"] = vol
+        if gc_now[2] != self._gc_seen[2]:
+            tick["gc_us"] = gc_us
+            tick["gc_gen2_us"] = gc_now[1] - self._gc_seen[1]
+            tick["gc_collections"] = gc_now[2] - self._gc_seen[2]
+        self._cpu_ns, self._nv, self._niv = cpu_ns, nv, niv
+        self._gc_seen, self._t_ns = gc_now, t_ns
+        prev, self._prev = self._prev, now
+        if compiled:
+            return None
+        get = tick.get
+        decode_wait = get("tick_decode_wait_us", 0)
+        admit_wait = get("tick_admit_wait_us", 0)
+        host = wall_us - get("tick_idle_us", 0) - decode_wait - admit_wait
+        stalled = self._over(0, host / (1 + len(buckets)), STALL_HOST_US)
+        if decode_wait:
+            stalled |= self._over(1, decode_wait, STALL_WAIT_US)
+        if admit_wait:
+            stalled |= self._over(
+                2, admit_wait / max(len(buckets), 1), STALL_WAIT_US)
+        if not stalled:
+            return None
+        tick["ticks_stalled"] = 1
+        tick["tick_stalled_us"] = wall_us
+        record = _stall_fields(now)
+        phases = record["phases"]
+        record["phase"] = max(phases, key=phases.get)[5:-3]
+        record["verdict"] = stall_verdict(wall_us, cpu_us, gc_us, invol)
+        if prev is not None:
+            record["before"] = _stall_fields(prev)
+        return record
+
+
+def _stall_fields(turn: tuple) -> Dict[str, Any]:
+    (t_ns, wall_us, tick, cpu_us, gc_us, invol, vol, slots, buckets,
+     ahead, fed) = turn
+    return {
+        "t_ns": t_ns, "wall_us": wall_us,
+        "phases": {k: v for k, v in tick.items() if k in _PHASE_KEYS},
+        "cpu_us": cpu_us, "gc_us": gc_us,
+        "invol_switches": invol, "vol_switches": vol,
+        "slots": slots, "buckets": list(buckets),
+        "ahead": bool(ahead), "fed": bool(fed),
+    }
+
+
 class ServeStats:
     """Thread-safe counters + latency reservoirs + gauges.
 
@@ -127,19 +320,39 @@ class ServeStats:
         # without the cache keep snapshots byte-identical to pre-cache
         # rounds (same contract as phases/adapters above).
         self._prefix: Optional[Dict[str, float]] = None
+        # The STALLS_KEPT longest stalled iterations (``LoopWatch``
+        # records), longest first; empty on a run without one, so its
+        # snapshots stay as they were.
+        self._stalls: List[Dict[str, Any]] = []
         self.gauges: Dict[str, float] = {}
 
     def bump(self, name: str, n: int = 1) -> None:
         with self._lock:
             self.counters[name] = self.counters.get(name, 0) + n
 
-    def bump_many(self, deltas: Dict[str, int]) -> None:
+    def bump_many(self, deltas: Dict[str, int],
+                  stall: Optional[Dict[str, Any]] = None) -> None:
         """Several counters under one lock hold (the engine's phases of
-        one tick)."""
+        one tick), and the tick's record where it stalled: logged once,
+        kept while among the longest."""
         with self._lock:
             counters = self.counters
             for name, n in deltas.items():
                 counters[name] = counters.get(name, 0) + n
+            if stall is not None:
+                self._stalls.append(stall)
+                self._stalls.sort(key=lambda r: -r["wall_us"])
+                del self._stalls[STALLS_KEPT:]
+        if stall is not None:
+            logging.getLogger(__name__).warning(
+                "serve loop stalled: %.3f s, longest in %s (%.3f s), "
+                "verdict %s (thread on a CPU %.3f s, collector %.3f s, "
+                "%d involuntary and %d voluntary switches); record: %s",
+                stall["wall_us"] / 1e6, stall["phase"],
+                stall["phases"][f"tick_{stall['phase']}_us"] / 1e6,
+                stall["verdict"], stall["cpu_us"] / 1e6,
+                stall["gc_us"] / 1e6, stall["invol_switches"],
+                stall["vol_switches"], stall)
 
     def note_admitted(self, wait_s: float,
                       since_receipt_s: Optional[float] = None) -> None:
@@ -280,4 +493,6 @@ class ServeStats:
                 }
             if self._prefix is not None:  # prefix-cache engines only
                 out["prefix"] = dict(self._prefix)
+            if self._stalls:  # a run that met a stall only
+                out["stalls"] = [dict(r) for r in self._stalls]
             return out

@@ -49,6 +49,7 @@ read :func:`snapshot` from jax-free processes.
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import threading
@@ -444,11 +445,19 @@ class LedgeredFunction:
     def __init__(self, fn: Callable, site: str,
                  registry: Optional[ProgramLedger] = None,
                  arg_names: Optional[Sequence[str]] = None,
+                 name_of: Optional[Callable[..., str]] = None,
                  **jit_kwargs: Any):
         import jax
 
         self._fn = fn
         self.site = site
+        # ``name_of(*args)``: the name a variant's program is lowered
+        # under (``jit_<name>`` on a device trace), for a site whose
+        # variants are worth telling apart there; else all carry
+        # ``fn``'s.  One jit a name, made when the name is first seen.
+        self._name_of = name_of
+        self._jit_kwargs = jit_kwargs
+        self._named: Dict[str, Any] = {}
         self._ledger = registry if registry is not None else _GLOBAL
         donate = jit_kwargs.get("donate_argnums", ())
         if isinstance(donate, int):
@@ -476,7 +485,27 @@ class LedgeredFunction:
     def lower(self, *args: Any, **kwargs: Any):
         """Pass through to the underlying jit's ``lower`` (warm-compile
         paths use it)."""
-        return self._jit.lower(*args, **kwargs)
+        return self._jit_for(args).lower(*args, **kwargs)
+
+    def _jit_for(self, args: Tuple[Any, ...]):
+        """The jit that lowers these arguments: the site's, or with
+        ``name_of`` the one of the name it gives them."""
+        if self._name_of is None:
+            return self._jit
+        name = self._name_of(*args)
+        jit = self._named.get(name)
+        if jit is None:
+            import jax
+
+            fn = self._fn
+
+            @functools.wraps(fn)
+            def named(*a: Any, **kw: Any):
+                return fn(*a, **kw)
+
+            named.__name__ = named.__qualname__ = name
+            jit = self._named[name] = jax.jit(named, **self._jit_kwargs)
+        return jit
 
     # -- dispatch ------------------------------------------------------------
     def __call__(self, *args: Any, **kwargs: Any):
@@ -564,7 +593,7 @@ class LedgeredFunction:
         # A compile inside a profiled window is a named span on the
         # trace's clock (``rlt:compile``, ``site=``), not only a count.
         with phase("compile", site=self.site) as ph:
-            compiled = self._jit.lower(*args, **kwargs).compile()
+            compiled = self._jit_for(args).lower(*args, **kwargs).compile()
         compile_s = ph.dur
         cost = _cost_dict(compiled)
         record = ProgramRecord(
@@ -624,19 +653,25 @@ def _enabled() -> bool:
 
 def ledgered_jit(fn: Callable, *, site: str,
                  arg_names: Optional[Sequence[str]] = None,
+                 name_of: Optional[Callable[..., str]] = None,
                  **jit_kwargs: Any) -> Callable:
     """Drop-in for ``jax.jit(fn, **jit_kwargs)`` that registers the
     call site with the process ledger.  ``site`` names the program in
     every surface (snapshot rows, recompile attributions,
-    ``rlt_program_*`` metrics, the rlt_top pane).
+    ``rlt_program_*`` metrics, the rlt_top pane).  ``name_of(*args)``
+    names each variant's lowered module (the engine's prefill: a name a
+    bucket), so that a device trace tells the variants of one site
+    apart; the site stays one.
 
     ``RLT_PROGRAM_LEDGER=0`` disables the observatory entirely and
-    returns a bare ``jax.jit`` — the overhead-A/B baseline."""
+    returns a bare ``jax.jit`` — the overhead-A/B baseline (every
+    variant then carries ``fn``'s name)."""
     if not _enabled():
         import jax
 
         return jax.jit(fn, **jit_kwargs)
-    return LedgeredFunction(fn, site, arg_names=arg_names, **jit_kwargs)
+    return LedgeredFunction(fn, site, arg_names=arg_names, name_of=name_of,
+                            **jit_kwargs)
 
 
 def hlo_text(site: str) -> Optional[str]:

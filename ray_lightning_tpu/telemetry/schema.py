@@ -601,7 +601,25 @@ _SERVE_SNAPSHOT_OPTIONAL = {
     # the headroom oracle's latest capacity_snapshot, so beats carry
     # it to the router for free).
     "capacity": dict,
+    # Only once an iteration of the loop stalled (serve/metrics.py
+    # ``LoopWatch``): the longest few, each a record of what the loop's
+    # thread was doing, with the iteration before it.
+    "stalls": list,
 }
+_SERVE_STALL_TURN = {
+    "t_ns": int,
+    "wall_us": int,
+    "phases": dict,
+    "cpu_us": int,
+    "gc_us": int,
+    "invol_switches": int,
+    "vol_switches": int,
+    "slots": int,
+    "buckets": list,
+}
+_SERVE_STALL_FLAGS = {"ahead": bool, "fed": bool}
+_SERVE_STALL_REQUIRED = {**_SERVE_STALL_TURN, "phase": str, "verdict": str}
+_SERVE_STALL_VERDICTS = ("blocked", "preempted", "collector", "running")
 _SERVE_PREFIX_REQUIRED = {
     "hit_rate": (int, float),
     "lookups": int,
@@ -627,6 +645,18 @@ _SERVE_PHASE_FIELDS = {
     "p50_ms": (int, float),
     "p95_ms": (int, float),
 }
+
+
+def _check_stall_turn(turn: Any, required: dict, optional: dict,
+                      where: str) -> List[str]:
+    """One iteration of a stall record (its two flags are booleans,
+    which ``_check_fields`` takes for no required key)."""
+    problems = _check_fields(
+        turn, required, {**_SERVE_STALL_FLAGS, **optional}, where)
+    if isinstance(turn, dict):
+        problems += [f"{where}: missing required key {key!r}"
+                     for key in _SERVE_STALL_FLAGS if key not in turn]
+    return problems
 
 
 def validate_serve_snapshot(doc: Any,
@@ -657,6 +687,23 @@ def validate_serve_snapshot(doc: Any,
         problems.append(
             f"{where}: lora_fairness_spread {spread} outside [0, 1]"
         )
+    for i, stall in enumerate(doc.get("stalls", ())):
+        at = f"{where}.stalls[{i}]"
+        stall_problems = _check_stall_turn(
+            stall, _SERVE_STALL_REQUIRED, {"before": dict}, at)
+        if not stall_problems:
+            if stall["verdict"] not in _SERVE_STALL_VERDICTS:
+                stall_problems.append(
+                    f"{at}: verdict {stall['verdict']!r} is not one of "
+                    f"{_SERVE_STALL_VERDICTS}")
+            if f"tick_{stall['phase']}_us" not in stall["phases"]:
+                stall_problems.append(
+                    f"{at}: phase {stall['phase']!r} is not among its "
+                    "phases")
+            if "before" in stall:
+                stall_problems += _check_stall_turn(
+                    stall["before"], _SERVE_STALL_TURN, {}, f"{at}.before")
+        problems += stall_problems
     if "prefix" in doc:
         prefix_problems = _check_fields(
             doc["prefix"], _SERVE_PREFIX_REQUIRED, {}, f"{where}.prefix"

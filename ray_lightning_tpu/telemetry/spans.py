@@ -47,10 +47,15 @@ __all__ = ["PHASES", "Span", "SpanTracer", "phase", "phase_label"]
 PHASES = {
     # One ServeEngine loop iteration, in order, without gap or overlap
     # (counters ``tick_<name>_us``; docs/OBSERVABILITY.md has the table).
+    # ``between`` runs from the clock read that closes one iteration to
+    # the one that opens the next and is counted in that next one, so
+    # the loop's whole wall is tiled, not only its iterations.  A
+    # stalled iteration is closed by the zero-length mark
+    # ``rlt:serve/stall`` (no counter: ``serve/metrics.py``).
     "serve": (
         "inbox", "schedule", "admit_dispatch", "admit_wait", "admit_emit",
         "chunk", "grow", "decode_dispatch", "decode_wait", "emit",
-        "housekeep", "idle",
+        "housekeep", "idle", "between",
     ),
     # Spans of ONE request (gated on its trace context, nested in or
     # recorded beside the tick phases; ``telemetry/trace_collect.py``).
@@ -169,6 +174,15 @@ class _PhaseCtx:
         self._close(t)
         self.name = name
         self.args = args or None
+        self._open(t)
+        return self
+
+    def after(self, prev: "_PhaseCtx") -> "_PhaseCtx":
+        """Enter this phase on the clock read that closes ``prev``:
+        :meth:`then` for two phases that are not one object (another
+        tracer, another sink)."""
+        t = time.perf_counter()
+        prev._close(t)
         self._open(t)
         return self
 

@@ -30,7 +30,7 @@ from ray_lightning_tpu.serve.dist.handoff import (
 from ray_lightning_tpu.serve.client import ServeClient
 from ray_lightning_tpu.serve.dist.router import Router
 from ray_lightning_tpu.serve.engine import ServeConfig, ServeEngine
-from ray_lightning_tpu.serve.metrics import ServeStats
+from ray_lightning_tpu.serve.metrics import LoopWatch, ServeStats
 from ray_lightning_tpu.telemetry import schema, trace_collect
 from ray_lightning_tpu.telemetry.flight_recorder import FlightRecorder
 from ray_lightning_tpu.telemetry.heartbeat import make_beat
@@ -295,7 +295,14 @@ def case_serve_snapshot(tmp_path, served):
                      lora_fairness_spread=1.0, spec_acceptance_rate=0.75)
     stats.set_prefix(hit_rate=0.5, lookups=4, hits=2, blocks_claimed=4,
                      blocks_inserted=8, blocks_evicted=0, cached_blocks=6)
+    watch = LoopWatch()     # a stalled iteration after four plain ones
+    for wall in (900, 1000, 1100, 1000, 900_000):
+        stall = watch.turn({"tick_emit_us": wall}, wall, False, 2, [16],
+                           True, False)
+    watch.close()
+    stats.bump_many({}, stall)
     carried = stats.snapshot()
+    assert carried["stalls"][0]["before"]["wall_us"] == 1000
     carried["capacity"] = _capacity()
     return [served["snapshot"], carried], _without("counters")
 
