@@ -573,9 +573,21 @@ class ServeFamily:
         self.n_sparse = self.cfg.n_sparse
         self.n_full = len(self.cfg.layers_of("full"))
         self.n_ring = len(self.cfg.layers_of("sliding"))
+        self.attn_impl = module.attn_impl
         kw = dict(attn_impl=module.attn_impl, moe_impl=module.moe_impl)
         self.prefill = functools.partial(paged_prefill, self.cfg, **kw)
         self.decode = functools.partial(paged_decode_step, self.cfg, **kw)
+
+    def walk_layers(self, pool) -> int:
+        """Layers whose decode attention runs ``rlt_paged_decode`` on
+        ``pool``: the full ones where the decode takes the kernel (the
+        sliding ones gather their ring)."""
+        from ray_lightning_tpu.ops.paged_attention import (
+            paged_decode_supported,
+        )
+        kernel = self.attn_impl == "pallas" or (
+            self.attn_impl == "auto" and paged_decode_supported(pool["k"]))
+        return self.n_full if kernel else 0
 
     def make_cache(self, num_blocks: int, block_size: int, num_slots: int,
                    dtype) -> TwoKindKVCache:

@@ -454,9 +454,21 @@ class ServeFamily:
         self.vocab_size = cfg.n_vocab_held
         self.n_sparse = cfg.n_sparse
         self.n_latent = cfg.n_layer
+        self.attn_impl = module.attn_impl
         kw = dict(attn_impl=module.attn_impl, moe_impl=module.moe_impl)
         self.prefill = functools.partial(paged_prefill, cfg, **kw)
         self.decode = functools.partial(paged_decode_step, cfg, **kw)
+
+    def walk_layers(self, pool) -> int:
+        """Layers whose decode attention runs ``rlt_mla_decode`` on
+        ``pool``: all where the decode takes the kernel, else none."""
+        from ray_lightning_tpu.ops.paged_attention import (
+            mla_decode_supported,
+        )
+        kernel = self.attn_impl == "pallas" or (
+            self.attn_impl == "auto" and mla_decode_supported(
+                pool["kv"], self.cfg.n_head, self.cfg.kv_lora_rank))
+        return self.cfg.n_layer if kernel else 0
 
     def latent_row_bytes(self, dtype) -> int:
         """The bytes of data one cached position of one layer holds

@@ -11,9 +11,20 @@ afterwards; this kernel
 * takes ``block_tables``, the number of cache positions each slot holds
   and the layer index by scalar prefetch, and walks per slot only the
   ``ceil(seq_len / Bs)`` blocks that hold them (none for an inactive
-  slot), ``pages_per_chunk`` blocks a step, double-buffered: the next
-  chunk (of this slot, or the first of the next slot) is in flight while
-  the current one is computed;
+  slot), in chunks of ``CHUNK_POSITIONS`` positions, double-buffered:
+  the next chunk (of this slot, or the first of the next slot) is in
+  flight while the current one is computed, its copies a loop over the
+  resident blocks only;
+* computes every chunk of a slot but its last whole, one online-softmax
+  step, and the last over the ``TAIL_POSITIONS``-position sub-tiles that
+  hold the slot's positions only (one static width a branch): a step's
+  fixed cost (its copies waited and started, a loop turn, two matmul
+  fills, the accumulator rescaled) is paid once per ``CHUNK_POSITIONS``
+  positions, while a slot still computes fewer than ``TAIL_POSITIONS``
+  masked positions a layer.  The value rows such a tail reads past the
+  blocks just copied are zeroed where no copy of the call has reached
+  them (a masked position contributes 0 x row), each at most once a
+  call;
 * leaves the pool in HBM (``memory_space=pl.ANY``) and never writes it:
   the current token's own K and V rows arrive as operands and open the
   online softmax as position ``seq_len``, so the caller scatters all
@@ -44,10 +55,11 @@ and :func:`mla_decode_supported`).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -57,12 +69,35 @@ from ray_lightning_tpu.ops.kernel_probe import (
 )
 
 __all__ = ["paged_decode_attention", "paged_decode_supported",
-           "mla_decode_attention", "mla_decode_supported"]
+           "mla_decode_attention", "mla_decode_supported", "walk_plan"]
 
-# Cache positions one compute step covers (whole blocks): wide enough
-# that the score tile fills the 128 lanes, narrow enough that a slot's
-# last chunk wastes little on positions past its length.
-CHUNK_POSITIONS = 128
+# Cache positions one step of the walk copies and computes (whole
+# blocks), in every chunk of a slot but its last; both kernels.  What a
+# wide step buys back is its fixed cost.  Kernel alone, a tick's layers
+# summed, before PR 35 (128 a step; latent 512) -> 1024 with the tail
+# trimmed (my chip runs, PR 35; PERF.md section 6): GPT-2's 16 slots of
+# ~420 positions 2.67 -> 2.03 ms (512: 2.17), K-EXAONE's full layers
+# 0.45 -> 0.34, sarvam's latent layers 3.73 -> 3.32 (512: 3.76); slots
+# of 33 and 100 positions 0.600 -> 0.620 and 0.791 -> 0.788.
+CHUNK_POSITIONS = 1024
+# The last chunk is computed over whole sub-tiles of this many positions
+# (whole lanes of the score tile), only those that hold the slot's
+# positions: at most TAIL_POSITIONS - 1 masked positions a slot a layer.
+# Computed whole instead, 1024 would cost GPT-2's cell 2.32 ms and slots
+# of 33 positions 1.72 (the same runs).
+TAIL_POSITIONS = 128
+
+
+def _vmem_limit(buffer_bytes: int) -> Optional[int]:
+    """The scoped VMEM a walk kernel asks for: the compiler's default
+    (16 MiB on a v5e) where its double buffers leave a step's tiles
+    room under it (bfloat16 rows of 1280 at 1024 positions: 10 MiB of
+    buffers), else the buffers and room for float32 tiles (float32 rows
+    of 1280: 20 MiB and 4.7 more).  No more than that: XLA plans its own
+    VMEM round the largest limit a program's kernels ask for
+    (``ops/flash_attention.py``)."""
+    mib = 2**20
+    return None if buffer_bytes <= 12 * mib else buffer_bytes + 8 * mib
 
 
 def _sublane(dtype) -> int:
@@ -91,7 +126,7 @@ def paged_decode_tiles(pool_k: jax.Array) -> bool:
     return hd % 128 == 0 and bs % _sublane(pool_k.dtype) == 0
 
 
-def _pages_per_chunk(m: int, bs: int, positions: int = CHUNK_POSITIONS) -> int:
+def _pages_per_chunk(m: int, bs: int, positions: int) -> int:
     """Largest divisor of the table width ``m`` whose blocks cover at
     most ``positions`` positions (a chunk never straddles the end of a
     table row)."""
@@ -99,6 +134,35 @@ def _pages_per_chunk(m: int, bs: int, positions: int = CHUNK_POSITIONS) -> int:
     while m % p:
         p -= 1
     return p
+
+
+def _tail_width(width: int, bs: int) -> int:
+    """The sub-tile of a chunk ``width`` positions wide that its last
+    chunk is trimmed to: ``TAIL_POSITIONS`` where whole blocks make it
+    and it divides the chunk, else the whole chunk (a table narrower
+    than a sub-tile)."""
+    t = TAIL_POSITIONS
+    return t if width % t == 0 and t % bs == 0 else width
+
+
+def walk_plan(seq_lens, table_width: int,
+              block_size: int) -> Tuple[int, int]:
+    """``(steps, positions computed)`` of one walk kernel's call, summed
+    over the slots of ``seq_lens`` (positions already in the pool, as
+    the kernel is handed them): a step is one online-softmax update, a
+    whole chunk or the trimmed last one; an idle slot (0) has none.  The
+    widths are the kernels' own, read from the same constants
+    (:func:`_pages_per_chunk`, :func:`_tail_width`); host integers, for
+    the engine's counters."""
+    M, Bs = table_width, block_size
+    width = _pages_per_chunk(M, Bs, CHUNK_POSITIONS) * Bs
+    tail = _tail_width(width, Bs)
+    lens = np.minimum(np.asarray(seq_lens, np.int64), M * Bs)
+    whole = np.maximum(lens - 1, 0) // width   # chunks before the last
+    rem = lens - whole * width
+    steps = whole + (rem > 0)
+    positions = whole * width + -(-rem // tail) * tail
+    return int(steps.sum()), int(positions.sum())
 
 
 def _split3(a: jax.Array) -> jax.Array:
@@ -135,56 +199,74 @@ def _table_walk(tables_ref, lens_ref, layer, streams, sems, *, pages,
                 table_width, block_size):
     """The walk of a slot's block table that both decode kernels share.
     ``streams`` pairs each pool in HBM with its VMEM buffer ``(2, P*Bs,
-    row)``; every stream follows the same table.  Returns ``(n_pages,
-    chunk_dma)``: the resident pages of a slot, and the start or wait
-    of the copies of one of its chunks."""
+    row)``; every stream follows the same table.  Returns ``(n_vis,
+    n_pages, chunk_dma)``: the visible positions and the resident pages
+    of a slot, and the start or wait of the copies of one of its
+    chunks."""
     P, M, Bs = pages, table_width, block_size
 
+    def n_vis(slot):
+        # A length past the table (the draft chain's strays) walks the
+        # table: no chunk holds more than its width.
+        return jnp.minimum(lens_ref[slot], M * Bs)
+
     def n_pages(slot):
-        return jnp.minimum(pl.cdiv(lens_ref[slot], Bs), M)
+        return pl.cdiv(n_vis(slot), Bs)
 
     def chunk_dma(slot, c, buf, op):
         """Start or wait the copies of chunk ``c`` of ``slot``: its
-        resident pages only (the rest of the buffer keeps older, finite
-        rows, and the mask hides them)."""
-        n = n_pages(slot)
-        for i in range(P):
-            j = c * P + i
+        resident pages only (the rest of the buffer keeps older rows,
+        which the mask hides), in a loop as long as they are: a step
+        costs its slot's pages, not its width's."""
+        lo = c * P
 
-            @pl.when(j < n)
-            def _():
-                blk = tables_ref[slot * M + j]
-                for s, (hbm, vmem) in enumerate(streams):
-                    copy = pltpu.make_async_copy(
-                        hbm.at[layer, blk],
-                        vmem.at[buf, pl.ds(i * Bs, Bs)],
-                        sems.at[s, buf],
-                    )
-                    getattr(copy, op)()
+        def page(j, _):
+            blk = tables_ref[slot * M + j]
+            at = pl.ds(pl.multiple_of((j - lo) * Bs, Bs), Bs)
+            for s, (hbm, vmem) in enumerate(streams):
+                copy = pltpu.make_async_copy(
+                    hbm.at[layer, blk], vmem.at[buf, at], sems.at[s, buf])
+                getattr(copy, op)()
 
-    return n_pages, chunk_dma
+        jax.lax.fori_loop(lo, jnp.minimum(lo + P, n_pages(slot)), page, None)
+
+    return n_vis, n_pages, chunk_dma
 
 
-def _open_walk(w, buffers, buf_ref, chunk_dma):
-    """Before the first slot: the buffers finite, the first chunk in
-    flight."""
+def _open_walk(w, marks, chunk_dma):
+    """Before the first slot: the walk on buffer 0, no row of either
+    buffer known finite, the first chunk in flight.  ``marks`` (SMEM,
+    3 int32) carries across the grid's steps: the buffer the next slot
+    starts on, then how many leading rows of each buffer are finite."""
     @pl.when(w == 0)
     def _():
-        # Rows no copy has filled yet must be finite: a masked position
-        # contributes 0 x row.
-        for b in buffers:
-            b[...] = jnp.zeros_like(b)
-        buf_ref[0] = 0
+        for i in range(3):
+            marks[i] = 0
         chunk_dma(0, 0, 0, "start")
 
 
-def _walk_chunks(w, n_slots, n_chunks, chunk_dma, buf0, step, carry):
-    """``carry = step(c, buf, carry)`` over the slot's chunks, double-
-    buffered: while chunk ``c`` is computed the next one is in flight,
-    this slot's or the first of the next slot.  Returns ``(carry, the
-    buffer the next slot starts on)``."""
-    def body(c, state):
-        carry, buf = state
+def _walk_chunks(w, n_slots, n_vis, n_pages, chunk_dma, marks, attend,
+                 values, carry, *, pages, block_size):
+    """``carry`` through slot ``w``'s chunks of ``pages`` blocks,
+    double-buffered: while one is computed the next is in flight, this
+    slot's or the first of the next slot.  Every chunk but the last is
+    whole: ``attend(buf, width, carry)`` over its ``width`` positions at
+    once.  The last holds the slot's remaining ``rem`` positions (0 for
+    an idle slot; at most ``width``, as ``n_vis`` is at most the table,
+    so the rows cleared below lie in the buffer) and is computed over
+    the first ``ceil(rem / tail) * tail`` only (:func:`_tail_width`),
+    one static width a branch: ``attend(buf, k * tail, carry, rem)``
+    masks from ``rem`` on.  The
+    rows it reads past the pages just copied must be finite in
+    ``values`` (a masked position contributes 0 x row): those no copy or
+    clearing of this call has reached yet are zeroed, a block at a time,
+    so a call clears each row of a buffer at most once."""
+    Bs = block_size
+    width = pages * Bs
+    tail = _tail_width(width, Bs)
+    n_chunks = jnp.maximum(pl.cdiv(n_pages, pages), 1)
+
+    def fetch(c, buf):
         chunk_dma(w, c, buf, "wait")
 
         @pl.when(c + 1 < n_chunks)
@@ -195,19 +277,50 @@ def _walk_chunks(w, n_slots, n_chunks, chunk_dma, buf0, step, carry):
         def _():
             chunk_dma(w + 1, 0, 1 - buf, "start")
 
-        return step(c, buf, carry), 1 - buf
+    def body(c, state):
+        carry, buf = state
+        fetch(c, buf)
+        marks[1 + buf] = width
+        return attend(buf, width, carry), 1 - buf
 
-    return jax.lax.fori_loop(0, n_chunks, body, (carry, buf0))
+    last = n_chunks - 1
+    carry, buf = jax.lax.fori_loop(0, last, body, (carry, marks[0]))
+    fetch(last, buf)
+    rem = n_vis - last * width
+    k = pl.cdiv(rem, tail)              # the tail's sub-tiles
+    need = k * tail
+
+    def clear(p, _):
+        for v in values:
+            v[buf, pl.ds(pl.multiple_of(p * Bs, Bs), Bs)] = jnp.zeros(
+                (Bs,) + v.shape[2:], v.dtype)
+
+    finite = marks[1 + buf]
+    jax.lax.fori_loop(jnp.maximum(finite, pl.cdiv(rem, Bs) * Bs) // Bs,
+                      need // Bs, clear, None)
+    marks[1 + buf] = jnp.maximum(finite, need)
+    marks[0] = 1 - buf
+
+    def trimmed(j):
+        return lambda carry: attend(buf, j * tail, carry, rem)
+
+    return jax.lax.switch(
+        k, [lambda carry: carry] + [trimmed(j)
+                                    for j in range(1, width // tail + 1)],
+        carry)
 
 
-def _softmax_step(s, visible, values, carry, round_probs=False):
-    """One chunk of the online softmax: scores ``s (rows, T)`` float32,
+def _softmax_step(s, rem, values, carry, round_probs=False):
+    """One update of the online softmax: scores ``s (rows, T)`` float32,
     ``values (T, C)`` as stored; statistics and accumulator float32.
-    ``round_probs``: the probabilities meet the values in the values'
-    own dtype (one matmul, as the flash kernels do) and not as exact
-    float32 terms."""
+    ``rem``: positions from ``rem`` on are masked (``None``: all are
+    visible).  ``round_probs``: the probabilities meet the values in the
+    values' own dtype (one matmul, as the flash kernels do) and not as
+    exact float32 terms."""
     acc, m, l = carry
-    s = jnp.where(visible, s, _NEG_INF)
+    if rem is not None:
+        pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < rem, s, _NEG_INF)
     m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m - m_new)
@@ -221,21 +334,16 @@ def _softmax_step(s, visible, values, carry, round_probs=False):
 def _kernel(layer_ref, tables_ref, lens_ref,            # scalar prefetch
             q_ref, kc_ref, vc_ref, k_hbm, v_hbm,        # inputs
             o_ref,                                      # output
-            kbuf, vbuf, sems, buf_ref,                  # scratch
+            kbuf, vbuf, sems, marks,                    # scratch
             *, n_head, n_kv_head, head_dim, rows, scale, pages,
             table_width, block_size):
     w = pl.program_id(0)
     n_slots = pl.num_programs(0)
-    P, Bs = pages, block_size
-    T = P * Bs
-    n_pages, chunk_dma = _table_walk(
+    n_vis, n_pages, chunk_dma = _table_walk(
         tables_ref, lens_ref, layer_ref[0],
         ((k_hbm, kbuf), (v_hbm, vbuf)), sems, pages=pages,
         table_width=table_width, block_size=block_size)
-    _open_walk(w, (kbuf, vbuf), buf_ref, chunk_dma)
-
-    n_vis = lens_ref[w]
-    n_chunks = jnp.maximum(pl.cdiv(n_pages(w), P), 1)
+    _open_walk(w, marks, chunk_dma)
 
     group = n_head // n_kv_head
     hd = n_kv_head * head_dim           # the pool's row
@@ -261,14 +369,16 @@ def _kernel(layer_ref, tables_ref, lens_ref,            # scalar prefetch
     l0 = jnp.ones((rows, 1), jnp.float32)
     acc0 = jnp.broadcast_to(vc_ref[0].astype(jnp.float32), (rows, hd))
 
-    def step(c, buf, carry):
-        s = _dot_f32(q_bd, kbuf[buf], (((1,), (1,)), ((), ()))) * scale
-        pos = c * T + jax.lax.broadcasted_iota(jnp.int32, (rows, T), 1)
-        return _softmax_step(s, pos < n_vis, vbuf[buf], carry)
+    def attend(buf, width, carry, rem=None):
+        at = (buf, pl.ds(0, width))
+        s = _dot_f32(q_bd, kbuf[at], (((1,), (1,)), ((), ()))) * scale
+        return _softmax_step(s, rem, vbuf[at], carry)
 
-    (acc, _, l), buf = _walk_chunks(
-        w, n_slots, n_chunks, chunk_dma, buf_ref[0], step, (acc0, m0, l0))
-    buf_ref[0] = buf
+    # A key row's score is masked by a select whatever the row holds:
+    # only the values need finite rows.
+    acc, _, l = _walk_chunks(
+        w, n_slots, n_vis(w), n_pages(w), chunk_dma, marks, attend, (vbuf,),
+        (acc0, m0, l0), pages=pages, block_size=block_size)
     out = jnp.where(diag, acc / l, 0.0)
     if group == 1:
         o_ref[0] = jnp.sum(out, axis=0, keepdims=True)
@@ -331,7 +441,7 @@ def paged_decode_attention(
             f"{head_dim} on {n_kv_head} K/V heads: take the XLA path "
             f"(attn_impl='xla' or 'auto')"
         )
-    P = _pages_per_chunk(M, Bs)
+    P = _pages_per_chunk(M, Bs, CHUNK_POSITIONS)
     rows = -(-n_head // 16) * 16  # whole bf16 sublane tiles, _split3 stacks
     kernel = functools.partial(
         _kernel, n_head=n_head, n_kv_head=n_kv_head, head_dim=head_dim,
@@ -354,7 +464,7 @@ def paged_decode_attention(
             pltpu.VMEM((2, P * Bs, hd), k_pool.dtype),
             pltpu.VMEM((2, P * Bs, hd), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SMEM((1,), jnp.int32),
+            pltpu.SMEM((3,), jnp.int32),
         ],
     )
     out = pl.pallas_call(
@@ -363,16 +473,19 @@ def paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct(
             (W, n_head, head_dim) if grouped else (W, 1, hd), jnp.float32),
         # Slots run in order: each step leaves the next slot's first
-        # chunk in flight and the buffer index in SMEM.
+        # chunk in flight and the walk's marks in SMEM.
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_vmem_limit(
+                2 * P * Bs * hd * (k_pool.dtype.itemsize
+                                   + v_pool.dtype.itemsize)),
         ),
         interpret=_interpret(),
         name="rlt_paged_decode",
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
         block_tables.astype(jnp.int32).reshape(-1),
-        jnp.minimum(seq_lens.astype(jnp.int32), M * Bs),
+        seq_lens.astype(jnp.int32),
         q.reshape(W, n_head, head_dim) if grouped else q.reshape(W, 1, hd),
         k_cur.reshape(W, 1, hd),
         v_cur.reshape(W, 1, hd),
@@ -386,31 +499,17 @@ def paged_decode_attention(
 # latent (MLA) decode: one shared row a position, absorbed form
 # ---------------------------------------------------------------------------
 
-# Cache positions one step of ``rlt_mla_decode`` covers.  Its tile is a
-# matmul operand for all heads at once, so a step's fixed cost (eight
-# copies waited and started, the accumulator rescaled) is worth
-# amortising over more positions than ``CHUNK_POSITIONS``: 8 layers x 64
-# slots of ~2600 positions took 7.76 / 5.03 / 4.20 ms at 128 / 256 / 512
-# (my chip run, PR 30; PERF.md section 6).
-MLA_CHUNK_POSITIONS = 512
-
-
 def _mla_kernel(layer_ref, tables_ref, lens_ref,        # scalar prefetch
                 q_ref, cur_ref, pool_hbm,               # inputs
                 o_ref,                                  # output
-                buf, sems, buf_ref,                     # scratch
+                buf, sems, marks,                       # scratch
                 *, n_head, rank, scale, pages, table_width, block_size):
     w = pl.program_id(0)
     n_slots = pl.num_programs(0)
-    P, Bs = pages, block_size
-    T = P * Bs
-    n_pages, chunk_dma = _table_walk(
+    n_vis, n_pages, chunk_dma = _table_walk(
         tables_ref, lens_ref, layer_ref[0], ((pool_hbm, buf),), sems,
         pages=pages, table_width=table_width, block_size=block_size)
-    _open_walk(w, (buf,), buf_ref, chunk_dma)
-
-    n_vis = lens_ref[w]
-    n_chunks = jnp.maximum(pl.cdiv(n_pages(w), P), 1)
+    _open_walk(w, marks, chunk_dma)
 
     q = q_ref[0]                                        # (H, row)
     q32 = q.astype(jnp.float32)
@@ -421,20 +520,20 @@ def _mla_kernel(layer_ref, tables_ref, lens_ref,        # scalar prefetch
     l0 = jnp.ones((n_head, 1), jnp.float32)
     acc0 = jnp.broadcast_to(cur[:, :rank], (n_head, rank))
 
-    def step(c, b, carry):
+    def attend(b, width, carry, rem=None):
         # One tile, read once from HBM: every column is a key column
         # (the padding lanes are zero in q), the first ``rank`` are the
         # values.  All heads share it: a matmul of H rows.
-        s = _dot_f32(q, buf[b], (((1,), (1,)), ((), ()))) * scale
-        pos = c * T + jax.lax.broadcasted_iota(jnp.int32, (n_head, T), 1)
+        at = (b, pl.ds(0, width))
+        s = _dot_f32(q, buf[at], (((1,), (1,)), ((), ()))) * scale
         # The probabilities meet the values in the pool's dtype, as in
         # the prefill's flash kernel: one matmul of H rows, not three.
-        return _softmax_step(s, pos < n_vis, buf[b, :, :rank], carry,
+        return _softmax_step(s, rem, buf[at + (pl.ds(0, rank),)], carry,
                              round_probs=True)
 
-    (acc, _, l), b = _walk_chunks(
-        w, n_slots, n_chunks, chunk_dma, buf_ref[0], step, (acc0, m0, l0))
-    buf_ref[0] = b
+    acc, _, l = _walk_chunks(
+        w, n_slots, n_vis(w), n_pages(w), chunk_dma, marks, attend, (buf,),
+        (acc0, m0, l0), pages=pages, block_size=block_size)
     o_ref[0] = acc / l
 
 
@@ -519,7 +618,7 @@ def mla_decode_attention(
             f"{pool.shape} for {H} heads on rows of {row} with {rank} "
             f"value columns: take the XLA path (attn_impl='xla' or 'auto')"
         )
-    P = _pages_per_chunk(M, Bs, MLA_CHUNK_POSITIONS)
+    P = _pages_per_chunk(M, Bs, CHUNK_POSITIONS)
     kernel = functools.partial(
         _mla_kernel, n_head=H, rank=rank, scale=scale, pages=P,
         table_width=M, block_size=Bs,
@@ -536,7 +635,7 @@ def mla_decode_attention(
         scratch_shapes=[
             pltpu.VMEM((2, P * Bs, row), pool.dtype),
             pltpu.SemaphoreType.DMA((1, 2)),
-            pltpu.SMEM((1,), jnp.int32),
+            pltpu.SMEM((3,), jnp.int32),
         ],
     )
     return pl.pallas_call(
@@ -545,7 +644,9 @@ def mla_decode_attention(
         out_shape=jax.ShapeDtypeStruct((W, H, rank), jnp.float32),
         # Slots run in order, as in rlt_paged_decode.
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_vmem_limit(
+                2 * P * Bs * row * pool.dtype.itemsize),
         ),
         interpret=_interpret(),
         name="rlt_mla_decode",

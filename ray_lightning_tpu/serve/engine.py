@@ -48,6 +48,7 @@ import numpy as np
 from ray_lightning_tpu.fault.inject import (
     FaultBlackhole, FaultInjected, fire as _fault_fire, set_member,
 )
+from ray_lightning_tpu.ops.paged_attention import walk_plan
 from ray_lightning_tpu.serve.metrics import LoopWatch, ServeStats
 from ray_lightning_tpu.telemetry.propagate import (
     child_context, trace_args,
@@ -460,6 +461,7 @@ class ServeEngine:
             self.stats.bump(
                 "latent_row_bytes", self.family.latent_row_bytes(self._c))
         self._pool = self.cache.init_pool()
+        self._walk_layers = self.family.walk_layers(self._pool)
         self._draft_pool = None
         if draft_module is not None:
             dcfg = draft_module.config
@@ -1642,6 +1644,17 @@ class ServeEngine:
             # rlt: noqa[RLT002] host ints (scheduler.seq_lens), no device value
             kv_blocks["decode_latent_positions"] = self._n_latent * int(
                 self.scheduler.seq_lens[active].sum() + len(active))
+        if self._walk_layers:
+            # What the walk kernels compute this tick, over the layers
+            # that run one (none where the decode takes the XLA gather):
+            # online-softmax steps, and positions, the trimmed tails'
+            # masked ones included.
+            steps, positions = walk_plan(
+                self.scheduler.seq_lens[active],
+                self.scheduler.block_tables.shape[1], self.config.block_size)
+            kv_blocks["decode_attn_steps"] = self._walk_layers * steps
+            kv_blocks["decode_attn_positions_computed"] = (
+                self._walk_layers * positions)
         if sums:
             kv_blocks["moe_tokens_routed"] = len(active) * self.family.n_sparse
         if fed:

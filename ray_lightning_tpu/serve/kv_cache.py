@@ -938,7 +938,9 @@ class GPTServeFamily:
     manager), and the engine asks nothing else of a family:
     ``make_cache``, ``prefill``, ``decode``, ``prepare_params``,
     ``vocab_size``, ``two_kind`` (ring tables beside the block tables),
-    ``refuses`` / ``refuses_why``."""
+    ``refuses`` / ``refuses_why``, ``walk_layers(pool)`` (how many
+    layers' decode attention runs a walk kernel on the pool: the
+    engine's counters, ``ops.paged_attention.walk_plan``)."""
 
     name = "gpt"
     two_kind = False     # one block table a slot, one pool a tensor
@@ -965,6 +967,14 @@ class GPTServeFamily:
         self.vocab_size = cfg.vocab_size
         self.prefill = functools.partial(paged_prefill, cfg)
         self.decode = functools.partial(paged_decode_step, cfg)
+
+    def walk_layers(self, pool) -> int:
+        """Layers whose decode attention runs ``rlt_paged_decode`` on
+        ``pool`` (``attn_impl="auto"``): all or, off the kernel, none."""
+        from ray_lightning_tpu.ops.paged_attention import (
+            paged_decode_supported,
+        )
+        return self.cfg.n_layer if paged_decode_supported(pool["k"]) else 0
 
     def make_cache(self, num_blocks: int, block_size: int, num_slots: int,
                    dtype) -> "PagedKVCache":

@@ -63,6 +63,10 @@ _COUNTER_KEYS = (
     # Per decode tick: blocks that hold the active slots' visible
     # positions, and the W x M entries of the block tables.
     "decode_kv_blocks_read", "decode_kv_blocks_table",
+    # Per decode tick, over the layers whose attention walks the block
+    # table: the walk's online-softmax steps and the positions they
+    # compute (``ops.paged_attention.walk_plan``).
+    "decode_attn_steps", "decode_attn_positions_computed",
     # Of ``decode_steps``: ticks read from a decode dispatched ahead of
     # them, and those of them whose ``tokens`` operand was the tick
     # before's output where it lay on the device.  Reply frames sent

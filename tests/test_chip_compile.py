@@ -607,6 +607,20 @@ def test_moe_prefill_fits_at_the_largest_bucket(moe_prefill):
     assert _footprint(moe_prefill) < 14.75e9
 
 
+# -- the three decode programs against the parent's bytes (PR 35) -----------
+
+@pytest.mark.parametrize("program,parent_bytes", [
+    ("serve_decode", 4_766_827_008), ("moe_decode", 13_333_024_768),
+    ("mla_decode", 13_262_705_664)])
+def test_decode_programs_keep_the_parents_bytes(request, program,
+                                                parent_bytes):
+    """The walk's wider buffers live in VMEM (at most the compiler's
+    default scoped limit for these bf16 pools): each cell's decode
+    program needs no more HBM than it did before PR 35, to the byte
+    (the same compile at PR 34's tree)."""
+    assert _footprint(request.getfixturevalue(program)) <= parent_bytes
+
+
 # -- the latent share (sarvam-105b-ep8.serve-longctx) -------------------------
 
 # benchmarks/workloads/sarvam-105b-ep8.serve-longctx.json: 64 slots x 7168

@@ -21,6 +21,7 @@ from ray_lightning_tpu.models.exaone_moe import (
     ExaoneMoE, ExaoneMoEConfig, exaone_moe_tiny,
 )
 from ray_lightning_tpu.ops import moe as moe_ops
+from ray_lightning_tpu.ops import paged_attention
 from ray_lightning_tpu.ops.paged_attention import paged_decode_attention
 from ray_lightning_tpu.serve.kv_cache import BlockAllocator
 from ray_lightning_tpu.serve.scheduler import Request, Scheduler
@@ -194,10 +195,29 @@ def test_router_scores_in_float32_and_gates_normalised(tiny):
 
 # -- grouped-query paged decode kernel --------------------------------------
 
+def _gqa_tables(case):
+    """``(tables, lens, table width, pool blocks)``: a short table, or
+    the walk's: four steps of the kernel's width, lengths at a step's
+    edges and past two, an idle slot between two long ones."""
+    if case == "short":
+        return ([[3, 7, 1, 9], [2, 5, 0, 0], [0, 0, 0, 0]], [50, 17, 0], 4,
+                14)
+    wide, bs = paged_attention.CHUNK_POSITIONS, 16
+    m = 4 * wide // bs
+    lens = [wide + 1, 0, 2 * wide + 129, 127, m * bs]
+    rows = [[1 + w * m + j for j in range(-(-n // bs))] for w, n in
+            enumerate(lens)]
+    return ([r + [0] * (m - len(r)) for r in rows], lens, m,
+            1 + len(lens) * m)
+
+
+@pytest.mark.parametrize("case", ["short", "walk"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_gqa_paged_decode_kernel_under_the_interpreter(dtype):
+def test_gqa_paged_decode_kernel_under_the_interpreter(dtype, case):
     """16 query heads on 2 K/V heads of 128 against the gathered form."""
-    W, Hq, Hkv, Dh, Bs, M, N = 3, 16, 2, 128, 16, 4, 14
+    Hq, Hkv, Dh, Bs = 16, 2, 128, 16
+    tables, lens, M, N = _gqa_tables(case)
+    W = len(lens)
     cfg = ExaoneMoEConfig(n_layer=4, n_head=Hq, n_kv_head=Hkv, head_dim=Dh,
                           d_model=64, vocab_size=64)
     ks = jax.random.split(jax.random.PRNGKey(5), 5)
@@ -206,8 +226,7 @@ def test_gqa_paged_decode_kernel_under_the_interpreter(dtype):
     q = jax.random.normal(ks[2], (W, Hq * Dh)).astype(dtype)
     k_cur = jax.random.normal(ks[3], (W, Hkv * Dh)).astype(dtype)
     v_cur = jax.random.normal(ks[4], (W, Hkv * Dh)).astype(dtype)
-    tables = jnp.asarray([[3, 7, 1, 9], [2, 5, 0, 0], [0, 0, 0, 0]])
-    lens = jnp.asarray([50, 17, 0])
+    tables, lens = jnp.asarray(tables), jnp.asarray(lens)
     got = paged_decode_attention(
         q, k_cur, v_cur, pool_k, pool_v, jnp.int32(1), tables, lens,
         n_head=Hq, n_kv_head=Hkv, scale=Dh ** -0.5)

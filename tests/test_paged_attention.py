@@ -25,22 +25,54 @@ pytestmark = pytest.mark.serve
 W, BS, M, N = 6, 16, 4, 32
 S = M * BS
 DH = 64
+# The walk's tables: four steps of the kernel's width, so a slot walks
+# one to four chunks, the last one trimmed.
+WIDE = paged_attention.CHUNK_POSITIONS
+M_WALK = 4 * WIDE // BS
 
 
-def _tables(rows):
-    bt = np.full((W, M), TRASH_BLOCK, np.int32)
+def _tables(rows, m=M):
+    bt = np.full((len(rows), m), TRASH_BLOCK, np.int32)
     for w, ids in enumerate(rows):
         bt[w, :len(ids)] = ids
     return bt
 
 
-def _own_blocks(w, n=M):
-    return [1 + w * M + j for j in range(n)]
+def _own_blocks(w, n=M, m=M):
+    return [1 + w * m + j for j in range(n)]
+
+
+def _walk(lens):
+    """Tables of ``M_WALK`` entries, each slot the blocks its length and
+    the new token need (at most the table), the rest trash."""
+    return _tables([_own_blocks(w, min(-(-(n + 1) // BS), M_WALK)
+                                if n else 0, M_WALK)
+                    for w, n in enumerate(lens)], M_WALK)
 
 
 def _scenario(name):
     """``(block_tables, seq_lens, write_limit, slots whose logits count)``."""
     every = list(range(W))
+    if name == "walk_short_edges":
+        # One chunk: the trimmed tail of one, one and two sub-tiles.
+        lens = [1, BS - 1, BS, 127, 128, 129]
+        return _walk(lens), lens, None, every
+    if name == "walk_long_edges":
+        # A chunk's edges, two whole chunks and a trimmed third, the
+        # table's last position, and an idle slot between two long ones
+        # (the next slot's first chunk is in flight across it).
+        lens = [WIDE - 1, WIDE, 0, WIDE + 1, 2 * WIDE + 129, 300,
+                M_WALK * BS - 1]
+        return _walk(lens), lens, None, [0, 1, 3, 4, 5, 6]
+    if name == "walk_past_table":
+        # The draft chain on the walk's table: slots past their table
+        # (a walk clamped to it, the write to trash), each followed by a
+        # live slot whose first chunk is in flight while the stray's
+        # tail is computed.  Strays' logits are never used (the XLA
+        # path leaves out their own token, written to trash).
+        end = M_WALK * BS
+        lens = [end + 3, 2 * WIDE + 129, end + WIDE + 1, WIDE + 1, 5]
+        return _walk(lens), lens, [end] * len(lens), [1, 3, 4]
     if name == "length_edges":
         lens = [0, 1, BS - 1, BS, BS + 1, S - 1]
         return _tables([_own_blocks(w) for w in every]), lens, None, every
@@ -70,7 +102,8 @@ def _scenario(name):
 
 
 _SCENARIOS = ["length_edges", "inactive_all_trash", "shared_prefix",
-              "write_limit_past_table"]
+              "write_limit_past_table", "walk_short_edges", "walk_long_edges",
+              "walk_past_table"]
 # dtype of the pool and of the compute; logits tolerance as flash's:
 # absolute 1e-5 in float32, 1e-2 of the largest logit in bfloat16.
 _DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
@@ -87,8 +120,8 @@ def models():
     return out
 
 
-def _filled_pool(cfg, dtype, block_size=BS):
-    cache = PagedKVCache(cfg, num_blocks=N, block_size=block_size,
+def _filled_pool(cfg, dtype, block_size=BS, num_blocks=N):
+    cache = PagedKVCache(cfg, num_blocks=num_blocks, block_size=block_size,
                          dtype=dtype)
     shape = cache.init_pool()["k"].shape
     kk, kv = jax.random.split(jax.random.PRNGKey(1))
@@ -100,20 +133,21 @@ def _step(cfg, params, pool, scenario, dtype, impl):
     bt, lens, limit, _ = scenario
     return paged_decode_step(
         cfg, params, pool, jnp.asarray(bt), jnp.asarray(lens, jnp.int32),
-        jnp.arange(1, W + 1, dtype=jnp.int32), compute_dtype=dtype,
+        jnp.arange(1, len(lens) + 1, dtype=jnp.int32), compute_dtype=dtype,
         write_limit=None if limit is None else jnp.asarray(limit, jnp.int32),
         attn_impl=impl,
     )
 
 
-@pytest.mark.parametrize("width", [128, 1280])
-@pytest.mark.parametrize("dtype", sorted(_DTYPES))
-@pytest.mark.parametrize("name", _SCENARIOS)
+# The walk's long tables at the small row only.
+@pytest.mark.parametrize("name,dtype,width", [
+    (name, dtype, width) for name in _SCENARIOS for dtype in sorted(_DTYPES)
+    for width in (128, 1280) if width == 128 or not name.startswith("walk")])
 def test_kernel_matches_xla_path(models, name, dtype, width):
     cfg, params = models[width]
     dt = _DTYPES[dtype]
     scenario = _scenario(name)
-    pool = _filled_pool(cfg, dt)
+    pool = _filled_pool(cfg, dt, num_blocks=int(scenario[0].max()) + 1)
     want, want_pool = _step(cfg, params, pool, scenario, dt, "xla")
     got, got_pool = _step(cfg, params, pool, scenario, dt, "pallas")
     used = scenario[3]
@@ -133,9 +167,53 @@ def test_kernel_matches_xla_path(models, name, dtype, width):
         np.testing.assert_allclose(
             a[1], b[1], rtol=0, atol=1e-5 if dt == jnp.float32 else 2e-2
         )
-        # ... and the step wrote W rows a layer, nothing else.
+        # ... and the step wrote a row a slot a layer, nothing else.
         changed = (a != np.asarray(pool[key].astype(jnp.float32))[:, 1:])
-        assert changed.any(axis=-1).sum() <= cfg.n_layer * W
+        assert changed.any(axis=-1).sum() <= cfg.n_layer * len(scenario[1])
+
+
+def _walk_by_hand(n, m, bs, wide):
+    """The kernel's walk of one slot of ``n`` positions, step by step:
+    chunks of the width ``_pages_per_chunk`` gives, every one whole but
+    the last, which computes the 128-position sub-tiles holding the
+    slot's positions (the whole chunk where 128 does not divide it)."""
+    n = min(n, m * bs)
+    pages = max(p for p in range(1, m + 1)
+                if m % p == 0 and (p * bs <= wide or p == 1))
+    width = pages * bs
+    tail = 128 if width % 128 == 0 else width
+    steps = positions = 0
+    start = 0
+    while start < n:
+        if start + width < n:
+            steps, positions = steps + 1, positions + width
+        else:
+            held = n - start
+            steps += 1
+            positions += tail * -(-held // tail)
+        start += width
+    return steps, positions
+
+
+@pytest.mark.parametrize("wide", [128, 256, 512, 1024])
+@pytest.mark.parametrize("table", [(32, 32), (96, 16), (224, 32), (6, 16)],
+                         ids=["gpt2_cell", "walk", "sarvam", "narrow"])
+def test_walk_plan_counts_the_walk(monkeypatch, table, wide):
+    """``walk_plan`` against the walk counted slot by slot, at every
+    length from 0 to the table's end and past it (clamped), at the
+    kernels' width and others."""
+    monkeypatch.setattr(paged_attention, "CHUNK_POSITIONS", wide)
+    m, bs = table
+    lens = np.arange(0, m * bs + 3 * bs)
+    want = [_walk_by_hand(int(n), m, bs, wide) for n in lens]
+    assert paged_attention.walk_plan(lens, m, bs) == (
+        sum(s for s, _ in want), sum(p for _, p in want))
+    for n, one in zip(lens[::7], want[::7]):
+        assert paged_attention.walk_plan([n], m, bs) == one
+    # The masked tail a slot computes is under a sub-tile.
+    for n, (_, p) in zip(lens, want):
+        if n:
+            assert p - min(n, m * bs) < 128
 
 
 _UNTILED = {

@@ -135,12 +135,12 @@ def test_absorbed_attention_is_the_unabsorbed(tiny):
 
 # -- the decode kernel ---------------------------------------------------------
 
-def _mla_inputs(lens, layers, dtype, seed):
+def _mla_inputs(lens, layers, dtype, seed, M=40):
     """``(q, cur, pool, tables, lens)`` for slots of ``lens`` cached
     positions: 16 heads, a latent of 128 and a rotary key of 64 in rows
-    of 256 (the padding lanes zero), blocks of 16, 40 a slot scattered
-    over a pool of ``layers``."""
-    H, data, Bs, M, row, W = 16, 128 + 64, 16, 40, 256, len(lens)
+    of 256 (the padding lanes zero), blocks of 16, ``M`` a slot
+    scattered over a pool of ``layers``."""
+    H, data, Bs, row, W = 16, 128 + 64, 16, 256, len(lens)
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
 
     def draw(key, *shape):
@@ -153,19 +153,34 @@ def _mla_inputs(lens, layers, dtype, seed):
             jnp.asarray(lens, jnp.int32))
 
 
+_WIDE = pa.CHUNK_POSITIONS
+_MLA_CASES = {
+    # Ragged lengths on a table of 40 blocks (two steps of 320).
+    "ragged": ([0, 5, 512, 530, 0, 639], 40),
+    # The walk's table, four steps of the kernel's width: a step's edges,
+    # two whole steps and a trimmed third (one to four sub-tiles), an
+    # idle slot between two long ones, the table's end, and a slot past
+    # it (the draft chain's strays: a walk clamped to the table) before
+    # a live one.
+    "walk": ([_WIDE - 1, _WIDE, _WIDE + 1, 0, 2 * _WIDE + 129, 300, 127,
+              4 * _WIDE, 4 * _WIDE + 37, 2 * _WIDE + 1], 4 * _WIDE // 16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_MLA_CASES))
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_mla_decode_kernel_under_the_interpreter(dtype):
+def test_mla_decode_kernel_under_the_interpreter(dtype, case):
     """``rlt_mla_decode`` against the XLA path of the same arithmetic:
-    ragged lengths (an empty cache, a partial block, the edge of a chunk
-    of 512, past it), an idle slot, blocks scattered over the pool, a
-    layer index that is not 0.  float32: the same sums in another order
-    (1e-5).  bfloat16 pool and queries: both paths multiply the stored
-    values exactly and accumulate in float32, and both round the
-    probabilities to bfloat16 where they meet the values, the kernel
-    before it normalises and the XLA path after: 8 bits each way on
-    outputs of order 1 (1e-2)."""
-    q, cur, pool, tables, lens = _mla_inputs(
-        [0, 5, 512, 530, 0, 639], 2, dtype, seed=0)
+    ragged lengths (an empty cache, a partial block, the edge of a chunk,
+    past it, the trimmed tail of every width), an idle slot, blocks
+    scattered over the pool, a layer index that is not 0.  float32: the
+    same sums in another order (1e-5).  bfloat16 pool and queries: both
+    paths multiply the stored values exactly and accumulate in float32,
+    and both round the probabilities to bfloat16 where they meet the
+    values, the kernel before it normalises and the XLA path after: 8
+    bits each way on outputs of order 1 (1e-2)."""
+    lens, m = _MLA_CASES[case]
+    q, cur, pool, tables, lens = _mla_inputs(lens, 2, dtype, seed=0, M=m)
     (W, H, _), r = q.shape, 128
     args = (q, cur, pool, jnp.int32(1), tables, lens)
     kw = dict(rank=r, scale=0.11)
@@ -176,8 +191,9 @@ def test_mla_decode_kernel_under_the_interpreter(dtype):
     assert float(jnp.abs(got - want).max()) < tol, float(
         jnp.abs(got - want).max())
     # A slot with nothing cached attends its own row alone.
+    idle = list(lens).index(0)
     np.testing.assert_allclose(
-        got[0], jnp.broadcast_to(cur[0, :r], (H, r)), rtol=1e-6)
+        got[idle], jnp.broadcast_to(cur[idle, :r], (H, r)), rtol=1e-6)
 
 
 def test_mla_decode_kernel_rounds_its_probabilities_within_bf16():

@@ -12,11 +12,13 @@ units, SLO stats schema, and the DriverQueue client plane.
 
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from ray_lightning_tpu.models.generate import generate
+from ray_lightning_tpu.ops import paged_attention
 from ray_lightning_tpu.serve.client import ServeClient
 from ray_lightning_tpu.serve.engine import (
     ServeConfig, ServeEngine, ServeRejected,
@@ -266,6 +268,48 @@ class TestEngine:
                                                  block_size=8))
         prompt = _rand_prompt(3, 7, m.config.vocab_size)
         assert eng.generate(prompt, 9) == _ref_tokens(m, params, prompt, 9)
+
+    @pytest.mark.parametrize("kernel", [True, False])
+    def test_walk_counters_are_the_walk_plan(self, monkeypatch, kernel):
+        """``decode_attn_steps`` / ``decode_attn_positions_computed``:
+        every tick's ``walk_plan`` of the active slots' lengths, times
+        the layers, where the decode runs the kernel (here under the
+        interpreter), and absent where it takes the XLA gather.  One
+        request at a time, so a request of prompt ``n`` decodes at
+        lengths ``n .. n + k - 2``: a short one, one across the edge of
+        the walk's width, one past two whole steps."""
+        from ray_lightning_tpu.models.gpt import GPT, GPTConfig
+        if kernel:
+            monkeypatch.setattr(paged_attention, "paged_decode_supported",
+                                paged_attention.paged_decode_tiles)
+        wide = paged_attention.CHUNK_POSITIONS
+        S = 2 * wide + 256
+        # Rows of 128 lanes: a pool the kernel tiles.
+        m = GPT(GPTConfig(vocab_size=128, n_layer=2, n_head=2, d_model=128,
+                          seq_len=S, warmup_steps=1), attn_impl="xla")
+        params = m.init_params(jax.random.PRNGKey(0))
+        eng = ServeEngine(m, params, ServeConfig(
+            num_slots=2, block_size=16, prefill_buckets=[64, S]))
+        M = S // 16
+        steps = positions = ticks = held = 0
+        for n in (5, wide - 2, 2 * wide + 100):
+            eng.generate(_rand_prompt(n, n, m.config.vocab_size), 5)
+            for length in range(n, n + 4):
+                s, p = paged_attention.walk_plan([length], M, 16)
+                steps, positions = steps + s, positions + p
+                ticks, held = ticks + 1, held + length
+        c = eng.stats.counters
+        eng.stop()
+        assert c["decode_steps"] == ticks
+        if not kernel:
+            assert not c.get("decode_attn_steps")
+            assert not c.get("decode_attn_positions_computed")
+            return
+        assert c["decode_attn_steps"] == m.config.n_layer * steps
+        assert c["decode_attn_positions_computed"] == (
+            m.config.n_layer * positions)
+        # Under a sub-tile of masked positions a slot a layer.
+        assert held <= positions < held + 128 * ticks
 
     def test_join_on_arrival_matches_isolated(self, model):
         """A request admitted MID-decode of another must not disturb
