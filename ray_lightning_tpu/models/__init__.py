@@ -2,6 +2,7 @@ from .boring import BoringModel, BoringDataModule, XORModel, XORDataModule
 from .data_text import ByteLMDataModule, decode_bytes
 from .exaone_moe import ExaoneMoE, ExaoneMoEConfig
 from .sarvam_mla import SarvamMLA, SarvamMLAConfig
+from .mimo_v2 import MiMoV2, MiMoV2Config
 from .generate import decode_step, generate, init_kv_cache, prefill
 from .gpt import (
     GPT,
@@ -36,6 +37,8 @@ __all__ = [
     "ExaoneMoEConfig",
     "SarvamMLA",
     "SarvamMLAConfig",
+    "MiMoV2",
+    "MiMoV2Config",
     "SyntheticLMDataModule",
     "add_lora_adapters",
     "extract_lora",

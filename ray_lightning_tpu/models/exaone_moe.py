@@ -41,9 +41,35 @@ from ray_lightning_tpu.core.module import TpuModule
 from ray_lightning_tpu.ops.attention import _NEG_INF
 from ray_lightning_tpu.ops.moe import dropless_moe, sigmoid_topk_routing
 
-__all__ = ["ExaoneMoE", "ExaoneMoEConfig", "exaone_moe_tiny"]
+__all__ = ["AttnGeometry", "ExaoneMoE", "ExaoneMoEConfig", "exaone_moe_tiny"]
 
 FAMILY = "exaone_moe"
+
+
+@dataclasses.dataclass(frozen=True)
+class AttnGeometry:
+    """One mixer kind's attention, as data: what ``qkv``,
+    ``attend_sequence``, ``attend_one``, the pool and the decode read of
+    it (a config's :meth:`~ExaoneMoEConfig.geometry`)."""
+
+    n_kv_head: int
+    head_dim: int               # query and key columns a head
+    v_head_dim: int             # value columns a head
+    rope_dim: int               # leading columns of a q / k head that rotate
+    rope_theta: float
+    window: Optional[int]       # keys i - window < j <= i; None: all j <= i
+    qk_norm: bool = False       # RMSNorm per head on q and k
+    sink: bool = False          # a learned logit per query head, no value
+    value_scale: float = 1.0    # v = value_scale * (x Wv)
+
+    @property
+    def k_width(self) -> int:
+        """One cache position of one layer: all K heads side by side."""
+        return self.n_kv_head * self.head_dim
+
+    @property
+    def v_width(self) -> int:
+        return self.n_kv_head * self.v_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,6 +103,8 @@ class ExaoneMoEConfig:
     # The family's norm placement (:func:`decoder_block`): exaone4's, on
     # each branch's output.  Not a field: no configuration changes it.
     pre_norm = False
+    # A shared expert beside the routed ones (:func:`feed_forward`).
+    shared_expert = True
 
     def __post_init__(self):
         L = self.n_layer
@@ -119,10 +147,15 @@ class ExaoneMoEConfig:
     def n_vocab_held(self) -> int:
         return self.vocab_held[1] - self.vocab_held[0]
 
-    @property
-    def kv_width(self) -> int:
-        """One cache position of one layer: all K/V heads side by side."""
-        return self.n_kv_head * self.head_dim
+    def geometry(self, kind: str) -> AttnGeometry:
+        """Both kinds: the same heads, q/k normed; sliding layers rotate
+        every column and see the window."""
+        sliding = kind == "sliding"
+        return AttnGeometry(
+            self.n_kv_head, self.head_dim, self.head_dim,
+            rope_dim=self.head_dim if sliding else 0,
+            rope_theta=self.rope_theta,
+            window=self.window if sliding else None, qk_norm=True)
 
     def layers_of(self, kind: str) -> List[int]:
         return [i for i, t in enumerate(self.layer_types) if t == kind]
@@ -188,66 +221,100 @@ def swiglu(x: jax.Array, p: Dict[str, jax.Array], pre: str) -> jax.Array:
     return _mm(h, p[pre + "down"])
 
 
+def _partial_rope(x: jax.Array, positions: jax.Array,
+                  g: AttnGeometry) -> jax.Array:
+    """Rotary positions on a head's first ``g.rope_dim`` columns (their
+    own frequencies, ``theta ** (-2i / rope_dim)``); the rest pass."""
+    if g.rope_dim == x.shape[-1]:
+        return rope(x, positions, g.rope_theta)
+    r = g.rope_dim
+    return jnp.concatenate(
+        [rope(x[..., :r], positions, g.rope_theta), x[..., r:]], axis=-1)
+
+
 def qkv(cfg: ExaoneMoEConfig, p, x: jax.Array, positions: jax.Array,
-        kind: str):
-    """x ``(..., S, d)`` -> q ``(..., S, Hq, Dh)``, k, v ``(..., S, Hkv,
-    Dh)``: projected, q and k normalised per head, rotated on sliding
-    layers only."""
+        g: AttnGeometry):
+    """x ``(..., S, d)`` -> q ``(..., S, Hq, Dh)``, k ``(..., S, Hkv,
+    Dh)``, v ``(..., S, Hkv, Dv)``: projected (v scaled), q and k
+    normalised per head and rotated as the kind's geometry says."""
     lead = x.shape[:-1]
-    q = _mm(x, p["wq"]).reshape(lead + (cfg.n_head, cfg.head_dim))
-    k = _mm(x, p["wk"]).reshape(lead + (cfg.n_kv_head, cfg.head_dim))
-    v = _mm(x, p["wv"]).reshape(lead + (cfg.n_kv_head, cfg.head_dim))
-    q = rms_norm(q, p["q_norm"], cfg.rms_eps)
-    k = rms_norm(k, p["k_norm"], cfg.rms_eps)
-    if kind == "sliding":
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+    q = _mm(x, p["wq"]).reshape(lead + (cfg.n_head, g.head_dim))
+    k = _mm(x, p["wk"]).reshape(lead + (g.n_kv_head, g.head_dim))
+    if g.value_scale == 1.0:
+        v = _mm(x, p["wv"])
+    else:
+        v = (jnp.dot(x, p["wv"], preferred_element_type=jnp.float32)
+             * g.value_scale).astype(x.dtype)
+    v = v.reshape(lead + (g.n_kv_head, g.v_head_dim))
+    if g.qk_norm:
+        q = rms_norm(q, p["q_norm"], cfg.rms_eps)
+        k = rms_norm(k, p["k_norm"], cfg.rms_eps)
+    if g.rope_dim:
+        q = _partial_rope(q, positions, g)
+        k = _partial_rope(k, positions, g)
     return q, k, v
 
 
-def _grouped(cfg, q):
+def _grouped(g: AttnGeometry, q):
     """``(..., Hq, Dh)`` -> ``(..., Hkv, G, Dh)``: query head ``h``
     attends K/V head ``h // G``."""
-    return q.reshape(q.shape[:-2] + (cfg.n_kv_head,
-                                     cfg.n_head // cfg.n_kv_head,
-                                     cfg.head_dim))
+    hq, dh = q.shape[-2:]
+    return q.reshape(q.shape[:-2] + (g.n_kv_head, hq // g.n_kv_head, dh))
 
 
-def _softmax_pv(scores, v, eq):
-    probs = jax.nn.softmax(scores, axis=-1)
+def _probs(scores, sink):
+    """Softmax over the last axis.  With a ``sink`` (one logit a query
+    head, broadcastable to the scores but their last axis) the sink is
+    one more key that no value follows: ``exp(s_j) / (exp(b) + sum_j
+    exp(s_j))``."""
+    if sink is None:
+        return jax.nn.softmax(scores, axis=-1)
+    col = jnp.broadcast_to(sink.astype(jnp.float32),
+                           scores.shape[:-1] + (1,))
+    return jax.nn.softmax(jnp.concatenate([scores, col], axis=-1),
+                          axis=-1)[..., :-1]
+
+
+def _softmax_pv(scores, v, eq, sink=None):
+    probs = _probs(scores, sink)
     return jnp.einsum(eq, probs.astype(v.dtype), v,
                       preferred_element_type=jnp.float32)
 
 
-def attend_sequence(cfg: ExaoneMoEConfig, kind: str, q, k, v,
-                    attn_impl: str) -> jax.Array:
+def attend_sequence(g: AttnGeometry, q, k, v, attn_impl: str,
+                    sink=None) -> jax.Array:
     """Causal attention over whole sequences.  q ``(B, T, Hq, Dh)``,
-    k / v ``(B, T, Hkv, Dh)`` -> ``(B, T, Hq*Dh)``.  On sliding layers
-    key ``j`` is visible to query ``i`` iff ``i - window < j <= i``.
+    k ``(B, T, Hkv, Dh)``, v ``(B, T, Hkv, Dv)`` -> ``(B, T, Hq*Dv)``.
+    With a window, key ``j`` is visible to query ``i`` iff ``i - window
+    < j <= i``; ``sink`` ``(Hq,)`` float32 is each query head's sink
+    logit (:func:`_probs`).
 
-    No ``T x T`` score tensor at serving sizes: full layers go through
-    the flash kernel (K/V heads repeated to the query heads), sliding
-    layers through a banded form, each ``window``-sized chunk of queries
-    against its own and the previous chunk of keys.  Short or ragged
-    sequences (a bucket under 128, the tests' sizes) take the plain
-    masked form."""
+    No ``T x T`` score tensor at serving sizes: layers without a window
+    go through the flash kernel (K/V heads repeated to the query heads),
+    windowed ones through a banded form, each ``window``-sized chunk of
+    queries against its own and the previous chunk of keys.  Short or
+    ragged sequences (a bucket under 128, the tests' sizes) take the
+    plain masked form."""
     B, T, Hq, Dh = q.shape
-    G = Hq // cfg.n_kv_head
+    G = Hq // g.n_kv_head
+    Dv = v.shape[-1]
     scale = Dh ** -0.5
-    w = cfg.window
-    if kind == "full" and attn_impl == "flash":
+    w = g.window
+    if sink is not None:
+        sink = sink.reshape(g.n_kv_head, G)[:, :, None, None]
+    if w is None and sink is None and attn_impl == "flash":
         from ray_lightning_tpu.ops.flash_attention import flash_attention
 
         out = flash_attention(q, jnp.repeat(k, G, axis=2),
                               jnp.repeat(v, G, axis=2), scale=scale)
-        return out.reshape(B, T, Hq * Dh)
-    qg = _grouped(cfg, q)
-    if kind == "sliding" and T > w and T % w == 0:
+        return out.reshape(B, T, Hq * Dv)
+    qg = _grouped(g, q)
+    if w is not None and T > w and T % w == 0:
         n = T // w
-        qc = qg.reshape(B, n, w, cfg.n_kv_head, G, Dh)
+        qc = qg.reshape(B, n, w, g.n_kv_head, G, Dh)
 
         def two_chunks(z):
-            zc = z.reshape(B, n, w, cfg.n_kv_head, Dh)
+            zc = z.reshape(B, n, w, g.n_kv_head, z.shape[-1])
             prev = jnp.pad(zc, ((0, 0), (1, 0), (0, 0), (0, 0), (0, 0)))
             return jnp.concatenate([prev[:, :-1], zc], axis=2)
 
@@ -260,31 +327,34 @@ def attend_sequence(cfg: ExaoneMoEConfig, kind: str, q, k, v,
         first = kj >= w                          # chunk 0 has no previous
         vis = jnp.where(jnp.arange(n)[:, None, None] == 0, vis & first, vis)
         s = jnp.where(vis[None, :, None, None], s, _NEG_INF)
-        out = _softmax_pv(s, vc, "bckgqs,bcskd->bcqkgd")
-        return out.reshape(B, T, Hq * Dh).astype(q.dtype)
+        out = _softmax_pv(s, vc, "bckgqs,bcskd->bcqkgd", sink)
+        return out.reshape(B, T, Hq * Dv).astype(q.dtype)
     s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k,
                    preferred_element_type=jnp.float32) * scale
     qi = jnp.arange(T)[:, None]
     kj = jnp.arange(T)[None, :]
     vis = kj <= qi
-    if kind == "sliding":
+    if w is not None:
         vis = vis & (kj > qi - w)
     s = jnp.where(vis, s, _NEG_INF)
-    out = _softmax_pv(s, v, "bkgqs,bskd->bqkgd")
-    return out.reshape(B, T, Hq * Dh).astype(q.dtype)
+    out = _softmax_pv(s, v, "bkgqs,bskd->bqkgd", sink)
+    return out.reshape(B, T, Hq * Dv).astype(q.dtype)
 
 
-def attend_one(cfg: ExaoneMoEConfig, q, keys, values, visible) -> jax.Array:
+def attend_one(g: AttnGeometry, q, keys, values, visible,
+               sink=None) -> jax.Array:
     """One query token a slot against gathered cache positions.  q
-    ``(W, Hq, Dh)``; keys / values ``(W, S, Hkv, Dh)``; visible ``(W,
-    S)`` -> ``(W, Hq*Dh)`` float32.  Products of the stored values in
-    float32, as the paged kernel computes them."""
-    s = jnp.einsum("wkgd,wskd->wkgs", _grouped(cfg, q).astype(jnp.float32),
-                   keys.astype(jnp.float32)) * cfg.head_dim ** -0.5
+    ``(W, Hq, Dh)``; keys ``(W, S, Hkv, Dh)``, values ``(W, S, Hkv,
+    Dv)``; visible ``(W, S)`` -> ``(W, Hq*Dv)`` float32.  Products of
+    the stored values in float32, as the paged kernel computes them."""
+    s = jnp.einsum("wkgd,wskd->wkgs", _grouped(g, q).astype(jnp.float32),
+                   keys.astype(jnp.float32)) * q.shape[-1] ** -0.5
     s = jnp.where(visible[:, None, None, :], s, _NEG_INF)
-    out = jnp.einsum("wkgs,wskd->wkgd", jax.nn.softmax(s, axis=-1),
+    if sink is not None:
+        sink = sink.reshape(g.n_kv_head, -1)[:, :, None]
+    out = jnp.einsum("wkgs,wskd->wkgd", _probs(s, sink),
                      values.astype(jnp.float32))
-    return out.reshape(q.shape[0], cfg.n_head * cfg.head_dim)
+    return out.reshape(q.shape[0], q.shape[1] * values.shape[-1])
 
 
 def feed_forward(cfg: ExaoneMoEConfig, kind: str, p, x: jax.Array,
@@ -292,9 +362,10 @@ def feed_forward(cfg: ExaoneMoEConfig, kind: str, p, x: jax.Array,
                  routing: Optional[list] = None):
     """x ``(S, d)`` -> ``(F(x), counts int32[2])``.  ``dense``: one
     SiLU-gated MLP.  ``sparse``: the held experts' part of ``scale *
-    sum_e gate_e Expert_e(x)`` plus the shared expert, once.  A
-    ``routing`` list is handed each sparse layer's ``(chosen experts,
-    scores)``, for comparisons with a reference."""
+    sum_e gate_e Expert_e(x)``, plus the shared expert, once, where the
+    family has one (``cfg.shared_expert``).  A ``routing`` list is
+    handed each sparse layer's ``(chosen experts, scores)``, for
+    comparisons with a reference."""
     if kind == "dense":
         return swiglu(x, p, "w_"), jnp.zeros((2,), jnp.int32)
     idx, gates, z = sigmoid_topk_routing(
@@ -305,6 +376,8 @@ def feed_forward(cfg: ExaoneMoEConfig, kind: str, p, x: jax.Array,
     routed, counts = dropless_moe(
         x, idx, gates, p["e_gate"], p["e_up"], p["e_down"],
         cfg.experts_held[0], row_valid=row_valid, impl=moe_impl)
+    if not cfg.shared_expert:
+        return routed, counts
     return routed + swiglu(x, p, "s_"), counts
 
 
@@ -350,7 +423,7 @@ def sequence_forward(cfg: ExaoneMoEConfig, params, tokens: jax.Array,
                      routing: Optional[list] = None):
     """The trunk over whole sequences: tokens ``(B, T)`` (ids within the
     held vocabulary slice) -> ``(hidden (B, T, d) before the final norm,
-    per-layer (k, v) each (B, T, Hkv*Dh), counts int32[2])``."""
+    per-layer (k (B, T, Hkv*Dh), v (B, T, Hkv*Dv)), counts int32[2])``."""
     B, T = tokens.shape
     attn_impl = _resolve_attn(attn_impl, T)
     x = params["embed"][tokens]
@@ -359,11 +432,12 @@ def sequence_forward(cfg: ExaoneMoEConfig, params, tokens: jax.Array,
     kv, counts = [], jnp.zeros((2,), jnp.int32)
     for p, kind, mlp in zip(params["layers"], cfg.layer_types,
                             cfg.mlp_types):
-        def mixer(h, p=p, kind=kind):
-            q, k, v = qkv(cfg, p, h, positions, kind)
-            kv.append((k.reshape(B, T, cfg.kv_width),
-                       v.reshape(B, T, cfg.kv_width)))
-            return _mm(attend_sequence(cfg, kind, q, k, v, attn_impl),
+        def mixer(h, p=p, g=cfg.geometry(kind)):
+            q, k, v = qkv(cfg, p, h, positions, g)
+            kv.append((k.reshape(B, T, g.k_width),
+                       v.reshape(B, T, g.v_width)))
+            return _mm(attend_sequence(g, q, k, v, attn_impl,
+                                       p["sink"] if g.sink else None),
                        p["wo"])
 
         x, c = decoder_block(cfg, p, x, mixer, mlp, valid, moe_impl, routing)
@@ -381,9 +455,11 @@ def head_logits(cfg: ExaoneMoEConfig, params, x: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 class TwoKindKVCache:
-    """Full layers keep ``(Lg, N, Bs, Hkv*Dh)`` and a block table that
-    grows with the sequence; sliding layers keep ``(Ls, Nw, Bs,
-    Hkv*Dh)`` and a ring of ``window_blocks = ceil(window / Bs) + 1``
+    """Full layers keep ``(Lg, N, Bs, row)`` and a block table that
+    grows with the sequence; sliding layers keep ``(Ls, Nw, Bs, row)``
+    (each kind's K and V rows of their own widths, ``Hkv*Dh`` and
+    ``Hkv*Dv`` of its geometry) and a ring of ``window_blocks =
+    ceil(window / Bs) + 1``
     blocks a slot: position ``p`` lives in ring block ``(p // Bs) %
     window_blocks`` at offset ``p % Bs``, so a write evicts the position
     one ring length back, which the window has already passed.  Both
@@ -391,10 +467,12 @@ class TwoKindKVCache:
     slot costs ``window_blocks + ceil(len / Bs)`` blocks."""
 
     def __init__(self, cfg: ExaoneMoEConfig, num_blocks: int,
-                 block_size: int, num_slots: int, dtype=jnp.bfloat16):
+                 block_size: int, num_slots: int, dtype=jnp.bfloat16,
+                 family=None):
         from ray_lightning_tpu.serve.kv_cache import BlockAllocator
 
         self.cfg = cfg
+        self.family = family or ServeFamily     # named in its refusals
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.dtype = dtype
@@ -406,22 +484,21 @@ class TwoKindKVCache:
 
     def init_pool(self) -> Dict[str, jax.Array]:
         cfg, Bs = self.cfg, self.block_size
-        full = (len(cfg.layers_of("full")), self.num_blocks, Bs,
-                cfg.kv_width)
-        ring = (len(cfg.layers_of("sliding")), self.num_window_blocks, Bs,
-                cfg.kv_width)
-        return {"k": jnp.zeros(full, self.dtype),
-                "v": jnp.zeros(full, self.dtype),
-                "wk": jnp.zeros(ring, self.dtype),
-                "wv": jnp.zeros(ring, self.dtype)}
+        full, ring = cfg.geometry("full"), cfg.geometry("sliding")
+        full_at = (len(cfg.layers_of("full")), self.num_blocks, Bs)
+        ring_at = (len(cfg.layers_of("sliding")), self.num_window_blocks, Bs)
+        return {"k": jnp.zeros(full_at + (full.k_width,), self.dtype),
+                "v": jnp.zeros(full_at + (full.v_width,), self.dtype),
+                "wk": jnp.zeros(ring_at + (ring.k_width,), self.dtype),
+                "wv": jnp.zeros(ring_at + (ring.v_width,), self.dtype)}
 
     def blocks_for(self, n_tokens: int) -> int:
         return -(-n_tokens // self.block_size)
 
     def export_blocks(self, pool, ids):
         raise ValueError(
-            f"export_blocks is not supported for the {FAMILY} family: "
-            f"{ServeFamily.refuses_why}")
+            f"export_blocks is not supported for the {self.family.name} "
+            f"family: {self.family.refuses_why}")
 
 
 def paged_prefill(cfg: ExaoneMoEConfig, params, pool, tokens, prompt_len,
@@ -501,17 +578,18 @@ def paged_decode_step(cfg: ExaoneMoEConfig, params, pool, block_tables,
     n_full = n_ring = 0
     for p, kind, mlp in zip(params["layers"], cfg.layer_types,
                             cfg.mlp_types):
-        def mixer(h, p=p, kind=kind, n_full=n_full, n_ring=n_ring):
-            q, k, v = qkv(cfg, p, h[:, None], pos[:, None], kind)
+        def mixer(h, p=p, kind=kind, g=cfg.geometry(kind), n_full=n_full,
+                  n_ring=n_ring):
+            q, k, v = qkv(cfg, p, h[:, None], pos[:, None], g)
             q, k, v = q[:, 0], k[:, 0], v[:, 0]
-            k_row = k.reshape(W, cfg.kv_width).astype(pool["k"].dtype)
-            v_row = v.reshape(W, cfg.kv_width).astype(pool["v"].dtype)
+            k_row = k.reshape(W, g.k_width).astype(pool["k"].dtype)
+            v_row = v.reshape(W, g.v_width).astype(pool["v"].dtype)
             if kind == "full" and attn_impl == "pallas":
                 att = paged_decode_attention(
                     q.reshape(W, -1), k_row, v_row, pool["k"], pool["v"],
                     jnp.int32(n_full), full_tables, pos,
-                    n_head=cfg.n_head, n_kv_head=cfg.n_kv_head,
-                    scale=cfg.head_dim ** -0.5)
+                    n_head=cfg.n_head, n_kv_head=g.n_kv_head,
+                    scale=g.head_dim ** -0.5)
             else:
                 names, n, tables, S, vis = (
                     (("k", "v"), n_full, full_tables, M * Bs, full_vis)
@@ -521,12 +599,13 @@ def paged_decode_step(cfg: ExaoneMoEConfig, params, pool, block_tables,
                 def ctx(t, row):
                     got = t[n][tables].reshape(W, S, -1)
                     got = jnp.concatenate([got, row[:, None]], axis=1)
-                    return got.reshape(W, S + 1, cfg.n_kv_head, -1)
+                    return got.reshape(W, S + 1, g.n_kv_head, -1)
 
                 att = attend_one(
-                    cfg, q, ctx(pool[names[0]], k_row),
+                    g, q, ctx(pool[names[0]], k_row),
                     ctx(pool[names[1]], v_row),
-                    jnp.concatenate([vis, one], axis=1))
+                    jnp.concatenate([vis, one], axis=1),
+                    p["sink"] if g.sink else None)
             pre = "" if kind == "full" else "w"
             new[pre + "k"].append(k_row)
             new[pre + "v"].append(v_row)
@@ -592,7 +671,7 @@ class ServeFamily:
     def make_cache(self, num_blocks: int, block_size: int, num_slots: int,
                    dtype) -> TwoKindKVCache:
         return TwoKindKVCache(self.cfg, num_blocks, block_size, num_slots,
-                              dtype)
+                              dtype, family=self)
 
     def prepare_params(self, tree: Dict[str, Any],
                        compute_dtype) -> Dict[str, Any]:
@@ -650,9 +729,10 @@ def init_tree(cfg, rng: jax.Array, mixer_leaves,
                                      jnp.float32) * 0.02,
             router_bias=bias,
             e_gate=experts(ks[5], (d, f)), e_up=experts(ks[6], (d, f)),
-            e_down=experts(ks[7], (f, d)),
-            s_gate=w(ks[8], (d, f)), s_up=w(ks[9], (d, f)),
-            s_down=w(ks[10], (f, d)))
+            e_down=experts(ks[7], (f, d)))
+        if cfg.shared_expert:
+            p.update(s_gate=w(ks[8], (d, f)), s_up=w(ks[9], (d, f)),
+                     s_down=w(ks[10], (f, d)))
         return p
 
     vh = cfg.n_vocab_held
@@ -702,11 +782,11 @@ class ExaoneMoE(TpuModule):
         d = cfg.d_model
 
         def mixer_leaves(w, ks, kind):
-            del kind        # both mixer kinds hold the same tensors
-            hq, hkv = cfg.n_head * cfg.head_dim, cfg.kv_width
+            g = cfg.geometry(kind)      # both kinds: the same widths
+            hq = cfg.n_head * g.head_dim
             return {
-                "wq": w(ks[0], (d, hq)), "wk": w(ks[1], (d, hkv)),
-                "wv": w(ks[2], (d, hkv)), "wo": w(ks[3], (hq, d)),
+                "wq": w(ks[0], (d, hq)), "wk": w(ks[1], (d, g.k_width)),
+                "wv": w(ks[2], (d, g.v_width)), "wo": w(ks[3], (hq, d)),
                 "q_norm": jnp.ones((cfg.head_dim,), jnp.float32),
                 "k_norm": jnp.ones((cfg.head_dim,), jnp.float32),
                 "attn_out_norm": jnp.ones((d,), jnp.float32),

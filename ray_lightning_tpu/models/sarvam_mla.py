@@ -92,8 +92,10 @@ class SarvamMLAConfig:
     vocab_held: Optional[Tuple[int, int]] = None
     param_dtype: str = "bfloat16"
 
-    # The family's norm placement (``exaone_moe.decoder_block``).
+    # The family's norm placement (``exaone_moe.decoder_block``) and its
+    # shared expert (``exaone_moe.feed_forward``).
     pre_norm = True
+    shared_expert = True
 
     def __post_init__(self):
         if self.experts_held is None:

@@ -127,13 +127,11 @@ def paged_decode_tiles(pool_k: jax.Array) -> bool:
 
 
 def _pages_per_chunk(m: int, bs: int, positions: int) -> int:
-    """Largest divisor of the table width ``m`` whose blocks cover at
-    most ``positions`` positions (a chunk never straddles the end of a
-    table row)."""
-    p = max(1, min(m, positions // bs))
-    while m % p:
-        p -= 1
-    return p
+    """Blocks of a chunk: as many as cover ``positions`` positions, at
+    most the table width ``m``.  It need not divide ``m``: a slot's walk
+    stops at its table's end (``_table_walk`` ``n_vis``), so the last
+    chunk of a full table holds what is left of it."""
+    return max(1, min(m, positions // bs))
 
 
 def _tail_width(width: int, bs: int) -> int:
@@ -335,7 +333,7 @@ def _kernel(layer_ref, tables_ref, lens_ref,            # scalar prefetch
             q_ref, kc_ref, vc_ref, k_hbm, v_hbm,        # inputs
             o_ref,                                      # output
             kbuf, vbuf, sems, marks,                    # scratch
-            *, n_head, n_kv_head, head_dim, rows, scale, pages,
+            *, n_head, n_kv_head, head_dim, v_head_dim, rows, scale, pages,
             table_width, block_size):
     w = pl.program_id(0)
     n_slots = pl.num_programs(0)
@@ -346,18 +344,22 @@ def _kernel(layer_ref, tables_ref, lens_ref,            # scalar prefetch
     _open_walk(w, marks, chunk_dma)
 
     group = n_head // n_kv_head
-    hd = n_kv_head * head_dim           # the pool's row
-    row = jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 1)
+
+    def heads_of(width):
+        """``(rows, Hkv*width)``: True where row h meets the columns of
+        the K/V head it reads, heads ``width`` columns apart."""
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, n_kv_head * width), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (rows, n_kv_head * width), 1)
+        kv = row if group == 1 else row // group
+        return (col >= kv * width) & (col < (kv + 1) * width)
+
+    hv = n_kv_head * v_head_dim         # the V pool's row
+    diag = heads_of(head_dim)           # on the K pool's row
     if group == 1:
-        diag = (col >= row * head_dim) & (col < (row + 1) * head_dim)
         q_rows = q_ref[0]               # (1, hd): every row the same
     else:
         # Query head h scores K/V head h // group: row h holds its
         # query in that head's columns, zeros elsewhere.
-        kv_of_row = row // group
-        diag = ((col >= kv_of_row * head_dim)
-                & (col < (kv_of_row + 1) * head_dim))
         q_rows = jnp.concatenate([q_ref[0]] * n_kv_head, axis=1)
     q32 = jnp.where(diag, q_rows.astype(jnp.float32), 0.0)  # (rows, hd)
     q_bd = q32.astype(q_ref.dtype)  # exact: a cast back to q's own dtype
@@ -367,7 +369,7 @@ def _kernel(layer_ref, tables_ref, lens_ref,            # scalar prefetch
     m0 = jnp.sum(q32 * kc_ref[0].astype(jnp.float32), axis=1,
                  keepdims=True) * scale
     l0 = jnp.ones((rows, 1), jnp.float32)
-    acc0 = jnp.broadcast_to(vc_ref[0].astype(jnp.float32), (rows, hd))
+    acc0 = jnp.broadcast_to(vc_ref[0].astype(jnp.float32), (rows, hv))
 
     def attend(buf, width, carry, rem=None):
         at = (buf, pl.ds(0, width))
@@ -379,14 +381,15 @@ def _kernel(layer_ref, tables_ref, lens_ref,            # scalar prefetch
     acc, _, l = _walk_chunks(
         w, n_slots, n_vis(w), n_pages(w), chunk_dma, marks, attend, (vbuf,),
         (acc0, m0, l0), pages=pages, block_size=block_size)
-    out = jnp.where(diag, acc / l, 0.0)
+    vdiag = diag if v_head_dim == head_dim else heads_of(v_head_dim)
+    out = jnp.where(vdiag, acc / l, 0.0)
     if group == 1:
         o_ref[0] = jnp.sum(out, axis=0, keepdims=True)
     else:
-        # Row h's output lies in its K/V head's columns: fold the
-        # column groups onto one head's width.
+        # Row h's output lies in its K/V head's value columns: fold the
+        # column groups onto one head's value width.
         o_ref[0] = functools.reduce(
-            jnp.add, [out[:, g * head_dim:(g + 1) * head_dim]
+            jnp.add, [out[:, g * v_head_dim:(g + 1) * v_head_dim]
                       for g in range(n_kv_head)])
 
 
@@ -407,16 +410,20 @@ def paged_decode_attention(
     """Attention of one new token per slot over its paged cache.
 
     With ``n_kv_head`` fewer than ``n_head`` (grouped queries) the
-    pool's row is ``Hkv*Dh`` wide and query head ``h`` attends K/V head
-    ``h // (H / Hkv)``; ``None`` is one K/V head per query head, the
-    program it always was.
+    pools' rows are ``Hkv*Dh`` (K) and ``Hkv*Dv`` (V) wide and query
+    head ``h`` attends K/V head ``h // (H / Hkv)``; the value width
+    ``Dv`` is the V pool's own (``Dh`` where the two pools' rows are
+    alike).  ``None`` is one K/V head per query head, the program it
+    always was.
 
     Args:
         q: ``(W, H*Dh)`` queries (any float dtype).
-        k_cur, v_cur: ``(W, Hkv*Dh)`` the new token's own K and V rows
-            in the pool's dtype (what the caller will store at position
-            ``seq_lens[w]``); the pool is not read there.
-        k_pool, v_pool: ``(L, N, Bs, Hkv*Dh)``, read at ``layer`` only.
+        k_cur, v_cur: ``(W, Hkv*Dh)``, ``(W, Hkv*Dv)`` the new token's
+            own K and V rows in the pools' dtype (what the caller will
+            store at position ``seq_lens[w]``); the pool is not read
+            there.
+        k_pool, v_pool: ``(L, N, Bs, Hkv*Dh)``, ``(L, N, Bs, Hkv*Dv)``,
+            read at ``layer`` only.
         layer: scalar int32 layer index.
         block_tables: ``(W, M)`` int32 physical block ids.
         seq_lens: ``(W,)`` int32 cache positions already in the pool per
@@ -424,45 +431,55 @@ def paged_decode_attention(
             (clamped to the table's ``M * Bs``).
 
     Returns:
-        ``(W, H*Dh)`` float32, head ``h`` in columns ``[h*Dh, (h+1)*Dh)``.
+        ``(W, H*Dv)`` float32, head ``h`` in columns ``[h*Dv, (h+1)*Dv)``.
     """
     W, qd = q.shape
     _, _, Bs, hd = k_pool.shape
+    hv = v_pool.shape[3]
     M = block_tables.shape[1]
     n_kv_head = n_head if n_kv_head is None else n_kv_head
     head_dim = qd // n_head
+    v_head_dim = hv // n_kv_head
     grouped = n_kv_head != n_head
-    if (not paged_decode_tiles(k_pool) or hd != n_kv_head * head_dim
+    # Grouped, the output folds value columns one head wide: whole lanes.
+    if (not paged_decode_tiles(k_pool) or not paged_decode_tiles(v_pool)
+            or hd != n_kv_head * head_dim or hv != n_kv_head * v_head_dim
             or n_head % n_kv_head
-            or (grouped and (head_dim % 128 or n_head % 16))):
+            or (grouped and (v_head_dim % 128 or n_head % 16))
+            or (not grouped and hv != hd)):
         raise ValueError(
             f"rlt_paged_decode does not tile a {k_pool.dtype} pool of "
-            f"shape {k_pool.shape} for {n_head} query heads of "
-            f"{head_dim} on {n_kv_head} K/V heads: take the XLA path "
-            f"(attn_impl='xla' or 'auto')"
+            f"shape {k_pool.shape} (values {v_pool.shape}) for {n_head} "
+            f"query heads of {head_dim} on {n_kv_head} K/V heads: take the "
+            f"XLA path (attn_impl='xla' or 'auto')"
         )
     P = _pages_per_chunk(M, Bs, CHUNK_POSITIONS)
     rows = -(-n_head // 16) * 16  # whole bf16 sublane tiles, _split3 stacks
     kernel = functools.partial(
         _kernel, n_head=n_head, n_kv_head=n_kv_head, head_dim=head_dim,
-        rows=rows, scale=scale, pages=P, table_width=M, block_size=Bs,
+        v_head_dim=v_head_dim, rows=rows, scale=scale, pages=P,
+        table_width=M, block_size=Bs,
     )
     row_spec = pl.BlockSpec((1, 1, hd), lambda w, *_: (w, 0, 0))
-    # Grouped queries arrive one head a row, (W, H, Dh), and leave so.
+    v_row_spec = pl.BlockSpec((1, 1, hv), lambda w, *_: (w, 0, 0))
+    # Grouped queries arrive one head a row, (W, H, Dh), and leave so
+    # at the value width, (W, H, Dv).
     q_spec = pl.BlockSpec((1, n_head, head_dim), lambda w, *_: (w, 0, 0)) \
+        if grouped else row_spec
+    o_spec = pl.BlockSpec((1, n_head, v_head_dim), lambda w, *_: (w, 0, 0)) \
         if grouped else row_spec
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(W,),
         in_specs=[
-            q_spec, row_spec, row_spec,
+            q_spec, row_spec, v_row_spec,
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=q_spec,
+        out_specs=o_spec,
         scratch_shapes=[
             pltpu.VMEM((2, P * Bs, hd), k_pool.dtype),
-            pltpu.VMEM((2, P * Bs, hd), v_pool.dtype),
+            pltpu.VMEM((2, P * Bs, hv), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((3,), jnp.int32),
         ],
@@ -471,14 +488,14 @@ def paged_decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
-            (W, n_head, head_dim) if grouped else (W, 1, hd), jnp.float32),
+            (W, n_head, v_head_dim) if grouped else (W, 1, hd), jnp.float32),
         # Slots run in order: each step leaves the next slot's first
         # chunk in flight and the walk's marks in SMEM.
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_vmem_limit(
-                2 * P * Bs * hd * (k_pool.dtype.itemsize
-                                   + v_pool.dtype.itemsize)),
+                2 * P * Bs * (hd * k_pool.dtype.itemsize
+                              + hv * v_pool.dtype.itemsize)),
         ),
         interpret=_interpret(),
         name="rlt_paged_decode",
@@ -488,11 +505,11 @@ def paged_decode_attention(
         seq_lens.astype(jnp.int32),
         q.reshape(W, n_head, head_dim) if grouped else q.reshape(W, 1, hd),
         k_cur.reshape(W, 1, hd),
-        v_cur.reshape(W, 1, hd),
+        v_cur.reshape(W, 1, hv),
         k_pool,
         v_pool,
     )
-    return out.reshape(W, qd)
+    return out.reshape(W, n_head * v_head_dim)
 
 
 # ---------------------------------------------------------------------------

@@ -1638,6 +1638,12 @@ class ServeEngine:
                 **by_kind,
             }
         kv_blocks = {f"decode_kv_blocks_{k}": v for k, v in kv_blocks.items()}
+        if self.family.two_kind:
+            # Positions the full layers' decode attends this tick, the
+            # current token's own included, over the full layers.
+            # rlt: noqa[RLT002] host ints (scheduler.seq_lens), no device value
+            kv_blocks["decode_full_positions"] = self.family.n_full * int(
+                self.scheduler.seq_lens[active].sum() + len(active))
         if self._n_latent:
             # Positions the latent decode attends this tick, the current
             # token's own included, over the latent layers.

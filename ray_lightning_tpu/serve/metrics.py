@@ -67,6 +67,9 @@ _COUNTER_KEYS = (
     # table: the walk's online-softmax steps and the positions they
     # compute (``ops.paged_attention.walk_plan``).
     "decode_attn_steps", "decode_attn_positions_computed",
+    # Per decode tick, a two-kind pool's: the positions its full layers
+    # attend (the active slots' seq_len + 1, over those layers).
+    "decode_full_positions",
     # Of ``decode_steps``: ticks read from a decode dispatched ahead of
     # them, and those of them whose ``tokens`` operand was the tick
     # before's output where it lay on the device.  Reply frames sent

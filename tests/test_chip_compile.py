@@ -510,6 +510,30 @@ def test_gqa_paged_decode_kernel_compiles_at_serve_cell_shapes(one_chip):
     )
 
 
+def test_gqa_paged_decode_kernel_compiles_with_values_of_their_own_width(
+        one_chip):
+    """64 query heads on 4 K/V heads, keys 192 wide and values 128, over
+    the three full layers' pool of ``mimo-v2-flash-ep16.serve-longprompt``
+    (32 slots x 212 blocks of 32, the engine's default 33 x 212 + 1
+    blocks; rows of 768 and 512 lanes): a table of no whole number of
+    the walk's steps (6 of 32 blocks and 20 more)."""
+    from ray_lightning_tpu.ops.paged_attention import paged_decode_attention
+
+    W, M, N = 32, 212, 33 * 212 + 1
+    assert "rlt_paged_decode" in _compile(
+        lambda *a: paged_decode_attention(
+            *a, n_head=64, n_kv_head=4, scale=192 ** -0.5),
+        _sds((W, 64 * 192), jnp.bfloat16, one_chip),
+        _sds((W, 768), jnp.bfloat16, one_chip),
+        _sds((W, 512), jnp.bfloat16, one_chip),
+        _sds((3, N, 32, 768), jnp.bfloat16, one_chip),
+        _sds((3, N, 32, 512), jnp.bfloat16, one_chip),
+        _sds((), jnp.int32, one_chip),
+        _sds((W, M), jnp.int32, one_chip),
+        _sds((W,), jnp.int32, one_chip),
+    )
+
+
 def _moe_programs(one_chip):
     from ray_lightning_tpu.models import exaone_moe as em
 

@@ -75,10 +75,11 @@ def test_loss_has_gradients_on_the_xla_path(tiny):
 def test_all_shares_add_up_to_the_uncut_layer(fam):
     """The parts all the shares give (four of 4 experts for
     ``exaone_moe``; eight of 2, under a drawn selection bias, for
-    ``sarvam_mla``), the shared expert counted once, are the uncut layer
-    (16 of 16 experts)."""
+    ``sarvam_mla``; sixteen of 1, under a drawn bias and with no shared
+    expert, for ``mimo_v2``), the shared expert counted once where the
+    family has one, are the uncut layer (16 of 16 experts)."""
     cfg, Module, ref_mod = fam.preset(), fam.Module, fam.ref
-    shares = 4 if fam is Exaone else 8
+    shares = fam.shares
     whole_cfg = dataclasses.replace(cfg, experts_held=(0, 16))
     module = Module(whole_cfg)
     p = module.init_params(jax.random.PRNGKey(3))["layers"][2]
@@ -88,7 +89,7 @@ def test_all_shares_add_up_to_the_uncut_layer(fam):
     feed_forward = jax.jit(em.feed_forward, static_argnums=(0, 1, 4, 5))
     whole, counts = feed_forward(whole_cfg, "sparse", p, x, None, "xla")
     assert int(counts[0]) == 24 * cfg.top_k
-    shared = em.swiglu(x, p, "s_")
+    shared = em.swiglu(x, p, "s_") if cfg.shared_expert else 0.0
     routed = jnp.zeros_like(x)
     held = 16 // shares
     for lo in range(0, 16, held):
@@ -238,8 +239,8 @@ def test_gqa_paged_decode_kernel_under_the_interpreter(dtype, case):
 
     vis = jnp.concatenate([jnp.arange(M * Bs)[None] < lens[:, None],
                            jnp.ones((W, 1), bool)], 1)
-    want = em.attend_one(cfg, q.reshape(W, Hq, Dh), ctx(pool_k, k_cur),
-                         ctx(pool_v, v_cur), vis)
+    want = em.attend_one(cfg.geometry("full"), q.reshape(W, Hq, Dh),
+                         ctx(pool_k, k_cur), ctx(pool_v, v_cur), vis)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2   # bf16: 8 bits of the inputs
     assert float(jnp.abs(got - want).max()) < tol
 
@@ -309,7 +310,9 @@ def test_two_kind_cache_sizes_its_pools():
     cfg = exaone_moe_tiny()
     cache = em.TwoKindKVCache(cfg, 33, 4, 5, jnp.float32)
     pool = cache.init_pool()
-    assert pool["k"].shape == (2, 33, 4, cfg.kv_width)
-    assert pool["wk"].shape == (6, 5 * 3 + 1, 4, cfg.kv_width)
+    row = cfg.geometry("full").k_width
+    assert row == cfg.geometry("sliding").k_width == cfg.n_kv_head * cfg.head_dim
+    assert pool["k"].shape == (2, 33, 4, row)
+    assert pool["wk"].shape == (6, 5 * 3 + 1, 4, row)
     served = ExaoneMoEConfig(n_layer=8)
     assert em.TwoKindKVCache(served, 9, 32, 32).window_blocks == 5

@@ -42,12 +42,11 @@ def _own_blocks(w, n=M, m=M):
     return [1 + w * m + j for j in range(n)]
 
 
-def _walk(lens):
-    """Tables of ``M_WALK`` entries, each slot the blocks its length and
-    the new token need (at most the table), the rest trash."""
-    return _tables([_own_blocks(w, min(-(-(n + 1) // BS), M_WALK)
-                                if n else 0, M_WALK)
-                    for w, n in enumerate(lens)], M_WALK)
+def _walk(lens, m=M_WALK):
+    """Tables of ``m`` entries, each slot the blocks its length and the
+    new token need (at most the table), the rest trash."""
+    return _tables([_own_blocks(w, min(-(-(n + 1) // BS), m) if n else 0, m)
+                    for w, n in enumerate(lens)], m)
 
 
 def _scenario(name):
@@ -73,6 +72,15 @@ def _scenario(name):
         end = M_WALK * BS
         lens = [end + 3, 2 * WIDE + 129, end + WIDE + 1, WIDE + 1, 5]
         return _walk(lens), lens, [end] * len(lens), [1, 3, 4]
+    if name == "walk_ragged_table":
+        # A table of no whole number of steps (253 blocks: 3 steps and
+        # 61 blocks): a full slot's last chunk is what is left of the
+        # table, a stray past it walks to its end; the idle slot writes
+        # trash, which the XLA path reads back.
+        m = M_WALK - 3
+        end = m * BS
+        lens = [end - 1, 3 * WIDE + 5, end + 7, 3 * WIDE - 1, 0, 129]
+        return (_walk(lens, m), lens, [end] * len(lens), [0, 1, 3, 5])
     if name == "length_edges":
         lens = [0, 1, BS - 1, BS, BS + 1, S - 1]
         return _tables([_own_blocks(w) for w in every]), lens, None, every
@@ -103,7 +111,7 @@ def _scenario(name):
 
 _SCENARIOS = ["length_edges", "inactive_all_trash", "shared_prefix",
               "write_limit_past_table", "walk_short_edges", "walk_long_edges",
-              "walk_past_table"]
+              "walk_past_table", "walk_ragged_table"]
 # dtype of the pool and of the compute; logits tolerance as flash's:
 # absolute 1e-5 in float32, 1e-2 of the largest logit in bfloat16.
 _DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
@@ -172,14 +180,61 @@ def test_kernel_matches_xla_path(models, name, dtype, width):
         assert changed.any(axis=-1).sum() <= cfg.n_layer * len(scenario[1])
 
 
+@pytest.mark.parametrize("name,dtype", [
+    (name, dtype) for name in ("length_edges", "walk_past_table",
+                               "walk_ragged_table")
+    for dtype in sorted(_DTYPES)])
+def test_grouped_kernel_reads_values_of_their_own_width(name, dtype):
+    """64 query heads on 4 K/V heads, keys 192 and values 128 wide (the
+    MiMo-V2-Flash full layers' rows of 768 and 512 lanes): the kernel
+    under the interpreter against the XLA gather of the pattern
+    decoder's decode (``exaone_moe.attend_one``), the walk's strays past
+    their table among the slots."""
+    from ray_lightning_tpu.models.exaone_moe import AttnGeometry, attend_one
+
+    H, g = 64, AttnGeometry(4, 192, 128, rope_dim=0, rope_theta=1.0,
+                            window=None)
+    dt = _DTYPES[dtype]
+    bt, lens, _, used = _scenario(name)
+    W_, M_ = bt.shape
+    n = int(bt.max()) + 1
+    ks = jax.random.split(jax.random.PRNGKey(2), 5)
+    k_pool = jax.random.normal(ks[0], (1, n, BS, g.k_width)).astype(dt)
+    v_pool = jax.random.normal(ks[1], (1, n, BS, g.v_width)).astype(dt)
+    q = jax.random.normal(ks[2], (W_, H * g.head_dim)).astype(dt)
+    k_cur = jax.random.normal(ks[3], (W_, g.k_width)).astype(dt)
+    v_cur = jax.random.normal(ks[4], (W_, g.v_width)).astype(dt)
+    tables, seq = jnp.asarray(bt), jnp.asarray(lens, jnp.int32)
+    got = paged_attention.paged_decode_attention(
+        q, k_cur, v_cur, k_pool, v_pool, jnp.int32(0), tables, seq,
+        n_head=H, n_kv_head=g.n_kv_head, scale=g.head_dim ** -0.5)
+
+    def ctx(pool, row, width):
+        rows = pool[0][tables].reshape(W_, M_ * BS, -1)
+        rows = jnp.concatenate([rows, row[:, None]], axis=1)
+        return rows.reshape(W_, M_ * BS + 1, g.n_kv_head, width)
+
+    visible = jnp.concatenate(
+        [jnp.arange(M_ * BS)[None, :] < seq[:, None],
+         jnp.ones((W_, 1), bool)], axis=1)
+    want = attend_one(g, q.reshape(W_, H, g.head_dim),
+                      ctx(k_pool, k_cur, g.head_dim),
+                      ctx(v_pool, v_cur, g.v_head_dim), visible)
+    assert got.shape == (W_, H * g.v_head_dim)
+    want, got = np.asarray(want)[used], np.asarray(got)[used]
+    assert np.isfinite(got).all()
+    gap = np.abs(got - want).max()
+    assert gap < (1e-5 if dt == jnp.float32 else 1e-2), gap
+
+
 def _walk_by_hand(n, m, bs, wide):
     """The kernel's walk of one slot of ``n`` positions, step by step:
-    chunks of the width ``_pages_per_chunk`` gives, every one whole but
-    the last, which computes the 128-position sub-tiles holding the
-    slot's positions (the whole chunk where 128 does not divide it)."""
+    chunks of ``wide`` positions (at most the table, at least a block),
+    every one whole but the last, which computes the 128-position
+    sub-tiles holding the slot's positions (the whole chunk where 128
+    does not divide it)."""
     n = min(n, m * bs)
-    pages = max(p for p in range(1, m + 1)
-                if m % p == 0 and (p * bs <= wide or p == 1))
+    pages = max(1, min(m, wide // bs))
     width = pages * bs
     tail = 128 if width % 128 == 0 else width
     steps = positions = 0
@@ -196,8 +251,10 @@ def _walk_by_hand(n, m, bs, wide):
 
 
 @pytest.mark.parametrize("wide", [128, 256, 512, 1024])
-@pytest.mark.parametrize("table", [(32, 32), (96, 16), (224, 32), (6, 16)],
-                         ids=["gpt2_cell", "walk", "sarvam", "narrow"])
+@pytest.mark.parametrize("table", [(32, 32), (96, 16), (224, 32), (6, 16),
+                                   (212, 32)],
+                         ids=["gpt2_cell", "walk", "sarvam", "narrow",
+                              "ragged"])
 def test_walk_plan_counts_the_walk(monkeypatch, table, wide):
     """``walk_plan`` against the walk counted slot by slot, at every
     length from 0 to the table's end and past it (clamped), at the

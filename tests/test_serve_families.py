@@ -4,7 +4,7 @@ decode through its cache, and the engine through the client plane,
 against the family's plain reference (``benchmarks/reference/``) at its
 tiny preset; what it refuses, by its name; its cell's rehearsal.  A new
 family is one row of ``FAMILIES`` here and a file of what only it has
-(``test_exaone_moe.py``, ``test_sarvam_mla.py``).
+(``test_exaone_moe.py``, ``test_sarvam_mla.py``, ``test_mimo_v2.py``).
 
 Tolerances.  The program and the reference compute the same float32
 sums in another order (``sarvam_mla``'s decode, in the absorbed form, a
@@ -28,8 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.reference import exaone_moe_ref, sarvam_mla_ref
-from ray_lightning_tpu.models import exaone_moe, sarvam_mla
+from benchmarks.reference import exaone_moe_ref, mimo_v2_ref, sarvam_mla_ref
+from ray_lightning_tpu.models import exaone_moe, mimo_v2, sarvam_mla
 from ray_lightning_tpu.serve import ServeClient, ServeConfig, ServeEngine
 from utils import draw_tokens, reference_logits, tiny_family
 
@@ -57,6 +57,8 @@ class Exaone:       # the tiny preset: ``test_exaone_moe.py``
     config_file = "k-exaone-236b-a23b-ep8.json"
     cell = "k-exaone-236b-a23b-ep8.serve-mixed"
     why = "window rings"
+    depth, reduced = 8, {"num_hidden_layers", "num_experts", "vocab_size"}
+    shares = 4          # of the preset's 16 experts, 4 a share
 
     @staticmethod
     def published(cfg, doc):
@@ -116,6 +118,8 @@ class Sarvam:       # the tiny preset: ``test_sarvam_mla.py``
     config_file = "sarvam-105b-ep8.json"
     cell = "sarvam-105b-ep8.serve-longctx"
     why = "latent rows"
+    depth, reduced = 8, {"num_hidden_layers", "num_experts", "vocab_size"}
+    shares = 8
 
     @staticmethod
     def published(cfg, doc):
@@ -174,7 +178,88 @@ class Sarvam:       # the tiny preset: ``test_sarvam_mla.py``
                 > in_window["original_context"])
 
 
-FAMILIES = (Exaone, Sarvam)
+class MiMo:         # the tiny preset: ``test_mimo_v2.py``
+    name = "mimo_v2"
+    program, ref = exaone_moe, mimo_v2_ref
+    Module, Config = mimo_v2.MiMoV2, mimo_v2.MiMoV2Config
+    preset = staticmethod(mimo_v2.mimo_v2_tiny)
+    gains = ("attn_norm", "ffn_norm")
+    # ``rlt_paged_decode`` at the published head widths: 16 query heads
+    # on 2 K/V heads of 192 (keys) and 128 (values) in the full layer.
+    pallas = dict(n_layer=2, n_head=16, n_kv_head=2, swa_n_kv_head=4,
+                  head_dim=192, v_head_dim=128, rope_dim=64, window=16)
+    pallas_prompts = (27, 22)
+    bad = (dict(experts_held=(8, 20)), dict(vocab_held=(0, 999)),
+           dict(swa_n_kv_head=3), dict(rope_dim=5),
+           dict(layer_types=("full",) * 3))
+    lengths = dict(short=5, ragged=24, banded=40)
+    config_file = "mimo-v2-flash-ep16.json"
+    cell = "mimo-v2-flash-ep16.serve-longprompt"
+    why = "window rings"
+    depth = 12
+    reduced = {"num_hidden_layers", "n_routed_experts", "vocab_size"}
+    shares = 16
+
+    @staticmethod
+    def published(cfg, doc):
+        full, ring = cfg.geometry("full"), cfg.geometry("sliding")
+        assert (cfg.d_model, cfg.n_head, cfg.d_ff, cfg.d_expert,
+                cfg.n_experts, cfg.top_k, cfg.window) == (
+            4096, 64, 16384, 2048, 256, 8, 128)
+        assert (full.n_kv_head, ring.n_kv_head, full.head_dim,
+                ring.head_dim, full.v_head_dim, ring.v_head_dim) == (
+            doc["num_key_value_heads"], doc["swa_num_key_value_heads"],
+            doc["head_dim"], doc["swa_head_dim"], doc["v_head_dim"],
+            doc["swa_v_head_dim"]) == (4, 8, 192, 192, 128, 128)
+        assert (full.rope_theta, ring.rope_theta) == (
+            doc["rope_theta"], doc["swa_rope_theta"])
+        assert full.rope_dim == ring.rope_dim == int(
+            doc["head_dim"] * doc["partial_rotary_factor"]) == 64
+        assert full.value_scale == doc["attention_value_scale"]
+        assert (full.sink, ring.sink) == (
+            doc["add_full_attention_sink_bias"],
+            doc["add_swa_attention_sink_bias"])
+        assert cfg.layer_types == tuple(
+            ("full", "sliding")[t] for t in doc["hybrid_layer_pattern"])
+        assert cfg.mlp_types == tuple(
+            ("dense", "sparse")[t] for t in doc["moe_layer_freq"])
+        assert cfg.n_vocab_held == doc["vocab_size"] == 149 * 128
+        assert not cfg.shared_expert and cfg.routed_scale == 1.0
+        assert doc["n_shared_experts"] is None
+        assert "16 chips share each layer" in doc["deployment"]
+
+    @staticmethod
+    def decoded(cfg, cache, pool, block_size, reached):
+        Exaone.decoded(cfg, cache, pool, block_size, reached)
+        # Each kind's K and V rows at their own widths.
+        for names, g in ((("k", "v"), cfg.geometry("full")),
+                         (("wk", "wv"), cfg.geometry("sliding"))):
+            assert (pool[names[0]].shape[-1], pool[names[1]].shape[-1]) == (
+                g.k_width, g.v_width)
+
+    @staticmethod
+    def served(cfg, engine, overlap):
+        c = engine.stats.counters
+        share = c["moe_local_assignments"] / (
+            c["moe_tokens_routed"] * cfg.top_k)
+        assert 0.15 < share < 0.35          # 4 of 16 experts held
+        if not overlap:
+            # One at a time: a request of prompt n decodes 25 tokens at
+            # lengths n .. n + 24, each attending its own row too, in
+            # each of the preset's two full layers.
+            want = sum(sum(n + t + 1 for t in range(25)) for n in PROMPTS)
+            assert c["decode_full_positions"] == want * 2
+        assert engine.family.two_kind and engine.family.name == "mimo_v2"
+
+    @staticmethod
+    def rehearsed(line, phases):
+        assert 0 < line["metrics"]["kv_read_share_pct.serve"]["value"] <= 100
+        # No chip: the kernel's time and its roofline share are left out.
+        assert "decode_attn_roofline.serve" not in line["metrics"]
+        assert phases["reference_check_window"]["ok"]
+
+
+FAMILIES = (Exaone, Sarvam, MiMo)
 per_family = pytest.mark.parametrize(
     "fam", FAMILIES, ids=[f.name for f in FAMILIES])
 
@@ -215,11 +300,10 @@ def test_config_file_holds_the_published_widths_uncut(fam):
     for key in ("experts_held", "vocab_held"):
         fields[key] = tuple(fields[key])
     cfg = fam.Config(**fields)
-    assert cfg.n_layer == 8 and cfg.n_experts_held == 16
+    assert cfg.n_layer == fam.depth and cfg.n_experts_held == 16
     # Widths under the source's own keys, and what was cut under its name.
     fam.published(cfg, doc)
-    assert set(doc["reduced"]) >= {"num_hidden_layers", "num_experts",
-                                   "vocab_size"}
+    assert set(doc["reduced"]) >= fam.reduced
     for key in ("changed", "assumed", "deployment", "published",
                 "reduced_how"):
         assert doc[key]
