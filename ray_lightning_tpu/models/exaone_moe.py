@@ -357,6 +357,36 @@ def attend_one(g: AttnGeometry, q, keys, values, visible,
     return out.reshape(q.shape[0], q.shape[1] * values.shape[-1])
 
 
+def ring_visible(pos, ring: int, window: int) -> jax.Array:
+    """``(W, ring)`` bool: the ring locations a slot's token at ``pos
+    (W,)`` sees.  Location ``u`` holds position ``pos - ((pos - u) mod
+    ring)``; the location the current token will take still holds the
+    one it evicts.  Visible: not negative, inside the window, not
+    ``pos``."""
+    held = pos[:, None] - (pos[:, None] - jnp.arange(ring)[None, :]) % ring
+    return ((held >= 0) & (held > pos[:, None] - window)
+            & (held != pos[:, None]))
+
+
+def attend_gathered(g: AttnGeometry, q, k_row, v_row, k_pool, v_pool,
+                    tables, visible, sink=None) -> jax.Array:
+    """The XLA path of a decode token's attention: each slot's blocks
+    ``tables (W, T)`` gathered from one layer's pools ``(N, Bs, row)``,
+    its own K and V rows after them, through :func:`attend_one`;
+    ``visible (W, T*Bs)`` masks the gathered positions (the own row is
+    always seen)."""
+    W, S = visible.shape
+
+    def ctx(pool, row):
+        got = pool[tables].reshape(W, S, -1)
+        got = jnp.concatenate([got, row[:, None]], axis=1)
+        return got.reshape(W, S + 1, g.n_kv_head, -1)
+
+    return attend_one(g, q, ctx(k_pool, k_row), ctx(v_pool, v_row),
+                      jnp.concatenate([visible, jnp.ones((W, 1), bool)],
+                                      axis=1), sink)
+
+
 def feed_forward(cfg: ExaoneMoEConfig, kind: str, p, x: jax.Array,
                  row_valid: Optional[jax.Array], moe_impl: str,
                  routing: Optional[list] = None):
@@ -546,15 +576,18 @@ def paged_decode_step(cfg: ExaoneMoEConfig, params, pool, block_tables,
 
     Full layers: the ``rlt_paged_decode`` kernel walks blocks ``0 ..
     len // Bs`` of the slot's table (``auto`` on a TPU; the XLA gather
-    elsewhere).  Sliding layers: the slot's ring is gathered (``window +
-    Bs`` positions) and masked by absolute position.  The pool is only
-    read inside the layer loop; every layer's new row is scattered into
-    it afterwards, one scatter a tensor."""
+    elsewhere).  Sliding layers: the ``rlt_ring_decode`` kernel reads the
+    slot's ring where it lies (:func:`ring_kernel_taken`); elsewhere the
+    ring is gathered (``window + Bs`` positions).  Either masks a ring
+    location by the absolute position it holds.  The pool is only read
+    inside the layer loop; every layer's new row is scattered into it
+    afterwards, one scatter a tensor."""
     from ray_lightning_tpu.ops.paged_attention import (
-        paged_decode_attention, paged_decode_supported,
+        paged_decode_attention, paged_decode_supported, ring_decode_attention,
     )
 
     full_tables, ring_tables = block_tables
+    ring_kernel = ring_kernel_taken(cfg, attn_impl, pool)
     if attn_impl == "auto":
         attn_impl = "pallas" if paged_decode_supported(pool["k"]) else "xla"
     W, M = full_tables.shape
@@ -565,14 +598,8 @@ def paged_decode_step(cfg: ExaoneMoEConfig, params, pool, block_tables,
     active = seq_lens > 0
     rows = jnp.arange(W)
     x = params["embed"][tokens]
-    # Ring location u holds position pos - ((pos - u) mod RB); the
-    # location the current token will take still holds the one it evicts.
-    u = jnp.arange(RB)[None, :]
-    ring_pos = pos[:, None] - (pos[:, None] - u) % RB
-    ring_vis = ((ring_pos >= 0) & (ring_pos > pos[:, None] - cfg.window)
-                & (ring_pos != pos[:, None]))
+    ring_vis = ring_visible(pos, RB, cfg.window)
     full_vis = jnp.arange(M * Bs)[None, :] < pos[:, None]
-    one = jnp.ones((W, 1), bool)
     new = {"k": [], "v": [], "wk": [], "wv": []}
     counts = jnp.zeros((2,), jnp.int32)
     n_full = n_ring = 0
@@ -590,22 +617,21 @@ def paged_decode_step(cfg: ExaoneMoEConfig, params, pool, block_tables,
                     jnp.int32(n_full), full_tables, pos,
                     n_head=cfg.n_head, n_kv_head=g.n_kv_head,
                     scale=g.head_dim ** -0.5)
+            elif kind != "full" and ring_kernel:
+                att = ring_decode_attention(
+                    q.reshape(W, -1), k_row, v_row, pool["wk"], pool["wv"],
+                    jnp.int32(n_ring), ring_tables, pos,
+                    n_head=cfg.n_head, n_kv_head=g.n_kv_head,
+                    scale=g.head_dim ** -0.5, window=g.window,
+                    sink=p["sink"] if g.sink else None)
             else:
-                names, n, tables, S, vis = (
-                    (("k", "v"), n_full, full_tables, M * Bs, full_vis)
+                names, n, tables, vis = (
+                    (("k", "v"), n_full, full_tables, full_vis)
                     if kind == "full" else
-                    (("wk", "wv"), n_ring, ring_tables, RB, ring_vis))
-
-                def ctx(t, row):
-                    got = t[n][tables].reshape(W, S, -1)
-                    got = jnp.concatenate([got, row[:, None]], axis=1)
-                    return got.reshape(W, S + 1, g.n_kv_head, -1)
-
-                att = attend_one(
-                    g, q, ctx(pool[names[0]], k_row),
-                    ctx(pool[names[1]], v_row),
-                    jnp.concatenate([vis, one], axis=1),
-                    p["sink"] if g.sink else None)
+                    (("wk", "wv"), n_ring, ring_tables, ring_vis))
+                att = attend_gathered(
+                    g, q, k_row, v_row, pool[names[0]][n], pool[names[1]][n],
+                    tables, vis, p["sink"] if g.sink else None)
             pre = "" if kind == "full" else "w"
             new[pre + "k"].append(k_row)
             new[pre + "v"].append(v_row)
@@ -630,6 +656,19 @@ def paged_decode_step(cfg: ExaoneMoEConfig, params, pool, block_tables,
                 (layer[:, None], b[None, :], (pos % Bs)[None, :])
             ].set(stacked)
     return logits, out, counts
+
+
+def ring_kernel_taken(cfg: ExaoneMoEConfig, attn_impl: str, pool) -> bool:
+    """Whether the sliding layers' decode runs ``rlt_ring_decode``:
+    ``pallas`` asks for it, ``auto`` takes it where
+    ``ring_decode_supported`` passes for the ring pools (a TPU, rows it
+    tiles); else the ring is gathered."""
+    from ray_lightning_tpu.ops.paged_attention import ring_decode_supported
+
+    if attn_impl != "auto":
+        return attn_impl == "pallas"
+    return ring_decode_supported(pool["wk"], pool["wv"], cfg.n_head,
+                                 cfg.geometry("sliding").n_kv_head)
 
 
 class ServeFamily:
@@ -660,13 +699,19 @@ class ServeFamily:
     def walk_layers(self, pool) -> int:
         """Layers whose decode attention runs ``rlt_paged_decode`` on
         ``pool``: the full ones where the decode takes the kernel (the
-        sliding ones gather their ring)."""
+        sliding ones read their ring whole: :meth:`ring_layers`)."""
         from ray_lightning_tpu.ops.paged_attention import (
             paged_decode_supported,
         )
         kernel = self.attn_impl == "pallas" or (
             self.attn_impl == "auto" and paged_decode_supported(pool["k"]))
         return self.n_full if kernel else 0
+
+    def ring_layers(self, pool) -> int:
+        """Sliding layers whose decode attention runs ``rlt_ring_decode``
+        on ``pool`` (:func:`ring_kernel_taken`): all or none."""
+        return self.n_ring if ring_kernel_taken(
+            self.cfg, self.attn_impl, pool) else 0
 
     def make_cache(self, num_blocks: int, block_size: int, num_slots: int,
                    dtype) -> TwoKindKVCache:

@@ -39,6 +39,13 @@ afterwards; this kernel
   (exact: 3 x 8 significand bits), stacked on the rows of ONE matmul, so
   the probabilities are never rounded to bfloat16 before they meet V.
 
+The same kernel body, under its own name ``rlt_ring_decode``, serves a
+window layer's decode (``models/exaone_moe.py``): the slot's table is
+its ring of ``ceil(window / Bs) + 1`` blocks, read whole as one step, a
+ring location masked by the absolute position it holds; where the layer
+has a sink (a logit a query head with no value) it is folded into the
+softmax's statistics once, after the ring.
+
 A second kernel, ``rlt_mla_decode``, serves latent (MLA) attention
 (``models/sarvam_mla.py``): one cached row ``[c | k_r | 0]`` a position
 shared by all heads, scored against the heads' absorbed queries as one
@@ -69,6 +76,7 @@ from ray_lightning_tpu.ops.kernel_probe import (
 )
 
 __all__ = ["paged_decode_attention", "paged_decode_supported",
+           "ring_decode_attention", "ring_decode_supported",
            "mla_decode_attention", "mla_decode_supported", "walk_plan"]
 
 # Cache positions one step of the walk copies and computes (whole
@@ -308,13 +316,34 @@ def _walk_chunks(w, n_slots, n_vis, n_pages, chunk_dma, marks, attend,
         carry)
 
 
-def _softmax_step(s, rem, values, carry, round_probs=False):
+def _ring_walk(w, n_slots, chunk_dma, values, attend, carry, *, width):
+    """``carry`` through slot ``w``'s ring, one step of ``width``
+    positions, double-buffered: slot ``w``'s copies were started into
+    buffer ``w % 2`` while slot ``w - 1`` was computed, and the next
+    slot's start before it is.  The value buffers are zeroed once, before
+    the first copy: a ring that holds fewer positions leaves rows that no
+    copy of the call has reached, and a masked position contributes 0 x
+    row."""
+    @pl.when(w == 0)
+    def _():
+        values[...] = jnp.zeros(values.shape, values.dtype)
+        chunk_dma(0, 0, 0, "start")
+
+    buf = w % 2
+    chunk_dma(w, 0, buf, "wait")
+    pl.when(w + 1 < n_slots)(lambda: chunk_dma(w + 1, 0, 1 - buf, "start"))
+    return attend(buf, width, carry)
+
+
+def _softmax_step(s, rem, values, carry, round_probs=False, heads=None):
     """One update of the online softmax: scores ``s (rows, T)`` float32,
     ``values (T, C)`` as stored; statistics and accumulator float32.
     ``rem``: positions from ``rem`` on are masked (``None``: all are
     visible).  ``round_probs``: the probabilities meet the values in the
     values' own dtype (one matmul, as the flash kernels do) and not as
-    exact float32 terms."""
+    exact float32 terms.  ``heads``: ``values`` holds that many heads
+    side by side and row group ``g`` reads head ``g``: the accumulator
+    is ``(rows, C / heads)``, the product's diagonal blocks."""
     acc, m, l = carry
     if rem is not None:
         pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -325,23 +354,36 @@ def _softmax_step(s, rem, values, carry, round_probs=False):
     l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
     if round_probs:
         p = p.astype(values.dtype)
-    acc_new = acc * corr + _dot_f32(p, values, (((1,), (0,)), ((), ())))
-    return acc_new, m_new, l_new
+    acc = acc * corr
+    pv = _dot_f32(p, values, (((1,), (0,)), ((), ())))
+    if heads is not None:
+        g, c = pv.shape[0] // heads, values.shape[1] // heads
+        pv = jnp.concatenate([pv[i * g:(i + 1) * g, i * c:(i + 1) * c]
+                              for i in range(heads)], axis=0)
+    return acc + pv, m_new, l_new
 
 
 def _kernel(layer_ref, tables_ref, lens_ref,            # scalar prefetch
-            q_ref, kc_ref, vc_ref, k_hbm, v_hbm,        # inputs
-            o_ref,                                      # output
-            kbuf, vbuf, sems, marks,                    # scratch
-            *, n_head, n_kv_head, head_dim, v_head_dim, rows, scale, pages,
-            table_width, block_size):
+            q_ref, kc_ref, vc_ref, *refs,   # inputs (a sink), output, scratch
+            n_head, n_kv_head, head_dim, v_head_dim, rows, scale, pages,
+            table_width, block_size, window=None, sink=False):
+    """``window`` None: the slot's block table, positions ``[0,
+    seq_len)``.  Else the table is the slot's ring (``rlt_ring_decode``):
+    location ``u`` holds position ``pos - ((pos - u) mod R*Bs)``, visible
+    iff that lies in ``(pos - window, pos)`` and is not negative.
+    ``sink``: an input ``(rows, 1)`` float32 after the current V row,
+    one logit a query head that no value follows."""
+    if sink:
+        sink_ref, *refs = refs
+    k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, marks = refs
     w = pl.program_id(0)
     n_slots = pl.num_programs(0)
     n_vis, n_pages, chunk_dma = _table_walk(
         tables_ref, lens_ref, layer_ref[0],
         ((k_hbm, kbuf), (v_hbm, vbuf)), sems, pages=pages,
         table_width=table_width, block_size=block_size)
-    _open_walk(w, marks, chunk_dma)
+    if window is None:
+        _open_walk(w, marks, chunk_dma)
 
     group = n_head // n_kv_head
 
@@ -369,18 +411,57 @@ def _kernel(layer_ref, tables_ref, lens_ref,            # scalar prefetch
     m0 = jnp.sum(q32 * kc_ref[0].astype(jnp.float32), axis=1,
                  keepdims=True) * scale
     l0 = jnp.ones((rows, 1), jnp.float32)
-    acc0 = jnp.broadcast_to(vc_ref[0].astype(jnp.float32), (rows, hv))
+    # A ring is one step and grouped (``ring_decode_tiles``): its heads
+    # take their values off the product's diagonal blocks there, so the
+    # accumulator is one value head wide, ``(rows, Dv)`` (rows are the
+    # heads: ``_heads_tile``).
+    heads = None if window is None else n_kv_head
+    if heads is None:
+        acc0 = jnp.broadcast_to(vc_ref[0].astype(jnp.float32), (rows, hv))
+    else:
+        acc0 = jnp.concatenate([jnp.broadcast_to(
+            vc_ref[0, :, g * v_head_dim:(g + 1) * v_head_dim].astype(
+                jnp.float32), (group, v_head_dim))
+            for g in range(n_kv_head)], axis=0)
 
     def attend(buf, width, carry, rem=None):
         at = (buf, pl.ds(0, width))
         s = _dot_f32(q_bd, kbuf[at], (((1,), (1,)), ((), ()))) * scale
-        return _softmax_step(s, rem, vbuf[at], carry)
+        if window is not None:
+            # How far back of pos location u's position lies, in [0,
+            # ring): 0 is the location the current token takes, which
+            # still holds the position it evicts; before the ring first
+            # wraps, u >= pos lies more than pos back.  The ring mask
+            # hides every location the clamped walk left uncopied.
+            ring = table_width * block_size
+            pos = lens_ref[w]
+            back = pos % ring - jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            back = jnp.where(back < 0, back + ring, back)
+            s = jnp.where((back > 0) & (back < window) & (back <= pos), s,
+                          _NEG_INF)
+        return _softmax_step(s, rem, vbuf[at], carry, heads=heads)
 
     # A key row's score is masked by a select whatever the row holds:
     # only the values need finite rows.
-    acc, _, l = _walk_chunks(
-        w, n_slots, n_vis(w), n_pages(w), chunk_dma, marks, attend, (vbuf,),
-        (acc0, m0, l0), pages=pages, block_size=block_size)
+    if window is None:
+        acc, m, l = _walk_chunks(
+            w, n_slots, n_vis(w), n_pages(w), chunk_dma, marks, attend,
+            (vbuf,), (acc0, m0, l0), pages=pages, block_size=block_size)
+    else:
+        acc, m, l = _ring_walk(w, n_slots, chunk_dma, vbuf, attend,
+                               (acc0, m0, l0), width=pages * block_size)
+    if sink:
+        # The sink is one more key, with no value: it joins the
+        # statistics once, after every position.
+        b = sink_ref[...]
+        m_new = jnp.maximum(m, b)
+        corr = jnp.exp(m - m_new)
+        l = l * corr + jnp.exp(b - m_new)
+        acc = acc * corr
+    if heads is not None:
+        o_ref[0] = acc / l
+        return
     vdiag = diag if v_head_dim == head_dim else heads_of(v_head_dim)
     out = jnp.where(vdiag, acc / l, 0.0)
     if group == 1:
@@ -433,32 +514,58 @@ def paged_decode_attention(
     Returns:
         ``(W, H*Dv)`` float32, head ``h`` in columns ``[h*Dv, (h+1)*Dv)``.
     """
+    n_kv_head = n_head if n_kv_head is None else n_kv_head
+    if not _heads_tile(k_pool, v_pool, n_head, n_kv_head, q.shape[1]):
+        raise ValueError(
+            f"rlt_paged_decode does not tile a {k_pool.dtype} pool of "
+            f"shape {k_pool.shape} (values {v_pool.shape}) for {n_head} "
+            f"query heads of {q.shape[1] // n_head} on {n_kv_head} K/V "
+            f"heads: take the XLA path (attn_impl='xla' or 'auto')"
+        )
+    M, Bs = block_tables.shape[1], k_pool.shape[2]
+    return _decode_call(
+        q, k_cur, v_cur, k_pool, v_pool, layer, block_tables, seq_lens,
+        n_head=n_head, n_kv_head=n_kv_head, scale=scale,
+        pages=_pages_per_chunk(M, Bs, CHUNK_POSITIONS))
+
+
+def _heads_tile(k_pool, v_pool, n_head: int, n_kv_head: int,
+                q_width: int) -> bool:
+    """What the grouped kernel body tiles: pools :func:`paged_decode_tiles`
+    takes, rows of ``n_kv_head`` heads; grouped, the output folds value
+    columns one head wide, so whole lanes, and the heads whole bf16
+    sublane tiles; one K/V head a query head, alike rows."""
+    if not (paged_decode_tiles(k_pool) and paged_decode_tiles(v_pool)):
+        return False
+    hd, hv = k_pool.shape[3], v_pool.shape[3]
+    head_dim, v_head_dim = q_width // n_head, hv // n_kv_head
+    grouped = n_kv_head != n_head
+    return (hd == n_kv_head * head_dim and hv == n_kv_head * v_head_dim
+            and not n_head % n_kv_head
+            and not (grouped and (v_head_dim % 128 or n_head % 16))
+            and (grouped or hv == hd))
+
+
+def _decode_call(q, k_cur, v_cur, k_pool, v_pool, layer, block_tables,
+                 seq_lens, *, n_head, n_kv_head, scale, pages, window=None,
+                 sink=None):
+    """The pallas call of :func:`_kernel` (shapes as
+    :func:`paged_decode_attention` takes them, checked by the caller):
+    ``rlt_paged_decode``, or with a ``window`` (and a ``sink``) as
+    :func:`ring_decode_attention` ``rlt_ring_decode``."""
     W, qd = q.shape
     _, _, Bs, hd = k_pool.shape
     hv = v_pool.shape[3]
     M = block_tables.shape[1]
-    n_kv_head = n_head if n_kv_head is None else n_kv_head
+    P = pages
     head_dim = qd // n_head
     v_head_dim = hv // n_kv_head
     grouped = n_kv_head != n_head
-    # Grouped, the output folds value columns one head wide: whole lanes.
-    if (not paged_decode_tiles(k_pool) or not paged_decode_tiles(v_pool)
-            or hd != n_kv_head * head_dim or hv != n_kv_head * v_head_dim
-            or n_head % n_kv_head
-            or (grouped and (v_head_dim % 128 or n_head % 16))
-            or (not grouped and hv != hd)):
-        raise ValueError(
-            f"rlt_paged_decode does not tile a {k_pool.dtype} pool of "
-            f"shape {k_pool.shape} (values {v_pool.shape}) for {n_head} "
-            f"query heads of {head_dim} on {n_kv_head} K/V heads: take the "
-            f"XLA path (attn_impl='xla' or 'auto')"
-        )
-    P = _pages_per_chunk(M, Bs, CHUNK_POSITIONS)
     rows = -(-n_head // 16) * 16  # whole bf16 sublane tiles, _split3 stacks
     kernel = functools.partial(
         _kernel, n_head=n_head, n_kv_head=n_kv_head, head_dim=head_dim,
         v_head_dim=v_head_dim, rows=rows, scale=scale, pages=P,
-        table_width=M, block_size=Bs,
+        table_width=M, block_size=Bs, window=window, sink=sink is not None,
     )
     row_spec = pl.BlockSpec((1, 1, hd), lambda w, *_: (w, 0, 0))
     v_row_spec = pl.BlockSpec((1, 1, hv), lambda w, *_: (w, 0, 0))
@@ -468,11 +575,22 @@ def paged_decode_attention(
         if grouped else row_spec
     o_spec = pl.BlockSpec((1, n_head, v_head_dim), lambda w, *_: (w, 0, 0)) \
         if grouped else row_spec
+    prefetch = (jnp.asarray(layer, jnp.int32).reshape(1),
+                block_tables.astype(jnp.int32).reshape(-1),
+                seq_lens.astype(jnp.int32))
+    inputs = [q.reshape(W, n_head, head_dim) if grouped
+              else q.reshape(W, 1, hd),
+              k_cur.reshape(W, 1, hd), v_cur.reshape(W, 1, hv)]
+    in_specs = [q_spec, row_spec, v_row_spec]
+    if sink is not None:
+        # One logit a query head, a row each (the padding rows' are 0).
+        inputs.append(jnp.pad(sink.astype(jnp.float32),
+                              (0, rows - n_head)).reshape(rows, 1))
+        in_specs.append(pl.BlockSpec((rows, 1), lambda w, *_: (0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(W,),
-        in_specs=[
-            q_spec, row_spec, v_row_spec,
+        in_specs=in_specs + [
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
@@ -498,18 +616,81 @@ def paged_decode_attention(
                               + hv * v_pool.dtype.itemsize)),
         ),
         interpret=_interpret(),
-        name="rlt_paged_decode",
-    )(
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        block_tables.astype(jnp.int32).reshape(-1),
-        seq_lens.astype(jnp.int32),
-        q.reshape(W, n_head, head_dim) if grouped else q.reshape(W, 1, hd),
-        k_cur.reshape(W, 1, hd),
-        v_cur.reshape(W, 1, hv),
-        k_pool,
-        v_pool,
-    )
+        name="rlt_paged_decode" if window is None else "rlt_ring_decode",
+    )(*prefetch, *inputs, k_pool, v_pool)
     return out.reshape(W, n_head * v_head_dim)
+
+
+# ---------------------------------------------------------------------------
+# a window layer's decode: the slot's ring, read whole
+# ---------------------------------------------------------------------------
+
+def ring_decode_tiles(k_pool, v_pool, n_head: int, n_kv_head: int) -> bool:
+    """What ``rlt_ring_decode`` tiles: grouped heads (several query heads
+    a K/V head) on the rows :func:`_heads_tile` takes (a query as wide as
+    a key head)."""
+    return (n_kv_head != n_head and k_pool.ndim == 4
+            and not k_pool.shape[3] % n_kv_head
+            and _heads_tile(k_pool, v_pool, n_head, n_kv_head,
+                            n_head * (k_pool.shape[3] // n_kv_head)))
+
+
+def ring_decode_supported(k_pool, v_pool, n_head: int,
+                          n_kv_head: int) -> bool:
+    """Whether ``attn_impl="auto"`` takes ``rlt_ring_decode`` for a window
+    layer's pools (as :func:`paged_decode_supported`: backend, shapes,
+    the ``paged`` kernel family's switch)."""
+    return (not kernel_family_disabled("paged")
+            and jax.default_backend() == "tpu"
+            and ring_decode_tiles(k_pool, v_pool, n_head, n_kv_head))
+
+
+def ring_decode_attention(
+    q: jax.Array,
+    k_cur: jax.Array,
+    v_cur: jax.Array,
+    k_pool: jax.Array,
+    v_pool: jax.Array,
+    layer: jax.Array,
+    ring_tables: jax.Array,
+    seq_lens: jax.Array,
+    *,
+    n_head: int,
+    n_kv_head: int,
+    scale: float,
+    window: int,
+    sink: Optional[jax.Array] = None,
+) -> jax.Array:
+    """A window layer's attention of one new token per slot over its
+    ring, read where it lies in the ring pools.
+
+    A slot's ring is ``R`` blocks of ``Bs`` positions: position ``p``
+    lives at location ``p mod R*Bs`` (block ``ring_tables[w, (p // Bs)
+    mod R]``), so the ring holds the last ``R*Bs`` positions.  The
+    current token, position ``seq_lens[w]``, sees the ``window - 1``
+    before it and itself; its own rows arrive as operands (the location
+    it will take still holds the position it evicts).  Shapes, heads and
+    arithmetic are :func:`paged_decode_attention`'s; ``sink`` ``(H,)``
+    float32 is each query head's logit of a key with no value
+    (``exp(s_j) / (exp(b) + sum_j exp(s_j))``).  A ring is one step of
+    the walk: ``R*Bs`` positions a slot, copied while the slot before is
+    computed.
+
+    Returns:
+        ``(W, H*Dv)`` float32, head ``h`` in columns ``[h*Dv, (h+1)*Dv)``.
+    """
+    if not ring_decode_tiles(k_pool, v_pool, n_head, n_kv_head) or (
+            q.shape[1] != n_head * (k_pool.shape[3] // n_kv_head)):
+        raise ValueError(
+            f"rlt_ring_decode does not tile a {k_pool.dtype} ring pool of "
+            f"shape {k_pool.shape} (values {v_pool.shape}) for {n_head} "
+            f"query heads on {n_kv_head} K/V heads: take the XLA path "
+            f"(attn_impl='xla' or 'auto')"
+        )
+    return _decode_call(
+        q, k_cur, v_cur, k_pool, v_pool, layer, ring_tables, seq_lens,
+        n_head=n_head, n_kv_head=n_kv_head, scale=scale,
+        pages=ring_tables.shape[1], window=window, sink=sink)
 
 
 # ---------------------------------------------------------------------------

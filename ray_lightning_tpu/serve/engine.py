@@ -462,6 +462,9 @@ class ServeEngine:
                 "latent_row_bytes", self.family.latent_row_bytes(self._c))
         self._pool = self.cache.init_pool()
         self._walk_layers = self.family.walk_layers(self._pool)
+        # Sliding layers whose decode runs the ring kernel (two-kind).
+        self._ring_layers = (self.family.ring_layers(self._pool)
+                             if self.family.two_kind else 0)
         self._draft_pool = None
         if draft_module is not None:
             dcfg = draft_module.config
@@ -1644,6 +1647,13 @@ class ServeEngine:
             # rlt: noqa[RLT002] host ints (scheduler.seq_lens), no device value
             kv_blocks["decode_full_positions"] = self.family.n_full * int(
                 self.scheduler.seq_lens[active].sum() + len(active))
+        if self._ring_layers:
+            # Positions the ring kernel attends this tick, the current
+            # token's own included: the window's, at most, a slot.
+            # rlt: noqa[RLT002] host ints (scheduler.seq_lens), no device value
+            kv_blocks["decode_ring_positions"] = self._ring_layers * int(
+                np.minimum(self.scheduler.seq_lens[active],
+                           self.family.cfg.window - 1).sum() + len(active))
         if self._n_latent:
             # Positions the latent decode attends this tick, the current
             # token's own included, over the latent layers.

@@ -68,8 +68,11 @@ _COUNTER_KEYS = (
     # compute (``ops.paged_attention.walk_plan``).
     "decode_attn_steps", "decode_attn_positions_computed",
     # Per decode tick, a two-kind pool's: the positions its full layers
-    # attend (the active slots' seq_len + 1, over those layers).
-    "decode_full_positions",
+    # attend (the active slots' seq_len + 1, over those layers), and
+    # those its sliding layers attend where ``rlt_ring_decode`` runs
+    # them (min(seq_len, window - 1) + 1, over those layers; 0 where the
+    # ring is gathered).
+    "decode_full_positions", "decode_ring_positions",
     # Of ``decode_steps``: ticks read from a decode dispatched ahead of
     # them, and those of them whose ``tokens`` operand was the tick
     # before's output where it lay on the device.  Reply frames sent

@@ -534,6 +534,35 @@ def test_gqa_paged_decode_kernel_compiles_with_values_of_their_own_width(
     )
 
 
+@pytest.mark.parametrize("n_kv_head,head_dim,layers,sink", [
+    (8, 128, 6, False),     # K-EXAONE's window layers: rows 1024 / 1024
+    (8, 192, 9, True),      # MiMo-V2-Flash's: rows 1536 / 1024, a sink
+], ids=["k-exaone", "mimo"])
+def test_ring_decode_kernel_compiles_at_serve_cell_shapes(
+        one_chip, n_kv_head, head_dim, layers, sink):
+    """64 query heads on 8 K/V heads over the window layers' ring pools
+    of both share cells (32 slots, rings of 5 blocks of 32, the engine's
+    32 x 5 + 1 blocks), values 128 wide, with and without a sink."""
+    from ray_lightning_tpu.ops.paged_attention import ring_decode_attention
+
+    W, R, N = MOE["W"], 5, MOE["W"] * 5 + 1
+    hk, hv = n_kv_head * head_dim, n_kv_head * 128
+    args = [_sds((W, 64 * head_dim), jnp.bfloat16, one_chip),
+            _sds((W, hk), jnp.bfloat16, one_chip),
+            _sds((W, hv), jnp.bfloat16, one_chip),
+            _sds((layers, N, 32, hk), jnp.bfloat16, one_chip),
+            _sds((layers, N, 32, hv), jnp.bfloat16, one_chip),
+            _sds((), jnp.int32, one_chip),
+            _sds((W, R), jnp.int32, one_chip),
+            _sds((W,), jnp.int32, one_chip)]
+    if sink:
+        args.append(_sds((64,), jnp.float32, one_chip))
+    assert "rlt_ring_decode" in _compile(
+        lambda *a: ring_decode_attention(
+            *a[:8], n_head=64, n_kv_head=n_kv_head, scale=head_dim ** -0.5,
+            window=128, sink=a[8] if sink else None), *args)
+
+
 def _moe_programs(one_chip):
     from ray_lightning_tpu.models import exaone_moe as em
 
@@ -573,12 +602,26 @@ def _footprint(compiled) -> float:
 
 
 def test_moe_decode_step_names_its_kernels_and_fits(moe_decode):
-    """Both kernels' names are in the program and it leaves the chip
-    (15.75 GB usable) over 2 GB: 13.33 GB at PR 26, 11.96 of it weights."""
+    """The kernels' names are in the program (the window layers' ring
+    kernel among them) and it leaves the chip (15.75 GB usable) over 2
+    GB: 13.23 GB with the ring kernel, 11.96 of it weights."""
     text = moe_decode.as_text()
-    for kernel in ("%rlt_moe_gate_up", "%rlt_moe_down", "%rlt_paged_decode"):
+    for kernel in ("%rlt_moe_gate_up", "%rlt_moe_down", "%rlt_paged_decode",
+                   "%rlt_ring_decode"):
         assert kernel in text
     assert 12e9 < _footprint(moe_decode) < 13.7e9
+
+
+def test_moe_decode_step_gathers_no_ring(moe_decode):
+    """The window layers' decode reads each slot's ring where it lies: no
+    operation at all, in HBM or in VMEM (``S(1)``), yields the slots'
+    gathered rings, with or without the current rows (the parent's
+    program held 96, the relayout copies for its einsums among them)."""
+    import re
+
+    W = MOE["W"]
+    found = re.findall(rf"= bf16\[{W},16[01],8,128\]", moe_decode.as_text())
+    assert not found, len(found)
 
 
 @pytest.mark.parametrize("opcode", ["copy", "convert", "gather",
@@ -586,17 +629,20 @@ def test_moe_decode_step_names_its_kernels_and_fits(moe_decode):
 def test_moe_decode_step_moves_no_pool_layer_and_no_expert_tensor(
         moe_decode, opcode):
     """No operation's result is a pool layer, a pool, every slot's whole
-    table of positions, or an expert-stacked weight tensor."""
+    table of positions, every slot's ring (the window layers' decode
+    reads it where it lies, ``rlt_ring_decode``), or an expert-stacked
+    weight tensor."""
     import re
 
     row, rings = 1024, MOE["W"] * 5 + 1
     N, Bs, W, M = MOE["N"], MOE["Bs"], MOE["W"], MOE["M"]
     d, f, E = MOE["d"], MOE["f"], MOE["E"]
     # By shape, not by size: W == Bs here, so a slot-major gather of the
-    # rings (W, 161, 8, 128: 10 MB, wanted) is as large as a ring layer.
+    # rings (W, 161, 8, 128: 10 MB) is as large as a ring layer.
     moved = {(N, Bs, row), (2, N, Bs, row), (rings, Bs, row),
              (6, rings, Bs, row), (W, M, Bs, row), (W, M * Bs, row),
-             (W, M * Bs, 8, 128), (E, d, f), (E, f, d), (d, f), (f, d)}
+             (W, M * Bs, 8, 128), (W, 160, 8, 128), (W, 161, 8, 128),
+             (E, d, f), (E, f, d), (d, f), (f, d)}
     # A result laid out in S(1) is XLA's own prefetch of an operand into
     # VMEM (the shared expert's down matrix), not a copy in HBM.
     rx = re.compile(

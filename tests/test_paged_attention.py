@@ -227,6 +227,90 @@ def test_grouped_kernel_reads_values_of_their_own_width(name, dtype):
     assert gap < (1e-5 if dt == jnp.float32 else 1e-2), gap
 
 
+# A window layer's ring (``rlt_ring_decode``): (query heads, K/V heads,
+# key width, value width, sink, window) at tiny sizes of both share
+# cells' geometries, blocks of BS; "wide" is a ring of more than one
+# 128-position sub-tile, still read and computed as one step.
+_RINGS = {
+    "exaone": (16, 2, 128, 128, False, 32),
+    "mimo": (16, 4, 192, 128, True, 40),
+    "wide": (16, 4, 192, 128, True, 240),
+}
+
+
+@pytest.mark.parametrize("geometry,dtype", [
+    (g, d) for g in _RINGS for d in sorted(_DTYPES)
+    if g != "wide" or d == "float32"])
+def test_ring_kernel_matches_the_gathered_ring(geometry, dtype):
+    """``rlt_ring_decode`` under the interpreter against the XLA path
+    (``attend_gathered`` over each slot's ring and its own row), whose
+    mask ``ring_visible`` is held to the ``window - 1`` positions before
+    the current one, counted here position by position: slots before
+    their ring first wraps, at ``window - 1``, ``window`` and ``window +
+    BS``, at the ring's edge, across wraps, and idle (its own row alone,
+    and the sink); ring tables whose blocks are in no order."""
+    from ray_lightning_tpu.models.exaone_moe import (
+        AttnGeometry, attend_gathered, ring_visible,
+    )
+
+    H, Hkv, Dh, Dv, sink, window = _RINGS[geometry]
+    g = AttnGeometry(Hkv, Dh, Dv, rope_dim=0, rope_theta=1.0, window=window,
+                     sink=sink)
+    dt = _DTYPES[dtype]
+    R = -(-window // BS) + 1
+    RB = R * BS
+    lens = [0, 1, 20, window - 1, window, window + BS, RB - 1, RB, RB + 5,
+            3 * RB + 7]
+    W_ = len(lens)
+    n = W_ * R + 1
+    tables = np.random.default_rng(3).permutation(
+        np.arange(1, n)).reshape(W_, R).astype(np.int32)
+    ks = jax.random.split(jax.random.PRNGKey(4), 6)
+    k_pool = jax.random.normal(ks[0], (2, n, BS, g.k_width)).astype(dt)
+    v_pool = jax.random.normal(ks[1], (2, n, BS, g.v_width)).astype(dt)
+    q = jax.random.normal(ks[2], (W_, H * Dh)).astype(dt)
+    k_cur = jax.random.normal(ks[3], (W_, g.k_width)).astype(dt)
+    v_cur = jax.random.normal(ks[4], (W_, g.v_width)).astype(dt)
+    b = jax.random.normal(ks[5], (H,), jnp.float32) if sink else None
+    got = paged_attention.ring_decode_attention(
+        q, k_cur, v_cur, k_pool, v_pool, jnp.int32(1), jnp.asarray(tables),
+        jnp.asarray(lens, jnp.int32), n_head=H, n_kv_head=Hkv,
+        scale=Dh ** -0.5, window=window, sink=b)
+
+    visible = np.zeros((W_, RB), bool)
+    for w, pos in enumerate(lens):
+        for p in range(max(0, pos - window + 1), pos):
+            visible[w, p % RB] = True
+    vis = ring_visible(jnp.asarray(lens, jnp.int32), RB, window)
+    np.testing.assert_array_equal(np.asarray(vis), visible)
+
+    want = attend_gathered(g, q.reshape(W_, H, Dh), k_cur, v_cur, k_pool[1],
+                           v_pool[1], jnp.asarray(tables), vis, b)
+    assert got.shape == (W_, H * Dv)
+    want, got = np.asarray(want), np.asarray(got)
+    assert np.isfinite(got).all()
+    gap = np.abs(got - want).max()
+    assert gap < (1e-5 if dt == jnp.float32 else 1e-2), gap
+
+
+def test_ring_kernel_refuses_what_it_cannot_tile(monkeypatch):
+    """Values 64 wide do not fold onto whole lanes, and one K/V head a
+    query head is no grouped ring: ``auto`` keeps the gathered ring on a
+    TPU, and the kernel asked for outright refuses by name."""
+    pool = jnp.zeros((1, 4, BS, 2 * 64), jnp.float32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert not paged_attention.ring_decode_supported(pool, pool, 16, 2)
+    wide = jnp.zeros((1, 4, BS, 2 * 128), jnp.float32)
+    assert paged_attention.ring_decode_supported(wide, wide, 16, 2)
+    assert not paged_attention.ring_decode_supported(wide, wide, 2, 2)
+    with pytest.raises(ValueError, match="rlt_ring_decode does not tile"):
+        paged_attention.ring_decode_attention(
+            jnp.zeros((1, 16 * 64)), jnp.zeros((1, 128)), jnp.zeros((1, 128)),
+            pool, pool, jnp.int32(0), jnp.zeros((1, 4), jnp.int32),
+            jnp.zeros((1,), jnp.int32), n_head=16, n_kv_head=2, scale=1.0,
+            window=8)
+
+
 def _walk_by_hand(n, m, bs, wide):
     """The kernel's walk of one slot of ``n`` positions, step by step:
     chunks of ``wide`` positions (at most the table, at least a block),

@@ -860,8 +860,11 @@ def _pallas_call_names(path):
                 and node.func.attr == "pallas_call"):
             kw = {k.arg: k.value for k in node.keywords}
             name = kw.get("name")
-            out.append(name.value if isinstance(name, ast.Constant)
-                       else None)
+            # One call site may serve two kernels, a literal name each.
+            names = ([name.body, name.orelse]
+                     if isinstance(name, ast.IfExp) else [name])
+            out += [n.value if isinstance(n, ast.Constant) else None
+                    for n in names]
     return out
 
 
@@ -870,7 +873,8 @@ KERNELS = {
     "cross_entropy.py": {"rlt_ce_fwd", "rlt_ce_bwd_dx", "rlt_ce_bwd_dw"},
     "layer_norm.py": {"rlt_ln_fwd", "rlt_ln_bwd"},
     "lora.py": {"rlt_lora_bgmv"},
-    "paged_attention.py": {"rlt_paged_decode", "rlt_mla_decode"},
+    "paged_attention.py": {"rlt_paged_decode", "rlt_ring_decode",
+                           "rlt_mla_decode"},
     "moe.py": {"rlt_moe_gate_up", "rlt_moe_down"},
 }
 

@@ -434,6 +434,40 @@ def test_engine_serves_the_family_through_the_client_plane(fam, overlap):
     assert engine.scheduler.snapshot()["blocks_live"] == 0
 
 
+@pytest.mark.parametrize("fam", [Exaone, MiMo], ids=["exaone_moe", "mimo_v2"])
+def test_engine_serves_the_same_tokens_on_the_decode_kernels(fam):
+    """The preset the decode kernels tile, served by an engine whose
+    module takes them (``attn_impl="pallas"``: ``rlt_paged_decode`` on the
+    full layers, ``rlt_ring_decode`` on the window layers, both under
+    the interpreter) and by one on the XLA paths: the same greedy tokens,
+    past the window and round the ring.  Only the kernel engine counts
+    ``decode_ring_positions``: a request of prompt ``n`` decodes at
+    lengths ``n .. n + 10``, each tick attending at most the window's
+    positions, its own row included, in every window layer."""
+    cfg, _, params = built(fam, pallas=True)
+    prompts = [np.asarray(draw_tokens(n, 30 + n)).tolist()
+               for n in fam.pallas_prompts]
+    served, counters = {}, {}
+    for impl in ("xla", "pallas"):
+        engine = ServeEngine(fam.Module(cfg, attn_impl=impl), params,
+                             ServeConfig(num_slots=2, block_size=16,
+                                         max_model_len=64,
+                                         coalesce_replies=False,
+                                         decode_lookahead=False))
+        try:
+            served[impl] = [engine.generate(p, 12, timeout=300)
+                            for p in prompts]
+        finally:
+            engine.stop()
+        counters[impl] = engine.stats.counters
+    assert served["pallas"] == served["xla"]
+    n_ring = len(cfg.layers_of("sliding"))
+    want = sum(min(n + t, cfg.window - 1) + 1
+               for n in fam.pallas_prompts for t in range(11))
+    assert counters["pallas"]["decode_ring_positions"] == n_ring * want
+    assert counters["xla"]["decode_ring_positions"] == 0
+
+
 # -- what the family refuses ------------------------------------------------
 
 @pytest.mark.parametrize("config,draft,names", [

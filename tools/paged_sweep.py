@@ -13,7 +13,14 @@ trim (``TAIL_POSITIONS`` set past the width: the last chunk computed
 whole, as before PR 35); with ``--parent`` each module as it stands
 under DIR (a ``git archive`` of the parent, or a variant of the walk)
 at its own widths, a row ``tree`` the DIR's name.  A time is the kernels'
-summed device time a tick (median of 5 traced ticks), in ms.  Writes
+summed device time a tick (median of 5 traced ticks), in ms.  Two more
+regimes time the window layers' decode of the two share cells (32 slots,
+rings of 5 blocks of 32, 64 query heads on 8): K-EXAONE's six layers
+(keys and values 128 wide) and MiMo-V2-Flash's nine (keys 192, values
+128, a sink), ``rlt_ring_decode`` against the gathered ring of the XLA
+path (``models/exaone_moe.py`` ``attend_gathered``), each the whole
+program's device time a tick (``module_ms``) and the kernel's (``ms``).
+Writes
 ``chiprun_out/pr35/sweep.jsonl``.
 """
 
@@ -60,6 +67,14 @@ def regimes(seed):
         "sarvam_latent": dict(
             kind="mla", W=64, M=224, Bs=32, H=64, r=512, row=640, L=8,
             lens=_lens(rng, 64, (512, 6144), (256, 1024))),
+        "exaone_ring": dict(
+            kind="ring", W=32, M=5, Bs=32, H=64, Hkv=8, Dh=128, Dv=128, L=6,
+            window=128, sink=False,
+            lens=_lens(rng, 32, (64, 3072), (64, 384))),
+        "mimo_ring": dict(
+            kind="ring", W=32, M=5, Bs=32, H=64, Hkv=8, Dh=192, Dv=128, L=9,
+            window=128, sink=True,
+            lens=_lens(rng, 32, (1024, 6144), (128, 640))),
     }
 
 
@@ -76,11 +91,11 @@ def operands(g, seed=0):
         q = bf(ks[2], (W, g["H"], row)).at[..., 576:].set(0)
         return dict(q=q, cur=bf(ks[3], (W, row)), pool=pool,
                     tables=tables, lens=lens)
-    hd = g["Hkv"] * g["Dh"]
+    hd, hv = g["Hkv"] * g["Dh"], g["Hkv"] * g.get("Dv", g["Dh"])
     return dict(q=bf(ks[1], (W, g["H"] * g["Dh"])), kc=bf(ks[2], (W, hd)),
-                vc=bf(ks[3], (W, hd)), pk=bf(ks[4], (L, W * M + 1, Bs, hd)),
-                pv=bf(ks[5], (L, W * M + 1, Bs, hd)), tables=tables,
-                lens=lens)
+                vc=bf(ks[3], (W, hv)), pk=bf(ks[4], (L, W * M + 1, Bs, hd)),
+                pv=bf(ks[5], (L, W * M + 1, Bs, hv)), tables=tables,
+                lens=lens, sink=jax.random.normal(ks[0], (L, g["H"])))
 
 
 def tick(mod, g):
@@ -90,6 +105,13 @@ def tick(mod, g):
             return mod.mla_decode_attention(
                 x["q"], x["cur"], x["pool"], i, x["tables"], x["lens"],
                 rank=g["r"], scale=0.135)
+    elif g["kind"] == "ring":
+        def one(x, i):
+            return mod.ring_decode_attention(
+                x["q"], x["kc"], x["vc"], x["pk"], x["pv"], i, x["tables"],
+                x["lens"], n_head=g["H"], n_kv_head=g["Hkv"],
+                scale=g["Dh"] ** -0.5, window=g["window"],
+                sink=x["sink"][i] if g["sink"] else None)
     else:
         def one(x, i):
             return mod.paged_decode_attention(
@@ -104,7 +126,34 @@ def tick(mod, g):
     return jax.jit(f)
 
 
-def device_ms(fn, x, calls=5):
+def ring_xla(g):
+    """The window layers' decode of a tick as the XLA path computes it:
+    each slot's ring gathered with its own row, masked by position."""
+    from ray_lightning_tpu.models.exaone_moe import (
+        AttnGeometry, attend_gathered, ring_visible,
+    )
+
+    W, R, Bs, H, Hkv = (g[k] for k in ("W", "M", "Bs", "H", "Hkv"))
+    geo = AttnGeometry(Hkv, g["Dh"], g["Dv"], 0, 1.0, window=g["window"],
+                       sink=g["sink"])
+
+    def one(x, i):
+        return attend_gathered(
+            geo, x["q"].reshape(W, H, g["Dh"]), x["kc"], x["vc"], x["pk"][i],
+            x["pv"][i], x["tables"], ring_visible(x["lens"], R * Bs,
+                                                  g["window"]),
+            x["sink"][i] if g["sink"] else None)
+
+    def f(x):
+        first = one(x, jnp.int32(0))
+        return jax.lax.fori_loop(
+            1, g["L"], lambda i, acc: acc + one(x, i), first)
+    return jax.jit(f), one
+
+
+def device_ms(fn, x, calls=5, whole=False):
+    """The kernels' device time a call (``whole``: the program's), the
+    median of ``calls`` traced calls, in ms."""
     jax.block_until_ready(fn(x))
     tmp = tempfile.mkdtemp(prefix="sweep")
     try:
@@ -113,8 +162,13 @@ def device_ms(fn, x, calls=5):
             jax.profiler.start_trace(tmp)
             jax.block_until_ready(fn(x))
             jax.profiler.stop_trace()
-            runs = xplane.load(xplane.find_xplane(tmp)).op_runs(
-                "rlt_paged_decode|rlt_mla_decode")
+            trace = xplane.load(xplane.find_xplane(tmp))
+            if whole:
+                runs = [(b - a) / 1e9 for d in trace.devices
+                        for _, a, b in d.modules]
+            else:
+                runs = trace.op_runs(
+                    "rlt_paged_decode|rlt_mla_decode|rlt_ring_decode")
             per.append(sum(runs) * 1e3)
             shutil.rmtree(tmp, ignore_errors=True)
             os.makedirs(tmp)
@@ -125,6 +179,8 @@ def device_ms(fn, x, calls=5):
 
 def reference(g, x):
     """Layer 0 of the tick by the XLA path's arithmetic (float32)."""
+    if g["kind"] == "ring":
+        return ring_xla(g)[1](x, jnp.int32(0))
     if g["kind"] == "mla":
         return pa.mla_decode_attention(
             x["q"], x["cur"], x["pool"], jnp.int32(0), x["tables"],
@@ -186,6 +242,20 @@ def main():
         got = tick(pa, dict(g, L=1))(x)
         emit(fh, tag="numerics", regime=name,
              max_abs=float(jnp.abs(got.reshape(want.shape) - want).max()))
+        if g["kind"] == "ring":
+            # One width (the ring); the XLA path is what a tree without
+            # the kernel runs, a DIR's module with it a row of its own.
+            paths = [("xla", None, ring_xla(g)[0]),
+                     ("ring", None, tick(pa, g))]
+            paths += [("ring", tree, tick(mod, g)) for tree, mod in
+                      parents.items() if hasattr(mod, "ring_decode_attention")]
+            for path, tree, fn in paths:
+                emit(fh, tag=path, regime=name, tree=tree,
+                     module_ms=round(device_ms(fn, x, whole=True), 4),
+                     ms=round(device_ms(fn, x), 4) if path == "ring"
+                     else None)
+            del x
+            continue
         for tree, mod in parents.items():
             emit(fh, tag="parent", regime=name, tree=tree,
                  ms=round(device_ms(tick(mod, g), x), 4))
