@@ -3,6 +3,7 @@ from .data_text import ByteLMDataModule, decode_bytes
 from .exaone_moe import ExaoneMoE, ExaoneMoEConfig
 from .sarvam_mla import SarvamMLA, SarvamMLAConfig
 from .mimo_v2 import MiMoV2, MiMoV2Config
+from .gigachat35 import GigaChat35, GigaChat35Config
 from .generate import decode_step, generate, init_kv_cache, prefill
 from .gpt import (
     GPT,
@@ -39,6 +40,8 @@ __all__ = [
     "SarvamMLAConfig",
     "MiMoV2",
     "MiMoV2Config",
+    "GigaChat35",
+    "GigaChat35Config",
     "SyntheticLMDataModule",
     "add_lora_adapters",
     "extract_lora",

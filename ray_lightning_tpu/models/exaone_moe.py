@@ -102,9 +102,14 @@ class ExaoneMoEConfig:
 
     # The family's norm placement (:func:`decoder_block`): exaone4's, on
     # each branch's output.  Not a field: no configuration changes it.
-    pre_norm = False
+    norms = "post"
+    # Gains stored as published: the gain itself (zero-centred ones are
+    # ``1 + w``; :func:`gain`).
+    zero_centred = False
     # A shared expert beside the routed ones (:func:`feed_forward`).
     shared_expert = True
+    # The gated MLPs' clamp (``swiglu_limit``; :func:`swiglu`): none.
+    swiglu_limit = None
 
     def __post_init__(self):
         L = self.n_layer
@@ -214,9 +219,21 @@ def _mm(x: jax.Array, w: jax.Array) -> jax.Array:
     return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
 
 
-def swiglu(x: jax.Array, p: Dict[str, jax.Array], pre: str) -> jax.Array:
+def gain(cfg, w: jax.Array) -> jax.Array:
+    """A norm's gain as the family stores it: ``1 + w`` where its gains
+    are zero-centred (``cfg.zero_centred``), else ``w``."""
+    return w + 1.0 if cfg.zero_centred else w
+
+
+def swiglu(x: jax.Array, p: Dict[str, jax.Array], pre: str,
+           limit: Optional[float] = None) -> jax.Array:
+    """``(silu(x Wg) * (x Wu)) Wd``; with a ``limit``, the gate's
+    pre-activation clamped from above and the up projection both ways
+    first (``swiglu_limit``)."""
     a = jnp.dot(x, p[pre + "gate"], preferred_element_type=jnp.float32)
     b = jnp.dot(x, p[pre + "up"], preferred_element_type=jnp.float32)
+    if limit is not None:
+        a, b = jnp.minimum(a, limit), jnp.clip(b, -limit, limit)
     h = (a * jax.nn.sigmoid(a) * b).astype(x.dtype)
     return _mm(h, p[pre + "down"])
 
@@ -395,9 +412,11 @@ def feed_forward(cfg: ExaoneMoEConfig, kind: str, p, x: jax.Array,
     sum_e gate_e Expert_e(x)``, plus the shared expert, once, where the
     family has one (``cfg.shared_expert``).  A ``routing`` list is
     handed each sparse layer's ``(chosen experts, scores)``, for
-    comparisons with a reference."""
+    comparisons with a reference.  Every gated MLP here, the experts
+    among them, clamps by ``cfg.swiglu_limit`` (:func:`swiglu`)."""
+    limit = cfg.swiglu_limit
     if kind == "dense":
-        return swiglu(x, p, "w_"), jnp.zeros((2,), jnp.int32)
+        return swiglu(x, p, "w_", limit), jnp.zeros((2,), jnp.int32)
     idx, gates, z = sigmoid_topk_routing(
         x, p["router"], p["router_bias"], cfg.top_k, cfg.routed_scale,
         return_scores=True)
@@ -405,10 +424,11 @@ def feed_forward(cfg: ExaoneMoEConfig, kind: str, p, x: jax.Array,
         routing.append((idx, z))
     routed, counts = dropless_moe(
         x, idx, gates, p["e_gate"], p["e_up"], p["e_down"],
-        cfg.experts_held[0], row_valid=row_valid, impl=moe_impl)
+        cfg.experts_held[0], row_valid=row_valid, impl=moe_impl,
+        limit=limit)
     if not cfg.shared_expert:
         return routed, counts
-    return routed + swiglu(x, p, "s_"), counts
+    return routed + swiglu(x, p, "s_", limit), counts
 
 
 def _residual(cfg, x, branch, gain):
@@ -418,14 +438,26 @@ def _residual(cfg, x, branch, gain):
 def decoder_block(cfg, p, x, mixer, mlp: str, row_valid, moe_impl: str,
                   routing: Optional[list] = None):
     """One layer: ``x`` plus its mixer's branch, then plus its
-    feed-forward's.  ``mixer(h)`` is the layer's attention of whatever
-    kind with its output projection; the feed-forward sees rows ``(S,
-    d)``.  Where the norms sit belongs to the family (``cfg.pre_norm``):
-    on each branch's output (``x + norm(branch(x))``, gains
-    ``attn_out_norm`` / ``ffn_out_norm``) or on its input (``x +
-    branch(norm(x))``, gains ``attn_norm`` / ``ffn_norm``).  Returns
-    ``(x, counts int32[2])``."""
-    if cfg.pre_norm:
+    feed-forward's.  ``mixer(h)`` is the layer's mixer of whatever kind
+    with its output projection; the feed-forward sees rows ``(S, d)``.
+    Where the norms sit belongs to the family (``cfg.norms``): on each
+    branch's output (``"post"``: ``x + norm(branch(x))``, gains
+    ``attn_out_norm`` / ``ffn_out_norm``), on its input (``"pre"``: ``x
+    + branch(norm(x))``, gains ``attn_norm`` / ``ffn_norm``) or on both
+    (``"pre_post"``: ``x + norm'(branch(norm(x)))``, all four gains).
+    Returns ``(x, counts int32[2])``."""
+    if cfg.norms == "pre_post":
+        def norm(z, name):
+            return rms_norm(z, gain(cfg, p[name]), cfg.rms_eps)
+
+        x = _residual(cfg, x, mixer(norm(x, "attn_norm")),
+                      gain(cfg, p["attn_out_norm"]))
+        h = norm(x, "ffn_norm")
+        f, c = feed_forward(cfg, mlp, p, h.reshape(-1, h.shape[-1]),
+                            row_valid, moe_impl, routing)
+        return _residual(cfg, x, f.reshape(x.shape),
+                         gain(cfg, p["ffn_out_norm"])), c
+    if cfg.norms == "pre":
         x = x + mixer(rms_norm(x, p["attn_norm"], cfg.rms_eps)).astype(x.dtype)
         h = rms_norm(x, p["ffn_norm"], cfg.rms_eps)
         f, c = feed_forward(cfg, mlp, p, h.reshape(-1, h.shape[-1]),
@@ -476,7 +508,7 @@ def sequence_forward(cfg: ExaoneMoEConfig, params, tokens: jax.Array,
 
 
 def head_logits(cfg: ExaoneMoEConfig, params, x: jax.Array) -> jax.Array:
-    h = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    h = rms_norm(x, gain(cfg, params["final_norm"]), cfg.rms_eps)
     return jnp.dot(h, params["head"], preferred_element_type=jnp.float32)
 
 

@@ -67,7 +67,7 @@ class MiMoV2Config(ExaoneMoEConfig):
     swa_n_kv_head: int = 8
     swa_rope_theta: float = 1e4
 
-    pre_norm = True
+    norms = "pre"
     shared_expert = False         # n_shared_experts: null
 
     def __post_init__(self):

@@ -68,6 +68,9 @@ class SarvamMLAConfig:
     n_layer: int = 32
     n_head: int = 64
     kv_lora_rank: int = 512
+    # The query's own latent (``q_lora_rank``): None, q_proj is direct
+    # (:func:`latent_projections`).
+    q_lora_rank: Optional[int] = None
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
@@ -92,10 +95,15 @@ class SarvamMLAConfig:
     vocab_held: Optional[Tuple[int, int]] = None
     param_dtype: str = "bfloat16"
 
-    # The family's norm placement (``exaone_moe.decoder_block``) and its
-    # shared expert (``exaone_moe.feed_forward``).
-    pre_norm = True
+    # The family's norm placement and gains (``exaone_moe.decoder_block``),
+    # its shared expert and its MLPs' clamp (``exaone_moe.feed_forward``):
+    # pre-norm, gains as published, a shared expert, no clamp.  The
+    # rotary key's columns rotate in halves (``rope_interleave`` false).
+    norms = "pre"
+    zero_centred = False
     shared_expert = True
+    swiglu_limit = None
+    rope_interleave = False
 
     def __post_init__(self):
         if self.experts_held is None:
@@ -237,16 +245,32 @@ def latent_projections(cfg: SarvamMLAConfig, p, h: jax.Array,
     """h ``(..., S, d)`` (the block's normed input) -> ``q_n (..., S, H,
     dn)``, ``q_r (..., S, H, dr)`` rotated, and the cache rows ``(...,
     S, r + dr)``: ``[c | k_r]``, the latent after its norm and the one
-    shared rotary key after its rotation."""
+    shared rotary key after its rotation.
+
+    The query is ``h Wq`` normed per head, or, with a ``q_lora_rank``,
+    ``RMSNorm(h W_qa) W_qb`` (``q_a_layernorm``, no norm per head).  With
+    ``rope_interleave`` the rotary columns rotate in adjacent pairs: the
+    even columns then the odd ones, rotated in halves, as they are
+    cached."""
     r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     lead = h.shape[:-1]
-    q = _mm(h, p["wq"]).reshape(lead + (cfg.n_head, cfg.q_head_dim))
-    q = rms_norm(q, p["q_norm"], cfg.rms_eps)
+    if cfg.q_lora_rank:
+        q = _mm(rms_norm(_mm(h, p["wqa"]), p["q_a_norm"], cfg.rms_eps),
+                p["wqb"]).reshape(lead + (cfg.n_head, cfg.q_head_dim))
+    else:
+        q = _mm(h, p["wq"]).reshape(lead + (cfg.n_head, cfg.q_head_dim))
+        q = rms_norm(q, p["q_norm"], cfg.rms_eps)
     ckr = _mm(h, p["wkva"])
     c = rms_norm(ckr[..., :r], p["kv_norm"], cfg.rms_eps)
     yarn = dict(inv_freq=cfg.rope_inv_freq, factor=cfg.rope_attention_factor)
-    q_r = rope(q[..., dn:], positions, cfg.rope_theta, **yarn)
-    k_r = rope(ckr[..., None, r:], positions, cfg.rope_theta, **yarn)
+
+    def rotated(z):
+        if cfg.rope_interleave:
+            z = jnp.concatenate([z[..., 0::2], z[..., 1::2]], axis=-1)
+        return rope(z, positions, cfg.rope_theta, **yarn)
+
+    q_r = rotated(q[..., dn:])
+    k_r = rotated(ckr[..., None, r:])
     return q[..., :dn], q_r, jnp.concatenate([c, k_r[..., 0, :]], axis=-1)
 
 
@@ -379,6 +403,28 @@ def paged_prefill(cfg: SarvamMLAConfig, params, pool, tokens, prompt_len,
     return logits, {"kv": kv}, counts
 
 
+def decode_latent(cfg: SarvamMLAConfig, p, h, pos, kv, layer,
+                  block_tables, attn_impl: str):
+    """One latent layer's decode attention, absorbed: h ``(W, d)`` (the
+    block's normed input) at positions ``pos (W,)`` against pool layer
+    ``layer`` of ``kv`` by ``rlt_mla_decode`` (``attn_impl`` "pallas") or
+    its XLA gather.  Returns ``(a (W, H*dv) in h's dtype, before the
+    output projection; the position's cache row (W, pool_row) to be
+    written)``."""
+    from ray_lightning_tpu.ops.paged_attention import mla_decode_attention
+
+    q_n, q_r, rows = latent_projections(cfg, p, h[:, None], pos[:, None])
+    row = _pad_row(cfg, rows[:, 0]).astype(kv.dtype)
+    att = mla_decode_attention(
+        absorbed_queries(cfg, p, q_n[:, 0], q_r[:, 0]), row, kv, layer,
+        block_tables, pos, rank=cfg.kv_lora_rank, scale=cfg.softmax_scale,
+        impl=attn_impl)
+    _, w_uv = up_projections(cfg, p)
+    out = jnp.einsum("whr,hrv->whv", att.astype(h.dtype), w_uv,
+                     preferred_element_type=jnp.float32)
+    return out.astype(h.dtype).reshape(h.shape[0], -1), row
+
+
 def paged_decode_step(cfg: SarvamMLAConfig, params, pool, block_tables,
                       seq_lens, tokens, compute_dtype=None,
                       attn_impl: str = "auto", moe_impl: str = "auto",
@@ -393,15 +439,8 @@ def paged_decode_step(cfg: SarvamMLAConfig, params, pool, block_tables,
     arithmetic elsewhere).  The pool is only read inside the layer
     loop; every layer's new row is scattered into it afterwards, in one
     scatter."""
-    from ray_lightning_tpu.ops.paged_attention import (
-        mla_decode_attention, mla_decode_supported,
-    )
-
     kv = pool["kv"]
-    r = cfg.kv_lora_rank
-    if attn_impl == "auto":
-        attn_impl = ("pallas" if mla_decode_supported(kv, cfg.n_head, r)
-                     else "xla")
+    attn_impl = latent_impl(cfg, attn_impl, kv)
     W, M = block_tables.shape
     Bs = kv.shape[2]
     pos = seq_lens
@@ -411,18 +450,10 @@ def paged_decode_step(cfg: SarvamMLAConfig, params, pool, block_tables,
     counts = jnp.zeros((2,), jnp.int32)
     for i, (p, mlp) in enumerate(zip(params["layers"], cfg.mlp_types)):
         def mixer(h, p=p, i=i):
-            q_n, q_r, rows = latent_projections(
-                cfg, p, h[:, None], pos[:, None])
-            row = _pad_row(cfg, rows[:, 0]).astype(kv.dtype)
+            a, row = decode_latent(cfg, p, h, pos, kv, jnp.int32(i),
+                                   block_tables, attn_impl)
             new.append(row)
-            att = mla_decode_attention(
-                absorbed_queries(cfg, p, q_n[:, 0], q_r[:, 0]), row, kv,
-                jnp.int32(i), block_tables, pos, rank=r,
-                scale=cfg.softmax_scale, impl=attn_impl)
-            _, w_uv = up_projections(cfg, p)
-            out = jnp.einsum("whr,hrv->whv", att.astype(h.dtype), w_uv,
-                             preferred_element_type=jnp.float32)
-            return _mm(out.astype(h.dtype).reshape(W, -1), p["wo"])
+            return _mm(a, p["wo"])
 
         x, c = decoder_block(cfg, p, x, mixer, mlp, active, moe_impl)
         counts = counts + c
@@ -435,6 +466,18 @@ def paged_decode_step(cfg: SarvamMLAConfig, params, pool, block_tables,
     kv = kv.at[(layer[:, None], blk[None, :], (pos % Bs)[None, :])].set(
         jnp.stack(new))
     return logits, {"kv": kv}, counts
+
+
+def latent_impl(cfg: SarvamMLAConfig, attn_impl: str, kv) -> str:
+    """The latent decode's path on the pool ``kv``: ``auto`` takes
+    ``rlt_mla_decode`` where it tiles the pool (a TPU), else the XLA
+    gather; ``pallas`` / ``xla`` as asked."""
+    from ray_lightning_tpu.ops.paged_attention import mla_decode_supported
+
+    if attn_impl != "auto":
+        return attn_impl
+    return ("pallas" if mla_decode_supported(kv, cfg.n_head, cfg.kv_lora_rank)
+            else "xla")
 
 
 class ServeFamily:
@@ -464,13 +507,8 @@ class ServeFamily:
     def walk_layers(self, pool) -> int:
         """Layers whose decode attention runs ``rlt_mla_decode`` on
         ``pool``: all where the decode takes the kernel, else none."""
-        from ray_lightning_tpu.ops.paged_attention import (
-            mla_decode_supported,
-        )
-        kernel = self.attn_impl == "pallas" or (
-            self.attn_impl == "auto" and mla_decode_supported(
-                pool["kv"], self.cfg.n_head, self.cfg.kv_lora_rank))
-        return self.cfg.n_layer if kernel else 0
+        kernel = latent_impl(self.cfg, self.attn_impl, pool["kv"])
+        return self.cfg.n_layer if kernel == "pallas" else 0
 
     def latent_row_bytes(self, dtype) -> int:
         """The bytes of data one cached position of one layer holds

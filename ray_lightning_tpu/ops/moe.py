@@ -252,8 +252,14 @@ def _gmm_visits(group_sizes: jax.Array, m: int, tm: int):
     return gid, tid, offsets, n_active.reshape(1)
 
 
+def _clamp(a, b, limit):
+    """The gated MLP's clamp (``swiglu_limit``): the gate's
+    pre-activation from above, the up projection both ways."""
+    return jnp.minimum(a, limit), jnp.clip(b, -limit, limit)
+
+
 def _gmm_kernel(gid_ref, tid_ref, off_ref, nact_ref, x_ref, *refs,
-                tm, swiglu):
+                tm, swiglu, limit):
     o_ref = refs[-1]
     v = pl.program_id(1)
 
@@ -264,6 +270,8 @@ def _gmm_kernel(gid_ref, tid_ref, off_ref, nact_ref, x_ref, *refs,
         if swiglu:
             a = jnp.dot(x, refs[0][0], preferred_element_type=jnp.float32)
             b = jnp.dot(x, refs[1][0], preferred_element_type=jnp.float32)
+            if limit is not None:
+                a, b = _clamp(a, b, limit)
             r = a * jax.nn.sigmoid(a) * b
         else:
             r = jnp.dot(x, refs[0][0], preferred_element_type=jnp.float32)
@@ -275,7 +283,7 @@ def _gmm_kernel(gid_ref, tid_ref, off_ref, nact_ref, x_ref, *refs,
         o_ref[...] = jnp.where(mine, r.astype(o_ref.dtype), o_ref[...])
 
 
-def _gmm_call(x, weights, visits, *, tm, swiglu):
+def _gmm_call(x, weights, visits, *, tm, swiglu, limit=None):
     """The ``pallas_call`` arguments of ``out[rows of expert e] =
     f(x[rows of expert e], weights[.][e])`` over the visits of
     :func:`_gmm_visits`; rows no expert owns are left as they lie (the
@@ -304,14 +312,17 @@ def _gmm_call(x, weights, visits, *, tm, swiglu):
             vmem_limit_bytes=_GMM_VMEM_LIMIT,
         ),
         interpret=_interpret(),
-    ), functools.partial(_gmm_kernel, tm=tm, swiglu=swiglu)
+    ), functools.partial(_gmm_kernel, tm=tm, swiglu=swiglu, limit=limit)
 
 
 def grouped_swiglu(
     xs: jax.Array, group_sizes: jax.Array, w_gate: jax.Array,
     w_up: jax.Array, w_down: jax.Array, impl: str = "auto",
+    limit: float = None,
 ) -> jax.Array:
-    """``(silu(x Wg_e) * (x Wu_e)) Wd_e`` for rows sorted by expert.
+    """``(silu(x Wg_e) * (x Wu_e)) Wd_e`` for rows sorted by expert;
+    with a ``limit``, ``(silu(min(x Wg_e, limit)) * clip(x Wu_e, -limit,
+    limit)) Wd_e``, the clamp in the gate/up kernel's epilogue.
 
     xs ``[M, d]``, the first ``group_sizes.sum()`` rows grouped by
     expert in expert order; w_gate / w_up ``[E, d, f]``; w_down
@@ -340,6 +351,8 @@ def grouped_swiglu(
             wg, wu, wd, lo, hi = e
             a = jnp.dot(xs, wg, preferred_element_type=jnp.float32)
             b = jnp.dot(xs, wu, preferred_element_type=jnp.float32)
+            if limit is not None:
+                a, b = _clamp(a, b, limit)
             h = (a * jax.nn.sigmoid(a) * b).astype(xs.dtype)
             y = jnp.dot(h, wd, preferred_element_type=jnp.float32)
             mine = (rows >= lo) & (rows < hi)
@@ -356,7 +369,8 @@ def grouped_swiglu(
         raise ValueError(f"grouped_swiglu: {m} rows are not whole tiles "
                          f"of {tm}")
     visits = _gmm_visits(group_sizes.astype(jnp.int32), m, tm)
-    kw, kernel = _gmm_call(xs, (w_gate, w_up), visits, tm=tm, swiglu=True)
+    kw, kernel = _gmm_call(xs, (w_gate, w_up), visits, tm=tm, swiglu=True,
+                           limit=limit)
     h = pl.pallas_call(kernel, name="rlt_moe_gate_up", **kw)(
         *visits, xs, w_gate, w_up)
     kw, kernel = _gmm_call(h, (w_down,), visits, tm=tm, swiglu=False)
@@ -367,7 +381,7 @@ def grouped_swiglu(
 def dropless_moe(
     x: jax.Array, idx: jax.Array, gates: jax.Array, w_gate: jax.Array,
     w_up: jax.Array, w_down: jax.Array, first_held: int,
-    row_valid: jax.Array = None, impl: str = "auto",
+    row_valid: jax.Array = None, impl: str = "auto", limit: float = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """The held experts' part of a routed layer, nothing dropped.
 
@@ -377,7 +391,7 @@ def dropless_moe(
     idle slots out of the routing.  Returns ``(y [S, d] in x's dtype:
     sum over the row's chosen experts held here of gate x expert(x),
     counts int32 [2]: assignments that fell on held experts, held
-    experts hit)``.
+    experts hit)``.  ``limit``: the experts' clamp (:func:`grouped_swiglu`).
     """
     s, k = idx.shape
     e_held = w_gate.shape[0]
@@ -393,7 +407,8 @@ def dropless_moe(
     m = s * k
     pad = -m % _gmm_tile(m)
     tok = jnp.pad(order // k, (0, pad))
-    ys = grouped_swiglu(x[tok], sizes, w_gate, w_up, w_down, impl=impl)
+    ys = grouped_swiglu(x[tok], sizes, w_gate, w_up, w_down, impl=impl,
+                        limit=limit)
     # Back to (row, choice) order by the inverse permutation; a gather,
     # then one weighted sum over the k choices in float32.  Rows past
     # n_local were never computed: selected away, not multiplied.

@@ -460,6 +460,13 @@ class ServeEngine:
         if self._n_latent:
             self.stats.bump(
                 "latent_row_bytes", self.family.latent_row_bytes(self._c))
+        # Layers whose state is a row a SLOT, not blocks (a family's
+        # ``slot_state``: recurrent state): the prefill is handed its slot
+        # beside its blocks, and writes that row whole.
+        self._slot_state = getattr(self.family, "slot_state", 0)
+        if self._slot_state:
+            self.stats.bump(
+                "state_row_bytes", self.family.state_row_bytes(self._c))
         self._pool = self.cache.init_pool()
         self._walk_layers = self.family.walk_layers(self._pool)
         # Sliding layers whose decode runs the ring kernel (two-kind).
@@ -1085,7 +1092,8 @@ class ServeEngine:
             ph.then("admit_dispatch", rid=req.rid, bucket=bucket,
                     prompt_len=req.prompt_len,
                     full_blocks=len(self.scheduler._blocks[slot]),
-                    window_blocks=len(self.scheduler._window[slot]))
+                    window_blocks=len(self.scheduler._window[slot]),
+                    **({"state_slot": slot} if self._slot_state else {}))
             wait = now - req.arrival_t
             self.stats.note_admitted(
                 wait, None if req.recv_t is None else now - req.recv_t)
@@ -1123,6 +1131,10 @@ class ServeEngine:
                     # (full blocks of the bucket, the slot's window ring)
                     ids = (ids, jnp.asarray(
                         self.scheduler.window_tables[slot]))
+                if self._slot_state:
+                    # (blocks of the bucket, the slot whose state rows
+                    # the prefill writes whole)
+                    ids = (ids, np.int32(slot))
                 handoff = getattr(req, "_handoff", None)
                 padded = None
                 if handoff is None or self.draft_module is not None:
@@ -1159,7 +1171,9 @@ class ServeEngine:
             elif bucket != 0:
                 self.stats.bump_many({
                     "prefills": 1, "prefill_bucket_positions": bucket,
-                    "prefill_prompt_positions": req.prompt_len})
+                    "prefill_prompt_positions": req.prompt_len,
+                    **({"state_rows_written": 1} if self._slot_state
+                       else {})})
                 ad = None if self.adapters is None \
                     else self.adapters.buffers
                 ad_id = None if self.adapters is None \
@@ -1660,6 +1674,11 @@ class ServeEngine:
             # rlt: noqa[RLT002] host ints (scheduler.seq_lens), no device value
             kv_blocks["decode_latent_positions"] = self._n_latent * int(
                 self.scheduler.seq_lens[active].sum() + len(active))
+        if self._slot_state:
+            # State rows the decode reads and rewrites this tick: the
+            # active slots' in each layer whose state is a slot's row.
+            kv_blocks["decode_state_slot_layers"] = (
+                self._slot_state * len(active))
         if self._walk_layers:
             # What the walk kernels compute this tick, over the layers
             # that run one (none where the decode takes the XLA gather):

@@ -73,6 +73,11 @@ _COUNTER_KEYS = (
     # them (min(seq_len, window - 1) + 1, over those layers; 0 where the
     # ring is gathered).
     "decode_full_positions", "decode_ring_positions",
+    # Per decode tick, a family whose layers keep a row of state a slot
+    # (``slot_state``: recurrent state): the active slots times those
+    # layers, each a row read and rewritten.  Of ``prefills``: those that
+    # wrote a slot's state rows whole.
+    "decode_state_slot_layers", "state_rows_written",
     # Of ``decode_steps``: ticks read from a decode dispatched ahead of
     # them, and those of them whose ``tokens`` operand was the tick
     # before's output where it lay on the device.  Reply frames sent

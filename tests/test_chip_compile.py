@@ -792,3 +792,89 @@ def test_mla_prefill_fits_at_the_largest_bucket(one_chip):
     text = compiled.as_text()
     assert "%rlt_flash_fwd" in text and "%rlt_moe_gate_up" in text
     assert _footprint(compiled) < 15.05e9
+
+
+# -- the recurrent-state share (gigachat3.5-432b-a28b-ep16.serve-longdecode) --
+
+# benchmarks/workloads/gigachat3.5-432b-a28b-ep16.serve-longdecode.json: 128
+# slots x 3584 positions in blocks of 32, the engine's default latent pool
+# of 129 x 112 + 1 blocks; a float32 state of 64 x 128 x 128 a slot in
+# each of 4 linear layers; published widths, 16 of 256 experts.
+GDN = dict(W=128, M=112, Bs=32, N=129 * 112 + 1, L=4, H=64, Hk=32)
+
+
+def test_gdn_decode_kernel_compiles_at_serve_cell_shapes(one_chip):
+    """Every slot's 64 states of 128 x 128 in one linear layer of the
+    pool, the pool aliased to the output (updated in place)."""
+    from ray_lightning_tpu.ops.gated_delta import decode_step
+
+    f32 = lambda *shape: _sds(shape, jnp.float32, one_chip)  # noqa: E731
+    W, H, Hk = GDN["W"], GDN["H"], GDN["Hk"]
+    compiled = jax.jit(
+        lambda s, *a: decode_step(s, jnp.int32(3), *a, impl="pallas"),
+        donate_argnums=0).lower(
+        f32(GDN["L"], W, H, 128, 128), f32(W, Hk, 128), f32(W, Hk, 128),
+        f32(W, H, 128), f32(W, H), f32(W, H),
+        _sds((W,), jnp.int32, one_chip)).compile()
+    assert "rlt_gdn_decode" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == GDN["L"] * W * H * 128 * 128 * 4
+
+
+def _gdn_programs(one_chip):
+    from ray_lightning_tpu.models import gigachat35 as gc
+
+    cfg = gc.GigaChat35Config(n_layer=5, full_layers=(1,), first_dense=1,
+                              experts_held=(0, 16), vocab_held=(0, 16128))
+    module = gc.GigaChat35(cfg)
+    fam = module.serve_family()
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda l: _sds(l.shape, l.dtype, one_chip), tree)
+
+    raw = jax.eval_shape(module.init_params, jax.random.PRNGKey(0))
+    params = abstract(jax.eval_shape(
+        lambda tree: fam.prepare_params(tree, jnp.bfloat16), raw))
+    cache = fam.make_cache(GDN["N"], GDN["Bs"], GDN["W"], jnp.bfloat16)
+    return fam, params, abstract(jax.eval_shape(cache.init_pool))
+
+
+def test_gdn_decode_step_names_its_kernels_and_fits(one_chip):
+    """The four kernels' names are in the family's decode program, which
+    moves no state layer (the kernel rewrites the pool in place) and
+    leaves the chip (16.91 GB usable) over 3 GB: 12.3 GB, 9.48 of it
+    weights and 2.2 the state."""
+    import re
+
+    fam, params, pool = _gdn_programs(one_chip)
+    i32 = lambda *shape: _sds(shape, jnp.int32, one_chip)  # noqa: E731
+    compiled = jax.jit(fam.decode, donate_argnums=1).lower(
+        params, pool, i32(GDN["W"], GDN["M"]), i32(GDN["W"]),
+        i32(GDN["W"])).compile()
+    text = compiled.as_text()
+    for kernel in ("%rlt_moe_gate_up", "%rlt_moe_down", "%rlt_mla_decode",
+                   "%rlt_gdn_decode"):
+        assert kernel in text
+    W, H = GDN["W"], GDN["H"]
+    moved = re.findall(
+        rf"= f32\[(?:{GDN['L']},)?{W},{H},128,128\]\S* (?:copy|fusion|"
+        rf"dynamic-update-slice|select)\(", text)
+    assert not moved, moved[:3]
+    assert 11.8e9 < _footprint(compiled) < 13.5e9
+
+
+def test_gdn_prefill_fits_at_the_largest_bucket(one_chip):
+    """Bucket 2048: the chunked scan of the linear layers and the flash
+    forward of the latent one, the slot's state rows written whole."""
+    fam, params, pool = _gdn_programs(one_chip)
+    i32 = lambda *shape: _sds(shape, jnp.int32, one_chip)  # noqa: E731
+
+    def prefill(params, pool, tokens, prompt_len, ids, slot):
+        return fam.prefill(params, pool, tokens, prompt_len, (ids, slot))
+
+    compiled = jax.jit(prefill, donate_argnums=1).lower(
+        params, pool, i32(2048), i32(), i32(2048 // GDN["Bs"]),
+        i32()).compile()
+    assert "%rlt_flash_fwd" in compiled.as_text()
+    assert _footprint(compiled) < 15.0e9

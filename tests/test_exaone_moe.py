@@ -76,8 +76,9 @@ def test_all_shares_add_up_to_the_uncut_layer(fam):
     """The parts all the shares give (four of 4 experts for
     ``exaone_moe``; eight of 2, under a drawn selection bias, for
     ``sarvam_mla``; sixteen of 1, under a drawn bias and with no shared
-    expert, for ``mimo_v2``), the shared expert counted once where the
-    family has one, are the uncut layer (16 of 16 experts)."""
+    expert, for ``mimo_v2``; sixteen of 1, every MLP clamped, for
+    ``gigachat35``), the shared expert counted once where the family has
+    one, are the uncut layer (16 of 16 experts)."""
     cfg, Module, ref_mod = fam.preset(), fam.Module, fam.ref
     shares = fam.shares
     whole_cfg = dataclasses.replace(cfg, experts_held=(0, 16))
@@ -89,7 +90,8 @@ def test_all_shares_add_up_to_the_uncut_layer(fam):
     feed_forward = jax.jit(em.feed_forward, static_argnums=(0, 1, 4, 5))
     whole, counts = feed_forward(whole_cfg, "sparse", p, x, None, "xla")
     assert int(counts[0]) == 24 * cfg.top_k
-    shared = em.swiglu(x, p, "s_") if cfg.shared_expert else 0.0
+    shared = (em.swiglu(x, p, "s_", cfg.swiglu_limit) if cfg.shared_expert
+              else 0.0)
     routed = jnp.zeros_like(x)
     held = 16 // shares
     for lo in range(0, 16, held):

@@ -876,6 +876,7 @@ KERNELS = {
     "paged_attention.py": {"rlt_paged_decode", "rlt_ring_decode",
                            "rlt_mla_decode"},
     "moe.py": {"rlt_moe_gate_up", "rlt_moe_down"},
+    "gated_delta.py": {"rlt_gdn_decode"},
 }
 
 

@@ -4,7 +4,8 @@ decode through its cache, and the engine through the client plane,
 against the family's plain reference (``benchmarks/reference/``) at its
 tiny preset; what it refuses, by its name; its cell's rehearsal.  A new
 family is one row of ``FAMILIES`` here and a file of what only it has
-(``test_exaone_moe.py``, ``test_sarvam_mla.py``, ``test_mimo_v2.py``).
+(``test_exaone_moe.py``, ``test_sarvam_mla.py``, ``test_mimo_v2.py``,
+``test_gigachat35.py``).
 
 Tolerances.  The program and the reference compute the same float32
 sums in another order (``sarvam_mla``'s decode, in the absorbed form, a
@@ -28,8 +29,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.reference import exaone_moe_ref, mimo_v2_ref, sarvam_mla_ref
-from ray_lightning_tpu.models import exaone_moe, mimo_v2, sarvam_mla
+from benchmarks.reference import (
+    exaone_moe_ref, gigachat35_ref, mimo_v2_ref, sarvam_mla_ref,
+)
+from ray_lightning_tpu.models import exaone_moe, gigachat35, mimo_v2, sarvam_mla
 from ray_lightning_tpu.serve import ServeClient, ServeConfig, ServeEngine
 from utils import draw_tokens, reference_logits, tiny_family
 
@@ -259,7 +262,122 @@ class MiMo:         # the tiny preset: ``test_mimo_v2.py``
         assert phases["reference_check_window"]["ok"]
 
 
-FAMILIES = (Exaone, Sarvam, MiMo)
+class GigaChat:     # the tiny preset: ``test_gigachat35.py``
+    name = "gigachat35"
+    program, ref = gigachat35, gigachat35_ref
+    Module, Config = gigachat35.GigaChat35, gigachat35.GigaChat35Config
+    preset = staticmethod(gigachat35.gigachat35_tiny)
+    gains = ("attn_norm", "attn_out_norm", "ffn_norm", "ffn_out_norm")
+    # ``rlt_mla_decode`` tiles 16 heads on rows of 256 (sarvam's), and
+    # ``rlt_gdn_decode`` states of 128 x 128 in steps of 32 value heads.
+    pallas = dict(n_layer=2, full_layers=(1,), n_head=16, kv_lora_rank=128,
+                  qk_rope_head_dim=64, rope_original_len=64,
+                  lin_key_heads=16, lin_value_heads=32, lin_key_dim=128,
+                  lin_value_dim=128)
+    pallas_prompts = (43, 38)
+    bad = (dict(experts_held=(4, 4)), dict(vocab_held=(0, 999)),
+           dict(full_layers=(7,)), dict(lin_value_heads=3),
+           dict(conv_width=1))
+    lengths = dict(short=5, past_original=40)
+    config_file = "gigachat3.5-432b-a28b-ep16.json"
+    cell = "gigachat3.5-432b-a28b-ep16.serve-longdecode"
+    why = "recurrent state"
+    depth = 5
+    reduced = {"num_hidden_layers", "full_attention_layers",
+               "first_k_dense_replace", "n_routed_experts", "vocab_size"}
+    shares = 16
+
+    @staticmethod
+    def published(cfg, doc):
+        assert (cfg.d_model, cfg.n_head, cfg.kv_lora_rank, cfg.q_lora_rank,
+                cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
+                cfg.d_ff, cfg.d_expert, cfg.n_experts, cfg.top_k,
+                cfg.routed_scale) == (
+            doc["hidden_size"], doc["num_attention_heads"],
+            doc["kv_lora_rank"], doc["q_lora_rank"], doc["qk_nope_head_dim"],
+            doc["qk_rope_head_dim"], doc["v_head_dim"],
+            doc["intermediate_size"], doc["moe_intermediate_size"],
+            doc["published"]["n_routed_experts"],
+            doc["num_experts_per_tok"], doc["routed_scaling_factor"]) == (
+            7168, 64, 512, 1536, 128, 64, 128, 18432, 2048, 256, 8, 2.5)
+        assert (cfg.lin_key_heads, cfg.lin_value_heads, cfg.lin_key_dim,
+                cfg.lin_value_dim, cfg.conv_width, cfg.gate_scale,
+                cfg.swiglu_limit) == (
+            doc["linear_num_key_heads"], doc["linear_num_value_heads"],
+            doc["linear_key_head_dim"], doc["linear_value_head_dim"],
+            doc["linear_conv_kernel_dim"], doc["linear_sigmoid_gate_scale"],
+            doc["swiglu_limit"]) == (32, 64, 128, 128, 4, 2, 10)
+        scaling = doc["rope_scaling"]
+        assert (cfg.rope_factor, cfg.rope_original_len, cfg.rope_beta_fast,
+                cfg.rope_beta_slow, cfg.rope_theta, cfg.rms_eps) == (
+            scaling["factor"], scaling["original_max_position_embeddings"],
+            scaling["beta_fast"], scaling["beta_slow"], doc["rope_theta"],
+            doc["rms_norm_eps"]) == (8, 32768, 32, 1, 1e5, 1e-6)
+        # The cut: published layers 0 and 3-6, L F L L L.
+        assert cfg.layer_types == ("linear", "latent") + ("linear",) * 3
+        assert cfg.mlp_types == ("dense",) + ("sparse",) * 4
+        assert [i + 2 * (i > 0) for i in cfg.full_layers] == [
+            i for i in doc["published"]["full_attention_layers"] if i < 7]
+        assert cfg.n_vocab_held == doc["vocab_size"] == 126 * 128
+        assert cfg.rope_interleave and doc["rope_interleave"]
+        assert "16 chips share each layer" in doc["deployment"]
+
+    @staticmethod
+    def decoded(cfg, cache, pool, block_size, reached):
+        n_lin = len(cfg.layers_of("linear"))
+        assert pool["S"].shape == (n_lin, 3, cfg.lin_value_heads,
+                                   cfg.lin_key_dim, cfg.lin_value_dim)
+        assert pool["S"].dtype == jnp.float32
+        assert pool["conv"].shape == (n_lin, 3, cfg.conv_width - 1,
+                                      cfg.conv_channels)
+        assert pool["kv"].shape[0] == len(cfg.layers_of("latent"))
+        assert reached > cfg.rope_original_len
+        # The idle slot's rows were never written; the two live ones were.
+        assert not np.asarray(pool["S"][:, 2]).any()
+        assert np.asarray(pool["S"][:, :2]).any(axis=(2, 3, 4)).all()
+
+    @staticmethod
+    def served(cfg, engine, overlap):
+        c = engine.stats.counters
+        n_lin = len(cfg.layers_of("linear"))
+        assert engine.family.slot_state == n_lin
+        assert c["decode_state_slot_layers"] == (
+            c["tokens_out"] - c["prefills"]) * n_lin
+        assert c["state_rows_written"] == c["prefills"] == 3
+        assert c["state_row_bytes"] == (
+            cfg.lin_value_heads * cfg.lin_key_dim * cfg.lin_value_dim * 4
+            + (cfg.conv_width - 1) * cfg.conv_channels * 4)   # float32 here
+        assert c["latent_row_bytes"] == cfg.cache_row * 4
+        if not overlap:
+            want = sum(sum(n + t + 1 for t in range(25)) for n in PROMPTS)
+            assert c["decode_latent_positions"] == want
+        assert engine.family.two_kind is False
+
+    @staticmethod
+    def rehearsed(line, phases):
+        assert 0 < line["metrics"]["kv_read_share_pct.serve"]["value"] <= 100
+        # No chip: the kernels' times and their roofline shares are left out.
+        for name in ("gdn_decode_ms.serve", "gdn_decode_roofline.serve",
+                     "mla_decode_roofline.serve"):
+            assert name not in line["metrics"]
+        assert phases["reference_check_window"]["ok"]
+        serve = phases["serve"]
+        c = serve["engine_counters_in_window"]
+        # A tick's rows are counted at its dispatch, its tokens when they
+        # are booked: a window's deltas differ by at most the slots of the
+        # one tick in flight at each end.
+        assert abs(c["decode_state_slot_layers"] / 4
+                   - (c["tokens_out"] - c["prefills"])) <= 2 * 4
+        assert c["state_rows_written"] == c["prefills"] > 0
+        # Each routed layer's selection bias was balanced before serving:
+        # its largest expert load nearer the mean share than the drawn one.
+        loads = phases["router_balance"]["loads_max_min_held_pct"]
+        assert len(loads) == 4
+        for layer in loads.values():
+            assert layer["balanced"][0] < layer["drawn"][0]
+
+
+FAMILIES =(Exaone, Sarvam, MiMo, GigaChat)
 per_family = pytest.mark.parametrize(
     "fam", FAMILIES, ids=[f.name for f in FAMILIES])
 
@@ -378,6 +496,8 @@ def test_prefill_then_decode_matches_the_full_forward(fam, attn_impl):
         blocks = jnp.asarray(ids[i][:bucket // Bs])
         if family.two_kind:
             blocks = (blocks, jnp.asarray(rings[i]))
+        if getattr(family, "slot_state", 0):
+            blocks = (blocks, jnp.int32(i))     # the slot's state rows
         logits, pool, _ = prefill(served, pool, padded, jnp.int32(n), blocks)
         assert _gap(logits, w[n - 1]) < F32_TOL
     step = jax.jit(lambda pool, lens, toks: fam.program.paged_decode_step(
